@@ -52,13 +52,14 @@ RULE = "hangcheck-schedule"
 #: in-run determinism probe (cheapest in-envelope conv program)
 _DET_PROBE = "cifar10_resnet50"
 
-#: jaxpr primitive name → normalized op kind. ``psum2`` is the
-#: shard_map-era spelling of psum; ``reduce_scatter`` implements
+#: jaxpr primitive name → normalized op kind. ``psum_invariant`` is what
+#: ``lax.psum`` traces to inside a ``shard_map`` that checks varying mesh
+#: axes (``check_vma=True``); ``reduce_scatter`` implements
 #: ``lax.psum_scatter``. ``pbroadcast`` is a replication-rule adjustment,
 #: not a wire collective — deliberately excluded.
 WIRE_PRIMS = {
     "psum": "psum",
-    "psum2": "psum",
+    "psum_invariant": "psum",
     "reduce_scatter": "psum_scatter",
     "all_gather": "all_gather",
     "all_to_all": "all_to_all",

@@ -66,7 +66,7 @@ def _micro_probe_bytes_per_sec(n_devices: int = 8,
         import numpy as np
         from jax import lax
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from ..parallel.mesh import shard_map_compat
+        from ..parallel.mesh import shard_map_unchecked
         if jax.device_count() < n_devices:
             return None
         devices = np.array(jax.devices()[:n_devices]).reshape(n_devices)
@@ -76,7 +76,7 @@ def _micro_probe_bytes_per_sec(n_devices: int = 8,
         def _psum(x):
             return lax.psum(x, ("data",))
 
-        fn = jax.jit(shard_map_compat(
+        fn = jax.jit(shard_map_unchecked(
             _psum, mesh, in_specs=P(), out_specs=P()))
         # deliberate direct put: the micro-probe times ONE replicated
         # psum on a throwaway mesh inside the analysis gate — routing

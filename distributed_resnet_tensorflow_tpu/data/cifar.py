@@ -67,7 +67,9 @@ def load_cifar(dataset: str, data_dir: str, mode: str,
     Records store CHW planes; transpose to NHWC, the TPU-native layout
     (reference parse_record did the same transpose, resnet_cifar_main.py:157-182).
     ``use_native`` parses in C++ (native/dataloader.cc) — identical output,
-    used for the high-rate path; falls back silently if the .so is absent.
+    used for the high-rate path. The library is built from the checkout on
+    first use; if that fails the call raises NativeUnavailable with the
+    reason (the python parser is ``use_native=False``, not a fallback).
     """
     label_bytes, label_offset = _record_layout(dataset)
     rec_len = label_bytes + _REC_IMG
@@ -80,16 +82,13 @@ def load_cifar(dataset: str, data_dir: str, mode: str,
             raise ValueError(f"{path}: size {size} not a multiple of "
                              f"record length {rec_len}")
     if use_native:
-        from .native_loader import native_available
-        if native_available():
-            from .native_loader import load_cifar_native
-            imgs, lbls = [], []
-            for path in paths:
-                im, lb = load_cifar_native(path, label_bytes, label_offset)
-                imgs.append(im)
-                lbls.append(lb)
-            return np.concatenate(imgs), np.concatenate(lbls)
-        # no toolchain/.so → behavior-identical python parser below
+        from .native_loader import load_cifar_native
+        imgs, lbls = [], []
+        for path in paths:
+            im, lb = load_cifar_native(path, label_bytes, label_offset)
+            imgs.append(im)
+            lbls.append(lb)
+        return np.concatenate(imgs), np.concatenate(lbls)
     images, labels = [], []
     for path in paths:
         raw = np.fromfile(path, dtype=np.uint8)

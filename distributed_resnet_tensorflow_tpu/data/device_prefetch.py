@@ -102,17 +102,17 @@ def device_prefetch(host_iter: Iterator, put: Callable, depth: int = 2
         def charge(entry):
             dev, items, issue_s = entry
             t0 = time.perf_counter()
-            try:
-                # StagedBatch exposes block_until_ready (transfer only);
-                # plain pytrees block leaf-wise
-                with span("input.transfer"):
-                    blocker = getattr(dev, "block_until_ready", None)
-                    if blocker is not None:
-                        blocker()
-                    else:
-                        jax.block_until_ready(dev)
-            except Exception:
-                pass  # non-jax payloads (tests stub put with plain values)
+            # StagedBatch exposes block_until_ready (transfer only); plain
+            # pytrees block leaf-wise (non-jax leaves pass through). A
+            # failed transfer raises here and re-raises on the consumer
+            # (threaded_iterator) — it must not train on a batch that
+            # never arrived.
+            with span("input.transfer"):
+                blocker = getattr(dev, "block_until_ready", None)
+                if blocker is not None:
+                    blocker()
+                else:
+                    jax.block_until_ready(dev)
             wait_s = time.perf_counter() - t0
             if put_records:
                 input_stages.add("transfer", wait_s)

@@ -4,13 +4,20 @@ The C++ tier replaces what the reference got from TensorFlow's native input
 runtime (queue runners / tf.data C++, SURVEY.md L0-L1): CRC32C, CIFAR binary
 parsing, and a multithreaded TFRecord prefetcher with a bounded ring buffer.
 
-Auto-builds with ``make`` on first use if a toolchain is present; callers can
-always fall back to the pure-python paths (data/cifar.py, data/tfrecord.py),
-which are behavior-identical (tests assert this).
+The library is built from the checkout's own sources: the first load of a
+process compares a hash of ``dataloader.cc`` + ``Makefile`` with the one
+recorded next to ``libdrtdata.so`` at its last build and rebuilds on any
+difference, so a ``.so`` left in the working tree from other sources is
+never used as is. A build or load that fails raises
+:class:`NativeUnavailable` with the reason; the pure-python paths
+(data/cifar.py, data/tfrecord.py) are behavior-identical (tests assert
+this), but taking them is the caller's decision, never a silent default.
 """
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import hashlib
 import logging
 import os
 import subprocess
@@ -20,8 +27,13 @@ import numpy as np
 
 log = logging.getLogger(__name__)
 
-_NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "native")
-_SO_PATH = os.path.abspath(os.path.join(_NATIVE_DIR, "libdrtdata.so"))
+_NATIVE_DIR = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "native"))
+_SO_PATH = os.path.join(_NATIVE_DIR, "libdrtdata.so")
+#: hash of the sources libdrtdata.so was last built from (ignored by git
+#: with the .so itself — native/.gitignore)
+_STAMP_PATH = _SO_PATH + ".srchash"
+_SOURCES = ("dataloader.cc", "Makefile")
 _lib = None
 
 
@@ -29,73 +41,59 @@ class NativeUnavailable(RuntimeError):
     pass
 
 
-def _build() -> bool:
+def _source_hash() -> str:
+    h = hashlib.sha256()
+    for name in _SOURCES:
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def _built_from(src_hash: str) -> bool:
+    """True iff libdrtdata.so exists and its stamp names ``src_hash``."""
     try:
-        subprocess.run(["make", "-C", os.path.abspath(_NATIVE_DIR)],
-                       check=True, capture_output=True, timeout=120)
-        return os.path.exists(_SO_PATH)
-    except Exception as e:  # toolchain missing etc.
-        log.info("native loader build failed: %s", e)
-        return False
-
-
-def _so_exports(symbol: bytes) -> bool:
-    """Probe the on-disk .so for an exported symbol WITHOUT dlopen-ing it.
-
-    Staleness must be decided before the first ``ctypes.CDLL``: glibc caches
-    dlopen handles by device/inode and ``make`` relinks in place, so once the
-    old mapping exists a rebuild+re-CDLL hands back the stale symbol table.
-
-    Asks ``nm -D`` for the dynamic symbol table (exact-token match, so a
-    string literal or archive-member occurrence of the name elsewhere in
-    the file can't report a stale pre-JPEG build as fresh); falls back to
-    a raw substring scan only when binutils is unavailable."""
-    try:
-        out = subprocess.run(["nm", "-D", "--defined-only", _SO_PATH],
-                             capture_output=True, timeout=30)
-        if out.returncode == 0 and out.stdout:
-            return any(line.split()[-1] == symbol.decode()
-                       for line in out.stdout.decode(errors="replace")
-                       .splitlines() if line.split())
-    except Exception:
-        pass
-    try:
-        with open(_SO_PATH, "rb") as f:
-            return symbol in f.read()
+        with open(_STAMP_PATH) as f:
+            return os.path.exists(_SO_PATH) and f.read().strip() == src_hash
     except OSError:
         return False
 
 
-def load_library(auto_build: bool = True) -> ctypes.CDLL:
+def _build(src_hash: str) -> None:
+    """``make -B`` the library and stamp it; raises with the tool's output."""
+    try:
+        subprocess.run(["make", "-B", "-C", _NATIVE_DIR], check=True,
+                       capture_output=True, text=True, timeout=300)
+    except subprocess.CalledProcessError as e:
+        raise NativeUnavailable(
+            f"building {_SO_PATH} failed (rc {e.returncode}):\n"
+            f"{(e.stderr or e.stdout or '').strip()[-2000:]}") from e
+    except (OSError, subprocess.TimeoutExpired) as e:
+        raise NativeUnavailable(
+            f"building {_SO_PATH} failed: {e!r} (needs make and a C++ "
+            "compiler)") from e
+    with open(_STAMP_PATH, "w") as f:  # under load_library's lock
+        f.write(src_hash + "\n")
+    log.info("native loader built from the checkout's sources: %s", _SO_PATH)
+
+
+def load_library() -> ctypes.CDLL:
     global _lib
     if _lib is not None:
         return _lib
-    stale = os.path.exists(_SO_PATH) and not _so_exports(b"drt_prefetch_stop")
-    if not os.path.exists(_SO_PATH) or stale:
-        if not (auto_build and _build()) and not os.path.exists(_SO_PATH):
-            raise NativeUnavailable(
-                f"{_SO_PATH} not built (run `make -C {_NATIVE_DIR}`)")
+    # staleness is decided BEFORE the first dlopen: glibc caches handles by
+    # device/inode and make relinks in place, so once an old mapping exists
+    # a rebuild + re-CDLL hands back the stale symbol table
+    src_hash = _source_hash()
+    # one builder at a time: a launcher starts several processes per host,
+    # and a peer must not dlopen a half-linked file
+    with open(_SO_PATH + ".lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if not _built_from(src_hash):
+            _build(src_hash)
     try:
         lib = ctypes.CDLL(_SO_PATH)
     except OSError as e:
-        # corrupt / wrong-arch / partially-written .so: the documented
-        # contract is silent fallback to the python paths, so map the
-        # loader error onto the exception callers already handle
         raise NativeUnavailable(f"{_SO_PATH} failed to load: {e}") from e
-    if not hasattr(lib, "drt_prefetch_stop"):
-        # stale build mapped and the rebuild failed (no toolchain, or
-        # another component dlopened the old file first — glibc caches by
-        # inode). The bindings below would AttributeError; surface the
-        # canonical exception so callers fall back to the python paths.
-        raise NativeUnavailable(
-            f"{_SO_PATH} is a stale build missing drt_prefetch_stop and "
-            f"could not be rebuilt (run `make -C {_NATIVE_DIR}`)")
-    if not hasattr(lib, "drt_has_jpeg"):
-        # pre-JPEG-tier build still mapped (rebuild failed, or another
-        # component dlopened the stale file first) — the JPEG fast path is
-        # unavailable for this process; core bindings below still work
-        log.warning("libdrtdata.so predates the JPEG tier and cannot be "
-                    "reloaded in-process; JPEG decode falls back to python")
     lib.drt_crc32c.restype = ctypes.c_uint32
     lib.drt_crc32c.argtypes = [ctypes.c_char_p, ctypes.c_uint64]
     lib.drt_masked_crc32c.restype = ctypes.c_uint32
@@ -121,10 +119,9 @@ def load_library(auto_build: bool = True) -> ctypes.CDLL:
     lib.drt_prefetch_stop.argtypes = [ctypes.c_void_p]
     lib.drt_prefetch_destroy.restype = None
     lib.drt_prefetch_destroy.argtypes = [ctypes.c_void_p]
-    if hasattr(lib, "drt_has_jpeg"):
-        lib.drt_has_jpeg.restype = ctypes.c_int
-        lib.drt_has_jpeg.argtypes = []
-    if hasattr(lib, "drt_has_jpeg") and lib.drt_has_jpeg():
+    lib.drt_has_jpeg.restype = ctypes.c_int
+    lib.drt_has_jpeg.argtypes = []
+    if lib.drt_has_jpeg():
         lib.drt_decode_resize_crop.restype = ctypes.c_int
         lib.drt_decode_resize_crop.argtypes = [
             ctypes.c_char_p, ctypes.c_uint64, ctypes.c_int, ctypes.c_int,
@@ -135,10 +132,13 @@ def load_library(auto_build: bool = True) -> ctypes.CDLL:
 
 
 def native_available() -> bool:
+    """False — with the reason logged at WARNING — when the library cannot
+    be built or loaded here."""
     try:
         load_library()
         return True
-    except NativeUnavailable:
+    except NativeUnavailable as e:
+        log.warning("native loader unavailable: %s", e)
         return False
 
 
@@ -316,7 +316,7 @@ def native_jpeg_available() -> bool:
     """True iff the .so was built against libjpeg (drt_has_jpeg)."""
     try:
         lib = load_library()
-        return bool(getattr(lib, "drt_has_jpeg", lambda: 0)())
+        return bool(lib.drt_has_jpeg())
     except NativeUnavailable:
         return False
 
@@ -334,7 +334,7 @@ def decode_resize_crop_native(data: bytes, resize_side: int, top: int,
         lib = load_library()
     except NativeUnavailable:
         return None
-    if not getattr(lib, "drt_has_jpeg", lambda: 0)():
+    if not lib.drt_has_jpeg():
         return None
     out = np.empty((out_size, out_size, 3), np.uint8)
     rc = lib.drt_decode_resize_crop(

@@ -27,6 +27,12 @@ Exit-code aggregation (docs/resilience.md):
     by the supervisor;
   * 0 only when every child finished cleanly.
 
+This is a CPU REHEARSAL tool, not a way to use the chips of a host: every
+child is forced onto the CPU platform (``virtual_cpu_env``), whatever the
+host holds. On a host with TPU chips ONE process drives all of them — run
+``main.py`` once and let ``mesh.*`` lay the model out over
+``jax.devices()``; a chip belongs to one process at a time.
+
 Modes:
   * ``--num_processes N`` local fan-out — the successor of the reference's
     1ps+2wk localhost smoke cluster (reference scripts/submit_mac_dist.sh,

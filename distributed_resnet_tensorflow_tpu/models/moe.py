@@ -340,7 +340,7 @@ class SwitchMlp(nn.Module):
         over ALL mesh devices (the einsum path replicates them across the
         batch axes), and the only exchanged buffers are the (E, C_sub, D)
         expert inputs/outputs."""
-        from ..parallel.mesh import shard_map_compat
+        from ..parallel.mesh import shard_map_unchecked
         mesh, e = self.mesh, self.num_experts
         ep = mesh.shape.get("expert", 1)
         n_tokens, d = flat_x.shape
@@ -385,7 +385,7 @@ class SwitchMlp(nn.Module):
 
         tok = P(("data", "fsdp", "expert"), None)
         tps = "tensor" if tp_axis else None
-        sharded = shard_map_compat(
+        sharded = shard_map_unchecked(
             body, mesh,
             in_specs=(tok, tok, P("expert", None, tps), P("expert", tps),
                       P("expert", tps, None), P("expert", None)),

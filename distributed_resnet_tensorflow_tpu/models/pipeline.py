@@ -458,7 +458,8 @@ class PipelinedEncoder(nn.Module):
             scale) and over the batch (and token, under seq sharding)
             shards. Shape (1,) rather than scalar end-to-end: a rank-0
             value at the shard_map boundary becomes a rank-0 residual
-            under AD, and jax 0.4.37's shard_map transpose assigns
+            under AD, and the shard_map transpose of jax 0.4.37 (which
+            this was written against; not re-checked on 0.9) assigned
             residual cotangents axis names on dim 0 — a _SpecError for
             scalars (the pp×ep MoE failure this comment documents; see
             analysis/elaborate.py which now catches the class)."""
@@ -569,7 +570,7 @@ class PipelinedEncoder(nn.Module):
                 "pipeline")
             return out.reshape(xg.shape), _aux_reduce(aux_acc)
 
-        from ..parallel.mesh import shard_map_compat
+        from ..parallel.mesh import shard_map_unchecked
         body = pipelined if v == 1 else pipelined_circular
         if inline:
             # the enclosing exchange shard_map (parallel/overlap.py)
@@ -578,13 +579,12 @@ class PipelinedEncoder(nn.Module):
             # (_local_param_shape), x as its batch slice, and every axis
             # name the body psums/ppermutes over is bound — run the
             # schedule directly. Building the inner shard_map here would
-            # re-map consumed axes (and jax 0.4.37 mis-transposes nested
-            # shard_map over auto axes — the exchange docstring has the
-            # measured failure).
+            # re-map consumed axes (the exchange docstring has the
+            # nested-shard_map failure seen on jax 0.4.37).
             y, aux = body(params, x)
         else:
-            fn = shard_map_compat(body, mesh, in_specs=(p_spec, x_spec),
-                                  out_specs=(x_spec, P(None)))
+            fn = shard_map_unchecked(body, mesh, in_specs=(p_spec, x_spec),
+                                     out_specs=(x_spec, P(None)))
             y, aux = fn(params, x)
         return finish(y, aux[0])
 

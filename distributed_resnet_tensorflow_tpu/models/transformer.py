@@ -59,6 +59,25 @@ def flash_or_dense(t: int) -> str:
                        and t >= 2048) else "dense"
 
 
+def _per_shard(fn, mesh):
+    """``fn(q, k, v)`` run on each device's local (B, T, H, D) shard.
+
+    GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+    shard_map"), and it only lowers where EVERY mesh axis is manual: so
+    under a multi-device mesh the kernel runs inside a full-manual
+    shard_map, batch dim split over the batch axes and heads over
+    ``tensor`` (attention is independent per example and per head).
+    Inside the gradient exchange's body (parallel/overlap.py) the axes are
+    already manual and the arrays already local — call through."""
+    from ..parallel.mesh import current_manual_axes, shard_map_unchecked
+    if mesh is None or mesh.size == 1 or current_manual_axes():
+        return fn
+    tensor = "tensor" if mesh.shape.get("tensor", 1) > 1 else None
+    spec = P(_batch_axes(mesh) or None, None, tensor, None)
+    return shard_map_unchecked(fn, mesh, in_specs=(spec, spec, spec),
+                               out_specs=spec)
+
+
 def _apply_attention(q, k, v, impl: str, mesh=None):
     if impl == "auto":
         # resolved HERE, where the true sequence length is known at trace
@@ -76,7 +95,10 @@ def _apply_attention(q, k, v, impl: str, mesh=None):
         return blockwise_attention(q, k, v)
     if impl in ("flash", "flash_interpret"):
         from ..ops.pallas import flash_attention
-        return flash_attention(q, k, v, False, impl == "flash_interpret")
+        interpret = impl == "flash_interpret"
+        return _per_shard(
+            lambda q, k, v: flash_attention(q, k, v, False, interpret),
+            mesh)(q, k, v)
     if impl == "ring":
         from ..ops.attention import ring_attention_sharded
         if mesh is None or mesh.shape.get("seq", 1) <= 1:

@@ -184,7 +184,7 @@ def ring_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
     faster at 8k-32k tokens, docs/ring_attention_r4.json);
     "flash_interpret" = the same kernels in the Pallas interpreter (CPU
     parity tests); "auto" = flash on TPU, lax elsewhere."""
-    from ..parallel.mesh import shard_map_compat
+    from ..parallel.mesh import shard_map_unchecked
 
     n = mesh.shape[seq_axis]
     mode = resolve_ring_kernel(kernel)
@@ -200,6 +200,6 @@ def ring_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
         def body(q, k, v):
             return ring_flash_attention(q, k, v, seq_axis, n, causal,
                                         interp)
-    fn = shard_map_compat(
+    fn = shard_map_unchecked(
         body, mesh, in_specs=(spec, spec, spec), out_specs=spec)
     return fn(q, k, v)

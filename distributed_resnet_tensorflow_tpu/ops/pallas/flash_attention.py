@@ -26,16 +26,9 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU backend bits; fall back gracefully on CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-    _HAVE_TPU_PARAMS = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = pl.ANY
-    _HAVE_TPU_PARAMS = False
-
+_VMEM = pltpu.VMEM
 _NEG_INF = -1e30
 BLOCK_Q = 256
 BLOCK_K = 256
@@ -159,10 +152,6 @@ def _flash_forward(q, k, v, causal=False, interpret=False,
         valid_len=(t if tpad else None), block_q=block_q, block_k=block_k,
         nk=nk)
 
-    if not _HAVE_TPU_PARAMS:  # pragma: no cover
-        raise NotImplementedError(
-            "flash_attention requires the Pallas TPU backend; use "
-            "ops.blockwise_attention on this platform")
     scratch = [pltpu.VMEM((block_q, 1), jnp.float32),
                pltpu.VMEM((block_q, 1), jnp.float32),
                pltpu.VMEM((block_q, dp), jnp.float32)]
@@ -311,11 +300,6 @@ def _flash_backward(q, k, v, out, lse, g, causal=False, interpret=False,
     # Δ = rowsum(dO ∘ O): tiny elementwise pass, let XLA fuse it
     delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32),
                     axis=-1, keepdims=True)                # (B·H, tp, 1)
-
-    if not _HAVE_TPU_PARAMS:  # pragma: no cover
-        raise NotImplementedError(
-            "flash_attention requires the Pallas TPU backend; use "
-            "ops.blockwise_attention on this platform")
 
     common = dict(scale=scale, causal=causal,
                   valid_len=(t if tpad else None),

@@ -18,14 +18,9 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU backend params; absent on pure-CPU installs
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = pl.ANY
-
+_VMEM = pltpu.VMEM
 _NEG_INF = -1e30
 _TILE_B = 128
 

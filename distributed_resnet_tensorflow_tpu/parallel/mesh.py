@@ -161,33 +161,22 @@ def batch_shard_count(mesh: Mesh) -> int:
     return mesh.shape.get("data", 1) * mesh.shape.get("fsdp", 1)
 
 
-def shard_map_compat(fn, mesh: Mesh, in_specs, out_specs,
-                     auto: frozenset = frozenset()):
-    """``jax.shard_map`` across jax versions, replication checks off.
-
-    One home for two version dances every caller needs: the import moved
-    out of ``jax.experimental`` in 0.8 (the old alias warns and will be
-    removed), and the don't-check-replication flag was renamed
-    ``check_rep`` → ``check_vma``. Checks stay off because our shard_map
-    bodies wrap collectives/pallas_call, which don't declare varying-mesh
-    -axes info.
+def shard_map_unchecked(fn, mesh: Mesh, in_specs, out_specs,
+                        auto: frozenset = frozenset()):
+    """``jax.shard_map`` with the varying-mesh-axes check off — our bodies
+    wrap collectives and ``pallas_call``, which don't declare that info.
 
     ``auto``: mesh axes left AUTOMATIC (GSPMD propagation inside the
     body, like under plain jit) while the rest go manual — the
     partial-manual form the layout-aware gradient exchange uses for the
     propagation-parallel ``tensor`` axis (parallel/overlap.py): specs may
-    only name manual axes; values keep their auto-axis sharding."""
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - jax < 0.8
-        from jax.experimental.shard_map import shard_map
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if auto:
-        kwargs["auto"] = frozenset(auto)
-    try:
-        return shard_map(fn, check_vma=False, **kwargs)
-    except TypeError:  # pragma: no cover - older jax spells it check_rep
-        return shard_map(fn, check_rep=False, **kwargs)
+    only name manual axes; values keep their auto-axis sharding.
+    ``jax.shard_map`` names the MANUAL set (``axis_names``), so it is the
+    mesh's axes minus ``auto``."""
+    manual = frozenset(mesh.axis_names) - frozenset(auto)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=manual,
+                         check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -195,9 +184,10 @@ def shard_map_compat(fn, mesh: Mesh, in_specs, out_specs,
 # manually-mapped shard_map body (the layout-aware gradient exchange,
 # parallel/overlap.make_bucketed_grad) rather than under plain jit.
 # Sharding constraints naming a manual axis are illegal inside the body,
-# model-internal shard_maps must not re-map an already-manual axis (jax
-# 0.4.37 mis-transposes nested shard_map over auto axes — measured, see
-# overlap.py), and per-shard batch math must stop dividing by shards the
+# model-internal shard_maps must not re-map an already-manual axis (and
+# nested shard_map over auto axes mis-transposed when this was written,
+# on jax 0.4.37 — see overlap.py; not re-checked on 0.9), and per-shard
+# batch math must stop dividing by shards the
 # enclosing body already split. The context is TRACE-time only: the body
 # runs during jit tracing, so its dynamic extent covers exactly the model
 # code whose behavior must flip.

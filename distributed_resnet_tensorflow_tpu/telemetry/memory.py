@@ -113,11 +113,9 @@ def sample_memory(process_index: Optional[int] = None) -> Dict[str, Any]:
                   "live_peak_bytes": peaks["by_device"].get(dev, n)}
             for dev, n in live.items()}
         for d in jax.local_devices():
-            stats = None
-            try:
-                stats = d.memory_stats()
-            except Exception:  # backend without allocator stats
-                stats = None
+            # None on a backend without allocator stats (the CPU); a
+            # failing probe is logged by the handler below, not dropped
+            stats = d.memory_stats()
             if stats:
                 cell = devices.setdefault(str(d.id), {})
                 for src, dst in (("bytes_in_use", "bytes_in_use"),

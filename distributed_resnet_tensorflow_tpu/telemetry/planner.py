@@ -258,7 +258,9 @@ def predict_compute_secs(cfg, n_devices: int, accum: int = 1,
                          peak_tflops: Optional[float] = None) -> float:
     """Roofline compute term for one OPTIMIZER step: global batch ×
     accum microbatches of forward+backward FLOPs, spread ideally over
-    the devices, at ``ASSUMED_MFU`` of peak."""
+    the devices, at ``ASSUMED_MFU`` of peak. ``peak_tflops=None`` is the
+    OFFLINE planner's reference constant (no device to ask); a live run
+    passes its own device's peak (predict_live)."""
     peak = (peak_tflops or REFERENCE_PEAK_TFLOPS) * 1e12
     examples = cfg.train.batch_size * max(1, accum)
     step_flops = examples * flops_per_example(cfg) * TRAIN_FLOPS_MULTIPLIER
@@ -681,7 +683,18 @@ def predict_live(cfg, trainer,
         bandwidth = measured_bandwidth_table() or BandwidthTable.reference()
     n_devices = jax.device_count()
     accum = max(1, int(snap.get("accum_steps", 1)))
+    # the attached accelerator's own peak; raises for a device_kind the
+    # peaks table does not know — a borrowed peak would make every
+    # plan_drift row on that machine a fiction
     peak = detect_peak_tflops()
+    if peak is None:
+        # CPU rehearsal: the host has no peak, so the compute term keeps
+        # the catalog's reference constant and the step_secs drift this
+        # produces there is expected (docs/planner.md)
+        log.info("plan: cpu backend — compute term costed at the "
+                 "reference %.0f TFLOP/s, not at a device peak",
+                 REFERENCE_PEAK_TFLOPS)
+        peak = REFERENCE_PEAK_TFLOPS
     compute = predict_compute_secs(cfg, n_devices, accum=accum,
                                    peak_tflops=peak)
     comm = 0.0

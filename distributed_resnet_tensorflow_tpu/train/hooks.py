@@ -111,6 +111,13 @@ class LoggingHook(_CadenceHook):
         self.throughput = Throughput(batch_size)
         self.print_fn = print_fn or (lambda s: log.info("%s", s))
         self.step_flops = step_flops  # enables an MFU column when known
+        if step_flops:
+            from ..utils.profiling import detect_peak_tflops
+            # raises for an accelerator the peaks table does not know
+            if detect_peak_tflops() is None:
+                self.step_flops = None
+                self.print_fn("no mfu column: the cpu backend has no peak "
+                              "in utils/profiling.TPU_PEAK_TFLOPS")
         self._last = 0
 
     def reset_window(self) -> None:

@@ -31,7 +31,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..parallel.mesh import (batch_shard_count, create_mesh, data_sharding,
-                             present_batch_axes, shard_map_compat)
+                             present_batch_axes, shard_map_unchecked)
 from ..telemetry.tracer import span
 from ..parallel.sharding import (finalize_staged, make_global_batch,
                                  shard_batch)
@@ -59,6 +59,18 @@ def cross_entropy_loss(logits: jax.Array, labels: jax.Array,
     return per_example_cross_entropy(logits, labels, label_smoothing).mean()
 
 
+def resolve_fused_xent(fused_xent: str, label_smoothing: float = 0.0) -> str:
+    """``train.fused_xent`` → "on" | "interpret" | "off" (see make_ce_fn).
+    "auto" never resolves to the interpreter: that is a CPU test seam."""
+    if fused_xent not in ("auto", "on", "interpret", "off"):
+        raise ValueError(f"unknown fused_xent mode {fused_xent!r}")
+    if label_smoothing > 0:
+        return "off"  # the kernel computes plain NLL
+    if fused_xent == "auto":
+        return "on" if jax.default_backend() == "tpu" else "off"
+    return fused_xent
+
+
 def make_ce_fn(label_smoothing: float = 0.0, fused_xent: str = "off",
                mesh: Optional[Mesh] = None,
                per_example: bool = False) -> Callable:
@@ -80,12 +92,8 @@ def make_ce_fn(label_smoothing: float = 0.0, fused_xent: str = "off",
     (parallel/overlap.make_bucketed_grad) is already per-shard, so the
     kernel runs directly on the local tile. One resolver for both paths:
     the overlap loss cannot drift from the jit loss."""
-    if fused_xent not in ("auto", "on", "interpret", "off"):
-        raise ValueError(f"unknown fused_xent mode {fused_xent!r}")
-    mode = fused_xent
-    if mode == "auto":
-        mode = "on" if jax.default_backend() == "tpu" else "off"
-    if mode == "off" or label_smoothing > 0:
+    mode = resolve_fused_xent(fused_xent, label_smoothing)
+    if mode == "off":
         per_ex = lambda logits, labels: per_example_cross_entropy(  # noqa: E731
             logits, labels, label_smoothing)
         if per_example:
@@ -102,7 +110,7 @@ def make_ce_fn(label_smoothing: float = 0.0, fused_xent: str = "off",
     if mesh is not None and batch_shard_count(mesh) > 1:
         batch_axes = present_batch_axes(mesh)
         batch_spec = P(batch_axes)
-        sharded = shard_map_compat(
+        sharded = shard_map_unchecked(
             per_ex, mesh,
             in_specs=(P(batch_axes, None), batch_spec),
             out_specs=batch_spec)
@@ -613,6 +621,40 @@ class Trainer:
                     lambda b: shard_stacked_batch(b, self.mesh)
             self._put_train_batch = self._put_batch
             self._put_train_multi_batch = self._put_multi_batch
+        import logging
+        logging.getLogger(__name__).info(
+            "resolved on %s: %s", jax.default_backend(),
+            " ".join(f"{k}={v}" for k, v in self.resolutions().items()))
+
+    def resolutions(self) -> Dict[str, str]:
+        """What every ``auto`` switch of this run resolved to — logged at
+        construction so any run shows which paths it took."""
+        from ..data import device_augment_enabled, device_dataset_enabled
+        cfg = self.cfg
+
+        def onoff(flag) -> str:
+            return "on" if flag else "off"
+
+        attention = "n/a"
+        if cfg.model.name == "vit":
+            attention = self.model.attention_impl
+            if attention == "auto":  # no seq axis (create_model resolves it)
+                from ..models.transformer import flash_or_dense
+                attention = flash_or_dense(
+                    (cfg.data.image_size // cfg.model.vit_patch_size) ** 2)
+            if attention == "ring":
+                from ..ops.attention import resolve_ring_kernel
+                attention = f"ring/{resolve_ring_kernel('auto')}"
+        return {
+            "fused_xent": resolve_fused_xent(cfg.train.fused_xent,
+                                             cfg.optimizer.label_smoothing),
+            "coalesced_transfer": onoff(self._coalesced),
+            "device_augment": onoff(device_augment_enabled(cfg, "train")),
+            "device_dataset": onoff(device_dataset_enabled(cfg, "train")),
+            "attention": attention,
+            "comm.overlap": onoff(self.comm_overlap_active),
+            "zero1": onoff(self.zero1_active),
+        }
 
     def _zero1_min_size(self) -> int:
         from ..parallel.sharding import ZERO1_MIN_SIZE
@@ -923,11 +965,13 @@ class Trainer:
     def _gathered_step(self):
         step = self._train_step
 
-        def fn(state, batch, images, labels):
+        # the name is the compiled module's (jit_gathered_train_step): it
+        # is how a trace or a compile-cache entry is told apart
+        def gathered_train_step(state, batch, images, labels):
             idx = batch["idx"]
             return step(state, {"images": jnp.take(images, idx, axis=0),
                                 "labels": jnp.take(labels, idx, axis=0)})
-        return fn
+        return gathered_train_step
 
     def jitted_index_step(self):
         if self._dev_data is None:
@@ -951,23 +995,38 @@ class Trainer:
                 lambda s, b: jit_fn(s, b, *self._dev_data)
         return self._jitted_idx
 
-    def step_flops(self, batch) -> Optional[float]:
-        """XLA cost-analysis FLOPs of one compiled optimizer step. ``batch``
-        is one host batch as the training iterator yields it ({"images",..}
-        or {"idx"}). Uses the same jit entry training uses, so the lowering
-        warms the compile cache rather than adding a compile."""
-        from ..utils import profiling
+    def device_batch(self, batch):
+        """One host batch — as the training iterator yields it
+        ({"images",..} or {"idx"}) — staged on the devices the way
+        ``train()`` stages it for the single-step dispatch."""
         if self._dev_data is not None and "idx" in batch:
-            self.jitted_index_step()
-            return profiling.flops_per_step(
-                self._jitted_idx_raw, self.state, self._put_idx(batch),
-                *self._dev_data)
+            return self._put_idx(batch)
         # the TRAIN put path: with the fused-augment stager the step's
         # traced program expects the unpack's augmented float32 images,
         # and the counted FLOPs then include the on-device augmentation
-        return profiling.flops_per_step(
-            self.jitted_train_step(), self.state,
-            finalize_staged(self._put_train_batch(batch)))
+        return finalize_staged(self._put_train_batch(batch))
+
+    def lowered_step(self, batch):
+        """The single train step as ``train()`` dispatches it, lowered for
+        one host ``batch`` through the same jit entry training uses — read
+        by step_flops and by chip_smoke.py (which looks for the Pallas
+        custom calls in the program)."""
+        dev = self.device_batch(batch)
+        if self._dev_data is not None and "idx" in batch:
+            self.jitted_index_step()
+            return self._jitted_idx_raw.lower(self.state, dev,
+                                              *self._dev_data)
+        return self.jitted_train_step().lower(self.state, dev)
+
+    def step_flops(self, batch) -> Optional[float]:
+        """XLA cost-analysis FLOPs of one compiled optimizer step. This
+        compiles the lowered step: a persistent-cache hit for a plain XLA
+        program, but a SECOND full compile when the step holds a Pallas
+        kernel — Mosaic serializes the kernel with its trace-time
+        call-stack locations, so the cache key differs from the dispatch
+        path's (PERF.md, PR 21)."""
+        from ..utils import profiling
+        return profiling.lowered_flops(self.lowered_step(batch))
 
     def jitted_index_multi_step(self, k: int = 0):
         del k

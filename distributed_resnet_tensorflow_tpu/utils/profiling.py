@@ -21,13 +21,19 @@ import jax
 
 log = logging.getLogger(__name__)
 
-# bf16 peak TFLOP/s per JAX DEVICE by TPU generation (public spec-sheet
-# numbers). mfu() multiplies by jax.device_count(), and on v2/v3 JAX
-# exposes each of the chip's 2 cores as a device — so those entries are
-# per-CORE (chip peak / 2); v4+ are one device per chip.
+# bf16 peak TFLOP/s per JAX DEVICE, keyed by a substring of the lowered
+# ``device_kind`` (public spec-sheet numbers). mfu() multiplies by
+# jax.device_count(), and on v2/v3 JAX exposes each of the chip's 2 cores
+# as a device — so those entries are per-CORE (chip peak / 2); v4+ are one
+# device per chip. The ONE peaks table of the repo: a device that is not
+# here is an error where a peak is asked for (detect_peak_tflops).
 TPU_PEAK_TFLOPS = {
     "v2": 45.0 / 2, "v3": 123.0 / 2,
-    "v4": 275.0, "v5e": 197.0, "v5 lite": 197.0, "v5p": 459.0, "v6e": 918.0,
+    "v4": 275.0,
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per chip;
+    # JAX reports the chip as device_kind "TPU v5 lite"
+    "v5e": 197.0, "v5 lite": 197.0,
+    "v5p": 459.0, "v6e": 918.0,
 }
 
 
@@ -35,34 +41,47 @@ def count_params(params: Any) -> int:
     return sum(p.size for p in jax.tree_util.tree_leaves(params))
 
 
-def flops_per_step(jitted_fn, *example_args) -> Optional[float]:
-    """FLOPs of one compiled step, from XLA's own cost analysis."""
+def lowered_flops(lowered) -> Optional[float]:
+    """FLOPs of one lowered step once compiled, from XLA's own cost
+    analysis."""
     try:
-        compiled = jitted_fn.lower(*example_args).compile()
-        cost = compiled.cost_analysis()
-        if isinstance(cost, list):  # older jax returns [dict]
-            cost = cost[0]
+        cost = lowered.compile().cost_analysis()
         return float(cost.get("flops", 0.0)) or None
     except Exception as e:  # cost analysis not supported on this backend
-        log.debug("cost analysis unavailable: %s", e)
+        log.warning("cost analysis unavailable, no FLOP count: %s", e)
         return None
+
+
+def flops_per_step(jitted_fn, *example_args) -> Optional[float]:
+    """FLOPs of one compiled step of ``jitted_fn(*example_args)``."""
+    return lowered_flops(jitted_fn.lower(*example_args))
 
 
 def detect_peak_tflops() -> Optional[float]:
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
+    """bf16 peak TFLOP/s of one attached device. None on the CPU backend —
+    a host CPU has no row, so callers there report no utilization and say
+    so. An ACCELERATOR whose ``device_kind`` is not in the table raises: a
+    utilization against a guessed or borrowed peak is worse than none."""
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
         return None
+    kind = dev.device_kind.lower()
     for key, peak in TPU_PEAK_TFLOPS.items():
         if key in kind:
             return peak
-    return None
+    raise ValueError(
+        f"no peak TFLOP/s for device_kind {dev.device_kind!r} (platform "
+        f"{dev.platform!r}) in utils/profiling.TPU_PEAK_TFLOPS — add the "
+        "row with its source before reporting a utilization on it")
 
 
 def mfu(steps_per_sec: float, step_flops: float,
         num_devices: Optional[int] = None,
         peak_tflops: Optional[float] = None) -> Optional[float]:
-    """Model FLOPs utilization in [0,1]: achieved / peak."""
+    """Model FLOPs utilization in [0,1]: achieved / peak. None when the
+    FLOP count is unknown or the backend is the CPU (no peak to hold it
+    against — see detect_peak_tflops, which raises for an accelerator it
+    does not know)."""
     peak = peak_tflops or detect_peak_tflops()
     if not peak or not step_flops:
         return None

@@ -328,7 +328,7 @@ def test_extract_schedule_orders_explicit_collectives(devices):
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from distributed_resnet_tensorflow_tpu.analysis.collectives import (
         extract_schedule)

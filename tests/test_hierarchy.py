@@ -34,7 +34,7 @@ from jax.sharding import PartitionSpec as P
 from distributed_resnet_tensorflow_tpu.parallel import create_mesh
 from distributed_resnet_tensorflow_tpu.parallel import overlap as ov
 from distributed_resnet_tensorflow_tpu.parallel.mesh import (
-    data_axis_host_factorization, shard_map_compat)
+    data_axis_host_factorization, shard_map_unchecked)
 from distributed_resnet_tensorflow_tpu.parallel.overlap import (
     autotune_mode, hierarchy_factor, hierarchy_groups, overlap_stats,
     resolve_hierarchy)
@@ -120,7 +120,7 @@ def _exchange(mesh, leaves, specs, hierarchy, data_size, out_specs=None,
             reduce_axes=reduce_axes, hierarchy=hierarchy,
             data_size=data_size))
     n = len(leaves)
-    f = shard_map_compat(
+    f = shard_map_unchecked(
         body, mesh,
         in_specs=tuple(in_specs or (P(),) * n),
         out_specs=tuple(run_out_specs or in_specs or (P(),) * n))

@@ -683,7 +683,7 @@ def test_fleet_kill_and_recover_subprocess(tmp_path):
     cfg.data.image_size = 8
     cfg.train.batch_size = 16
     cfg.data.eval_batch_size = 16
-    cfg.mesh.data = 1
+    cfg.mesh.data = -1  # replicas inherit the suite's 8 virtual devices
     cfg.log_root = str(tmp_path)
     cfg.checkpoint.directory = os.path.join(str(tmp_path), "ckpt")
     cfg.serve.max_queue_delay_ms = 5.0

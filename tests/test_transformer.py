@@ -150,6 +150,36 @@ def test_vit_tensor_parallel_matches_unsharded():
     np.testing.assert_allclose(out_tp, out_plain, rtol=2e-5, atol=2e-5)
 
 
+def test_vit_flash_runs_per_shard_under_a_mesh():
+    """The Pallas kernel cannot be partitioned by GSPMD (on the chip a
+    batch-sharded jit of it refuses to lower — found by chip_smoke on four
+    chips, PR 21), so under a mesh it runs per shard inside a full-manual
+    shard_map, batch over data and heads over tensor: same logits and
+    parameter gradients as dense attention on one device."""
+    mesh = _mesh(data=2, tensor=4)
+    x = jnp.asarray(np.random.RandomState(2).randn(4, 16, 16, 3), jnp.float32)
+    labels = jnp.asarray([0, 1, 2, 3])
+    dense = _small_vit("dense")
+    flash = _small_vit("flash_interpret", mesh=mesh)
+    variables = dense.init(jax.random.PRNGKey(0), x)
+
+    def loss(model):
+        def fn(p):
+            logits = model.apply({"params": p}, x)
+            return -jnp.mean(jax.nn.log_softmax(logits)[jnp.arange(4), labels])
+        return fn
+
+    ld, gd = jax.jit(jax.value_and_grad(loss(dense)))(variables["params"])
+    jaxpr = str(jax.make_jaxpr(loss(flash))(variables["params"]))
+    assert "shard_map" in jaxpr
+    lf, gf = jax.jit(jax.value_and_grad(loss(flash)))(variables["params"])
+    np.testing.assert_allclose(float(lf), float(ld), rtol=2e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(gf),
+                    jax.tree_util.tree_leaves(gd)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-5)
+
+
 def test_vit_ring_routed_through_trainer():
     """mesh.sequence > 1 + attention_impl=auto resolves to ring and trains
     end-to-end through the Trainer."""

@@ -19,8 +19,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import numpy as np
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from distributed_resnet_tensorflow_tpu.utils.compile_cache import (  # noqa: E402
+    configure_compile_cache)
+
+configure_compile_cache()
 
 
 def build(num_experts: int, top_k: int, bs=32, image=64, patch=4,

@@ -41,9 +41,10 @@ def _merge(update: dict) -> None:
 
 
 def bench_tpu():
+    from distributed_resnet_tensorflow_tpu.utils.compile_cache import (
+        configure_compile_cache)
+    configure_compile_cache()
     import jax
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
     import jax.numpy as jnp
     import numpy as np
     from bench import attention_grad_ms

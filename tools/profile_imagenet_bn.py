@@ -32,8 +32,10 @@ import numpy as np
 
 # persistent compile cache: each variant is one compile of a large RN50 scan
 # graph; re-runs (and re-invocations per variant) hit the cache
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from distributed_resnet_tensorflow_tpu.utils.compile_cache import (  # noqa: E402
+    configure_compile_cache)
+
+configure_compile_cache()
 
 
 def build_step(bs: int, k: int, stat_subsample: int = 1):

@@ -23,11 +23,13 @@ import time
 import jax
 import numpy as np
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
+
+from distributed_resnet_tensorflow_tpu.utils.compile_cache import (  # noqa: E402
+    configure_compile_cache)
+
+configure_compile_cache()
 OUT = os.path.join(REPO, "docs", "perf_imagenet_r4.json")
 
 

@@ -28,8 +28,10 @@ sys.path.insert(0, REPO)
 
 import jax  # noqa: E402
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from distributed_resnet_tensorflow_tpu.utils.compile_cache import (  # noqa: E402
+    configure_compile_cache)
+
+configure_compile_cache()
 
 OUT = os.path.join(REPO, "docs", "perf_vit_classic_r5.json")
 

@@ -23,8 +23,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from distributed_resnet_tensorflow_tpu.utils.compile_cache import (  # noqa: E402
+    configure_compile_cache)
+
+configure_compile_cache()
 
 BLOCKS = (128, 256, 512)
 SEQS = (1024, 2048, 4096, 8192)
