@@ -1,0 +1,175 @@
+"""BENCHMARK.json against its contract, the harness driven by data, the
+device gate, and the refusal of a share over 100."""
+import json
+import os
+import re
+import subprocess
+import sys
+
+import pytest
+
+from benchmark.harness import device, spec, window
+from benchmark.tests import tiny
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+
+
+def _line(text):
+    return 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text
+
+
+def test_benchmark_json_keeps_to_the_contract():
+    bench = spec.load_benchmark()
+    assert set(bench) == {"command", "paths", "run_seconds", "configs", "workloads",
+                          "end_to_end", "per_layer"}
+    assert bench["paths"] == ["benchmark"] and bench["command"][:2] == ["python3", "benchmark/run.py"]
+    assert 1 <= bench["run_seconds"] <= 51
+    names = [c["name"] for c in bench["configs"]] + [w["name"] for w in bench["workloads"]]
+    assert len(set(names)) == len(names)
+    for c in bench["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert NAME.match(c["name"]) and _line(c["source"]) and _line(c["why"])
+        assert c["file"].startswith("benchmark/") and os.path.isfile(os.path.join(spec.ROOT, c["file"]))
+        with open(os.path.join(spec.ROOT, c["file"])) as f:
+            assert json.load(f)["reduced"] == c["reduced"]
+    used = {w["config"] for w in bench["workloads"]}
+    assert used == {c["name"] for c in bench["configs"]}
+    four = [w for w in bench["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(bench["workloads"]) // 4)
+    for w in bench["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert NAME.match(w["name"]) and NAME.match(w["traffic"]) and _line(w["why"])
+        assert w["chips"] in (1, 4)
+    metrics = bench["end_to_end"] + bench["per_layer"]
+    assert len({m["name"] for m in metrics}) == len(metrics)
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    assert set(e2e) == {"examples_per_s", "peak_hbm_gib", "setup_s"}
+    for m in bench["end_to_end"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "bound", "source"}
+        assert 0.01 <= m["bound"] <= 0.1 and m["source"] in ("host_clock", "device_trace")
+    layers = set()
+    for m in bench["per_layer"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "source", "layer", "moves"}
+        assert m["moves"] in e2e and _line(m["layer"])
+        assert m["source"] in ("device_trace", "program_span", "program_counter", "host_clock")
+        layers.add(m["layer"])
+    for m in metrics:
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
+    assert any("mfu" in m["name"] for m in bench["per_layer"])
+    for m in bench["per_layer"]:  # what exists only across chips is read only there
+        if m["name"] == "collective_exposed_ms":
+            assert m["workloads"] == [w["name"] for w in four]
+    assert len(json.dumps(bench)) < 64 * 1024
+
+
+@pytest.mark.parametrize("name", spec.cell_names())
+def test_every_cell_resolves_with_a_reader_per_metric(name):
+    cell = spec.resolve(name)
+    assert set(cell.readers()) == {m["name"] for m in cell.per_layer}
+    assert {m["name"] for m in cell.end_to_end} == {"examples_per_s", "peak_hbm_gib", "setup_s"}
+    assert set(cell.limits["limits"]) | set(cell.limits.get("not_compared", {}))
+    assert cell.traffic["chips"] == cell.chips
+
+
+def test_a_metric_without_a_reader_or_a_cell_without_files_is_an_error(tmp_path):
+    root = tiny.make_root(str(tmp_path))
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as f:
+        bench = json.load(f)
+    bench["per_layer"].append({"name": "no_such_ms", "unit": "ms", "better": "lower",
+                               "source": "device_trace", "layer": "step",
+                               "moves": "examples_per_s"})
+    bench["workloads"].append({"name": "orphan", "config": "tiny_vit", "traffic": "nowhere",
+                               "chips": 1, "why": "x"})
+    with open(path, "w") as f:
+        json.dump(bench, f)
+    with pytest.raises(spec.SpecError, match="layer_metrics/no_such_ms.py"):
+        spec.resolve("tiny_vit1", root)
+    with pytest.raises(spec.SpecError, match="missing file"):
+        spec.resolve("orphan", root)
+
+
+def test_a_cell_is_added_by_files_and_entries_alone(tmp_path):
+    """Copy the benchmark, add a configuration, a traffic mix, a per-layer
+    metric and a cell, edit no file that was there but BENCHMARK.json's
+    lists, and the copy's own command lists and resolves the new cell."""
+    root = tiny.make_root(str(tmp_path))
+    before = {}
+    for base, _, files in os.walk(os.path.join(root, "benchmark")):
+        for f in files:
+            p = os.path.join(base, f)
+            before[p] = os.path.getmtime(p), os.path.getsize(p)
+    here = os.path.join(root, "benchmark")
+    with open(os.path.join(here, "layer_metrics", "steps_traced.py"), "w") as f:
+        f.write("def read(run):\n    return run['trace'] and run['trace']['steps']\n")
+    with open(os.path.join(here, "traffic", "tiny_f32_b2.json"), "w") as f:
+        json.dump(dict(tiny.TRAFFIC["tiny_f32_b4"], per_chip_batch=2), f)
+    with open(os.path.join(here, "configs", "tiny_vit_deep.json"), "w") as f:
+        body = json.loads(json.dumps(tiny.CONFIGS["tiny_vit"]))
+        body["overrides"]["model.vit_depth"] = body["model"]["vit_depth"] = 3
+        json.dump(body, f)
+    with open(os.path.join(here, "limits", "new_cell.json"), "w") as f:
+        json.dump(tiny.LIMITS, f)
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as f:
+        bench = json.load(f)
+    bench["configs"].append({"name": "tiny_vit_deep", "source": "test",
+                             "file": "benchmark/configs/tiny_vit_deep.json",
+                             "reduced": [], "why": "a third block"})
+    bench["workloads"].append({"name": "new_cell", "config": "tiny_vit_deep",
+                               "traffic": "tiny_f32_b2", "chips": 1, "why": "added as data"})
+    bench["per_layer"].append({"name": "steps_traced", "unit": "steps", "better": "higher",
+                               "source": "device_trace", "layer": "step",
+                               "moves": "examples_per_s", "workloads": ["new_cell"]})
+    with open(path, "w") as f:
+        json.dump(bench, f)
+    for p, stamp in before.items():
+        assert (os.path.getmtime(p), os.path.getsize(p)) == stamp
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    listed = subprocess.run([sys.executable, os.path.join(here, "run.py"), "--list"],
+                            cwd=root, env=env,
+                            capture_output=True, text=True, timeout=120)
+    assert listed.returncode == 0 and "new_cell" in listed.stdout.split()
+    code = ("import sys; sys.path.insert(0, '.'); from benchmark.harness import spec; "
+            "c = spec.resolve('new_cell'); assert spec.ROOT == sys.argv[1], spec.ROOT; "
+            "print(sorted(c.readers()), c.config['model']['vit_depth'], "
+            "c.traffic['per_chip_batch'])")
+    got = subprocess.run([sys.executable, "-c", code, root], cwd=root, env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert got.returncode == 0, got.stderr
+    assert "steps_traced" in got.stdout and got.stdout.strip().endswith("3 2")
+
+
+def test_the_device_gate_exits_non_zero_on_the_cpu_and_prints_no_result():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    got = subprocess.run([sys.executable, os.path.join(spec.HERE, "run.py"), "--workload",
+                          "rn50_staged", "--seed", "1", "--seconds", "1", "--trace", "0"],
+                         cwd=spec.ROOT, env=env, capture_output=True, text=True, timeout=300)
+    assert got.returncode != 0
+    assert got.stdout.strip() == ""
+    assert "no accelerator" in got.stderr
+
+
+def test_an_unknown_device_kind_is_an_error():
+    with pytest.raises(device.DeviceError):
+        device.peaks_for("TPU v9 imaginary")
+    with pytest.raises(device.DeviceError):
+        device.peaks_for("_source")
+    assert device.peaks_for("TPU v5 lite")["bf16_flops_per_s"] == 197e12
+
+
+def test_a_share_over_100_is_refused():
+    cell = spec.resolve("rn50_staged")
+    run = {"trace": {"steps": 10, "window_s": 1.0, "busy_s": 0.5, "busy_s_device0": 0.5,
+                     "collective_exposed_s": 0.0, "collective_events": 0},
+           "peaks": {"bf16_flops_per_s": 197e12}, "chips": 1, "compile_s": 1.0,
+           "stages_before": {}, "stages_after": {},
+           "flops_per_step": 197e12 * 0.2}  # 10 steps a second: 200% of peak
+    with pytest.raises(RuntimeError, match="over 100"):
+        window.layer_metrics(cell, run)
+    run["flops_per_step"] = 197e12 * 0.05
+    got = window.layer_metrics(cell, run)
+    assert got["step_mfu"] == pytest.approx(50.0)
+    assert got["device_idle_share"] == pytest.approx(50.0)
+    assert "stage_ms" not in got and "collective_exposed_ms" not in got  # nothing to read
