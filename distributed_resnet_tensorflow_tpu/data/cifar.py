@@ -22,12 +22,11 @@ cifar_input.py:66-75). Eval: standardization only.
 from __future__ import annotations
 
 import os
-import time
 from typing import Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
-from ..utils.metrics import input_stages
+from ..telemetry.tracer import span
 
 IMAGE_SIZE = 32
 DEPTH = 3
@@ -182,25 +181,21 @@ def cifar_iterator(dataset: str, data_dir: str, batch_size: int, mode: str,
                                            np.zeros(pad, np.float32)])
                 else:
                     mask = None
-                t0 = time.perf_counter()
-                batch_imgs = images[idx]
-                if is_train and device_augment:
-                    out = {"images": batch_imgs,  # raw uint8; device augments
-                           "labels": labels[idx].copy()}
-                    input_stages.add("decode", time.perf_counter() - t0,
-                                     items=batch_size)
-                    yield out
-                    continue
-                if is_train:
-                    batch_imgs = augment_train(batch_imgs, rng)
-                out = {"images": standardize(batch_imgs),
-                       "labels": labels[idx].copy()}
-                if mask is not None:
-                    out["mask"] = mask
-                # host-side parse/augment/standardize busy time (the cifar
+                # host-side gather/augment/standardize busy time (the cifar
                 # analog of the imagenet decode stage)
-                input_stages.add("decode", time.perf_counter() - t0,
-                                 items=batch_size)
+                with span("input.decode") as sp:
+                    batch_imgs = images[idx]
+                    if is_train and device_augment:
+                        out = {"images": batch_imgs,  # raw uint8; device augments
+                               "labels": labels[idx].copy()}
+                    else:
+                        if is_train:
+                            batch_imgs = augment_train(batch_imgs, rng)
+                        out = {"images": standardize(batch_imgs),
+                               "labels": labels[idx].copy()}
+                        if mask is not None:
+                            out["mask"] = mask
+                sp.charge("decode", items=batch_size)
                 yield out
 
     if prefetch > 0 and is_train:

@@ -17,10 +17,11 @@ Here:
   * ``threaded_stacker``  — draw K batches + np.stack on a background thread
     (the input side of the fused ``steps_per_loop`` dispatch).
 
-Every stage records busy time + item counts into
-``utils.metrics.input_stages`` (stages: decode / stack / stage / transfer /
-dispatch_wait — see docs/input_pipeline.md), so attribution of the
-end-to-end input rate comes from the pipeline as it actually ran.
+Every stage is timed by ONE flight-recorder span (telemetry/tracer.py),
+which charges busy time + item counts into ``utils.metrics.input_stages``
+(stages: decode / stack / stage / transfer / dispatch_wait — see
+docs/input_pipeline.md), so attribution of the end-to-end input rate comes
+from the pipeline as it actually ran.
 
 All returned generators stop their worker thread when closed — a replaced
 or abandoned pipeline must not leave a thread parked on its queue holding
@@ -31,8 +32,7 @@ from __future__ import annotations
 import logging
 import queue as queue_mod
 import threading
-import time
-from typing import Callable, Iterator, Optional
+from typing import Callable, Iterator
 
 log = logging.getLogger(__name__)
 
@@ -75,11 +75,13 @@ def device_prefetch(host_iter: Iterator, put: Callable, depth: int = 2
     import jax
 
     from ..telemetry.tracer import span
-    from ..utils.metrics import input_stages
 
-    # a put that records its own stage counters (CoalescedStager splits
-    # pack → "stage" and issue → "transfer") must not have its items
-    # double-counted; we then only charge the completion wait
+    # a put that records its own stage spans (CoalescedStager splits
+    # pack → input.stage/"stage" and issue → input.issue/"transfer") must
+    # not be timed or have its items counted twice; we then only charge
+    # the completion wait. Any other put IS the issue (per-leaf
+    # device_put): its seconds join the completion wait in ONE "transfer"
+    # cell per batch, with the items.
     put_records = getattr(put, "records_stages", False)
 
     def staged():
@@ -101,31 +103,30 @@ def device_prefetch(host_iter: Iterator, put: Callable, depth: int = 2
 
         def charge(entry):
             dev, items, issue_s = entry
-            t0 = time.perf_counter()
             # StagedBatch exposes block_until_ready (transfer only); plain
             # pytrees block leaf-wise (non-jax leaves pass through). A
             # failed transfer raises here and re-raises on the consumer
             # (threaded_iterator) — it must not train on a batch that
             # never arrived.
-            with span("input.transfer"):
+            with span("input.transfer") as wait:
                 blocker = getattr(dev, "block_until_ready", None)
                 if blocker is not None:
                     blocker()
                 else:
                     jax.block_until_ready(dev)
-            wait_s = time.perf_counter() - t0
             if put_records:
-                input_stages.add("transfer", wait_s)
+                wait.charge("transfer")
             else:
-                input_stages.add("transfer", issue_s + wait_s, items=items)
+                wait.charge("transfer", items=items, extra_s=issue_s or 0.0)
 
         try:
             for batch in host_iter:
-                items = _batch_items(batch)
-                t0 = time.perf_counter()
-                with span("input.stage"):
-                    out = put(batch)
-                issue_s = time.perf_counter() - t0
+                if put_records:
+                    out, items, issue_s = put(batch), 0, 0.0
+                else:
+                    with span("input.issue") as issue:
+                        out = put(batch)
+                    items, issue_s = _batch_items(batch), issue.seconds
                 pending.append((out, items, issue_s))
                 while len(pending) > 2:  # double-buffered issue window
                     charge(pending.popleft())
@@ -139,16 +140,20 @@ def device_prefetch(host_iter: Iterator, put: Callable, depth: int = 2
             if close is not None:
                 close()
 
-    inner = threaded_iterator(staged(), depth, name="drt-device-stage",
-                              wait_stage="dispatch_wait")
+    inner = threaded_iterator(staged(), depth, name="drt-device-stage")
 
     def finalized():
         # runs on the CONSUMER thread: resolve StagedBatch handles into
-        # their leaf pytrees (an async multi-device dispatch, ~µs)
+        # their leaf pytrees (an async multi-device dispatch of the unpack
+        # program — µs when the runtime takes it, longer when it pushes
+        # back; input.finalize says which)
         try:
             for item in inner:
                 fin = getattr(item, "finalize", None)
-                yield fin() if fin is not None else item
+                if fin is not None:
+                    with span("input.finalize"):
+                        item = fin()
+                yield item
         finally:
             inner.close()
 
@@ -164,23 +169,16 @@ _STOP = object()
 
 
 def threaded_iterator(src: Iterator, depth: int = 2,
-                      name: str = "drt-input-worker",
-                      wait_stage: Optional[str] = None) -> Iterator:
+                      name: str = "drt-input-worker") -> Iterator:
     """Run ``src`` on a daemon thread feeding a bounded queue of ``depth``.
 
     Worker exceptions re-raise on the consuming thread; closing the returned
     generator (or GC'ing it) sets a stop event that EVERY queue put honors —
     including the terminal sentinel/error puts — so the thread can never
     park forever on a full queue.
-
-    ``wait_stage``: when set, consumer time spent blocked on an empty queue
-    is recorded under that stage name in ``utils.metrics.input_stages``
-    (the dispatch-wait counter: how long input made the consumer wait).
     """
     q: queue_mod.Queue = queue_mod.Queue(maxsize=depth)
     stop = threading.Event()
-    if wait_stage is not None:
-        from ..utils.metrics import input_stages
 
     def put_checked(item) -> bool:
         while not stop.is_set():
@@ -227,13 +225,7 @@ def threaded_iterator(src: Iterator, depth: int = 2,
 
     try:
         while True:
-            if wait_stage is None:
-                item = get_checked()
-            else:
-                t0 = time.perf_counter()
-                item = get_checked()
-                input_stages.add(wait_stage, time.perf_counter() - t0,
-                                 items=1)
+            item = get_checked()
             if item is _STOP:
                 return
             if isinstance(item, _WorkerError):
@@ -279,7 +271,6 @@ def threaded_stacker(host_iter: Iterator, k: int, depth: int = 2) -> Iterator:
     import numpy as np
 
     from ..telemetry.tracer import span
-    from ..utils.metrics import input_stages
 
     def groups():
         while True:
@@ -294,12 +285,10 @@ def threaded_stacker(host_iter: Iterator, k: int, depth: int = 2) -> Iterator:
                         "at stream end (shorter than the k=%d fused-loop "
                         "group)", len(batches), k)
                 return
-            t0 = time.perf_counter()
-            with span("input.stack"):
+            with span("input.stack") as sp:
                 out = {key: np.stack([b[key] for b in batches])
                        for key in batches[0]}
-            input_stages.add("stack", time.perf_counter() - t0,
-                             items=_batch_items(out))
+            sp.charge("stack", items=_batch_items(out))
             yield out
 
     return threaded_iterator(groups(), depth, name="drt-batch-stacker")

@@ -27,15 +27,14 @@ which is what keeps echoed steps from being exact repeats. The
 transfer-level analog (one H2D transfer feeding multiple steps) is
 ``data.echo_transfer`` in the train loop, not here.
 
-Telemetry: emission busy time lands in ``utils.metrics.input_stages``
-under the "echo" stage; hits/misses/evictions in
+Telemetry: emission busy time (the ``input.echo_emit`` span) lands in
+``utils.metrics.input_stages`` under the "echo" stage; hits/misses/evictions in
 ``utils.metrics.echo_stats`` (``{"event": "input_echo"}`` rows via
 InputEchoHook; registered in EVENT_SCHEMAS).
 """
 from __future__ import annotations
 
 import logging
-import time
 from typing import Dict, Iterator, Optional
 
 import numpy as np
@@ -76,7 +75,6 @@ def echoing_iterator(src: Iterator[Dict[str, np.ndarray]],
 
     def gen():
         from ..telemetry.tracer import span
-        from ..utils.metrics import input_stages
         rng = np.random.RandomState((seed * 1_000_003 + 12345) % (2 ** 32))
         # FIFO of _Entry: live entries are pool[head:] — eviction only
         # advances `head` (O(1)); the dead prefix is trimmed periodically
@@ -102,46 +100,45 @@ def echoing_iterator(src: Iterator[Dict[str, np.ndarray]],
             (duplicates only when the pool holds fewer distinct samples
             than a batch — a byte-capped pool or the drain tail)."""
             nonlocal pool, head, pool_bytes, pending_uses
-            t0 = time.perf_counter()
-            n = len(pool) - head
-            if n >= batch_size:
-                # distinct samples per batch (within-batch uniqueness)
-                take = rng.permutation(n)[:batch_size]
-            else:
-                # pool smaller than a batch (byte-capped / tiny stream /
-                # drain tail): draw from the multiset of remaining
-                # servings so no entry is served past its uses — epoch
-                # accounting stays exact (each sample emitted exactly
-                # echo_factor times)
-                avail = np.repeat(np.arange(n),
-                                  [e.uses for e in pool[head:]])
-                take = avail[rng.permutation(len(avail))[:batch_size]]
-            hits = 0
-            rows = []
-            exhausted = False
-            for i in take:
-                e = pool[head + i]
-                if e.served:
-                    hits += 1
-                e.served = True
-                e.uses -= 1
-                pending_uses -= 1
-                exhausted = exhausted or e.uses <= 0
-                rows.append(e.leaves)
-            out = {k: np.stack([r[ki] for r in rows])
-                   for ki, k in enumerate(keys)}
-            if exhausted:
-                kept = []
-                for e in pool[head:]:
-                    if e.uses > 0:
-                        kept.append(e)
-                    else:
-                        pool_bytes -= e.nbytes
-                pool = kept
-                head = 0
-            nbytes = sum(v.nbytes for v in out.values())
-            input_stages.add("echo", time.perf_counter() - t0,
-                             items=batch_size, nbytes=nbytes)
+            with span("input.echo_emit") as sp:
+                n = len(pool) - head
+                if n >= batch_size:
+                    # distinct samples per batch (within-batch uniqueness)
+                    take = rng.permutation(n)[:batch_size]
+                else:
+                    # pool smaller than a batch (byte-capped / tiny stream /
+                    # drain tail): draw from the multiset of remaining
+                    # servings so no entry is served past its uses — epoch
+                    # accounting stays exact (each sample emitted exactly
+                    # echo_factor times)
+                    avail = np.repeat(np.arange(n),
+                                      [e.uses for e in pool[head:]])
+                    take = avail[rng.permutation(len(avail))[:batch_size]]
+                hits = 0
+                rows = []
+                exhausted = False
+                for i in take:
+                    e = pool[head + i]
+                    if e.served:
+                        hits += 1
+                    e.served = True
+                    e.uses -= 1
+                    pending_uses -= 1
+                    exhausted = exhausted or e.uses <= 0
+                    rows.append(e.leaves)
+                out = {k: np.stack([r[ki] for r in rows])
+                       for ki, k in enumerate(keys)}
+                if exhausted:
+                    kept = []
+                    for e in pool[head:]:
+                        if e.uses > 0:
+                            kept.append(e)
+                        else:
+                            pool_bytes -= e.nbytes
+                    pool = kept
+                    head = 0
+                nbytes = sum(v.nbytes for v in out.values())
+            sp.charge("echo", items=batch_size, nbytes=nbytes)
             stats.add(emitted=batch_size, hits=hits, cache_bytes=pool_bytes)
             return out
 

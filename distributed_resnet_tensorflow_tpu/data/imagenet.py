@@ -31,7 +31,6 @@ import glob
 import os
 import queue as queue_mod
 import threading
-import time
 from typing import Dict, Iterator, List, Optional
 
 import numpy as np
@@ -532,8 +531,7 @@ def _decode_loop(in_q, out_q, wseed, is_train, image_size, native_decode,
             else:
                 seq, (data, label) = None, item
                 rng = wrng
-            t0 = time.perf_counter()
-            with span("input.decode"):
+            with span("input.decode") as sp:
                 if is_train:
                     img = train_crop_from_bytes(data, rng, image_size,
                                                 use_native=native_decode,
@@ -543,13 +541,15 @@ def _decode_loop(in_q, out_q, wseed, is_train, image_size, native_decode,
                                                use_native=native_decode)
                 if not emit_uint8:
                     img = img.astype(np.float32) / 255.0 - RGB_MEANS
-            # decode busy time (stage counters, utils/metrics.py); worker
-            # PROCESSES flush deltas to the parent (flush_counters)
-            pend_n += 1
-            pend_s += time.perf_counter() - t0
-            pend_b += img.nbytes
-            if pend_n >= 16:
-                flush_counters()
+            # decode busy time, from the span's own measurement (stage
+            # counters, utils/metrics.py; none when telemetry is off);
+            # worker PROCESSES flush deltas to the parent (flush_counters)
+            if sp.seconds is not None:
+                pend_n += 1
+                pend_s += sp.seconds
+                pend_b += img.nbytes
+                if pend_n >= 16:
+                    flush_counters()
             out = (img, label) if seq is None else (seq, (img, label))
             if not put_checked(out):
                 return

@@ -183,6 +183,7 @@ def _flash_forward(q, k, v, causal=False, interpret=False,
         ],
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_fwd",  # what a trace's events are found by
         **extra,
     )(qf, kf, vf)
     # the lse output is computed even when discarded (no-grad path): a
@@ -334,6 +335,7 @@ def _flash_backward(q, k, v, out, lse, g, causal=False, interpret=False,
         out_shape=jax.ShapeDtypeStruct((b * h, tp, dp), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, dp), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
         **extra,
     )(qf, kf, vf, dof, lse, delta)
 
@@ -350,6 +352,7 @@ def _flash_backward(q, k, v, out, lse, g, causal=False, interpret=False,
         scratch_shapes=[pltpu.VMEM((block_k, dp), jnp.float32),
                         pltpu.VMEM((block_k, dp), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv",
         **extra,
     )(qf, kf, vf, dof, lse, delta)
 

@@ -80,6 +80,7 @@ def _run_fwd(logits, labels, interpret=False):
                                memory_space=_VMEM),
         out_shape=jax.ShapeDtypeStruct((bp, 1), jnp.float32),
         interpret=interpret,
+        name="softmax_xent_fwd",  # what a trace's events are found by
     )(logits, labels.astype(jnp.int32).reshape(-1, 1))
     return loss[:b, 0]
 
@@ -102,6 +103,7 @@ def _run_bwd(logits, labels, g, interpret=False):
                                memory_space=_VMEM),
         out_shape=jax.ShapeDtypeStruct((bp, cp), dtype),
         interpret=interpret,
+        name="softmax_xent_bwd",
     )(logits, labels.astype(jnp.int32).reshape(-1, 1), g)
     return grad[:b, :c]
 

@@ -10,7 +10,6 @@ all-gather/reduce-scatter instead of grpc push/pull.
 from __future__ import annotations
 
 import threading
-import time
 import weakref
 from typing import Any, Dict, Optional, Tuple
 
@@ -761,50 +760,53 @@ def _build_unpack(mesh: Mesh, fields: Tuple, stacked: bool, n_shards: int,
     def unpack(flat):
         import jax.numpy as jnp
         out = {}
-        for name, shape, dtype, off, nbytes in fields:
-            jdt = dtype if dtype != np.bool_ else np.dtype(np.uint8)
-            seg = jax.lax.slice(flat, (0, off), (n_shards, off + nbytes))
-            if stacked:
-                k_steps, rest = shape[0], shape[2:]
-                tgt = (n_shards, k_steps, pb) + rest
-            else:
-                rest = shape[1:]
-                tgt = (n_shards, pb) + rest
-            isize = np.dtype(dtype).itemsize
-            if isize > 1:
-                seg = seg.reshape(tgt + (isize,))
-            else:
-                seg = seg.reshape(tgt)
-            val = jax.lax.bitcast_convert_type(seg, jdt)
-            if dtype == np.bool_:
-                val = val.astype(jnp.bool_)
-            if stacked:
-                val = val.transpose((1, 0, 2) + tuple(
-                    range(3, 3 + len(rest))))
-                val = val.reshape((shape[0], n_shards * pb) + rest)
-            else:
-                val = val.reshape((n_shards * pb,) + rest)
-            out[name] = val
+        # the scopes name the device ops in a profiler trace
+        with jax.named_scope("unpack"):
+            for name, shape, dtype, off, nbytes in fields:
+                jdt = dtype if dtype != np.bool_ else np.dtype(np.uint8)
+                seg = jax.lax.slice(flat, (0, off), (n_shards, off + nbytes))
+                if stacked:
+                    k_steps, rest = shape[0], shape[2:]
+                    tgt = (n_shards, k_steps, pb) + rest
+                else:
+                    rest = shape[1:]
+                    tgt = (n_shards, pb) + rest
+                isize = np.dtype(dtype).itemsize
+                if isize > 1:
+                    seg = seg.reshape(tgt + (isize,))
+                else:
+                    seg = seg.reshape(tgt)
+                val = jax.lax.bitcast_convert_type(seg, jdt)
+                if dtype == np.bool_:
+                    val = val.astype(jnp.bool_)
+                if stacked:
+                    val = val.transpose((1, 0, 2) + tuple(
+                        range(3, 3 + len(rest))))
+                    val = val.reshape((shape[0], n_shards * pb) + rest)
+                else:
+                    val = val.reshape((n_shards * pb,) + rest)
+                out[name] = val
         if augment is not None:
-            from ..ops.augment import device_augment_fn
-            leaf_name, kind, pad = augment
-            fn = device_augment_fn(kind, pad)
-            seg = jax.lax.slice(flat, (0, seed_off), (1, seed_off + 4))
-            ctr = jax.lax.bitcast_convert_type(seg.reshape((4,)),
-                                               jnp.uint32)
-            akey = jax.random.fold_in(
-                jax.random.PRNGKey(augment_seed), ctr)
-            img = out[leaf_name]
-            if stacked:
-                # one key per scan step of the fused-loop group, applied
-                # with lax.map so the float32 intermediate is one
-                # microbatch at a time, not the whole (K, B, ...) group
-                keys = jax.random.split(akey, img.shape[0])
-                img = jax.lax.map(lambda kv: fn(kv[0], kv[1]),
-                                  (img, keys))
-            else:
-                img = fn(img, akey)
-            out[leaf_name] = img
+            with jax.named_scope("augment"):
+                from ..ops.augment import device_augment_fn
+                leaf_name, kind, pad = augment
+                fn = device_augment_fn(kind, pad)
+                seg = jax.lax.slice(flat, (0, seed_off), (1, seed_off + 4))
+                ctr = jax.lax.bitcast_convert_type(seg.reshape((4,)),
+                                                   jnp.uint32)
+                akey = jax.random.fold_in(
+                    jax.random.PRNGKey(augment_seed), ctr)
+                img = out[leaf_name]
+                if stacked:
+                    # one key per scan step of the fused-loop group, applied
+                    # with lax.map so the float32 intermediate is one
+                    # microbatch at a time, not the whole (K, B, ...) group
+                    keys = jax.random.split(akey, img.shape[0])
+                    img = jax.lax.map(lambda kv: fn(kv[0], kv[1]),
+                                      (img, keys))
+                else:
+                    img = fn(img, akey)
+                out[leaf_name] = img
         return out
 
     out_sh = {name: leaf_sh for name, *_ in fields}
@@ -927,9 +929,10 @@ class CoalescedStager:
     addressable devices' regions). Thread-safe: one lock serializes pack +
     issue, so the train and eval staging threads may share a stager.
 
-    Stage counters: pack time → "stage", transfer issue → "transfer"
-    (``records_stages`` tells device_prefetch to only add its completion
-    wait, not re-count items).
+    Stage spans and counters: the pack is ``input.stage`` → "stage", the
+    transfer issue ``input.issue`` → "transfer" (``records_stages`` tells
+    device_prefetch to only add its completion wait, not to time the put
+    again or re-count items).
 
     ``augment`` = (leaf_name, kind, pad): fuse the device-side train
     augmentation for that leaf into the unpack program (see
@@ -970,7 +973,7 @@ class CoalescedStager:
         return self.put(batch)
 
     def put(self, batch):
-        from ..utils.metrics import input_stages
+        from ..telemetry.tracer import span
         batch = coerce_batch_dtypes(
             {k: np.asarray(v) for k, v in batch.items()})
         items = 0
@@ -979,29 +982,28 @@ class CoalescedStager:
                 items = int(batch[key].size)
                 break
         with self._lock:
-            t0 = time.perf_counter()
-            spec = self._spec_of(batch)
-            layout = self._layouts.get(spec)
-            if layout is None:
-                layout = _StagingLayout(self.mesh, spec, self.stacked,
-                                        self.ring, self._shards,
-                                        augment=self.augment,
-                                        augment_seed=self.augment_seed)
-                self._layouts[spec] = layout
-            ctr = self._put_ctr
-            self._put_ctr += 1
-            slot, views = layout.pack(batch, self._shards, self._lo_shard,
-                                      ctr)
-            t1 = time.perf_counter()
+            with span("input.stage") as pack:
+                spec = self._spec_of(batch)
+                layout = self._layouts.get(spec)
+                if layout is None:
+                    layout = _StagingLayout(self.mesh, spec, self.stacked,
+                                            self.ring, self._shards,
+                                            augment=self.augment,
+                                            augment_seed=self.augment_seed)
+                    self._layouts[spec] = layout
+                ctr = self._put_ctr
+                self._put_ctr += 1
+                slot, views = layout.pack(batch, self._shards,
+                                          self._lo_shard, ctr)
             nbytes = len(views) * layout.region_nbytes
-            input_stages.add("stage", t1 - t0, items=items, nbytes=nbytes)
-            pieces = _issue_device_put(views, self._devices)
-            layout.inflight[slot] = pieces
-            flat = jax.make_array_from_single_device_arrays(
-                (self._n_shards, layout.region_nbytes),
-                NamedSharding(self.mesh, P(("data", "fsdp"))), pieces)
-            input_stages.add("transfer", time.perf_counter() - t1,
-                             items=items, nbytes=nbytes)
+            pack.charge("stage", items=items, nbytes=nbytes)
+            with span("input.issue") as issue:
+                pieces = _issue_device_put(views, self._devices)
+                layout.inflight[slot] = pieces
+                flat = jax.make_array_from_single_device_arrays(
+                    (self._n_shards, layout.region_nbytes),
+                    NamedSharding(self.mesh, P(("data", "fsdp"))), pieces)
+            issue.charge("transfer", items=items, nbytes=nbytes)
             return StagedBatch(flat, layout.unpack)
 
     def put_now(self, batch):

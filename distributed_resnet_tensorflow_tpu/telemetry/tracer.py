@@ -8,9 +8,26 @@ because distributed step-time mysteries cannot be debugged from scalars
   * ``span("input.wait")`` — a context manager recording one timed event
     per use into a BOUNDED in-memory ring (a crashed/wedged run holds the
     last ~N events per process, like an aircraft flight recorder). The hot
-    path is two ``perf_counter`` reads plus one locked deque append — cheap
-    enough to leave on in production (the bench acceptance bar is <2% on
-    the CIFAR headline).
+    path is two ``perf_counter`` reads, one locked deque append, one
+    ``(count, seconds)`` cell and one ``TraceMe`` — cheap enough to leave
+    on in production (the bench acceptance bar is <2% on the CIFAR
+    headline).
+  * ONE CLOCK: every span also enters a ``jax.profiler.TraceAnnotation``
+    of its own name (a ``StepTraceAnnotation`` when it carries
+    ``step_num``), so whenever a profiler session listens — the
+    benchmark's ``--trace 1``, ``telemetry.profile_on_anomaly``, an
+    operator's own ``start_trace`` — the span is an event on the host
+    plane of the ``.xplane.pb``, on the profiler's clock, on the thread
+    that ran it, beside the device ops. No switch: ``TraceMe`` records
+    only while a session is active. A process in which ``jax`` is not
+    loaded (a spawned worker that never imports it) skips the annotation.
+  * ONE TIMER: the span is the only thing that reads the clock at a site.
+    On exit it charges a ``(count, seconds)`` cell under its own name in
+    ``utils.metrics.input_stages``; a site that feeds a legacy stage key
+    (``decode``/``echo``/``stack``/``stage``/``transfer``/
+    ``dispatch_wait``, with items and bytes) calls ``sp.charge(stage,
+    ...)`` after the ``with`` — charged from the span's measured
+    duration, never from a second ``perf_counter`` pair.
   * ``FlightRecorder.dump()`` — serialize the ring as a Chrome-trace /
     Perfetto ``trace.json`` (``{"traceEvents": [...]}``, complete "X"
     events with per-thread lanes and thread-name metadata), atomically.
@@ -41,15 +58,24 @@ import collections
 import json
 import logging
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, Optional
+
+from ..utils.metrics import input_stages
 
 log = logging.getLogger(__name__)
 
 #: bump when the trace.json event shape changes (consumers key on it via
 #: the ``trace_dump`` metrics row and the file's otherData block)
-SPAN_SCHEMA_VERSION = 9  # 9: + route.attempt/route.health (serving
+SPAN_SCHEMA_VERSION = 10  # 10: + train.hooks/train.build/
+#                               train.init_state/input.finalize/
+#                               input.issue; input.stage narrowed to the
+#                               pack; every span is also a profiler
+#                               TraceAnnotation and a (count, seconds)
+#                               cell in input_stages (PR 25)
+#                          9: + route.attempt/route.health (serving
 #                              fleet front door, round 19)
 #                          8: + plan.predict/plan.drift_check (what-if
 #                              performance planner, round 17)
@@ -74,19 +100,40 @@ SPAN_SCHEMA_VERSION = 9  # 9: + route.attempt/route.health (serving
 #: runtime warns once). Value = one-line description for the docs.
 SPAN_CATALOG = {
     # input pipeline (data/device_prefetch.py, data/imagenet.py)
-    "input.decode": "one image decoded + cropped (decode worker thread)",
-    "input.stack": "K host batches drawn + np.stack'ed (stacker thread)",
+    "input.decode": "one image decoded + cropped (imagenet decode worker) "
+                    "or one batch gathered + augmented (cifar iterator); "
+                    "charges the 'decode' stage",
+    "input.stack": "K host batches np.stack'ed (stacker thread; charges "
+                   "the 'stack' stage)",
     "input.echo": "one source batch absorbed into the decoded-sample echo "
-                  "cache (data/echo.py; emission busy time rides the "
-                  "'echo' stage counter)",
-    "input.stage": "host batch packed/staged by the put path (staging "
-                   "thread; CoalescedStager pack + issue)",
-    "input.transfer": "wait for the previous batch's H2D transfer to "
-                      "complete (staging thread)",
-    "input.wait": "train loop blocked waiting for the next device batch "
-                  "(goodput: input_wait)",
+                  "cache (data/echo.py)",
+    "input.echo_emit": "one batch drawn from the echo cache (charges the "
+                       "'echo' stage)",
+    "input.stage": "host batch packed into the staging ring "
+                   "(CoalescedStager.put, staging thread; charges the "
+                   "'stage' stage)",
+    "input.issue": "the batch's host→device transfer issued: the "
+                   "stager's device_put + global-array assembly, or the "
+                   "whole per-leaf put (staging thread; charges "
+                   "'transfer') — what XlaLinearize gaps are put down to",
+    "input.transfer": "wait for an issued H2D transfer to complete "
+                      "(staging thread; charges 'transfer')",
+    "input.wait": "train (or eval) loop blocked waiting for the next "
+                  "device batch, its input.finalize included (goodput: "
+                  "input_wait in the train loop; charges 'dispatch_wait')",
+    "input.finalize": "consumer-thread finalize of a StagedBatch: the "
+                      "dispatch of the unpack(+augment) program (a child "
+                      "of input.wait)",
     # train loop (train/loop.py)
-    "train.step": "one optimizer-step (or fused K-step) dispatch",
+    "train.build": "Trainer.__init__: mesh, model, optimizer, resolvers, "
+                   "stagers",
+    "train.init_state": "Trainer.init_state: the jitted state "
+                        "initialisation + placement",
+    "train.step": "one optimizer-step (or fused K-step) dispatch: host "
+                  "time inside the jitted call; a StepTraceAnnotation "
+                  "(step_num = first step of the dispatch)",
+    "train.hooks": "the hook loop after one dispatch (all hooks, one "
+                   "span)",
     "eval.round": "one full evaluation round (goodput: eval)",
     "eval.batch": "one eval batch: stage wait + step dispatch",
     # checkpointing (checkpoint/manager.py)
@@ -173,9 +220,11 @@ _UNKNOWN_SPANS_WARNED: set = set()
 
 
 class _NoopSpan:
-    """Shared do-nothing span for a disabled recorder."""
+    """Shared do-nothing span for a disabled recorder: no ring entry, no
+    annotation, no counter."""
 
     __slots__ = ()
+    seconds = None  # nothing was measured
 
     def __enter__(self):
         return self
@@ -183,19 +232,39 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    def charge(self, stage, items=0, nbytes=0, extra_s=0.0):
+        pass
+
 
 _NOOP = _NoopSpan()
 
+# (TraceAnnotation, StepTraceAnnotation) once jax is loaded in this process
+_ANNOTATIONS = None
+
+
+def _annotations():
+    """jax.profiler's annotation classes, or None in a process that has not
+    loaded jax (the tracer must not be what imports it there)."""
+    global _ANNOTATIONS
+    if _ANNOTATIONS is None and "jax" in sys.modules:
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+        _ANNOTATIONS = (TraceAnnotation, StepTraceAnnotation)
+    return _ANNOTATIONS
+
 
 class _Span:
-    __slots__ = ("_rec", "name", "category", "args", "_t0", "_counted")
+    __slots__ = ("_rec", "name", "category", "args", "step_num", "_t0",
+                 "_counted", "_ann", "seconds")
 
     def __init__(self, rec: "FlightRecorder", name: str,
-                 category: Optional[str], args: Optional[dict]):
+                 category: Optional[str], args: Optional[dict],
+                 step_num: Optional[int] = None):
         self._rec = rec
         self.name = name
         self.category = category
         self.args = args
+        self.step_num = step_num
+        self.seconds = None  # the measured duration, once exited
 
     def __enter__(self):
         self._counted = False
@@ -209,12 +278,25 @@ class _Span:
             if depth:
                 self.category = None
         self._t0 = time.perf_counter()
+        # the annotation starts inside the ring entry's interval and ends
+        # inside it; TraceMe records only while a profiler session listens
+        ann = _annotations()
+        if ann is None:
+            self._ann = None
+        elif self.step_num is not None:
+            self._ann = ann[1](self.name, step_num=self.step_num)
+        elif self.args:
+            self._ann = ann[0](self.name, **self.args)
+        else:
+            self._ann = ann[0](self.name)
         return self
 
     def __exit__(self, *exc):
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         t1 = time.perf_counter()
         rec = self._rec
-        dur = t1 - self._t0
+        self.seconds = dur = t1 - self._t0
         tid = threading.get_ident()
         if tid not in rec._thread_names:
             rec._thread_names[tid] = threading.current_thread().name
@@ -225,7 +307,16 @@ class _Span:
         if self.category is not None:
             from .goodput import goodput
             goodput.add(self.category, dur)
+        input_stages.add(self.name, dur)
         return False
+
+    def charge(self, stage: str, items: int = 0, nbytes: int = 0,
+               extra_s: float = 0.0) -> None:
+        """After the ``with``: charge the measured duration (plus
+        ``extra_s``, another span's ``seconds``) to a legacy stage key of
+        ``utils.metrics.input_stages`` with its items and bytes."""
+        input_stages.add(stage, self.seconds + extra_s, items=items,
+                         nbytes=nbytes)
 
 
 class FlightRecorder:
@@ -286,11 +377,14 @@ class FlightRecorder:
         return len(self._events)
 
     # -- recording ----------------------------------------------------------
-    def span(self, name: str, category: Optional[str] = None, **args):
+    def span(self, name: str, category: Optional[str] = None,
+             step_num: Optional[int] = None, **args):
         """Context manager timing one event. ``category`` charges the
         duration to the goodput meter (outermost categorized span per
-        thread only); ``**args`` ride into the trace event (keep them off
-        hot paths — the dict allocation is the cost)."""
+        thread only); ``**args`` ride into the trace event and the
+        profiler annotation (keep them off hot paths — the dict
+        allocation is the cost). ``step_num`` makes the profiler event a
+        step (``StepTraceAnnotation``) and rides nowhere else."""
         if not self._enabled:
             return _NOOP
         if name not in SPAN_CATALOG and name not in _UNKNOWN_SPANS_WARNED:
@@ -299,7 +393,7 @@ class FlightRecorder:
                 "span %r is not declared in telemetry.tracer.SPAN_CATALOG "
                 "— register it (the registry-drift lint rejects "
                 "undeclared literals)", name)
-        return _Span(self, name, category, args or None)
+        return _Span(self, name, category, args or None, step_num)
 
     # -- dumping ------------------------------------------------------------
     def trace_events(self) -> list:

@@ -177,14 +177,19 @@ def make_train_step(schedule: Callable, weight_decay: float,
         apply_gradients_fn = lambda state, grads: \
             state.apply_gradients(grads)  # noqa: E731
 
+    # the named scopes are metadata only: they name the device ops of a
+    # profiler trace (input_prep / forward / transpose(jvp(forward)) = the
+    # backward pass / optimizer), so the step's device time splits by phase
     def prep(images, step, midx=None):
         if augment_fn is None:
             return images
-        rng = jax.random.fold_in(jax.random.PRNGKey(augment_seed), step)
-        if midx is not None:  # distinct draws per accumulation microbatch
-            rng = jax.random.fold_in(rng, midx)
-        return augment_fn(images, rng)
+        with jax.named_scope("input_prep"):
+            rng = jax.random.fold_in(jax.random.PRNGKey(augment_seed), step)
+            if midx is not None:  # distinct draws per accumulation microbatch
+                rng = jax.random.fold_in(rng, midx)
+            return augment_fn(images, rng)
 
+    @jax.named_scope("forward")
     def loss_fn(params, batch_stats, images, labels, apply_fn):
         variables = {"params": params, "batch_stats": batch_stats}
         if precision is not None:
@@ -360,6 +365,12 @@ class Trainer:
     """
 
     def __init__(self, cfg, mesh: Optional[Mesh] = None):
+        # the trainer's own construction is a third of set-up that no
+        # compile counter covers (PERF.md): one span, train.build
+        with span("train.build"):
+            self._build(cfg, mesh)
+
+    def _build(self, cfg, mesh: Optional[Mesh]) -> None:
         self.cfg = cfg
         self.mesh = mesh if mesh is not None else create_mesh(cfg.mesh)
         from ..models import create_model
@@ -684,6 +695,7 @@ class Trainer:
         min_size = self._zero1_min_size()
         plan = self._overlap
 
+        @jax.named_scope("optimizer")
         def apply_gradients_fn(state, grads):
             from jax.lax import with_sharding_constraint
             from ..parallel.sharding import zero1_grad_specs
@@ -804,25 +816,28 @@ class Trainer:
 
     # -- state ------------------------------------------------------------
     def init_state(self, seed: Optional[int] = None) -> TrainState:
-        rng = jax.random.PRNGKey(self.cfg.train.seed if seed is None else seed)
-        c = self.cfg
-        # one example per batch shard: shard_map-based ops (ring attention)
-        # need the init dummy batch divisible by the batch mesh axes
-        nb = batch_shard_count(self.mesh)
-        shape = (nb, c.data.image_size, c.data.image_size, 3) \
-            if c.model.name != "logistic" else (nb, c.model.input_size)
-        self.state = create_train_state(
-            rng, self.model, self.tx, shape, mesh=self.mesh,
-            zero1=self._zero1, zero1_min_size=self._zero1_min_size())
-        if self._precision is not None:
-            # the policy's checkpoint contract: f32 MASTERS only — a cast
-            # param leaf here would bake the compute dtype into every
-            # checkpoint this run writes (parallel/precision.py)
-            from ..parallel.precision import (check_master_dtypes,
-                                              precision_stats)
-            check_master_dtypes(self.state.params,
-                                self._precision.master_dtype)
-            precision_stats.record_params(self.state.params)
+        with span("train.init_state"):
+            rng = jax.random.PRNGKey(
+                self.cfg.train.seed if seed is None else seed)
+            c = self.cfg
+            # one example per batch shard: shard_map-based ops (ring
+            # attention) need the init dummy batch divisible by the batch
+            # mesh axes
+            nb = batch_shard_count(self.mesh)
+            shape = (nb, c.data.image_size, c.data.image_size, 3) \
+                if c.model.name != "logistic" else (nb, c.model.input_size)
+            self.state = create_train_state(
+                rng, self.model, self.tx, shape, mesh=self.mesh,
+                zero1=self._zero1, zero1_min_size=self._zero1_min_size())
+            if self._precision is not None:
+                # the policy's checkpoint contract: f32 MASTERS only — a
+                # cast param leaf here would bake the compute dtype into
+                # every checkpoint this run writes (parallel/precision.py)
+                from ..parallel.precision import (check_master_dtypes,
+                                                  precision_stats)
+                check_master_dtypes(self.state.params,
+                                    self._precision.master_dtype)
+                precision_stats.record_params(self.state.params)
         return self.state
 
     # -- jitted steps ------------------------------------------------------
@@ -1268,18 +1283,22 @@ class Trainer:
                 if batch_uses <= 0:
                     try:
                         # flight-recorder + goodput: time blocked on input
-                        # (telemetry/; the span is ~2 clock reads when
-                        # enabled, a shared no-op otherwise)
-                        with span("input.wait", category="input_wait"), \
+                        # (telemetry/; the span is the one timer of the
+                        # site — ring, goodput, profiler annotation and the
+                        # dispatch_wait stage all read it — and a shared
+                        # no-op when telemetry is off)
+                        with span("input.wait",
+                                  category="input_wait") as wait, \
                                 fetch_cm():
                             batch = next(dev_iter)
+                        wait.charge("dispatch_wait", items=1)
                     except StopIteration:
                         # finite stream exhausted: end training cleanly,
                         # same contract as the fused k>1 path
                         return self.state, metrics
                     batch_uses = reuse
                 batch_uses -= 1
-                with span("train.step"):
+                with span("train.step", step_num=step):
                     self.state, metrics = step_fn(self.state, batch)
                 self._maybe_probe_comm()
                 if self._comm_retuned:
@@ -1288,8 +1307,9 @@ class Trainer:
                     # accessor is a cached-attribute check afterwards)
                     step_fn = self.jitted_index_step() if use_idx \
                         else self.jitted_train_step()
-                for h in hooks:
-                    h(step + 1, self.state, metrics)
+                with span("train.hooks"):
+                    for h in hooks:
+                        h(step + 1, self.state, metrics)
                 if stop_fn is not None and stop_fn():
                     return self.state, metrics
             return self.state, metrics
@@ -1331,14 +1351,15 @@ class Trainer:
                 if stop_fn is not None and stop_fn():
                     return i - offset
                 b = jax.tree_util.tree_map(lambda x, i=i: x[i], stacked)
-                with span("train.step"):
+                with span("train.step", step_num=step):
                     self.state, metrics = step_fn(self.state, b)
                 self._maybe_probe_comm()
                 if self._comm_retuned:
                     step_fn = single_fn()  # autotuned rebuild — swap in
                 step += 1
-                for h in hooks:
-                    h(step, self.state, metrics)
+                with span("train.hooks"):
+                    for h in hooks:
+                        h(step, self.state, metrics)
             return count
 
         # 1) consume a previous tail's remainder, one step at a time
@@ -1358,14 +1379,16 @@ class Trainer:
             if stop_fn is not None and stop_fn():
                 return self.state, metrics
             try:
-                with span("input.wait", category="input_wait"), fetch_cm():
+                with span("input.wait", category="input_wait") as wait, \
+                        fetch_cm():
                     stacked = next(stacked_iter)
+                wait.charge("dispatch_wait", items=1)
             except StopIteration:
                 return self.state, metrics
             for _r in range(reuse):
                 if step + k > num_steps:
                     break
-                with span("train.step"):
+                with span("train.step", step_num=step):
                     self.state, metrics = multi_fn(self.state, stacked)
                 self._maybe_probe_comm()
                 if self._comm_retuned:
@@ -1373,8 +1396,9 @@ class Trainer:
                     multi_fn = self.jitted_index_multi_step(k) if use_idx \
                         else self.jitted_multi_step(k)
                 step += k
-                for h in hooks:
-                    h(step, self.state, metrics)
+                with span("train.hooks"):
+                    for h in hooks:
+                        h(step, self.state, metrics)
                 if _r + 1 < reuse and stop_fn is not None and stop_fn():
                     return self.state, metrics
         # 3) tail shorter than k: draw one more group, run the first
@@ -1447,7 +1471,11 @@ class Trainer:
                         hb.tick(phase="eval_init" if i == 0 else "eval")
                     with span("eval.batch"):
                         try:
-                            batch = next(dev_iter)
+                            # no goodput category of its own: the round's
+                            # `eval` is the outermost and takes the time
+                            with span("input.wait") as wait:
+                                batch = next(dev_iter)
+                            wait.charge("dispatch_wait", items=1)
                         except StopIteration:
                             # one-pass streams (ImageNet eval) can exhaust
                             # before num_batches; single-process, return

@@ -35,6 +35,7 @@ class TrainState(struct.PyTreeNode):
     apply_fn: Callable = struct.field(pytree_node=False)
     tx: optax.GradientTransformation = struct.field(pytree_node=False)
 
+    @jax.named_scope("optimizer")  # names the update's ops in a trace
     def apply_gradients(self, grads) -> "TrainState":
         updates, new_opt_state = self.tx.update(grads, self.opt_state, self.params)
         new_params = optax.apply_updates(self.params, updates)

@@ -27,10 +27,13 @@ log = logging.getLogger(__name__)
 
 class StageStats:
     """Thread-safe busy-time/item counters for the input-pipeline stages
-    (decode / stack / stage / transfer / dispatch_wait).
+    (decode / echo / stack / stage / transfer / dispatch_wait) and, under
+    its own name, for every flight-recorder span (telemetry/tracer.py).
 
-    Every stage worker calls ``add(stage, seconds, items=...)`` around its
-    unit of work; totals are kept PER THREAD so ``rates()`` can estimate a
+    A span's exit calls ``add(<span name>, seconds)``; a site that feeds a
+    stage key charges it from the same span (``sp.charge(stage, items=...,
+    nbytes=...)``) — no site reads the clock a second time. Totals are
+    kept PER THREAD so ``rates()`` can estimate a
     stage's throughput as items / busiest-thread-seconds — the number that
     stays honest for multi-worker stages (a 4-thread decode pool that spent
     40 thread-seconds decoding 1000 images over a 10 s wall ran at ~100
@@ -95,7 +98,8 @@ class StageStats:
 
 # process-global input-pipeline telemetry: decode workers, the batch
 # stacker, the echo cache, the staging/transfer thread and the dispatch
-# loop all feed this one registry; InputStagesHook exports it to
+# loop all feed this one registry through their spans (so does every
+# other span, under its own name); InputStagesHook exports it to
 # metrics.jsonl and bench.py reads it for end-to-end attribution. Decode
 # worker PROCESSES (data.decode_processes > 0) accumulate in their own
 # process and ship counter snapshots back over the result queue; the
@@ -293,10 +297,10 @@ EVENT_SCHEMAS = {
         "emitted_by": "train/hooks.py InputStagesHook",
         "fields": {
             "step": "step at export time",
-            "stages": "per-stage {count, items, seconds, "
-                      "max_thread_seconds, workers, bytes} — cumulative "
-                      "since process start/reset (difference consecutive "
-                      "rows for window rates)",
+            "stages": "per stage key and per span name {count, items, "
+                      "seconds, max_thread_seconds, workers, bytes} — "
+                      "cumulative since process start/reset (difference "
+                      "consecutive rows for window rates)",
         },
     },
     "input_echo": {

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import contextlib
 import logging
-from typing import Any, Dict, Iterator, Optional
+from typing import Any, Iterator, Optional
 
 import jax
 
@@ -120,16 +120,3 @@ def trace_window(logdir: str, duration_secs: float = 5.0) -> str:
     log.info("jax.profiler window (%.1fs) captured to %s",
              duration_secs, logdir)
     return logdir
-
-
-def summarize_model(trainer, batch=None) -> Dict[str, Any]:
-    """Params + per-step FLOPs + peak for the trainer's compiled step."""
-    out: Dict[str, Any] = {
-        "params": count_params(trainer.state.params),
-        "devices": jax.device_count(),
-        "peak_tflops_per_chip": detect_peak_tflops(),
-    }
-    if batch is not None:
-        step = trainer.jitted_train_step()
-        out["flops_per_step"] = flops_per_step(step, trainer.state, batch)
-    return out

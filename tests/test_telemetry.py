@@ -113,6 +113,256 @@ def test_span_catalog_covers_every_emitted_literal():
 
 
 # ---------------------------------------------------------------------------
+# one clock: spans in a profiler trace; one timer: spans charge the counters
+# ---------------------------------------------------------------------------
+
+def _tiny_trainer(**overrides):
+    from distributed_resnet_tensorflow_tpu.train import Trainer
+    from distributed_resnet_tensorflow_tpu.utils.config import get_preset
+    cfg = get_preset("smoke")
+    cfg.model.compute_dtype = "float32"
+    cfg.model.resnet_size = 8
+    cfg.model.num_classes = 4
+    cfg.data.image_size = 8
+    cfg.train.batch_size = 16
+    for key, value in overrides.items():
+        cfg.override(key, value)
+    tr = Trainer(cfg)
+    tr.init_state()
+    return tr
+
+
+def _host_events(trace_dir, names):
+    """(name, start_ns, duration_ns, stats, line) of the host plane's
+    events under ``names``, from the one .xplane.pb under trace_dir."""
+    from jax.profiler import ProfileData
+    files = glob.glob(os.path.join(str(trace_dir), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    assert len(files) == 1, files
+    out = []
+    for plane in ProfileData.from_file(files[0]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in names:
+                    out.append((e.name, e.start_ns, e.duration_ns,
+                                dict(e.stats), line.name))
+    return out
+
+
+def test_spans_enter_the_profiler_trace_inside_their_ring_interval(tmp_path):
+    """Under a profiler session every span is an event on the host plane
+    of the .xplane.pb, under its own name, on the profiler's clock, inside
+    the ring entry's interval; train.step is the trace's step event. A
+    span that ran with no session listening is not there."""
+    import jax
+
+    from distributed_resnet_tensorflow_tpu.data import (
+        learnable_synthetic_iterator)
+    tr = _tiny_trainer()
+    it = learnable_synthetic_iterator(16, 8, 4)
+    tr.train(it, num_steps=2)  # compile outside the session
+    jax.block_until_ready(tr.state)
+    with recorder.span("input.stack"):  # no session: ring only
+        pass
+    recorder.clear()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with recorder.span("input.wait"):
+            time.sleep(0.003)
+        tr.train(it, num_steps=5, start_step=2)
+        jax.block_until_ready(tr.state)
+    finally:
+        jax.profiler.stop_trace()
+    ring = [e for e in recorder.trace_events() if e["ph"] == "X"]
+    names = {"input.wait", "train.step", "train.hooks", "input.stack"}
+    host = _host_events(tmp_path, names)
+    assert "input.stack" not in {h[0] for h in host}
+    assert {"input.wait", "train.step", "train.hooks"} <= {h[0] for h in host}
+    steps = sorted((h for h in host if h[0] == "train.step"),
+                   key=lambda h: h[1])
+    assert [h[3].get("step_num") for h in steps] == [2, 3, 4]
+    assert all(h[3].get("_r") == 1 for h in steps)  # a step event
+    # the two clocks differ by one offset: pair the k-th event of a name
+    # in the trace with the k-th in the ring, take the median offset, and
+    # hold every annotation inside its ring interval (200 us of slack
+    # for the drift between the two clocks over the session)
+    pairs = []
+    for name in ("input.wait", "train.step", "train.hooks"):
+        mine = sorted((e for e in ring if e["name"] == name),
+                      key=lambda e: e["ts"])
+        theirs = sorted((h for h in host if h[0] == name),
+                        key=lambda h: h[1])
+        assert len(mine) == len(theirs), name
+        pairs += list(zip(mine, theirs))
+    offset = float(np.median([e["ts"] - h[1] / 1e3 for e, h in pairs]))
+    for e, h in pairs:
+        start, end = h[1] / 1e3 + offset, (h[1] + h[2]) / 1e3 + offset
+        assert e["ts"] - 200 <= start <= end <= e["ts"] + e["dur"] + 200, \
+            (e, h)
+        assert h[2] / 1e3 <= e["dur"] + 1  # inside: never longer
+    # the loop thread's three spans share one line of the host plane
+    assert len({h[4] for _, h in pairs}) == 1
+
+
+def test_disabled_span_is_the_shared_noop_and_charges_nothing():
+    from distributed_resnet_tensorflow_tpu.telemetry import tracer
+    from distributed_resnet_tensorflow_tpu.utils.metrics import input_stages
+    input_stages.reset()
+    rec = FlightRecorder(ring=64, enabled=False)
+    sp = rec.span("input.wait", category="input_wait")
+    assert sp is tracer._NOOP and sp is rec.span("train.step", step_num=3)
+    with sp as inside:
+        pass
+    inside.charge("dispatch_wait", items=1)
+    assert inside.seconds is None and len(rec) == 0
+    assert input_stages.snapshot() == {}
+
+
+@pytest.mark.parametrize("name", sorted(SPAN_CATALOG))
+def test_every_span_charges_a_cell_under_its_own_name(name):
+    """One timer: the span's exit leaves (count, seconds) under its name in
+    input_stages, so a reader of the snapshot needs no second counter."""
+    from distributed_resnet_tensorflow_tpu.utils.metrics import input_stages
+    input_stages.reset()
+    rec = FlightRecorder(ring=16)
+    for _ in range(3):
+        with rec.span(name) as sp:
+            pass
+    cell = input_stages.snapshot()[name]
+    assert cell["count"] == 3 and cell["items"] == 0 and cell["bytes"] == 0
+    assert 0 < cell["seconds"] < 1 and sp.seconds <= cell["seconds"]
+    assert len(rec) == 3
+
+
+def test_span_charge_feeds_a_legacy_stage_from_its_own_duration():
+    from distributed_resnet_tensorflow_tpu.utils.metrics import input_stages
+    input_stages.reset()
+    rec = FlightRecorder(ring=16)
+    with rec.span("input.transfer") as sp:
+        time.sleep(0.002)
+    sp.charge("transfer", items=7, nbytes=11, extra_s=0.5)
+    snap = input_stages.snapshot()
+    assert snap["transfer"]["count"] == 1 and snap["transfer"]["items"] == 7
+    assert snap["transfer"]["bytes"] == 11
+    assert snap["transfer"]["seconds"] == pytest.approx(sp.seconds + 0.5)
+    assert snap["input.transfer"]["seconds"] == pytest.approx(sp.seconds)
+
+
+@pytest.mark.parametrize("coalesced", ["on", "off"])
+def test_put_paths_keep_the_legacy_stage_cells(coalesced, mesh8):
+    """N puts through device_prefetch: the coalesced stager charges N
+    `stage` and N + N `transfer` cells (issue, then completion wait) with
+    the items and bytes of its packs; the per-leaf path charges ONE
+    `transfer` cell a batch (issue + wait) and no `stage` — what the
+    benchmark's stage_ms divides by."""
+    from distributed_resnet_tensorflow_tpu.data.device_prefetch import (
+        device_prefetch)
+    from distributed_resnet_tensorflow_tpu.parallel.sharding import (
+        CoalescedStager, shard_batch)
+    from distributed_resnet_tensorflow_tpu.utils.metrics import input_stages
+    n, b = 5, 16
+    rng = np.random.RandomState(0)
+    batches = [{"images": rng.rand(b, 8, 8, 3).astype(np.float32),
+                "labels": rng.randint(0, 4, (b,)).astype(np.int32)}
+               for _ in range(n)]
+    input_stages.reset()
+    if coalesced == "on":
+        stager = CoalescedStager(mesh8, ring=3)
+        direct = [stager.put(dict(x)) for x in batches]
+        snap = input_stages.snapshot()
+        nbytes = n * direct[0].flat.size
+        for stage, span_name in (("stage", "input.stage"),
+                                 ("transfer", "input.issue")):
+            assert snap[stage]["count"] == n == snap[span_name]["count"]
+            assert snap[stage]["items"] == n * b
+            assert snap[stage]["bytes"] == nbytes
+            assert snap[stage]["seconds"] == pytest.approx(
+                snap[span_name]["seconds"])
+        put = stager
+    else:
+        put = lambda x: shard_batch(x, mesh8)  # noqa: E731
+    input_stages.reset()
+    got = list(device_prefetch(iter(batches), put, depth=2))
+    assert len(got) == n
+    snap = input_stages.snapshot()
+    if coalesced == "on":
+        assert snap["stage"]["count"] == n
+        assert snap["transfer"]["count"] == 2 * n
+        assert snap["transfer"]["items"] == n * b
+        assert snap["input.finalize"]["count"] == n
+    else:
+        assert "stage" not in snap and "input.finalize" not in snap
+        assert snap["transfer"]["count"] == n
+        assert snap["transfer"]["items"] == n * b
+        assert snap["transfer"]["seconds"] == pytest.approx(
+            snap["input.issue"]["seconds"] + snap["input.transfer"]["seconds"])
+    assert snap["input.issue"]["count"] == snap["input.transfer"]["count"] == n
+
+
+# ---------------------------------------------------------------------------
+# names the device trace keeps: scopes on the step's ops, names on kernels
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("zero1", ["off", "on"])
+def test_train_step_ops_carry_forward_backward_optimizer_scopes(zero1):
+    """The lowered step (debug info on) names its ops by phase, so a
+    profiler trace can split the step's device time: forward, its
+    transpose (the backward pass) and the optimizer (plain and ZeRO-1)."""
+    tr = _tiny_trainer(**{"optimizer.zero1": zero1})
+    assert tr.zero1_active == (zero1 == "on")
+    batch = {"images": np.zeros((16, 8, 8, 3), np.float32),
+             "labels": np.zeros((16,), np.int32)}
+    text = tr.jitted_train_step().lower(
+        tr.state, tr._put_batch(batch)).as_text(debug_info=True)
+    for scope in ("/jvp(forward)/", "/transpose(jvp(forward))/",
+                  "/optimizer/"):
+        assert scope in text, scope
+
+
+@pytest.mark.parametrize("augment", [None, ("images", "imagenet_train", 0)])
+def test_unpack_program_ops_carry_unpack_and_augment_scopes(augment, mesh8):
+    from distributed_resnet_tensorflow_tpu.parallel.sharding import (
+        CoalescedStager)
+    rng = np.random.RandomState(0)
+    batch = {"images": rng.randint(0, 256, (8, 8, 8, 3)).astype(np.uint8),
+             "labels": rng.randint(0, 10, (8,)).astype(np.int32)}
+    staged = CoalescedStager(mesh8, ring=3, augment=augment).put(batch)
+    text = staged._unpack.lower(staged.flat).as_text(debug_info=True)
+    assert "/unpack/" in text
+    assert ("/augment/" in text) == (augment is not None)
+
+
+@pytest.mark.parametrize("kernel", [
+    "softmax_xent_fwd", "softmax_xent_bwd",
+    "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"])
+def test_pallas_kernels_lower_under_their_names(kernel):
+    """Lowered for the TPU (no chip needed to lower), each Pallas call is
+    a custom call whose kernel_name is the stable name a trace's events
+    are found by."""
+    import jax
+    import jax.numpy as jnp
+    if kernel.startswith("softmax"):
+        from distributed_resnet_tensorflow_tpu.ops.pallas import softmax_xent
+        labels = jnp.zeros((16,), jnp.int32)
+        fn = jax.value_and_grad(lambda x: softmax_xent(x, labels).sum())
+        args = (jnp.ones((16, 1001), jnp.float32),)
+    else:
+        from distributed_resnet_tensorflow_tpu.ops.pallas import (
+            flash_attention)
+        fn = jax.value_and_grad(
+            lambda q, k, v: flash_attention(q, k, v).astype(
+                jnp.float32).sum(), argnums=(0, 1, 2))
+        args = (jnp.ones((1, 256, 2, 64), jnp.bfloat16),) * 3
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert f'kernel_name = "{kernel}"' in text
+
+
+# ---------------------------------------------------------------------------
 # goodput classification (fake clock)
 # ---------------------------------------------------------------------------
 
@@ -296,6 +546,27 @@ def test_decode_loop_process_mode_ships_counter_deltas():
     delta_idx = [i for i, it in enumerate(items)
                  if isinstance(it, _StageDelta)]
     assert max(delta_idx) < min(ends), "delta after _END would be dropped"
+
+
+def test_decode_loop_with_telemetry_off_ships_no_counters():
+    """telemetry.enabled=false: the decode span is the shared no-op, so
+    the loop has no measured duration to count and ships no delta."""
+    from distributed_resnet_tensorflow_tpu.data.imagenet import (
+        _decode_loop, _END, _StageDelta)
+    in_q, out_q = queue.Queue(), queue.Queue()
+    in_q.put((_jpeg_bytes(), 1))
+    in_q.put(_END)
+    recorder.configure(enabled=False)
+    try:
+        _decode_loop(in_q, out_q, wseed=0, is_train=False, image_size=32,
+                     native_decode=False, emit_uint8=True, stop=None)
+    finally:
+        recorder.configure(enabled=True)
+    items = []
+    while not out_q.empty():
+        items.append(out_q.get_nowait())
+    assert len(items) == 2  # the image and the end marker
+    assert not any(isinstance(i, _StageDelta) for i in items)
 
 
 def test_decode_process_counters_merge_into_parent_registry(tmp_path):
