@@ -19,6 +19,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def standardize(images: jax.Array) -> jax.Array:
@@ -99,16 +100,53 @@ def vgg_standardize(images: jax.Array, rng: jax.Array = None) -> jax.Array:
     return x - jnp.asarray(RGB_MEANS)
 
 
-def random_flip(images: jax.Array, rng: jax.Array) -> jax.Array:
-    """Per-image random horizontal flip (a width-reversed select — no
-    gather, no matmul). Output dtype follows the input."""
-    flip = jax.random.bernoulli(rng, 0.5, (images.shape[0],))
-    return jnp.where(flip[:, None, None, None], images[:, :, ::-1, :],
-                     images)
+def _as_nhwc(images: jax.Array, channels) -> jax.Array:
+    """Undo the lane-dense ``[B, H, W·C]`` view (``channels`` given) for an
+    augmentation that computes on NHWC."""
+    if channels is None:
+        return images
+    b, h, wc = images.shape
+    return images.reshape(b, h, wc // channels, channels)
+
+
+def random_flip(rows: jax.Array, rng: jax.Array, channels: int) -> jax.Array:
+    """Per-image random horizontal flip of uint8 crops, computed on their
+    lane-dense view: ``rows`` is ``[B, H, W·C]`` (the bytes of NHWC, the
+    width and channel dimensions merged), the result the same view in
+    float32, pixel scale.
+
+    On that view a flip is the constant lane permutation
+    ``j·C + c → (W−1−j)·C + c``, applied as a one-hot selection matmul on
+    the MXU like ``random_crop_flip``'s: one non-zero per column, bf16
+    operands (exact for uint8 values), float32 accumulation — every output
+    element IS one input element. The per-image draw then selects between
+    the permuted and the plain rows.
+
+    Why not ``images[:, :, ::-1, :]``: on a TPU a ``[B, H, W, 3]`` tensor
+    keeps the 3 in the lanes, padded to 128 — 125 of 128 lanes idle, 42×
+    the bytes — and the reverse runs along its sublanes. In the staged
+    unpack of ``[256,224,224,3]`` crops that form cost 27.0 ms for the
+    ``rev`` and 14.8 ms for the standardize behind it, of a 148 ms step
+    (TPU v5e, PR 25's trace); see ``imagenet_train_augment`` for the
+    numbers after."""
+    if rows.dtype != jnp.uint8:
+        raise TypeError(
+            f"random_flip permutes uint8 pixels exactly in bf16; got "
+            f"{rows.dtype}")
+    b, _, wc = rows.shape
+    flip = jax.random.bernoulli(rng, 0.5, (b,))
+    lane = jnp.arange(wc)
+    # lane j·C + c of a flipped row reads lane (W−1−j)·C + c
+    src = (wc - channels) - (lane // channels) * channels + lane % channels
+    perm = (lane[:, None] == src[None, :]).astype(jnp.bfloat16)
+    flipped = jax.lax.dot_general(
+        rows.astype(jnp.bfloat16), perm, (((2,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return jnp.where(flip[:, None, None], flipped, rows.astype(jnp.float32))
 
 
 def imagenet_train_augment(images: jax.Array, rng: jax.Array,
-                           pad: int = 0) -> jax.Array:
+                           pad: int = 0, channels=None) -> jax.Array:
     """ImageNet TRAIN augmentation for raw uint8 NHWC crops, on device:
     random horizontal flip (+ optional ``pad``-pixel random-crop jitter)
     then the VGG standardize. The host decode keeps the reference's random
@@ -121,26 +159,61 @@ def imagenet_train_augment(images: jax.Array, rng: jax.Array,
     ``random_crop_flip`` — spatial diversity for echoed appearances of
     one decoded crop (data/echo.py). Draws are per appearance: the same
     staged sample augments differently every time it feeds a step, which
-    is what keeps data echoing from replaying identical batches."""
+    is what keeps data echoing from replaying identical batches.
+
+    ``images`` is NHWC or, with ``channels`` given, the same bytes already
+    viewed as ``[B, H, W·C]`` — how the stager's fused unpack slices them
+    out of the staged region. At pad=0 everything before the result is
+    computed on that lane-dense view: the flip as a lane permutation
+    (``random_flip``), then ``/ 255`` and minus ``RGB_MEANS`` tiled to
+    ``[W·C]``, the same two float32 operations per element as on NHWC, so
+    the same bits; one reshape gives the NHWC result. No ``[…, W, 3]``
+    tensor exists before it (tests/test_echo.py holds the lowered program
+    to that). In ``rn50_staged`` this took the unpack program from 61.7 ms
+    a step to 2.4 ms and the step period from 148.2 to 89.1 ms, 1,723 →
+    2,868 examples/s (TPU v5e; PERF.md §5 and §6, PR 26): what is left is
+    the slice out of the staged bytes 0.63 ms, the uint8 reshape and its
+    move of the batch into the lanes 0.42, the product + select +
+    standardize fusion 0.37, and two dense float32 copies into the layout
+    the device keeps the NHWC result in (``[H][C][W][B]``, batch in the
+    lanes), 0.47 each."""
     from ..data.preprocessing import RGB_MEANS
     if pad > 0:
-        x = random_crop_flip(images, rng, pad)  # float32, pixel scale
+        # float32, pixel scale
+        x = random_crop_flip(_as_nhwc(images, channels), rng, pad)
+        return x / 255.0 - jnp.asarray(RGB_MEANS)
+    if channels is None:
+        b, h, w, c = images.shape
     else:
-        x = random_flip(images, rng).astype(jnp.float32)
-    return x / 255.0 - jnp.asarray(RGB_MEANS)
+        (b, h, wc), c = images.shape, channels
+        w = wc // c
+    if c != len(RGB_MEANS):
+        raise ValueError(
+            f"imagenet standardize has {len(RGB_MEANS)} channel means; "
+            f"got {c} channels")
+    x = random_flip(images.reshape(b, h, w * c), rng, c)
+    x = x / 255.0 - jnp.asarray(np.tile(RGB_MEANS, w))
+    return x.reshape(b, h, w, c)
 
 
 def device_augment_fn(kind: str, pad: int = 0):
     """Resolve a HASHABLE device-augment spec — ``(leaf, kind, pad)`` is
     what the CoalescedStager's fused unpack (parallel/sharding.py) and the
-    static elaborator cache/trace on — into the ``fn(images, rng)``
-    callable. One resolution point so the fused-unpack path, the step-side
-    path and the analysis gate can never disagree about what a spec
-    means."""
+    static elaborator cache/trace on — into the
+    ``fn(images, rng, channels=None)`` callable. ``images`` is NHWC, or
+    with ``channels`` given its lane-dense ``[B, H, W·C]`` view (the fused
+    unpack passes that; see ``imagenet_train_augment``). One resolution
+    point so the fused-unpack path, the step-side path and the analysis
+    gate can never disagree about what a spec means."""
     if kind == "imagenet_train":
-        return lambda images, rng: imagenet_train_augment(images, rng, pad)
+        return lambda images, rng, channels=None: imagenet_train_augment(
+            images, rng, pad, channels)
     if kind == "imagenet_eval":
-        return vgg_standardize
-    if kind == "cifar_train":
-        return lambda images, rng: cifar_train_augment(images, rng, pad or 4)
-    raise ValueError(f"unknown device augment kind {kind!r}")
+        fn = vgg_standardize
+    elif kind == "cifar_train":
+        def fn(images, rng):
+            return cifar_train_augment(images, rng, pad or 4)
+    else:
+        raise ValueError(f"unknown device augment kind {kind!r}")
+    return lambda images, rng, channels=None: fn(
+        _as_nhwc(images, channels), rng)

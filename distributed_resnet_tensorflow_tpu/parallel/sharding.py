@@ -760,16 +760,25 @@ def _build_unpack(mesh: Mesh, fields: Tuple, stacked: bool, n_shards: int,
     def unpack(flat):
         import jax.numpy as jnp
         out = {}
+        channels = None
         # the scopes name the device ops in a profiler trace
         with jax.named_scope("unpack"):
             for name, shape, dtype, off, nbytes in fields:
                 jdt = dtype if dtype != np.bool_ else np.dtype(np.uint8)
                 seg = jax.lax.slice(flat, (0, off), (n_shards, off + nbytes))
+                rest = shape[2:] if stacked else shape[1:]
+                if augment is not None and name == augment[0] \
+                        and len(rest) == 3:
+                    # the augmented image leaf leaves the staged bytes as
+                    # the lane-dense (.., H, W*C) view its augmentation
+                    # computes on (ops/augment.py): a (.., W, C) tensor
+                    # keeps C=3 in the TPU's 128 lanes
+                    channels = rest[2]
+                    rest = (rest[0], rest[1] * rest[2])
                 if stacked:
-                    k_steps, rest = shape[0], shape[2:]
+                    k_steps = shape[0]
                     tgt = (n_shards, k_steps, pb) + rest
                 else:
-                    rest = shape[1:]
                     tgt = (n_shards, pb) + rest
                 isize = np.dtype(dtype).itemsize
                 if isize > 1:
@@ -802,10 +811,11 @@ def _build_unpack(mesh: Mesh, fields: Tuple, stacked: bool, n_shards: int,
                     # with lax.map so the float32 intermediate is one
                     # microbatch at a time, not the whole (K, B, ...) group
                     keys = jax.random.split(akey, img.shape[0])
-                    img = jax.lax.map(lambda kv: fn(kv[0], kv[1]),
-                                      (img, keys))
+                    img = jax.lax.map(
+                        lambda kv: fn(kv[0], kv[1], channels=channels),
+                        (img, keys))
                 else:
-                    img = fn(img, akey)
+                    img = fn(img, akey, channels=channels)
                 out[leaf_name] = img
         return out
 
@@ -816,17 +826,16 @@ def _build_unpack(mesh: Mesh, fields: Tuple, stacked: bool, n_shards: int,
     return jitted
 
 
-def abstract_staged_unpack(mesh: Mesh, batch_shapes: Dict,
-                           stacked: bool = False,
-                           augment: Optional[Tuple] = None,
-                           augment_seed: int = 0):
-    """Trace the coalesced unpack(+fused augment) program ABSTRACTLY —
-    zero allocation, zero compile — and return its output
-    ShapeDtypeStructs. The static-elaboration gate (analysis/elaborate.py)
-    calls this per preset so an unpack or fused-augment program that
-    cannot trace is a pre-submit finding, not a step-1 crash on the
-    cluster. ``batch_shapes`` maps leaf name → ShapeDtypeStruct exactly
-    as the host iterator would deliver the batch."""
+def staged_unpack_program(mesh: Mesh, batch_shapes: Dict,
+                          stacked: bool = False,
+                          augment: Optional[Tuple] = None,
+                          augment_seed: int = 0):
+    """The coalesced unpack(+fused augment) program a stager would run for
+    this batch spec, with the ShapeDtypeStruct of its flat input: ``(jitted
+    unpack, flat)`` — zero allocation, nothing compiled. ``batch_shapes``
+    maps leaf name → ShapeDtypeStruct exactly as the host iterator would
+    deliver the batch. For the analysis gate (``abstract_staged_unpack``)
+    and for tests that read the lowered program."""
     spec = tuple(sorted(
         (k, tuple(v.shape), np.dtype(v.dtype))
         for k, v in batch_shapes.items()))
@@ -847,8 +856,21 @@ def abstract_staged_unpack(mesh: Mesh, batch_shapes: Dict,
     unpack = _build_unpack(mesh, fields, stacked, n_shards, pb,
                            augment=augment, seed_off=seed_off,
                            augment_seed=augment_seed)
-    return jax.eval_shape(
-        unpack, jax.ShapeDtypeStruct((n_shards, region), np.uint8))
+    return unpack, jax.ShapeDtypeStruct((n_shards, region), np.uint8)
+
+
+def abstract_staged_unpack(mesh: Mesh, batch_shapes: Dict,
+                           stacked: bool = False,
+                           augment: Optional[Tuple] = None,
+                           augment_seed: int = 0):
+    """Trace the coalesced unpack(+fused augment) program ABSTRACTLY —
+    zero allocation, zero compile — and return its output
+    ShapeDtypeStructs. The static-elaboration gate (analysis/elaborate.py)
+    calls this per preset so an unpack or fused-augment program that
+    cannot trace is a pre-submit finding, not a step-1 crash on the
+    cluster."""
+    return jax.eval_shape(*staged_unpack_program(
+        mesh, batch_shapes, stacked, augment, augment_seed))
 
 
 class StagedBatch:

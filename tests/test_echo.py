@@ -3,6 +3,7 @@ augmentation (round 9: data/echo.py, ops/augment.imagenet_train_augment,
 the CoalescedStager's fused unpack, data.echo_transfer reuse, and the
 decode-pool auto-scaling resolution)."""
 import collections
+import re
 
 import jax
 import jax.numpy as jnp
@@ -172,6 +173,79 @@ def test_imagenet_train_augment_parity_modulo_rng():
     assert flips.any() and not flips.all()  # both branches exercised
 
 
+def _plain_flip(imgs, key):
+    """The formula the lane-dense path must reproduce bit for bit: the
+    draws from the same key, a width-reversed select, then float32."""
+    flips = np.asarray(jax.random.bernoulli(key, 0.5, (imgs.shape[0],)))
+    assert flips.any() and not flips.all()  # both branches occur
+    return np.where(flips[:, None, None, None], imgs[:, :, ::-1, :],
+                    imgs).astype(np.float32)
+
+
+def _all_pixel_values(b, h, w, c, seed):
+    """uint8 NHWC batch in which every one of the 256 values occurs."""
+    n = b * h * w * c
+    assert n >= 256
+    vals = np.concatenate([np.arange(256), np.random.RandomState(
+        seed).randint(0, 256, n - 256)]).astype(np.uint8)
+    np.random.RandomState(seed + 1).shuffle(vals)
+    assert len(np.unique(vals)) == 256
+    return vals.reshape(b, h, w, c)
+
+
+@pytest.mark.parametrize("w", [7, 8])
+@pytest.mark.parametrize("c", [1, 3])
+def test_random_flip_lane_permutation_bit_identical(w, c):
+    """The flip on the lane-dense [B, H, W*C] view — a one-hot lane
+    permutation in bf16 — returns exactly the pixels of a width reverse:
+    odd and even W, C = 3 and C = 1, all 256 values."""
+    from distributed_resnet_tensorflow_tpu.ops.augment import random_flip
+
+    b, h = 8, 5
+    imgs = _all_pixel_values(b, h, w, c, seed=w * 10 + c)
+    key = jax.random.PRNGKey(4)
+    rows = random_flip(jnp.asarray(imgs.reshape(b, h, w * c)), key, c)
+    assert rows.dtype == jnp.float32 and rows.shape == (b, h, w * c)
+    np.testing.assert_array_equal(
+        np.asarray(rows).reshape(b, h, w, c), _plain_flip(imgs, key))
+
+
+@pytest.mark.parametrize("w", [7, 8])
+@pytest.mark.parametrize("as_view", [False, True])
+def test_imagenet_train_augment_pad0_bit_identical(w, as_view):
+    """pad-0 train augmentation == where(flip, x[:, :, ::-1, :], x)
+    .astype(f32) / 255 - RGB_MEANS, bit for bit (not allclose), given as
+    NHWC and as the [B, H, W*C] view the fused unpack hands over."""
+    from distributed_resnet_tensorflow_tpu.data.preprocessing import RGB_MEANS
+    from distributed_resnet_tensorflow_tpu.ops.augment import (
+        imagenet_train_augment)
+
+    b, h, c = 8, 6, 3
+    imgs = _all_pixel_values(b, h, w, c, seed=w)
+    key = jax.random.PRNGKey(9)
+    if as_view:
+        dev = imagenet_train_augment(
+            jnp.asarray(imgs.reshape(b, h, w * c)), key, pad=0, channels=c)
+    else:
+        dev = imagenet_train_augment(jnp.asarray(imgs), key, pad=0)
+    assert dev.shape == (b, h, w, c) and dev.dtype == jnp.float32
+    host = _plain_flip(imgs, key) / np.float32(255.0) - RGB_MEANS
+    np.testing.assert_array_equal(np.asarray(dev), host)
+
+
+def test_imagenet_train_augment_refuses_what_it_cannot_keep_exact():
+    """bf16 holds uint8 pixels exactly and nothing wider; the standardize
+    has three channel means."""
+    from distributed_resnet_tensorflow_tpu.ops.augment import (
+        imagenet_train_augment)
+
+    key = jax.random.PRNGKey(0)
+    with pytest.raises(TypeError, match="uint8"):
+        imagenet_train_augment(jnp.zeros((2, 4, 4, 3), jnp.float32), key)
+    with pytest.raises(ValueError, match="channel"):
+        imagenet_train_augment(jnp.zeros((2, 4, 4, 1), jnp.uint8), key)
+
+
 def test_imagenet_train_augment_pad_jitter_windows():
     """augment_pad > 0: every output is a valid window of the padded
     original (possibly flipped), standardized — the crop machinery is the
@@ -256,6 +330,95 @@ def test_fused_unpack_augment_stacked_per_step_keys():
     exp = np.stack([np.asarray(fn(jnp.asarray(sb["images"][k]), keys[k]))
                     for k in range(3)])
     np.testing.assert_allclose(out, exp, atol=1e-5)
+
+
+@pytest.mark.parametrize("stacked", [False, True])
+def test_fused_unpack_pad0_bit_identical_to_plain_formula(stacked):
+    """The fused unpack of a CoalescedStager (single-step and stacked)
+    returns the plain formula for the counter it embedded: draws
+    bernoulli(fold_in(PRNGKey(seed), n-th put), 1/2) over the rows
+    (per-step split keys when stacked), the width-reversed select on
+    NHWC, x / 255 - RGB_MEANS in float32.
+
+    Held in two parts, because a COMPILED program does not pin the last
+    bit of `x / 255 - m` on the CPU (XLA multiplies by the reciprocal and
+    LLVM contracts multiply-add into fma where its vectoriser pleases:
+    the NHWC form this path replaced came out fma in two channels and
+    not in the third): every pixel, read back out of the float32 result,
+    is exactly the pixel the plain formula puts there (flip, draws,
+    counter and layout, bit for bit), and the float32 value is within
+    two roundings of a number under 1 (2**-23) of the formula's. The
+    op-by-op tests above hold the arithmetic itself to the bit."""
+    from distributed_resnet_tensorflow_tpu.data.preprocessing import RGB_MEANS
+    from distributed_resnet_tensorflow_tpu.parallel.mesh import create_mesh
+    from distributed_resnet_tensorflow_tpu.parallel.sharding import (
+        CoalescedStager)
+    from distributed_resnet_tensorflow_tpu.utils.config import MeshConfig
+
+    mesh = create_mesh(MeshConfig())  # data=-1: all (virtual) devices
+    k, b, h, w, c = 3, 8, 6, 7, 3
+    imgs = _all_pixel_values(k * b, h, w, c, seed=5).reshape(k, b, h, w, c)
+    labels = np.arange(k * b, dtype=np.int32).reshape(k, b)
+    batch = {"images": imgs, "labels": labels} if stacked else \
+        {"images": imgs[0], "labels": labels[0]}
+    st = CoalescedStager(mesh, stacked=stacked, ring=3,
+                         augment=("images", "imagenet_train", 0),
+                         augment_seed=13)
+    for ctr in range(2):
+        out = st.put_now(dict(batch))
+        got = np.asarray(out["images"])
+        assert got.dtype == np.float32 and got.shape == batch["images"].shape
+        key = jax.random.fold_in(jax.random.PRNGKey(13), np.uint32(ctr))
+        if stacked:
+            keys = jax.random.split(key, k)
+            flipped = np.stack([_plain_flip(imgs[i], keys[i])
+                                for i in range(k)])
+        else:
+            flipped = _plain_flip(imgs[0], key)
+        pixels = np.rint((got.astype(np.float64) + RGB_MEANS) * 255.0)
+        np.testing.assert_array_equal(pixels, flipped)
+        np.testing.assert_allclose(
+            got, flipped / np.float32(255.0) - RGB_MEANS, rtol=0,
+            atol=2.0 ** -23)
+        np.testing.assert_array_equal(np.asarray(out["labels"]),
+                                      batch["labels"])
+
+
+def _minor_dims(stablehlo_text):
+    """Minor dimension of every ranked tensor type in a lowered module."""
+    return [(int(m.group(1)), m.group(0)) for m in re.finditer(
+        r"tensor<(?:\d+x)*(\d+)x[a-z]+\d+>", stablehlo_text)]
+
+
+@pytest.mark.parametrize("stacked", [False, True])
+def test_fused_unpack_lowers_without_reverse_or_minor3(stacked):
+    """Structure of the lowered fused unpack for [8,16,16,3] uint8 crops:
+    no `reverse` op, and no tensor whose minor dimension is 3 other than
+    the float32 result. On a TPU a [.., W, 3] tensor keeps the 3 in the
+    128 lanes (42x the bytes) and `rev` runs along its sublanes: together
+    41% of rn50_staged's device time before PR 26. A later edit must not
+    quietly bring either back."""
+    from distributed_resnet_tensorflow_tpu.parallel.mesh import create_mesh
+    from distributed_resnet_tensorflow_tpu.parallel.sharding import (
+        staged_unpack_program)
+    from distributed_resnet_tensorflow_tpu.utils.config import MeshConfig
+
+    mesh = create_mesh(MeshConfig())  # data=-1: all (virtual) devices
+    lead = (2, 8) if stacked else (8,)
+    shapes = {"images": jax.ShapeDtypeStruct(lead + (16, 16, 3), np.uint8),
+              "labels": jax.ShapeDtypeStruct(lead, np.int32)}
+    unpack, flat = staged_unpack_program(
+        mesh, shapes, stacked=stacked,
+        augment=("images", "imagenet_train", 0), augment_seed=3)
+    text = unpack.lower(flat).as_text()
+    assert "stablehlo.dot_general" in text  # the lane permutation engaged
+    assert "reverse" not in text
+    result = "tensor<" + "x".join(map(str, lead + (16, 16, 3))) + "xf32>"
+    assert result in text
+    minor3 = {ty for d, ty in _minor_dims(text) if d == 3} - {result}
+    if stacked:  # lax.map's carry and per-step slot of the same result
+        minor3 -= {"tensor<8x16x16x3xf32>", "tensor<1x8x16x16x3xf32>"}
+    assert not minor3, minor3
 
 
 def test_abstract_staged_unpack_traces_augment():
