@@ -14,7 +14,9 @@ Numbers compared, each against a limit of its own from
   whichever is larger;
 * ``change_gap``: the same for the norm of the parameters' change after the
   last boundary, over the leaves whose first reference gradient is not
-  under a thousandth of the median leaf's (those move by round-off alone);
+  under a thousandth of the median leaf's (those move by round-off alone)
+  and the leaves that a rule of the family moves and no gradient does
+  (``ruled`` in the reference's readings);
 * ``grad_mid``, ``change_mid``: the median leaf's gap instead of the worst;
 * ``grad_dir``, ``change_dir``: the median leaf's gap between the inner
   products of the two vectors with one seeded probe vector, on the same
@@ -58,7 +60,7 @@ def compare(mine: dict, ref: dict) -> Tuple[Dict[str, float], Dict[str, str]]:
     leaves = sorted(ref["moment"]["norm"])
     g1 = ref["grad1"]
     floor = statistics.median(g1.values()) * 1e-3
-    moving = [n for n in leaves if g1[n] >= floor]
+    moving = [n for n in leaves if g1[n] >= floor or n in ref.get("ruled", ())]
     for name, key, over in (("grad", "moment", leaves), ("change", "change", moving)):
         norm_gaps = gaps(mine[key]["norm"], ref[key]["norm"], over)
         where[f"{name}_gap"] = max(norm_gaps, key=norm_gaps.get)

@@ -43,14 +43,28 @@ def place_compile_cache():
     return placed
 
 
-def _stated(cfg, config: dict) -> None:
+#: keys of a configuration's ``model`` / ``optimizer`` block that the
+#: program fixes in its code and not in its config, and keys it holds in
+#: another group: the default, for a family that states neither
+#: (``FIXED_IN_CODE`` / ``HELD_ELSEWHERE`` in its reference module); a
+#: configuration file adds keys of its own under ``fixed_in_code``
+FIXED_IN_CODE = {"model": ["mlp_ratio", "layer_norm_epsilon", "gelu", "pooling",
+                           "compute_dtype_control"],
+                 "optimizer": ["b1", "b2", "eps"]}
+HELD_ELSEWHERE = {"image_size": ["data", "image_size"]}
+
+
+def _stated(cfg, config: dict, family) -> None:
     """The configuration file says what runs: every number it states for the
-    model and the optimizer has to be what the program's config holds."""
+    model and the optimizer has to be what the program's config holds. What
+    the program's config does not hold (a published count beside the count
+    held here) the family's module or the configuration file names."""
     where = {"model": cfg.model, "optimizer": cfg.optimizer}
-    aliases = {"image_size": ("data", "image_size")}
-    free = {"model": {"mlp_ratio", "layer_norm_epsilon", "gelu", "pooling",
-                      "compute_dtype_control"},
-            "optimizer": {"b1", "b2", "eps"}}
+    aliases = getattr(family, "HELD_ELSEWHERE", HELD_ELSEWHERE)
+    by_family = getattr(family, "FIXED_IN_CODE", FIXED_IN_CODE)
+    by_file = config.get("fixed_in_code", {})
+    free = {group: set(by_family.get(group, ())) | set(by_file.get(group, ()))
+            for group in where}
     for group, obj in where.items():
         for key, want in config[group].items():
             if key in free[group]:
@@ -81,11 +95,11 @@ class Program:
             overrides[f"mesh.{axis}"] = n
         for key, value in overrides.items():
             cfg.override(key, value)
-        _stated(cfg, config)
+        self.family = spec.module("reference", config["family"])
+        _stated(cfg, config, self.family)
         self.cell, self.cfg, self.seed = cell, cfg, seed
         self.k = max(1, cfg.train.steps_per_loop)
         self.global_batch = cfg.train.batch_size
-        self.family = spec.module("reference", config["family"])
         self.trainer = Trainer(cfg, mesh=create_mesh(cfg.mesh, devices=list(devices)))
         self.trainer.init_state(seed31(seed))
         self._paths = self.family.program_paths(config["model"])

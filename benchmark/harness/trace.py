@@ -4,9 +4,12 @@ Works on a plain structure, so that a hand-built trace tests it:
 
     {"planes": [{"name": "/device:TPU:0",
                  "lines": [{"name": "XLA Ops",
-                            "events": [(name, start_ns, duration_ns), ...]}]}]}
+                            "events": [(name, start_ns, duration_ns), ...]}],
+                 "tf_op": {name: "jit(step)/jit(main)/forward/dot_general"}}]}
 
-``load`` reads that from the ``.xplane.pb`` the JAX profiler writes.
+``load`` reads that from the ``.xplane.pb`` the JAX profiler writes; a
+plane's ``tf_op`` (optional in a hand-built trace) gives, per operation, the
+``jax.named_scope`` path its instruction was traced under.
 
 * The *busy union* of a device is the union of the intervals in which any
   operation of its ``XLA Ops`` line runs; nested operations (a ``while``
@@ -23,12 +26,22 @@ Works on a plain structure, so that a hand-built trace tests it:
   with their ``-start``/``-done``) runs on the device and no other
   operation does (containers such as ``while`` do not count as another;
   a collective in flight on the ``Async XLA Ops`` line counts as running).
+* Every family's self time and event count are kept (``op_s``,
+  ``op_events``), so a kernel is found by the name its ``name=`` gave the
+  instruction (``softmax_xent_fwd``) whatever its rank; the ten most
+  expensive are the result line's ``device_ops``.
+* An operation's *scope* is its ``tf_op`` path without the last component
+  (the primitive): ``scope_s`` sums self time by scope, ``""`` for what the
+  compiler added from no source line; ``scope_seconds`` sums the scopes that
+  hold a component. ``module_s`` sums it by the ``XLA Modules`` execution
+  the operation starts in.
 * An *idle gap* is a hole in the busy union inside the window; it is named
   after the host event that overlaps it most, thread and process ids
   stripped.
 """
 from __future__ import annotations
 
+import bisect
 import re
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -40,13 +53,67 @@ DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):(\d+)")
 def load(path: str) -> dict:
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
+    scopes = _tf_ops(path)
     planes = []
     for plane in data.planes:
         lines = [{"name": line.name,
                   "events": [(e.name, float(e.start_ns), float(e.duration_ns))
                              for e in line.events]} for line in plane.lines]
-        planes.append({"name": plane.name, "lines": lines})
+        planes.append({"name": plane.name, "lines": lines,
+                       "tf_op": scopes.get(plane.name, {})})
     return {"planes": planes}
+
+
+def _tf_ops(path: str) -> Dict[str, Dict[str, str]]:
+    """Per device plane: operation name -> its ``tf_op`` stat. The stat sits
+    on the event's metadata, which ``ProfileData`` does not show, so the
+    file is parsed once more against the four messages of TSL's
+    ``xplane.proto`` that hold it, declared here (lines and events are left
+    as unknown fields; no TensorFlow import)."""
+    from google.protobuf import descriptor_pb2, descriptor_pool, message_factory
+    F = descriptor_pb2.FieldDescriptorProto
+    file = descriptor_pb2.FileDescriptorProto(
+        name="benchmark/xplane_metadata.proto", package="benchmark_xplane", syntax="proto3")
+
+    def message(name, *fields):
+        m = file.message_type.add(name=name)
+        for fname, number, ftype, repeated, of in fields:
+            m.field.add(name=fname, number=number, type=ftype,
+                        label=F.LABEL_REPEATED if repeated else F.LABEL_OPTIONAL,
+                        type_name=f".benchmark_xplane.{of}" if of else None)
+    message("Stat", ("metadata_id", 1, F.TYPE_INT64, False, None),
+            ("str_value", 5, F.TYPE_STRING, False, None),
+            ("ref_value", 7, F.TYPE_UINT64, False, None))
+    message("EventMetadata", ("name", 2, F.TYPE_STRING, False, None),
+            ("stats", 5, F.TYPE_MESSAGE, True, "Stat"))
+    message("StatMetadata", ("name", 2, F.TYPE_STRING, False, None))
+    # a map<int64, M> field is on the wire a repeated {key = 1; value = 2}
+    message("EventEntry", ("value", 2, F.TYPE_MESSAGE, False, "EventMetadata"))
+    message("StatEntry", ("key", 1, F.TYPE_INT64, False, None),
+            ("value", 2, F.TYPE_MESSAGE, False, "StatMetadata"))
+    message("Plane", ("name", 2, F.TYPE_STRING, False, None),
+            ("event_metadata", 4, F.TYPE_MESSAGE, True, "EventEntry"),
+            ("stat_metadata", 5, F.TYPE_MESSAGE, True, "StatEntry"))
+    message("Space", ("planes", 1, F.TYPE_MESSAGE, True, "Plane"))
+    pool = descriptor_pool.DescriptorPool()
+    pool.Add(file)
+    space = message_factory.GetMessageClass(
+        pool.FindMessageTypeByName("benchmark_xplane.Space"))()
+    with open(path, "rb") as f:
+        space.ParseFromString(f.read())
+    out = {}
+    for plane in space.planes:
+        if not DEVICE_PLANE.match(plane.name):
+            continue
+        names = {e.key: e.value.name for e in plane.stat_metadata}
+        wanted = {k for k, n in names.items() if n == "tf_op"}
+        found = {}
+        for entry in plane.event_metadata:
+            for st in entry.value.stats:
+                if st.metadata_id in wanted:
+                    found[entry.value.name] = st.str_value or names.get(st.ref_value, "")
+        out[plane.name] = found
+    return out
 
 
 def device_planes(trace: dict) -> List[dict]:
@@ -148,6 +215,21 @@ def step_window(plane: dict) -> Optional[Tuple[float, float, int, str]]:
     return starts[0], starts[-1], len(starts) - 1, top
 
 
+def scope_of(tf_op: str) -> str:
+    """``jit(step)/jit(main)/forward/conv/dot_general`` -> the path without
+    its primitive; ``""`` for an operation that carries no ``tf_op``."""
+    return tf_op.rsplit("/", 1)[0] if "/" in tf_op else ""
+
+
+def scope_seconds(reduced: dict, component: str, but_not: Sequence[str] = ()) -> Optional[float]:
+    """Self seconds of device 0's operations whose scope path holds
+    ``component`` as one of its components and none of ``but_not``; None
+    where no operation does (the reader then has nothing to read)."""
+    found = [s for path, s in reduced["scope_s"].items()
+             if component in path.split("/") and not set(but_not).intersection(path.split("/"))]
+    return sum(found) if found else None
+
+
 def reduce(trace: dict, steps_per_dispatch: int = 1) -> dict:
     """Everything the per-layer readers and the result line take from a trace."""
     planes = device_planes(trace)
@@ -168,14 +250,28 @@ def reduce(trace: dict, steps_per_dispatch: int = 1) -> dict:
     ops0 = _line(first, "XLA Ops")
     busy0 = _clip(union([(s, s + d) for _, s, d in ops0]), lo, hi)
     fam_ns: Dict[str, float] = {}
+    fam_events: Dict[str, int] = {}
+    scope_ns: Dict[str, float] = {}
+    module_ns: Dict[str, float] = {}
+    tf_op = first.get("tf_op", {})
+    modules = [(s, s + d, family(name)) for name, s, d in _line(first, "XLA Modules")]
+    module_starts = [m[0] for m in modules]
     collective, compute = [], []
     for name, a, b, self_ns, parent in self_times(ops0):
         inside = _clip([(a, b)], lo, hi)
         if not inside or b <= a:
             continue
         share = _total(inside) / (b - a) * self_ns
-        fam_ns[family(name)] = fam_ns.get(family(name), 0.0) + share
-        if COLLECTIVE.search(family(name)):
+        fam = family(name)
+        fam_ns[fam] = fam_ns.get(fam, 0.0) + share
+        if lo <= a < hi:  # an event is counted in the period it starts in
+            fam_events[fam] = fam_events.get(fam, 0) + 1
+        scope = scope_of(tf_op.get(name, ""))
+        scope_ns[scope] = scope_ns.get(scope, 0.0) + share
+        at = bisect.bisect_right(module_starts, a) - 1
+        runs_in = modules[at][2] if at >= 0 and a < modules[at][1] else ""
+        module_ns[runs_in] = module_ns.get(runs_in, 0.0) + share
+        if COLLECTIVE.search(fam):
             collective.append((a, b))
         elif not parent:  # a while or a call only holds what runs inside it
             compute.append((a, b))
@@ -216,4 +312,8 @@ def reduce(trace: dict, steps_per_dispatch: int = 1) -> dict:
         "collective_events": collective_events,
         "device_ops": [[name, ns / 1e9] for name, ns in top_ops],
         "idle_gaps": named,
+        "op_s": {name: ns / 1e9 for name, ns in fam_ns.items()},
+        "op_events": fam_events,
+        "scope_s": {name: ns / 1e9 for name, ns in scope_ns.items()},
+        "module_s": {name: ns / 1e9 for name, ns in module_ns.items()},
     }

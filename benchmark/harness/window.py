@@ -186,11 +186,18 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     failed += int(np.sum(~np.isfinite(losses))) * k
     attempted = counter.steps
     dev = device.describe(devices)
+    flops = spec.module("flops", cell.config["family"])
+    if hasattr(flops, "train_flops_per_step"):  # the count needs the traffic (a sequence length)
+        flops_per_step = flops.train_flops_per_step(cell.config, cell.traffic)
+    else:
+        flops_per_step = flops.train_flops_per_example(cell.config["model"]) * program.global_batch
     run = {
         "chips": len(devices), "peaks": peaks, "compile_s": setup_compile_s,
         "stages_before": stages_before, "stages_after": stages_after, "trace": None,
-        "flops_per_step": spec.module("flops", cell.config["family"])
-        .train_flops_per_example(cell.config["model"]) * program.global_batch,
+        "flops_per_step": flops_per_step,
+        # what a reader in a new file needs to count a kernel's operations
+        "config": cell.config, "traffic": cell.traffic,
+        "global_batch": program.global_batch, "steps_per_dispatch": k,
     }
     metrics = {"examples_per_s": attempted * program.global_batch / wall,
                "peak_hbm_gib": dev["memory_peak_bytes"] / 2 ** 30,

@@ -47,12 +47,13 @@ def schedule(opt: dict) -> Callable[[int], float]:
 
 
 def decayed(name: str, leaf) -> bool:
-    """Kernels only: not biases, not normalisation scales, not pos_embed."""
+    """The default, for a family that states no rule of its own. Kernels
+    only: not biases, not normalisation scales, not pos_embed."""
     last = name.rsplit(".", 1)[-1]
     return leaf.ndim > 1 and "bias" not in last and "pos_embed" not in last
 
 
-def l2_term(params: Dict[str, jnp.ndarray], rate: float):
+def l2_term(params: Dict[str, jnp.ndarray], rate: float, decayed=decayed):
     """0.5 * rate * sum ||kernel||^2, the loss-side decay of momentum SGD."""
     return 0.5 * rate * sum(jnp.sum(jnp.square(p)) for n, p in params.items()
                             if decayed(n, p))
@@ -71,8 +72,9 @@ def first_moment(opt: dict, state):
     return state["t"] if opt["name"] == "momentum" else state["mu"]
 
 
-def update(opt: dict, params, grads, state, lr: float, n_updates: int):
-    """One update; ``n_updates`` counts this one (1 for the first)."""
+def update(opt: dict, params, grads, state, lr: float, n_updates: int, decayed=decayed):
+    """One update; ``n_updates`` counts this one (1 for the first).
+    ``decayed(name, leaf)`` is the family's rule where it states one."""
     if opt["name"] == "momentum":
         t = {n: grads[n] + opt["momentum"] * state["t"][n] for n in params}
         return {n: params[n] - lr * t[n] for n in params}, {"t": t}

@@ -3,6 +3,7 @@ device gate, and the refusal of a share over 100."""
 import json
 import os
 import re
+import shutil
 import subprocess
 import sys
 
@@ -90,16 +91,21 @@ def test_a_metric_without_a_reader_or_a_cell_without_files_is_an_error(tmp_path)
         spec.resolve("orphan", root)
 
 
-def test_a_cell_is_added_by_files_and_entries_alone(tmp_path):
-    """Copy the benchmark, add a configuration, a traffic mix, a per-layer
-    metric and a cell, edit no file that was there but BENCHMARK.json's
-    lists, and the copy's own command lists and resolves the new cell."""
-    root = tiny.make_root(str(tmp_path))
+def _stamps(root):
     before = {}
     for base, _, files in os.walk(os.path.join(root, "benchmark")):
         for f in files:
             p = os.path.join(base, f)
             before[p] = os.path.getmtime(p), os.path.getsize(p)
+    return before
+
+
+def test_a_cell_is_added_by_files_and_entries_alone(tmp_path):
+    """Copy the benchmark, add a configuration, a traffic mix, a per-layer
+    metric and a cell, edit no file that was there but BENCHMARK.json's
+    lists, and the copy's own command lists and resolves the new cell."""
+    root = tiny.make_root(str(tmp_path))
+    before = _stamps(root)
     here = os.path.join(root, "benchmark")
     with open(os.path.join(here, "layer_metrics", "steps_traced.py"), "w") as f:
         f.write("def read(run):\n    return run['trace'] and run['trace']['steps']\n")
@@ -139,6 +145,103 @@ def test_a_cell_is_added_by_files_and_entries_alone(tmp_path):
                          capture_output=True, text=True, timeout=120)
     assert got.returncode == 0, got.stderr
     assert "steps_traced" in got.stdout and got.stdout.strip().endswith("3 2")
+
+
+TOY = os.path.join(spec.HERE, "tests", "toy")
+
+
+def _add_the_toy_family(root):
+    """``benchmark/tests/toy`` laid over the copy, file by new file, and its
+    entries appended to BENCHMARK.json's lists."""
+    here = os.path.join(root, "benchmark")
+    for base, _, files in os.walk(TOY):
+        for f in files:
+            dst = os.path.join(here, os.path.relpath(os.path.join(base, f), TOY))
+            assert not os.path.exists(dst), dst
+            shutil.copy(os.path.join(base, f), dst)
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as f:
+        bench = json.load(f)
+    bench["configs"].append({"name": "toy_tokens", "source": "test",
+                             "file": "benchmark/configs/toy_tokens.json",
+                             "reduced": [], "why": "a family that is no image classifier"})
+    bench["workloads"].append({"name": "toy_cell", "config": "toy_tokens",
+                               "traffic": "toy_tokens_b16", "chips": 1, "why": "as files"})
+    for name, unit in (("toy_ffn_roofline", "%"), ("toy_ffn_scope_ms", "ms")):
+        bench["per_layer"].append({"name": name, "unit": unit, "better": "higher",
+                                   "source": "device_trace", "layer": "kernels",
+                                   "moves": "examples_per_s", "workloads": ["toy_cell"]})
+    with open(path, "w") as f:
+        json.dump(bench, f)
+
+
+@pytest.fixture(scope="module")
+def toy_walk(tmp_path_factory):
+    """The toy family added to a copy as files and entries alone, and what
+    ``benchmark/tests/toy_walk.py`` reads there (one process, the copy's
+    own modules)."""
+    root = tiny.make_root(str(tmp_path_factory.mktemp("toy")))
+    before = _stamps(root)
+    _add_the_toy_family(root)
+    for p, stamp in before.items():  # no file that was there changed
+        assert (os.path.getmtime(p), os.path.getsize(p)) == stamp, p
+    got = subprocess.run([sys.executable, os.path.join(root, "benchmark", "tests", "toy_walk.py")],
+                         cwd=root, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                         capture_output=True, text=True, timeout=900)
+    assert got.returncode == 0, got.stderr[-3000:]
+    out = json.loads(got.stdout.strip().splitlines()[-1])
+    assert out["root"] == root
+    return out
+
+
+def test_a_family_that_is_no_image_classifier_comes_as_files_alone(toy_walk):
+    """Token ids, a loss of two terms, a vector that a rule moves, a decay
+    mask and blocks of whole sequences of its own: the cell resolves with
+    its two readers, the walk in blocks gives the numbers of the walk in
+    one, and put in the program's place it is correct."""
+    assert sorted(toy_walk["read"]) == ["toy_ffn_roofline", "toy_ffn_scope_ms"]
+    assert toy_walk["blocks_a_step"] >= 2
+    assert toy_walk["ruled_moved"] > 0
+    assert toy_walk["sound"]["correct"], toy_walk["sound"]
+    assert max(toy_walk["sound"]["numbers"].values()) < 1e-5
+
+
+@pytest.mark.parametrize("fault", ["half_batch", "second_term_left_out", "rule_left_out",
+                                   "control_fp8"])
+def test_a_fault_of_the_toy_family_fails_a_limit(toy_walk, fault):
+    assert not toy_walk[fault]["correct"] and toy_walk[fault]["over"], toy_walk[fault]
+
+
+def test_the_toy_familys_readers_find_a_kernel_by_name_and_a_scope(toy_walk):
+    """A reader in a new file finds its kernel among every family's seconds
+    and counts, whatever its rank, counts its operations from the shapes
+    ``run`` carries, and reads its scope's self seconds; with nothing to
+    read it returns nothing."""
+    macs = 3 * 16 * 32 * 16 * 12  # gate, up and down over 16 sequences of 12 tokens
+    assert toy_walk["read"] == {
+        "toy_ffn_roofline": pytest.approx(100 * 2 * macs * 4 / (2e-6 * 1e12)),
+        "toy_ffn_scope_ms": pytest.approx(2.0)}
+    assert toy_walk["read_nothing"] == {"toy_ffn_roofline": None, "toy_ffn_scope_ms": None}
+    assert toy_walk["flops_per_step"] == 6 * 16 * 12 * (3 * 16 * 32 + 2 * 16 * 32)
+
+
+def test_what_the_program_does_not_hold_comes_from_the_family_or_the_file():
+    from types import SimpleNamespace as NS
+
+    from benchmark.harness import program
+    cfg = NS(model=NS(d_model=16), optimizer=NS(name="adamw"), data=NS(image_size=32))
+    config = {"model": {"d_model": 16, "vocab_published": 256, "experts_published": 8},
+              "optimizer": {"name": "adamw", "b1": 0.9}}
+    family = NS(FIXED_IN_CODE={"model": ["vocab_published"], "optimizer": ["b1"]},
+                HELD_ELSEWHERE={})
+    with pytest.raises(AttributeError, match="experts_published"):
+        program._stated(cfg, config, family)
+    program._stated(cfg, dict(config, fixed_in_code={"model": ["experts_published"]}), family)
+    # a family that states neither gets the table the two image families have
+    program._stated(cfg, {"model": {"d_model": 16, "image_size": 32, "mlp_ratio": 4},
+                          "optimizer": {"eps": 1e-8}}, NS())
+    with pytest.raises(spec.SpecError, match="d_model=32"):
+        program._stated(cfg, {"model": {"d_model": 32}, "optimizer": {}}, NS())
 
 
 def test_the_device_gate_exits_non_zero_on_the_cpu_and_prints_no_result():

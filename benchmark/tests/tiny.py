@@ -62,12 +62,27 @@ LIMITS = {"limits": {"loss_8": 1e-4, "grad_gap": 2e-4, "change_gap": 2e-4,
                      "grad_mid": 1e-4, "change_mid": 1e-4, "grad_dir": 1e-4,
                      "change_dir": 1e-4},
           "not_compared": {}}
-#: batch normalisation over 8 rows of 2x2 pixels amplifies rounding: the
-#: third step and the parameters' change read a hundred times what the
-#: first step does (float32 against float32, this file's seed)
-LIMITS_RN = {"limits": {"loss_1": 1e-4, "loss_2": 1e-4, "loss_3": 1e-3, "grad_gap": 5e-3,
-                        "grad_mid": 1e-4, "grad_dir": 1e-3, "change_gap": 0.03,
-                        "change_mid": 1e-3, "change_dir": 0.03},
+#: batch normalisation over 8 rows of 2x2 pixels amplifies rounding, by
+#: how much depends on the seed: float32 against float32 reads grad_dir
+#: 1e-6 on one seed and 6e-3 on another. Set as the chip cells' limits are,
+#: limit = lower * (upper/lower)^0.6, from benchmark/tools/readings.py
+#: --cpu-root on 10 seeds from 2**31 + 4321 (control and half batch on 4):
+#: lower, the largest sound reading; upper, the bf16 control's smallest
+#: where that is three times the lower or more (all but loss_3), else half
+#: a batch's
+#:           lower    control  half batch
+#: loss_1    1.1e-7   1.2e-4   0.017
+#: loss_2    3.1e-5   6.8e-4   0.028
+#: loss_3    1.7e-4   1.3e-4   7.7e-3
+#: grad_gap  4.3e-3   0.049    0.60
+#: grad_mid  2.0e-4   4.1e-3   0.31
+#: grad_dir  6.0e-3   0.105    0.49
+#: change_gap 0.0127  0.048    0.52
+#: change_mid 4.7e-4  3.9e-3   0.29
+#: change_dir 0.0192  0.133    0.41
+LIMITS_RN = {"limits": {"loss_1": 1e-5, "loss_2": 2e-4, "loss_3": 1.5e-3, "grad_gap": 0.018,
+                        "grad_mid": 1.2e-3, "grad_dir": 0.033, "change_gap": 0.028,
+                        "change_mid": 1.7e-3, "change_dir": 0.06},
              "not_compared": {}}
 LIMITS_BY_CELL = {"tiny_rn": LIMITS_RN, "tiny_vit1": LIMITS, "tiny_vit4": LIMITS}
 
