@@ -155,17 +155,28 @@ class Program:
             opt_state=restart(state.opt_state))
 
     # -- what the comparison reads ---------------------------------------
-    def first_moment(self, opt_state):
+    @staticmethod
+    def first_moment(opt_state):
+        """The first ``trace`` or ``mu`` in the optimizer's state, through
+        chains (tuples), wrappers (``inner_state``) and the dict of states a
+        partitioned optimizer keeps, one per label (``inner_states``)."""
         def find(node):
+            if isinstance(node, jax.Array):  # an array has a method called trace
+                return None
             for attr in ("trace", "mu"):
                 if hasattr(node, attr):
                     return getattr(node, attr)
+            if isinstance(node, dict):
+                node = tuple(node.values())
             if isinstance(node, tuple):
                 for n in node:
                     got = find(n)
                     if got is not None:
                         return got
-            return find(node.inner_state) if hasattr(node, "inner_state") else None
+            for attr in ("inner_state", "inner_states"):
+                if hasattr(node, attr):
+                    return find(getattr(node, attr))
+            return None
         got = find(opt_state)
         if got is None:
             raise spec.SpecError("no first moment in the optimizer's state")

@@ -233,7 +233,12 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     ref = follow.follow(cell.config, seed, batches, bounds, augment_seed=augment_seed)
     numbers, where = check.compare(mine, ref)
     ok, rows = check.verdict(numbers, cell.limits)
-    log(f"reference followed {warm_steps} steps in {time.perf_counter() - t_ref:.1f} s")
+    walk = ref["walk"]
+    gb = lambda b: "not stated" if b is None else f"{b / 1e9:.2f} GB"  # noqa: E731
+    log(f"reference followed {warm_steps} steps in {time.perf_counter() - t_ref:.1f} s; "
+        f"device peak {gb(walk['device_peak_bytes_before'])} before the walk, "
+        f"{gb(walk['device_peak_bytes'])} after; the moments waited on the "
+        f"{walk['moments']}, {walk['groups']} group(s) of leaves")
     units = {m["name"]: m["unit"] for m in cell.end_to_end + cell.per_layer}
     result = {
         "correct": bool(ok and failed == 0), "attempted": int(attempted),

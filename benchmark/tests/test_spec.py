@@ -3,14 +3,14 @@ device gate, and the refusal of a share over 100."""
 import json
 import os
 import re
-import shutil
 import subprocess
 import sys
 
 import pytest
 
 from benchmark.harness import device, spec, window
-from benchmark.tests import tiny
+from benchmark.tests import tiny, walks
+from benchmark.tools import walk_size
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -147,18 +147,10 @@ def test_a_cell_is_added_by_files_and_entries_alone(tmp_path):
     assert "steps_traced" in got.stdout and got.stdout.strip().endswith("3 2")
 
 
-TOY = os.path.join(spec.HERE, "tests", "toy")
-
-
 def _add_the_toy_family(root):
     """``benchmark/tests/toy`` laid over the copy, file by new file, and its
     entries appended to BENCHMARK.json's lists."""
-    here = os.path.join(root, "benchmark")
-    for base, _, files in os.walk(TOY):
-        for f in files:
-            dst = os.path.join(here, os.path.relpath(os.path.join(base, f), TOY))
-            assert not os.path.exists(dst), dst
-            shutil.copy(os.path.join(base, f), dst)
+    walk_size.add_the_toy_files(os.path.join(root, "benchmark"))
     path = os.path.join(root, "BENCHMARK.json")
     with open(path) as f:
         bench = json.load(f)
@@ -185,11 +177,7 @@ def toy_walk(tmp_path_factory):
     _add_the_toy_family(root)
     for p, stamp in before.items():  # no file that was there changed
         assert (os.path.getmtime(p), os.path.getsize(p)) == stamp, p
-    got = subprocess.run([sys.executable, os.path.join(root, "benchmark", "tests", "toy_walk.py")],
-                         cwd=root, env=dict(os.environ, JAX_PLATFORMS="cpu"),
-                         capture_output=True, text=True, timeout=900)
-    assert got.returncode == 0, got.stderr[-3000:]
-    out = json.loads(got.stdout.strip().splitlines()[-1])
+    out = walks.run_toy_walk(root)
     assert out["root"] == root
     return out
 
@@ -210,6 +198,23 @@ def test_a_family_that_is_no_image_classifier_comes_as_files_alone(toy_walk):
                                    "control_fp8"])
 def test_a_fault_of_the_toy_family_fails_a_limit(toy_walk, fault):
     assert not toy_walk[fault]["correct"] and toy_walk[fault]["over"], toy_walk[fault]
+
+
+@pytest.mark.parametrize("variant", ["sound", "half_batch", "quarter_batch", "control"])
+def test_every_number_of_the_toy_walk_is_the_pinned_one(toy_walk, variant):
+    """As ``test_walk.py`` holds the two image families: what the walk gave
+    at PR 27, before its buffers were donated (to 1e-6)."""
+    assert walks.gap(walks.numbers(toy_walk["raw"][variant]),
+                     walks.pinned()["toy"][variant]) <= 1e-6
+
+
+def test_the_toy_walk_with_its_moments_on_the_host_changes_no_number(toy_walk):
+    """The rule that ``after_update`` moves and the leaf with no gradient go
+    through the grouped update too."""
+    on_host = toy_walk["raw_on_host"]
+    assert on_host["walk"] == dict(on_host["walk"], moments="host", groups=3)
+    assert toy_walk["raw"]["sound"]["walk"]["moments"] == "device"
+    assert walks.gap(walks.numbers(on_host), walks.numbers(toy_walk["raw"]["sound"])) <= 1e-7
 
 
 def test_the_toy_familys_readers_find_a_kernel_by_name_and_a_scope(toy_walk):
@@ -242,6 +247,28 @@ def test_what_the_program_does_not_hold_comes_from_the_family_or_the_file():
                           "optimizer": {"eps": 1e-8}}, NS())
     with pytest.raises(spec.SpecError, match="d_model=32"):
         program._stated(cfg, {"model": {"d_model": 32}, "optimizer": {}}, NS())
+
+
+def test_the_first_moment_is_found_in_a_partitioned_optimizers_state():
+    """A program that gives a rule-moved leaf an optimizer of its own keeps
+    a dict of states, one per label: the reader walks it, passes the label
+    that has no moment, and does not take an array's ``trace`` method for one."""
+    import jax.numpy as jnp
+    import optax
+
+    from benchmark.harness.program import Program
+    params = {"w": jnp.ones((2, 3)), "count_bias": jnp.zeros(3)}
+    tx = optax.multi_transform({"a_ruled": optax.set_to_zero(), "adam": optax.adamw(1e-3)},
+                               {"w": "adam", "count_bias": "a_ruled"})
+    state = tx.init(params)
+    assert isinstance(state.inner_states, dict)
+    mu = Program.first_moment((optax.EmptyState(), state))
+    assert mu["w"].shape == (2, 3) and not hasattr(mu["count_bias"], "shape")  # masked out
+    # the chain of one optimizer, as the accepted cells have it, reads as before
+    chain = optax.chain(optax.clip_by_global_norm(1.0), optax.sgd(0.1, momentum=0.9)).init(params)
+    assert set(Program.first_moment(chain)) == {"w", "count_bias"}
+    with pytest.raises(spec.SpecError, match="no first moment"):
+        Program.first_moment((optax.EmptyState(), {"count": jnp.zeros(())}))
 
 
 def test_the_device_gate_exits_non_zero_on_the_cpu_and_prints_no_result():

@@ -39,6 +39,14 @@ def main() -> int:
 
     in_blocks = walk()
     blocks_a_step = len(calls) // bounds[-1]
+    faulted = {"half_batch": walk(fault="half_batch"),
+               "quarter_batch": walk(fault="quarter_batch"),
+               "control": walk(precision=cell.config["control_precision"])}
+    # the same walk with the moments made to wait on the host: a limit that
+    # the walk's 20 bytes a parameter pass the half of, three groups of leaves
+    stated_limit, follow.device_bytes_limit = follow.device_bytes_limit, lambda devices: 8 * 4 * 3120
+    on_host = walk()
+    follow.device_bytes_limit = stated_limit
     per_device, fam.EXAMPLE_BLOCK = fam.EXAMPLE_BLOCK, None
     ref = walk()
     fam.EXAMPLE_BLOCK = per_device
@@ -66,10 +74,12 @@ def main() -> int:
         "blocks_a_step": blocks_a_step,
         "ruled_moved": ref["change"]["norm"]["count_bias"],
         "sound": verdict(in_blocks),
-        "half_batch": verdict(walk(fault="half_batch")),
+        "half_batch": verdict(faulted["half_batch"]),
         "second_term_left_out": verdict(walk(changed(second_head_weight=0.0))),
         "rule_left_out": verdict(walk(changed(rule_rate=0.0))),
-        "control_fp8": verdict(walk(precision=cell.config["control_precision"])),
+        "control_fp8": verdict(faulted["control"]),
+        # every number of the walk in blocks, for benchmark/tests/pinned_walks.json
+        "raw": dict(faulted, sound=in_blocks), "raw_on_host": on_host,
     }
     print(json.dumps(out))
     return 0
