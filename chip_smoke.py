@@ -61,7 +61,7 @@ DATA_PREP = {
 
 #: run order. ``serve`` is last and is the one leg the issue allows dropping
 LEGS = ("kernels", "cifar", "cifar_resume", "imagenet", "long_context",
-        "serve")
+        "overlap", "serve")
 #: legs that need an earlier leg's artifacts
 NEEDS = {"cifar_resume": "cifar", "serve": "cifar"}
 
@@ -74,6 +74,8 @@ CIFAR_STEPS = 200
 CIFAR_RESUME_STEPS = 50
 IMAGENET_STEPS = 20
 LONG_CONTEXT_STEPS = 3
+#: chips the ``overlap`` leg lays ``mesh.data`` over (one v5e host)
+OVERLAP_CHIPS = 4
 
 
 # ---------------------------------------------------------------------------
@@ -471,6 +473,96 @@ def leg_long_context(device, cache_dir, extra) -> dict:
         min_custom_calls=5, cache_dir=cache_dir, extra=extra)
 
 
+def _two_fused_steps(overrides: dict, devices, options: bool = True):
+    """Build a tiny Trainer over ``mesh.data=OVERLAP_CHIPS`` with
+    ``comm.overlap`` left at ``auto`` and run ONE dispatch of two fused
+    steps on seeded batches. ``options=False`` takes the exchange's
+    compiler options off the step before it is built (the comparison; no
+    config field does that). Returns (the trainer's resolutions, the
+    bucket plan's row or None, the second step's loss)."""
+    import numpy as np
+    from distributed_resnet_tensorflow_tpu.parallel.mesh import create_mesh
+    from distributed_resnet_tensorflow_tpu.parallel.overlap import (
+        overlap_stats)
+    from distributed_resnet_tensorflow_tpu.parallel.sharding import (
+        shard_stacked_batch)
+    from distributed_resnet_tensorflow_tpu.train.loop import Trainer
+    from distributed_resnet_tensorflow_tpu.utils.config import get_preset
+    cfg = get_preset("smoke")
+    for key, value in {**overrides, "mesh.data": OVERLAP_CHIPS}.items():
+        cfg.override(key, value)
+    overlap_stats.reset()
+    trainer = Trainer(cfg, mesh=create_mesh(cfg.mesh, devices=devices))
+    if not options:
+        trainer._step_compiler_options = None
+    trainer.init_state(0)
+    rng = np.random.RandomState(30)
+    size, batch = cfg.data.image_size, cfg.train.batch_size
+    stacked = {
+        "images": rng.randn(2, batch, size, size, 3).astype(np.float32),
+        "labels": rng.randint(0, cfg.model.num_classes,
+                              (2, batch)).astype(np.int32)}
+    _, metrics = trainer.jitted_multi_step()(
+        trainer.state, shard_stacked_batch(stacked, trainer.mesh))
+    return (trainer.resolutions(), overlap_stats.snapshot(),
+            float(np.asarray(metrics["loss"])))
+
+
+def leg_overlap(device, cache_dir, extra) -> dict:
+    """Leg 5 — the gradient exchange of a one-host mesh. A tiny ViT and a
+    tiny cross-replica-BN ResNet on ``mesh.data=4`` with ``comm.overlap``
+    left at ``auto`` must resolve it OFF and build the step under the
+    exchange's compiler options (parallel/overlap.
+    exchange_compiler_options: one process, a mesh of data shards alone,
+    a TPU backend); two fused steps must then give the loss of the same
+    steps built without the options."""
+    import jax
+    from distributed_resnet_tensorflow_tpu.parallel.overlap import (
+        EXCHANGE_COMPILER_OPTIONS)
+    if device["count"] < OVERLAP_CHIPS:
+        reason = (f"{device['count']} chip(s) present; the leg lays "
+                  f"mesh.data={OVERLAP_CHIPS} over one host's chips")
+        say("overlap", f"SKIPPED: {reason}")
+        return {"skipped": reason}
+    devices = jax.devices()[:OVERLAP_CHIPS]
+    models = {
+        "vit": {"model.name": "vit", "model.vit_dim": 128,
+                "model.vit_depth": 2, "model.vit_heads": 4,
+                "model.vit_patch_size": 4, "model.attention_impl": "dense",
+                "train.batch_size": 32},
+        "resnet_cross_replica_bn": {"model.resnet_size": 8,
+                                    "model.cross_replica_bn": "true",
+                                    "train.batch_size": 32},
+    }
+    out = {}
+    for name, overrides in models.items():
+        got, no_plan, loss = _two_fused_steps(overrides, devices)
+        built = got["step.compiler_options"]
+        check(got["comm.overlap"] == "off" and no_plan is None,
+              f"{name}: comm.overlap=auto resolved {got['comm.overlap']!r} "
+              f"on {OVERLAP_CHIPS} TPU chips of one process (plan "
+              f"{no_plan})")
+        check(all(f"{k}={v}" in built
+                  for k, v in EXCHANGE_COMPILER_OPTIONS.items()),
+              f"{name}: the step was built under {built!r}")
+        plain, _, loss_plain = _two_fused_steps(overrides, devices,
+                                                options=False)
+        check(plain["step.compiler_options"] == "none",
+              f"{name}: the comparison was built under "
+              f"{plain['step.compiler_options']!r}")
+        # the options move when an all-reduce runs, not what it sums
+        gap = abs(loss - loss_plain) / max(abs(loss_plain), 1e-6)
+        check(gap < 1e-3,
+              f"{name}: loss after two steps {loss!r} with the options, "
+              f"{loss_plain!r} without them")
+        say("overlap", f"{name}: auto resolved comm.overlap=off, step "
+                       f"built under {built}; loss after 2 fused steps "
+                       f"{loss:.6f}, {loss_plain:.6f} without the options "
+                       f"(gap {gap:.1e})")
+        out[name] = {"loss": loss, "loss_plain": loss_plain}
+    return out
+
+
 def leg_serve(device, cache_dir, extra) -> dict:
     """Leg 4 — ``main.py serve`` against leg 1's checkpoints with a short
     built-in open-loop load: every bucket AOT-compiled, nothing dropped,
@@ -600,7 +692,8 @@ def leg_kernels(device, cache_dir, extra) -> dict:
 
 CHILD_LEGS = {"kernels": leg_kernels, "cifar": leg_cifar,
               "cifar_resume": leg_cifar_resume, "imagenet": leg_imagenet,
-              "long_context": leg_long_context, "serve": leg_serve}
+              "long_context": leg_long_context, "overlap": leg_overlap,
+              "serve": leg_serve}
 
 
 def child_main(leg: str, extra) -> int:

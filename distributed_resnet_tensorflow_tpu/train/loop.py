@@ -387,8 +387,13 @@ class Trainer:
         # cross-replica-BN numerics. comm.overlap=on raises here when the
         # (model, mesh, train) combination is outside the envelope.
         from ..parallel.overlap import (BATCH_AXES, compress_dtype,
+                                        exchange_compiler_options,
                                         resolve_overlap)
         self._overlap = resolve_overlap(cfg, self.mesh)
+        # what every train-step program is compiled under: on one TPU
+        # host with a mesh of data shards alone, the options that let the
+        # compiler hide the gradient all-reduces (None everywhere else)
+        self._step_compiler_options = exchange_compiler_options(self.mesh)
         bn_axis_name = BATCH_AXES if self._overlap is not None else None
         # compressed gradient exchange (comm.compress) rides the bucketed
         # overlap — validate the knob even when the exchange is off, and
@@ -665,6 +670,9 @@ class Trainer:
             "attention": attention,
             "comm.overlap": onoff(self.comm_overlap_active),
             "zero1": onoff(self.zero1_active),
+            "step.compiler_options": ",".join(
+                f"{k}={v}" for k, v in
+                (self._step_compiler_options or {}).items()) or "none",
         }
 
     def _zero1_min_size(self) -> int:
@@ -850,7 +858,8 @@ class Trainer:
                 self._train_step,
                 in_shardings=(st_sh, {"images": b_sh, "labels": b_sh}),
                 out_shardings=(st_sh, None),
-                donate_argnums=(0,))
+                donate_argnums=(0,),
+                compiler_options=self._step_compiler_options)
         return self._jitted_train
 
     @property
@@ -910,7 +919,8 @@ class Trainer:
                 multi,
                 in_shardings=(st_sh, {"images": b_sh, "labels": b_sh}),
                 out_shardings=(st_sh, None),
-                donate_argnums=(0,))
+                donate_argnums=(0,),
+                compiler_options=self._step_compiler_options)
         return self._jitted_multi
 
     def jitted_eval_step(self):
@@ -1004,7 +1014,8 @@ class Trainer:
                 self._gathered_step(),
                 in_shardings=(st_sh, {"idx": b_sh}, rep, rep),
                 out_shardings=(st_sh, None),
-                donate_argnums=(0,))
+                donate_argnums=(0,),
+                compiler_options=self._step_compiler_options)
             self._jitted_idx_raw = jit_fn
             self._jitted_idx = \
                 lambda s, b: jit_fn(s, b, *self._dev_data)
@@ -1070,7 +1081,8 @@ class Trainer:
                 multi,
                 in_shardings=(st_sh, {"idx": b_sh}, rep, rep),
                 out_shardings=(st_sh, None),
-                donate_argnums=(0,))
+                donate_argnums=(0,),
+                compiler_options=self._step_compiler_options)
             self._jitted_idx_multi = \
                 lambda s, b: jit_fn(s, b, *self._dev_data)
         return self._jitted_idx_multi

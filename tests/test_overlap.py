@@ -15,9 +15,10 @@ import pytest
 import jax
 
 from distributed_resnet_tensorflow_tpu.parallel import create_mesh
+from distributed_resnet_tensorflow_tpu.parallel import overlap as overlap_mod
 from distributed_resnet_tensorflow_tpu.parallel.overlap import (
-    overlap_stats, overlap_unsupported_reason, plan_buckets,
-    resolve_overlap)
+    EXCHANGE_COMPILER_OPTIONS, exchange_compiler_options, overlap_stats,
+    overlap_unsupported_reason, plan_buckets, resolve_overlap)
 from distributed_resnet_tensorflow_tpu.train import Trainer
 from distributed_resnet_tensorflow_tpu.utils.config import (MeshConfig,
                                                             get_preset)
@@ -334,7 +335,8 @@ def test_resolver_gates(devices):
     mesh = create_mesh(MeshConfig(data=8))
     # off → None regardless of support
     assert resolve_overlap(_tiny_cfg(**{"comm.overlap": "off"}), mesh) is None
-    # auto on a single-process run stays off (the DCN path is the target)
+    # auto on a single-process run stays off (the DCN path is the target;
+    # test_auto_rule walks the whole rule, backend included)
     assert resolve_overlap(_tiny_cfg(), mesh) is None
     # on → forced
     plan = resolve_overlap(_tiny_cfg(**{"comm.overlap": "on"}), mesh)
@@ -381,6 +383,150 @@ def test_resolver_gates(devices):
     single = create_mesh(MeshConfig(data=1), devices=jax.devices()[:1])
     assert resolve_overlap(_tiny_cfg(**{"comm.overlap": "on"}),
                            single) is None
+
+
+@pytest.fixture
+def compiler_takes_the_options(monkeypatch):
+    """The CPU compiler knows none of the TPU compiler's options: stand in
+    for a libtpu that takes them (the refusal has its own test)."""
+    monkeypatch.setattr(overlap_mod, "_compiler_refusal", lambda options: None)
+
+
+@pytest.mark.parametrize("in_envelope", [True, False])
+@pytest.mark.parametrize("processes", [1, 2])
+@pytest.mark.parametrize("shards", [1, 8])
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_auto_rule(monkeypatch, devices, compiler_takes_the_options,
+                   backend, shards, processes, in_envelope):
+    """What a mesh and a backend get. ``comm.overlap=auto``: the bucketed
+    exchange iff there is an exchange (more than one batch shard), the
+    envelope takes the combination and the run has peers — whatever the
+    backend (on one TPU host the bucketed path lost, PERF.md §6 PR 30).
+    The step programs' compiler options: one process, more than one data
+    shard, a TPU backend — whatever the envelope says."""
+    mesh = create_mesh(MeshConfig(data=shards),
+                       devices=jax.devices()[:shards])
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax, "process_count", lambda: processes)
+    cfg = _tiny_cfg(**({} if in_envelope
+                       else {"model.cross_replica_bn": "false"}))
+    plan = resolve_overlap(cfg, mesh)
+    assert (plan is not None) == (shards > 1 and in_envelope
+                                  and processes > 1)
+    if plan is not None:
+        # nothing else resolves on with it: the wire stays float32, flat
+        assert plan.compress is None and plan.hierarchy is None
+        assert plan.autotune == "off" and not plan.tuned
+    options = exchange_compiler_options(mesh)
+    if shards > 1 and processes == 1 and backend == "tpu":
+        assert options == EXCHANGE_COMPILER_OPTIONS
+        assert options is not EXCHANGE_COMPILER_OPTIONS  # a copy
+    else:
+        assert options is None
+    # the seams are untouched by backend and process count
+    cfg.comm.overlap = "off"
+    assert resolve_overlap(cfg, mesh) is None
+
+
+@pytest.mark.parametrize("axes,expect", [
+    ({"data": 8}, True),
+    ({"data": 4}, True),                  # the shape that ran on the chips
+    ({"data": 1}, False),
+    ({"data": 4, "fsdp": 2}, False),      # reduce-scatter and all-gather
+    ({"data": 1, "fsdp": 8}, False),
+    ({"data": 4, "tensor": 2}, False),    # activation all-reduces beside
+    ({"data": 2, "pipeline": 2}, False),
+    ({"data": 2, "expert": 2}, False),
+    ({"data": 2, "sequence": 2}, False),
+])
+def test_options_only_on_a_mesh_of_data_shards(
+        monkeypatch, devices, compiler_takes_the_options, axes, expect):
+    """The options were measured on ``mesh.data=4`` and nothing else: a
+    mesh with any other axis of size > 1 keeps the compiler's defaults."""
+    count = int(np.prod(list(axes.values())))
+    mesh = create_mesh(MeshConfig(**axes), devices=jax.devices()[:count])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    got = exchange_compiler_options(mesh)
+    assert got == (EXCHANGE_COMPILER_OPTIONS if expect else None)
+
+
+def test_a_compiler_that_refuses_the_options_gets_none(monkeypatch, devices,
+                                                       caplog):
+    """On another libtpu an option's name may be gone, and an unknown name
+    fails every compile. The CPU compiler here is such a compiler: asked
+    once, it refuses, and the Trainer builds its steps without options and
+    says so, instead of failing its first step."""
+    overlap_mod._compiler_refusal.cache_clear()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = create_mesh(MeshConfig(data=8))
+    with caplog.at_level("WARNING", logger=overlap_mod.__name__):
+        assert exchange_compiler_options(mesh) is None
+    assert "No such compile option" in caplog.text
+    tr = Trainer(_tiny_cfg(), mesh=mesh)
+    assert tr._step_compiler_options is None
+    assert tr.resolutions()["step.compiler_options"] == "none"
+    assert overlap_mod._compiler_refusal.cache_info().misses == 1  # asked once
+    overlap_mod._compiler_refusal.cache_clear()
+
+
+@pytest.mark.parametrize("backend,shards,expect", [
+    ("cpu", 8, False),   # the tier-1 mesh: the programs it always had
+    ("tpu", 1, False),   # one chip: no exchange, no option
+    ("tpu", 8, True),    # one TPU host, more than one data shard
+])
+def test_step_jit_sites_get_the_options(monkeypatch, devices,
+                                        compiler_takes_the_options, backend,
+                                        shards, expect):
+    """All four train-step ``jax.jit`` sites (a streamed batch or a device
+    dataset's indices, one step or a fused group) are handed the
+    exchange's compiler options exactly when ``exchange_compiler_options``
+    gives them, no other jit site of the Trainer is, and the resolved
+    line says which options the step was built with."""
+    cfg = _tiny_cfg()
+    mesh = create_mesh(MeshConfig(data=shards),
+                       devices=jax.devices()[:shards])
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    tr = Trainer(cfg, mesh=mesh)
+    # init_state and attach compile for the backend that is there
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    tr.init_state()
+    tr.attach_device_dataset(np.zeros((16, 8, 8, 3), np.uint8),
+                             np.zeros((16,), np.int32))
+    seen = []
+    real_jit = jax.jit
+
+    def recording_jit(fun, **kw):
+        seen.append((fun, kw.get("compiler_options")))
+        return real_jit(fun, **{k: v for k, v in kw.items()
+                                if k != "compiler_options"})
+
+    monkeypatch.setattr(jax, "jit", recording_jit)
+    tr.jitted_train_step()
+    tr.jitted_multi_step()
+    tr.jitted_index_step()
+    tr.jitted_index_multi_step()
+    tr.jitted_eval_step()
+    tr.jitted_predict_step()
+    want = EXCHANGE_COMPILER_OPTIONS if expect else None
+    steps, others = seen[:4], seen[4:]
+    assert steps[0][0] is tr._train_step
+    assert [f.__name__ for f, _ in steps[1:]] == \
+        ["multi", "gathered_train_step", "multi"]
+    assert all(got == want for _, got in steps)
+    assert len(others) == 2 and all(got is None for _, got in others)
+    line = tr.resolutions()["step.compiler_options"]
+    assert tr.resolutions()["comm.overlap"] == "off"
+    if expect:
+        assert all(f"{k}={v}" in line
+                   for k, v in EXCHANGE_COMPILER_OPTIONS.items())
+    else:
+        assert line == "none"
+
+
+def test_chip_smoke_has_the_overlap_leg():
+    import chip_smoke
+    assert "overlap" in chip_smoke.LEGS and \
+        chip_smoke.CHILD_LEGS["overlap"] is chip_smoke.leg_overlap
 
 
 def test_per_replica_bn_envelope_exceptions(devices):
