@@ -474,16 +474,13 @@ def leg_long_context(device, cache_dir, extra) -> dict:
 
 
 def _two_fused_steps(overrides: dict, devices, options: bool = True):
-    """Build a tiny Trainer over ``mesh.data=OVERLAP_CHIPS`` with
-    ``comm.overlap`` left at ``auto`` and run ONE dispatch of two fused
-    steps on seeded batches. ``options=False`` takes the exchange's
-    compiler options off the step before it is built (the comparison; no
-    config field does that). Returns (the trainer's resolutions, the
-    bucket plan's row or None, the second step's loss)."""
+    """Build a tiny Trainer over ``mesh.data=OVERLAP_CHIPS`` and run ONE
+    dispatch of two fused steps on seeded batches. ``options=False`` takes
+    the exchange's compiler options off the step before it is built (the
+    comparison; no config field does that). Returns (the trainer's
+    resolutions, the second step's loss)."""
     import numpy as np
     from distributed_resnet_tensorflow_tpu.parallel.mesh import create_mesh
-    from distributed_resnet_tensorflow_tpu.parallel.overlap import (
-        overlap_stats)
     from distributed_resnet_tensorflow_tpu.parallel.sharding import (
         shard_stacked_batch)
     from distributed_resnet_tensorflow_tpu.train.loop import Trainer
@@ -491,7 +488,6 @@ def _two_fused_steps(overrides: dict, devices, options: bool = True):
     cfg = get_preset("smoke")
     for key, value in {**overrides, "mesh.data": OVERLAP_CHIPS}.items():
         cfg.override(key, value)
-    overlap_stats.reset()
     trainer = Trainer(cfg, mesh=create_mesh(cfg.mesh, devices=devices))
     if not options:
         trainer._step_compiler_options = None
@@ -504,15 +500,13 @@ def _two_fused_steps(overrides: dict, devices, options: bool = True):
                               (2, batch)).astype(np.int32)}
     _, metrics = trainer.jitted_multi_step()(
         trainer.state, shard_stacked_batch(stacked, trainer.mesh))
-    return (trainer.resolutions(), overlap_stats.snapshot(),
-            float(np.asarray(metrics["loss"])))
+    return trainer.resolutions(), float(np.asarray(metrics["loss"]))
 
 
 def leg_overlap(device, cache_dir, extra) -> dict:
     """Leg 5 — the gradient exchange of a one-host mesh. A tiny ViT and a
-    tiny cross-replica-BN ResNet on ``mesh.data=4`` with ``comm.overlap``
-    left at ``auto`` must resolve it OFF and build the step under the
-    exchange's compiler options (parallel/overlap.
+    tiny cross-replica-BN ResNet on ``mesh.data=4`` must build the step
+    under the exchange's compiler options (parallel/overlap.
     exchange_compiler_options: one process, a mesh of data shards alone,
     a TPU backend); two fused steps must then give the loss of the same
     steps built without the options."""
@@ -536,17 +530,13 @@ def leg_overlap(device, cache_dir, extra) -> dict:
     }
     out = {}
     for name, overrides in models.items():
-        got, no_plan, loss = _two_fused_steps(overrides, devices)
+        got, loss = _two_fused_steps(overrides, devices)
         built = got["step.compiler_options"]
-        check(got["comm.overlap"] == "off" and no_plan is None,
-              f"{name}: comm.overlap=auto resolved {got['comm.overlap']!r} "
-              f"on {OVERLAP_CHIPS} TPU chips of one process (plan "
-              f"{no_plan})")
         check(all(f"{k}={v}" in built
                   for k, v in EXCHANGE_COMPILER_OPTIONS.items()),
               f"{name}: the step was built under {built!r}")
-        plain, _, loss_plain = _two_fused_steps(overrides, devices,
-                                                options=False)
+        plain, loss_plain = _two_fused_steps(overrides, devices,
+                                             options=False)
         check(plain["step.compiler_options"] == "none",
               f"{name}: the comparison was built under "
               f"{plain['step.compiler_options']!r}")
@@ -555,8 +545,7 @@ def leg_overlap(device, cache_dir, extra) -> dict:
         check(gap < 1e-3,
               f"{name}: loss after two steps {loss!r} with the options, "
               f"{loss_plain!r} without them")
-        say("overlap", f"{name}: auto resolved comm.overlap=off, step "
-                       f"built under {built}; loss after 2 fused steps "
+        say("overlap", f"{name}: step built under {built}; loss after 2 fused steps "
                        f"{loss:.6f}, {loss_plain:.6f} without the options "
                        f"(gap {gap:.1e})")
         out[name] = {"loss": loss, "loss_plain": loss_plain}

@@ -41,8 +41,7 @@ def main_check(argv: Optional[Sequence[str]] = None) -> int:
                         "collective-schedule extraction and the "
                         "thread/lock contract rules (cross-thread-"
                         "dispatch, untimed-blocking-call, chief-gated-"
-                        "collective, lock-order-cycle); elaborate then "
-                        "re-owns the overlap/compress step traces")
+                        "collective, lock-order-cycle)")
     p.add_argument("--no-plan-drift", action="store_true",
                    help="skip the plan-drift phase (ISSUE 17): the "
                         "what-if planner's predictions over the "
@@ -97,8 +96,7 @@ def main_check(argv: Optional[Sequence[str]] = None) -> int:
         from .elaborate import run_elaborate
         t1 = time.perf_counter()
         presets = ns.preset or None  # None = all
-        efs = run_elaborate(presets, n_devices=ns.devices,
-                            trace_comm_variants=ns.no_hangcheck)
+        efs = run_elaborate(presets, n_devices=ns.devices)
         print(f"elaborate: {len(efs)} finding(s) "
               f"[{time.perf_counter() - t1:.1f}s]")
         findings += efs
@@ -111,11 +109,8 @@ def main_check(argv: Optional[Sequence[str]] = None) -> int:
             findings += zfs
         if not ns.no_hangcheck:
             # hangcheck-schedule (docs/static_analysis.md): collective
-            # schedules extracted from the traced jaxprs, determinism +
-            # declared-bucket-plan cross-checks, reviewable artifact.
-            # This phase OWNS the overlap/compress step traces while it
-            # runs (trace_comm_variants=False above) — same trace, more
-            # signal.
+            # schedules extracted from the traced jaxprs, determinism
+            # check, reviewable artifact.
             from .collectives import run_collectives, write_artifact
             t3 = time.perf_counter()
             cfs, sigs = run_collectives(presets, n_devices=ns.devices)

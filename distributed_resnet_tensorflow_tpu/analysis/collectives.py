@@ -1,41 +1,33 @@
 """Collective-schedule extraction: pin the comm program statically.
 
 The collective *schedule* — which collectives a step issues, in what
-order, over which axes, with how many wire bytes — is a real program
-property now that the exchange is explicit (bucketed overlap, ZeRO-1
-scatter/gather, compressed payloads, pipeline ppermute chains): a
-reordering or a silently-merged bucket is a perf regression at best and
-a cross-host deadlock at worst (two hosts issuing collectives in
-different orders is the hang class the watchdog can only kill). This
-phase walks the jaxprs of the already-elaborated step variants and:
+order, over which axes, with how many wire bytes — is a program property
+wherever the program writes its collectives out (the pipeline's ppermute
+chains, the expert all-to-all, ring attention): a reordering is a perf
+regression at best and a cross-host deadlock at worst (two hosts issuing
+collectives in different orders is the hang class the watchdog can only
+kill). This phase walks the jaxprs of the already-elaborated step
+variants and:
 
   * emits an ordered signature of collective ops (kind, axis names,
     operand count, payload bytes) per preset × layout × variant. Bytes
     are PER-PARTICIPANT payloads (inside shard_map the traced avals are
-    the local shards), and the traced dtype makes compressed payloads
-    show their true wire bytes;
+    the local shards);
   * asserts the signature is DETERMINISTIC across two elaborations for
     every variant that carries collectives (a schedule that differs
     between traces would differ between hosts);
-  * cross-checks the overlap variants against the DECLARED bucket plan
-    exported by ``parallel/overlap.py`` (``overlap_stats`` →
-    ``declared_collectives``): reverse-param-order bucket psums,
-    reduce-scatter-before-psum for fsdp/ZeRO leaves, one tuple-psum per
-    replicated group — the traced order must contain the declared
-    sequence in order, or the gate fails;
   * dumps everything as ``analysis/collective_schedules.json`` (inside
     the package, committed) — byte-identical across runs, so any PR that
     changes comm behavior shows a reviewable diff.
 
 Variants per preset (deduped across presets sharing the program, the
-``trace_forward`` lesson): the plain jit train step (its jaxpr-level
-schedule is EMPTY for the batch-parallel families by construction — the
+``trace_forward`` lesson): the jit train step (its jaxpr-level schedule
+is EMPTY for the batch-parallel families by construction — the gradient
 exchange is left to XLA sharding propagation; non-empty is itself
-information the artifact records), the shard_map'd overlap body on every
-in-envelope layout, the ZeRO-1 scatter/gather composition, the bf16 +
-compressed-exchange composition, the pipeline/tensor/expert layouts of
-the transformer family, and the serve/predict step (smallest + largest
-AOT bucket).
+information the artifact records) on the batch layout and on the
+pipeline/tensor/expert layouts of the transformer family, the same step
+on the survivor meshes of an elastic shrink, and the serve/predict step
+(smallest + largest AOT bucket).
 """
 from __future__ import annotations
 
@@ -48,8 +40,8 @@ from .report import Finding
 
 RULE = "hangcheck-schedule"
 
-#: the preset whose dp_fsdp overlap variant is double-traced as the
-#: in-run determinism probe (cheapest in-envelope conv program)
+#: the preset whose step is re-traced on the survivor meshes of an
+#: elastic shrink (cheapest conv program)
 _DET_PROBE = "cifar10_resnet50"
 
 #: jaxpr primitive name → normalized op kind. ``psum_invariant`` is what
@@ -117,24 +109,12 @@ def collect_ops(jaxpr) -> List[dict]:
     for eqn in jaxpr.eqns:
         kind = WIRE_PRIMS.get(eqn.primitive.name)
         if kind is not None:
-            op = {
+            out.append({
                 "op": kind,
                 "axes": list(_axes_of(eqn)),
                 "operands": len(eqn.invars),
                 "bytes": _payload_bytes(eqn),
-            }
-            # grouped (two-tier) collectives — the hierarchical exchange
-            # (parallel/overlap): record the group SIZE (the tier width)
-            # and which tier the grouping selects — consecutive device
-            # blocks are the intra-host tier under the host-aware device
-            # order, strided columns the inter-host tier
-            groups = eqn.params.get("axis_index_groups")
-            if groups:
-                g0 = [int(x) for x in groups[0]]
-                op["groups"] = len(g0)
-                op["tier"] = "intra" if g0 == list(
-                    range(g0[0], g0[0] + len(g0))) else "inter"
-            out.append(op)
+            })
         for sub in _sub_jaxprs(eqn):
             out.extend(collect_ops(sub))
     return out
@@ -145,38 +125,6 @@ def extract_schedule(fn, *abstract_args) -> List[dict]:
     collective signature."""
     import jax
     return collect_ops(jax.make_jaxpr(fn)(*abstract_args).jaxpr)
-
-
-def _op_sig(op: dict) -> str:
-    sig = f"{op['op']}@" + "+".join(op["axes"])
-    # grouped collectives carry the group size, matching the declared
-    # plan's tier suffix ("psum_scatter@data[4]"); ungrouped ops keep the
-    # PRE-EXISTING signature form so committed artifacts stay byte-stable
-    if op.get("groups"):
-        sig += f"[{op['groups']}]"
-    return sig
-
-
-def check_declared_plan(schedule: Sequence[dict],
-                        declared: Sequence[Sequence[str]],
-                        locus: str) -> List[Finding]:
-    """The declared per-bucket collective sequences must appear, in
-    order, within the traced schedule (the trace additionally carries
-    the forward fsdp all-gathers and the loss/metric psums around the
-    exchange — subsequence matching, not equality)."""
-    flat_declared = [sig for bucket in declared for sig in bucket]
-    traced = [_op_sig(op) for op in schedule]
-    it = iter(traced)
-    missing = [sig for sig in flat_declared
-               if not any(t == sig for t in it)]
-    if missing:
-        return [Finding(
-            RULE, locus, 0,
-            f"traced collective schedule does not contain the declared "
-            f"bucket plan in order — first missing {missing[0]!r} "
-            f"(declared {len(flat_declared)} exchange ops over "
-            f"{len(declared)} buckets; traced {traced})")]
-    return []
 
 
 def _schedule_key(name: str, layout: str, variant: str) -> str:
@@ -200,11 +148,9 @@ def _abstract_state(trainer, cfg):
     from ..parallel.mesh import batch_shard_count
     nb = batch_shard_count(trainer.mesh)
     # the memoized state embeds apply_fn — a module bound to ITS mesh.
-    # Shaping axes bake into the module's program (pipeline microbatching,
-    # the exchange-inline local param shapes), so two layouts may share a
-    # state only when their full shaping signature matches; keying on the
-    # batch-shard count alone handed dp_pp_ep a dp_pp-meshed apply_fn
-    # (same nb=2) and the exchange-inline flax shape check caught it
+    # Shaping axes bake into the module's program (pipeline
+    # microbatching), so two layouts may share a state only when their
+    # full shaping signature matches
     key = repr((dataclasses.asdict(cfg.model), cfg.optimizer.name,
                 cfg.data.dataset, cfg.data.image_size, nb,
                 tuple(trainer.mesh.shape.get(a, 1)
@@ -230,8 +176,6 @@ def run_collectives(preset_names: Optional[Sequence[str]] = None,
     import dataclasses
     import jax
     from ..parallel.mesh import create_mesh
-    from ..parallel.overlap import (overlap_stats,
-                                    overlap_unsupported_reason)
     from ..utils.config import MeshConfig, PRESETS, get_preset
     from .elaborate import candidate_layouts, _abstract_batch, \
         _axis_product
@@ -256,12 +200,10 @@ def run_collectives(preset_names: Optional[Sequence[str]] = None,
         return False
 
     def record(name: str, layout: str, variant: str, builder,
-               deterministic_retrace: bool, plan_check: bool) -> None:
-        """Trace (maybe twice), cross-check, record the signature."""
+               deterministic_retrace: bool) -> None:
+        """Trace (maybe twice) and record the signature."""
         locus = _schedule_key(name, layout, variant)
         try:
-            if plan_check:
-                overlap_stats.reset()
             schedule = builder()
         except Exception as e:
             msg = f"{type(e).__name__}: {e}".splitlines()[0][:300]
@@ -270,34 +212,6 @@ def run_collectives(preset_names: Optional[Sequence[str]] = None,
                                     detail=str(e)[:4000]))
             return
         entry: dict = {"ops": schedule}
-        if plan_check:
-            snap = overlap_stats.snapshot()
-            if snap is None or not snap.get("declared_collectives"):
-                findings.append(Finding(
-                    RULE, locus, 0,
-                    "overlap variant traced but parallel/overlap.py "
-                    "recorded no declared bucket plan — the exchange "
-                    "did not run through make_bucketed_grad"))
-            else:
-                findings.extend(check_declared_plan(
-                    schedule, snap["declared_collectives"], locus))
-                entry["plan"] = {
-                    "buckets": snap["buckets"],
-                    "bucket_bytes": snap["bucket_bytes"],
-                    "bucket_wire_bytes": snap["bucket_wire_bytes"],
-                    "compress": snap["compress"],
-                    "declared_collectives": snap["declared_collectives"],
-                }
-                # hierarchical plans carry the tier factor, the per-op
-                # wire ledger and the inter-tier bytes (the 1/k claim,
-                # diffable in the artifact). Flat plans omit the keys so
-                # every PRE-EXISTING family stays byte-identical.
-                if snap.get("hierarchy"):
-                    entry["plan"]["hierarchy"] = snap["hierarchy"]
-                    entry["plan"]["bucket_op_wire_bytes"] = \
-                        snap["bucket_op_wire_bytes"]
-                    entry["plan"]["bucket_inter_wire_bytes"] = \
-                        snap["bucket_inter_wire_bytes"]
         if deterministic_retrace and schedule:
             second = builder()
             if second != schedule:
@@ -313,22 +227,6 @@ def run_collectives(preset_names: Optional[Sequence[str]] = None,
         cfg = get_preset(name)
         layouts = candidate_layouts(cfg, n_devices)
         traced_plain = False
-        # the low-precision composition (variant 3 below) prefers dp_fsdp
-        # (both batch axes live) but must not vanish for a family whose
-        # only in-envelope layout is dp — elaborate's elab-precision-step
-        # traced it on the first supported layout before hangcheck took
-        # the comm traces over (trace_comm_variants=False)
-        compress_label = None
-        if cfg.train.precision == "off":
-            for _lbl, _mc in layouts:
-                try:
-                    _m = create_mesh(_mc, devices=jax.devices()
-                                     [:_axis_product(_mc)])
-                except Exception:
-                    continue
-                if overlap_unsupported_reason(cfg, _m) is None and \
-                        (compress_label is None or _lbl == "dp_fsdp"):
-                    compress_label = _lbl
         for label, mesh_cfg in layouts:
             n = _axis_product(mesh_cfg)
             try:
@@ -362,133 +260,9 @@ def run_collectives(preset_names: Optional[Sequence[str]] = None,
                                             batch)
 
                 record(name, label, "train", build_train,
-                       deterministic_retrace=shaping, plan_check=False)
+                       deterministic_retrace=shaping)
 
-            # (2) bucketed-overlap exchange, per in-envelope layout; the
-            # ZeRO-1 scatter/gather composition rides the same trace for
-            # presets that enable the knob
-            if overlap_unsupported_reason(cfg, mesh) is None:
-                zero1 = cfg.optimizer.zero1 != "off"
-                if not dedupe("overlap", cfg, label,
-                              (cfg.comm.bucket_mb, cfg.comm.compress,
-                               cfg.train.precision, zero1,
-                               cfg.optimizer.zero1_min_size)):
-
-                    def build_overlap(cfg=cfg, mesh=mesh, zero1=zero1):
-                        ocfg = copy.deepcopy(cfg)
-                        ocfg.comm.overlap = "on"
-                        if zero1:
-                            ocfg.optimizer.zero1 = "on"
-                        trainer = _trainer_for(ocfg, mesh)
-                        state = _abstract_state(trainer, cfg)
-                        batch = _abstract_batch(ocfg,
-                                                ocfg.train.batch_size)
-                        return extract_schedule(trainer._train_step,
-                                                state, batch)
-
-                    # determinism double-trace rides the cheapest
-                    # in-envelope program's dp_fsdp layout (both batch
-                    # axes live) — re-tracing EVERY variant would double
-                    # the phase for no additional signal: the machinery
-                    # under test (tree flatten order, greedy bucketing,
-                    # shard_map lowering) is shared, and cross-RUN
-                    # byte-identity of the artifact covers the rest
-                    record(name, label,
-                           "overlap+zero1" if zero1 else "overlap",
-                           build_overlap,
-                           deterministic_retrace=(label == "dp_fsdp"
-                                                  and name == _DET_PROBE),
-                           plan_check=True)
-
-                # the accumulation composition (the scan inside the
-                # exchange body, ONE bucketed exchange per optimizer
-                # step): its schedule is the family's witness that wire
-                # traffic is 1× per step — the scan body carries no
-                # exchange collectives, the declared bucket plan follows
-                # it. ONE witness per model family (the conv det-probe on
-                # both batch layouts — dp_fsdp adds the scatter+accum
-                # composition — and the smallest transformer preset):
-                # per-preset accum traces re-record the identical bucket
-                # plan and doubled the phase's cost AND the committed
-                # artifact for the big presets.
-                if not shaping and name in (_DET_PROBE, "vit_moe"):
-                    accum = 4 if cfg.train.batch_size % (n * 4) == 0 \
-                        else (2 if cfg.train.batch_size % (n * 2) == 0
-                              else 0)
-                    if accum and not dedupe(
-                            "overlap_accum", cfg, label,
-                            (cfg.comm.bucket_mb, accum)):
-
-                        def build_accum(cfg=cfg, mesh=mesh, accum=accum):
-                            acfg = copy.deepcopy(cfg)
-                            acfg.comm.overlap = "on"
-                            acfg.train.grad_accum_steps = accum
-                            trainer = _trainer_for(acfg, mesh)
-                            state = _abstract_state(trainer, cfg)
-                            batch = _abstract_batch(
-                                acfg, acfg.train.batch_size)
-                            return extract_schedule(trainer._train_step,
-                                                    state, batch)
-
-                        record(name, label, f"overlap+accum{accum}",
-                               build_accum, deterministic_retrace=False,
-                               plan_check=True)
-
-                # the hierarchical exchange (comm.hierarchy, the staged
-                # RS→psum→AG restaging of every data-reducing bucket):
-                # one witness per batch layout of the det-probe — dp
-                # factors its 8-way data axis 4×2 (the virtual "2 hosts
-                # × 4 devices"), dp_fsdp factors 4-way as 2×2 and adds
-                # the fsdp-scatter composition. The explicit
-                # intra_axis_size override stands in for multi-host
-                # device order on the single-host CPU gate.
-                if not shaping and name == _DET_PROBE:
-                    dsz = max(mesh_cfg.data, 1)
-                    hk = dsz // 2 if dsz >= 4 and dsz % 2 == 0 else 0
-                    if hk > 1 and not dedupe(
-                            "overlap_hier", cfg, label,
-                            (cfg.comm.bucket_mb, hk)):
-
-                        def build_hier(cfg=cfg, mesh=mesh, hk=hk):
-                            hcfg = copy.deepcopy(cfg)
-                            hcfg.comm.overlap = "on"
-                            hcfg.comm.hierarchy = "on"
-                            hcfg.comm.intra_axis_size = hk
-                            trainer = _trainer_for(hcfg, mesh)
-                            state = _abstract_state(trainer, cfg)
-                            batch = _abstract_batch(
-                                hcfg, hcfg.train.batch_size)
-                            return extract_schedule(trainer._train_step,
-                                                    state, batch)
-
-                        record(name, label, "overlap+hier", build_hier,
-                               deterministic_retrace=(label == "dp"),
-                               plan_check=True)
-
-                # (3) the full low-precision composition: bf16 step ×
-                # bucketed exchange × compressed payload — wire bytes in
-                # the signature come out halved because the traced
-                # operands ARE bf16. One layout (dp_fsdp exercises both
-                # batch axes) per program.
-                if label == compress_label \
-                        and not dedupe("compress", cfg, label, ()):
-
-                    def build_compress(cfg=cfg, mesh=mesh):
-                        ccfg = copy.deepcopy(cfg)
-                        ccfg.train.precision = "bf16"
-                        ccfg.comm.overlap = "on"
-                        ccfg.comm.compress = "bf16"
-                        trainer = _trainer_for(ccfg, mesh)
-                        state = _abstract_state(trainer, cfg)
-                        batch = _abstract_batch(ccfg,
-                                                ccfg.train.batch_size)
-                        return extract_schedule(trainer._train_step,
-                                                state, batch)
-
-                    record(name, label, "bf16+compress", build_compress,
-                           deterministic_retrace=False, plan_check=True)
-
-        # (3b) reshard shrink topologies (docs/resilience.md): after an
+        # (2) reshard shrink topologies (docs/resilience.md): after an
         # elastic shrink the SAME program is re-elaborated over the
         # survivor sub-mesh, and every survivor traces it independently
         # inside the reshard barrier — so the schedule on each shrunken
@@ -514,9 +288,9 @@ def run_collectives(preset_names: Optional[Sequence[str]] = None,
                                             batch)
 
                 record(name, "dp", f"reshard_s{shrink}", build_shrink,
-                       deterministic_retrace=True, plan_check=False)
+                       deterministic_retrace=True)
 
-        # (4) serve/predict step: smallest + largest AOT bucket on the
+        # (3) serve/predict step: smallest + largest AOT bucket on the
         # first layout — forward-only, so the signature pins that serving
         # carries NO hidden collectives on the batch-parallel meshes
         if layouts and not dedupe("serve", cfg, layouts[0][0],
@@ -550,7 +324,7 @@ def run_collectives(preset_names: Optional[Sequence[str]] = None,
                         return extract_schedule(trainer._predict_step,
                                                 state, sbatch)
                     record(name, label, f"serve_b{bucket}", build_serve,
-                           deterministic_retrace=False, plan_check=False)
+                           deterministic_retrace=False)
             except Exception as e:
                 findings.append(Finding(
                     RULE, _schedule_key(name, layouts[0][0], "serve"), 0,
@@ -590,6 +364,21 @@ def write_artifact(signatures: Dict[str, dict],
         f.write("\n")
     os.replace(tmp, path)
     return path
+
+
+def load_schedules(path: Optional[str] = None) -> Dict[str, dict]:
+    """The committed artifact's ``signatures`` map; empty when the file is
+    absent or unreadable (``main.py plan`` then says to run the gate)."""
+    import json
+    import logging
+    path = path or artifact_path()
+    try:
+        with open(path) as f:
+            return json.load(f).get("signatures", {})
+    except (OSError, ValueError) as e:
+        logging.getLogger(__name__).warning(
+            "no readable collective schedule at %s (%s)", path, e)
+        return {}
 
 
 def artifact_path() -> str:

@@ -14,10 +14,6 @@ pushes shape/dtype-only values through:
     errors surface at trace time; the pp×ep MoE ``_SpecError`` of
     tests/test_pipeline.py was located exactly this way),
   * the eval step,
-  * the bucketed-overlap train step (``comm.overlap=on``,
-    parallel/overlap.py) for every layout inside its envelope — the
-    shard_map'd exchange traces per preset × layout so the knob can't
-    compile-crash on first cluster use,
   * the serve/predict step, once per batch bucket the inference server
     would AOT-compile (serve/compile_cache.bucket_sizes),
   * the coalesced staged-unpack program — with the fused on-device
@@ -151,7 +147,6 @@ def check_spec_tree(state_shapes, shardings, mesh,
 def elaborate_config(cfg, mesh_cfg, locus: str,
                      trace_steps: bool = True,
                      trace_forward: bool = True,
-                     trace_comm_variants: bool = True,
                      _state_cache: Optional[dict] = None,
                      _precision_seen: Optional[set] = None) -> List[Finding]:
     """Elaborate ONE (config, mesh layout): returns findings (empty=clean).
@@ -171,17 +166,7 @@ def elaborate_config(cfg, mesh_cfg, locus: str,
     them: the large-batch optimizer variants (lars4k/lamb4k/lars32k)
     share imagenet_resnet50's forward exactly, and re-sweeping every
     serve bucket per optimizer would triple the gate's largest cost for
-    zero coverage.
-
-    ``trace_comm_variants=False`` skips the comm-program traces this
-    phase shares with hangcheck's schedule extractor — the
-    ``comm.overlap=on`` step and the bf16 + compressed-exchange
-    composition. When the hangcheck-schedule phase runs (the gate's
-    default), ``analysis/collectives.py`` traces those SAME programs via
-    ``jax.make_jaxpr`` (reporting trace failures as findings with the
-    same semantics), so re-eval_shaping them here would double the
-    gate's largest cost for zero coverage; ``--no-hangcheck`` flips them
-    back on."""
+    zero coverage."""
     import jax
     from ..parallel.mesh import batch_shard_count, create_mesh
     from ..train.loop import Trainer
@@ -292,83 +277,6 @@ def elaborate_config(cfg, mesh_cfg, locus: str,
                     "elab-serve-step", locus,
                     f"serve step (bucket {bucket})", e))
 
-        # bucketed-overlap train step (parallel/overlap.py): the
-        # comm.overlap=on variant of this preset × layout, traced
-        # abstractly — a shard_map spec/rank error, a bucket plan that
-        # cannot exchange a leaf, or a BN-axis mistake is a gate finding
-        # here, not a step-1 crash when an operator first flips the knob
-        # on a cluster. The layout-aware envelope covers the transformer
-        # family too (dp_tp / dp_pp / dp_pp_ep trace their partial-auto /
-        # inline-pipeline exchanges); the state shapes are reused — the
-        # axis-named model has an identical param tree.
-        try:
-            import copy
-            from ..parallel.overlap import overlap_unsupported_reason
-            if trace_comm_variants and \
-                    overlap_unsupported_reason(cfg, mesh) is None:
-                ocfg = copy.deepcopy(cfg)
-                ocfg.comm.overlap = "on"
-                otrainer = Trainer(ocfg, mesh=mesh)
-                batch = _abstract_batch(ocfg, ocfg.train.batch_size)
-                jax.eval_shape(otrainer._train_step, state_shapes, batch)
-        except Exception as e:
-            findings.append(_findings_from_exc("elab-overlap-step", locus,
-                                               "bucketed overlap step", e))
-
-        # the gradient-accumulation composition: the scan runs INSIDE
-        # the exchange body (one bucketed exchange per optimizer step),
-        # so its trace is a different program than the plain overlap
-        # step. One accum factor per preset, on its batch-only layout —
-        # the shaped layouts share the body machinery just traced above.
-        try:
-            import copy
-            from ..parallel.overlap import overlap_unsupported_reason
-            shaped = any(mesh.shape.get(a, 1) > 1
-                         for a in ("pipeline", "tensor", "expert", "seq"))
-            if trace_comm_variants and not shaped:
-                acfg = copy.deepcopy(cfg)
-                acfg.comm.overlap = "on"
-                acfg.train.grad_accum_steps = 4 if cfg.train.batch_size \
-                    % (batch_shard_count(mesh) * 4) == 0 else 2
-                if overlap_unsupported_reason(acfg, mesh) is None:
-                    atrainer = Trainer(acfg, mesh=mesh)
-                    batch = _abstract_batch(acfg, acfg.train.batch_size)
-                    jax.eval_shape(atrainer._train_step, state_shapes,
-                                   batch)
-        except Exception as e:
-            findings.append(_findings_from_exc(
-                "elab-overlap-step", locus,
-                "bucketed overlap + accumulation step", e))
-
-        # the hierarchical-exchange composition (comm.hierarchy=on): the
-        # staged RS -> inter-psum -> AG program is a different trace
-        # than the flat exchange — a grouped-collective spec error or a
-        # padding/rank bug in the staged concat must surface here, not
-        # when an operator first factors a real multi-host mesh. Forced
-        # via comm.intra_axis_size (no real host boundary on the gate's
-        # virtual mesh); batch-only layouts, data axis factorable.
-        try:
-            import copy
-            from ..parallel.overlap import overlap_unsupported_reason
-            shaped = any(mesh.shape.get(a, 1) > 1
-                         for a in ("pipeline", "tensor", "expert", "seq"))
-            dsize = int(mesh.shape.get("data", 1))
-            if trace_comm_variants and not shaped and dsize >= 4 \
-                    and dsize % 2 == 0:
-                hcfg = copy.deepcopy(cfg)
-                hcfg.comm.overlap = "on"
-                hcfg.comm.hierarchy = "on"
-                hcfg.comm.intra_axis_size = dsize // 2
-                if overlap_unsupported_reason(hcfg, mesh) is None:
-                    htrainer = Trainer(hcfg, mesh=mesh)
-                    batch = _abstract_batch(hcfg, hcfg.train.batch_size)
-                    jax.eval_shape(htrainer._train_step, state_shapes,
-                                   batch)
-        except Exception as e:
-            findings.append(_findings_from_exc(
-                "elab-overlap-step", locus,
-                "bucketed overlap + hierarchical exchange step", e))
-
         # bf16 precision-policy step (parallel/precision.py): the
         # train.precision=bf16 variant of this preset × layout, traced
         # abstractly over the SAME f32 master state shapes (the policy's
@@ -376,12 +284,10 @@ def elaborate_config(cfg, mesh_cfg, locus: str,
         # a model family that can't take the dtype override, or a
         # fused-kernel dtype mismatch is a gate finding here, not a
         # step-1 crash when an operator first flips the knob. Presets
-        # that already pin precision=bf16 were traced above; the
-        # compressed-exchange composition rides the overlap envelope.
+        # that already pin precision=bf16 were traced above.
         try:
             import copy
             import dataclasses as _dc
-            from ..parallel.overlap import overlap_unsupported_reason
             # dedupe across presets sharing the identical
             # (model, data, optimizer) triple — the schedule/batch
             # variants of one base preset would re-trace the same bf16
@@ -420,17 +326,6 @@ def elaborate_config(cfg, mesh_cfg, locus: str,
                         vstate = jax.eval_shape(
                             make_variant_cast(variant), state_shapes)
                         jax.eval_shape(vstep, vstate, vbatch)
-                if trace_comm_variants and \
-                        overlap_unsupported_reason(pcfg, mesh) is None:
-                    # bf16 step × bucketed exchange × compressed payload
-                    # — the full low-precision composition (skipped when
-                    # hangcheck's schedule phase traces it instead)
-                    ccfg = copy.deepcopy(pcfg)
-                    ccfg.comm.overlap = "on"
-                    ccfg.comm.compress = "bf16"
-                    ctrainer = Trainer(ccfg, mesh=mesh)
-                    jax.eval_shape(ctrainer._train_step, state_shapes,
-                                   batch)
         except Exception as e:
             findings.append(_findings_from_exc(
                 "elab-precision-step", locus, "bf16 precision step", e))
@@ -631,8 +526,7 @@ def run_elaborate_zero1(preset_names: Optional[Sequence[str]] = None,
 
 
 def run_elaborate(preset_names: Optional[Sequence[str]] = None,
-                  n_devices: int = 8,
-                  trace_comm_variants: bool = True) -> List[Finding]:
+                  n_devices: int = 8) -> List[Finding]:
     """Elaborate the named presets (default: all) across their candidate
     layouts. Call ``apply_virtual_cpu(n_devices)`` BEFORE the jax backend
     initializes (main.py's ``check`` subcommand does)."""
@@ -675,7 +569,6 @@ def run_elaborate(preset_names: Optional[Sequence[str]] = None,
                 elaborate_config(cfg, mesh_cfg, f"{name}@{label}",
                                  trace_steps=trace,
                                  trace_forward=trace and fwd,
-                                 trace_comm_variants=trace_comm_variants,
                                  _state_cache=state_cache,
                                  _precision_seen=precision_seen))
             traced = True

@@ -133,55 +133,6 @@ def check_bandwidth_catalog(probe_bps: Optional[float] = None
     return findings
 
 
-#: canonical host-factorization for the committed tuned_comm rows: the
-#: virtual-8 gate mesh factored 2 hosts × 4 devices (the same k the
-#: hangcheck overlap+hier families pin)
-TUNE_CANONICAL_K = 4
-
-
-def _tuned_comm(preset: str, signatures: Dict[str, dict],
-                table) -> Optional[dict]:
-    """Re-run the startup autotune's chooser (planner.tune_comm_plan)
-    against the committed overlap plan of one preset, on the reference
-    table. Deterministic by construction; on the tier-row-less reference
-    table the chooser documents its flat fallback in the committed row —
-    exactly the drift sentinel: a chooser change, a new candidate grid,
-    or a plan-bytes change diffs here in review."""
-    from ..telemetry import planner
-    key = f"{preset}@dp/overlap"
-    if key not in signatures:
-        cands = sorted(k for k in signatures
-                       if k.startswith(preset + "@") and
-                       k.endswith("/overlap"))
-        if not cands:
-            return None
-        key = cands[0]
-    plan = signatures[key].get("plan") or {}
-    sizes = [int(b) for b in plan.get("bucket_bytes") or []]
-    if not sizes:
-        return None
-    declared = plan.get("declared_collectives") or []
-    axes = []
-    for ops in declared:
-        first = ops[0] if ops else "psum@data"
-        sig = first.split("@", 1)[-1].split("[", 1)[0]
-        axes.append(sig)
-    while len(axes) < len(sizes):
-        axes.append("data")
-    snap = {
-        "grad_bytes": sum(sizes),
-        "bucket_bytes": sizes,
-        "bucket_reduce_axes": axes[:len(sizes)],
-        "compress": plan.get("compress", "off"),
-    }
-    # configured cap = the CommConfig default (4 MB) — every committed
-    # preset leaves comm.bucket_mb at the default
-    tuned = planner.tune_comm_plan(
-        snap, table, intra_k=TUNE_CANONICAL_K, bucket_mb=4.0)
-    tuned["schedule_key"] = key
-    return tuned
-
-
 def build_catalog(signatures: Dict[str, dict],
                   presets: Sequence[str] = PLAN_PRESETS,
                   n_devices: int = 8) -> Tuple[List[Finding], dict]:
@@ -205,8 +156,7 @@ def build_catalog(signatures: Dict[str, dict],
                                        n_devices=n_devices,
                                        bandwidth=table)
         for key, cand in sorted(plan["candidates"].items()):
-            for field in ("step_secs", "compute_secs", "comm_secs",
-                          "comm_exposed_secs"):
+            for field in ("step_secs", "compute_secs", "comm_secs"):
                 v = cand.get(field)
                 if v is None or not math.isfinite(v) or v < 0 or \
                         (field in ("step_secs", "compute_secs") and v == 0):
@@ -225,16 +175,6 @@ def build_catalog(signatures: Dict[str, dict],
             "ranked": plan["ranked"],
             "recommended": plan["recommended"],
         }
-        tuned = _tuned_comm(preset, signatures, table)
-        if tuned is not None:
-            plans[preset]["tuned_comm"] = tuned
-            if not math.isfinite(tuned["predicted_secs"]) or \
-                    tuned["predicted_secs"] < 0:
-                findings.append(Finding(
-                    RULE, preset, 0,
-                    f"degenerate tuned_comm prediction "
-                    f"{tuned['predicted_secs']!r} — tune_comm_plan lost "
-                    "an input (bucket bytes or bandwidth row)"))
     doc = {
         "schema_version": 1,
         "devices": n_devices,
@@ -243,12 +183,8 @@ def build_catalog(signatures: Dict[str, dict],
             "latency_secs": planner.REFERENCE_LATENCY_SECS,
             "peak_tflops": planner.REFERENCE_PEAK_TFLOPS,
             "assumed_mfu": planner.ASSUMED_MFU,
-            "overlap_efficiency": planner.OVERLAP_EFFICIENCY,
             "train_flops_multiplier": planner.TRAIN_FLOPS_MULTIPLIER,
             "act_flops_per_byte": planner.ACT_FLOPS_PER_BYTE,
-            "tune_bucket_mb": list(planner.TUNE_BUCKET_MB),
-            "tune_sanity_factor": planner.TUNE_SANITY_FACTOR,
-            "tune_canonical_k": TUNE_CANONICAL_K,
         },
         "plans": plans,
     }
@@ -263,7 +199,7 @@ def run_plan_drift(signatures: Optional[Dict[str, dict]] = None,
     cross-check. ``signatures`` defaults to the committed schedule
     artifact (the check CLI passes the freshly traced map so the
     catalog matches what the same run just committed)."""
-    from ..telemetry.comm_report import load_schedules
+    from .collectives import load_schedules
     if signatures is None:
         signatures = load_schedules()
     findings, doc = build_catalog(signatures, n_devices=n_devices)

@@ -20,7 +20,7 @@ DOC = __doc__
 
 def check(ctx) -> Iterable[Finding]:
     # package files only: tests/ are not scanned by the driver, and
-    # repo-top driver glue (__graft_entry__.py, bench.py) asserts on its
+    # repo-top driver glue (__graft_entry__.py, chip_smoke.py) asserts on its
     # own argv contracts, which die loudly either way
     for sf in ctx.package_py:
         if sf.tree is None:

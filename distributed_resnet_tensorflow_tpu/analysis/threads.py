@@ -142,7 +142,7 @@ def iter_spawn_sites(ctx) -> Iterator[SpawnSite]:
     """Every ``threading.Thread(target=...)`` construction and every
     ``<executor>.submit(fn, ...)`` whose first argument resolves to a
     package function. Tests are out of scope (the linter never sees
-    them); repo-top python (bench.py etc.) is included."""
+    them); repo-top python (chip_smoke.py etc.) is included."""
     graph = get_callgraph(ctx)
     for key, fn in sorted(graph.funcs.items()):
         for node in body_walk(fn.node):

@@ -12,8 +12,7 @@ def resolve_decode_workers(cfg, mode: str = "train"):
     min(8, cores) when the host has more than 2 cores (below that a
     process pool only adds queue pickling — the GIL-releasing decoders
     already share the core), threads = min(8, cores) with a floor of 4
-    (threads hide I/O even on small hosts). bench.py records the resolved
-    pair next to ``host_cores`` in the imagenet_input row."""
+    (threads hide I/O even on small hosts)."""
     import os
     d = cfg.data
     cpu = os.cpu_count() or 1

@@ -226,7 +226,7 @@ def imagenet_iterator(data_dir: str, batch_size: int, mode: str,
 
     # worker processes ship their decode stage-counters back as
     # _StageDelta messages on the result queue (merged below): without the
-    # merge, bench's input attribution under decode_processes > 0
+    # merge, the input attribution under decode_processes > 0
     # undercounted decode busy time — the workers' own registries die with
     # the workers
     if use_procs:
@@ -435,7 +435,7 @@ class _EndMarker:
 class _StageDelta:
     """A decode worker PROCESS's stage-counter increment, shipped to the
     parent over the result queue (pickle-friendly; see ``_decode_loop``).
-    The parent merges it into ``utils.metrics.input_stages`` so bench's
+    The parent merges it into ``utils.metrics.input_stages`` so the
     input attribution sees process-pool decode busy time too."""
 
     __slots__ = ("widx", "count", "seconds", "nbytes")

@@ -42,12 +42,10 @@ from .resilience.sentinel import train_with_nan_recovery
 from .telemetry import configure_from_config as _configure_telemetry
 from .telemetry.tracer import recorder as _flight_recorder
 from .train.hooks import (CheckpointHook, CkptAsyncHook, CkptShardHook,
-                          CommCompressHook, CommOverlapHook,
-                          CommTimingHook, CorruptRecordsHook, GoodputHook,
-                          HeartbeatHook, InputEchoHook, InputStagesHook,
-                          LoggingHook, MemoryHook, NanGuardHook,
-                          PlanDriftHook, PrecisionHook, SummaryHook,
-                          Zero1Hook)
+                          CorruptRecordsHook, GoodputHook, HeartbeatHook,
+                          InputEchoHook, InputStagesHook, LoggingHook,
+                          MemoryHook, NanGuardHook, PrecisionHook,
+                          SummaryHook, Zero1Hook)
 from .train.loop import Trainer
 from .utils.compile_cache import configure_compile_cache
 from .utils.config import (ExperimentConfig, parse_args,
@@ -234,10 +232,6 @@ def _arm_watchdog_hooks(hooks: list, publisher) -> None:
         # cadence saves flip to the unmonitored "save" phase — a slow
         # shared-FS save must not read as a hang
         if isinstance(h, CheckpointHook):
-            h.heartbeat = publisher
-        # the drift sentinel's measured step time should be the
-        # watchdog's own EWMA, not a second competing estimate
-        if isinstance(h, PlanDriftHook):
             h.heartbeat = publisher
 
 
@@ -611,38 +605,14 @@ def _train_one_generation(cfg: ExperimentConfig, listener,
         # async-checkpoint charge split (loop-thread vs writer-thread
         # seconds) — rows only appear once a save actually ran
         hooks.append(CkptAsyncHook(writer, cfg.train.summary_every_steps))
-        # bucketed gradient-exchange plan (parallel/overlap.py) — one row
-        # per traced plan; silent when comm.overlap resolved off
-        if trainer.comm_overlap_active:
-            hooks.append(CommOverlapHook(writer,
-                                         cfg.train.summary_every_steps))
         # ZeRO-1 partition plan (parallel/sharding.py rule table) — one
         # row per resolved plan; silent when optimizer.zero1 resolved off
         if trainer.zero1_active:
             hooks.append(Zero1Hook(writer, cfg.train.summary_every_steps))
-        # per-run precision/compression summary (parallel/precision.py) —
-        # one row per resolved policy; silent when everything runs f32
-        if trainer.precision_active or trainer.comm_compress_active:
+        # per-run precision summary (parallel/precision.py) — one row
+        # per resolved policy; silent when everything runs f32
+        if trainer.precision_active:
             hooks.append(PrecisionHook(writer,
-                                       cfg.train.summary_every_steps))
-        # compressed-exchange payload accounting — one row per traced
-        # plan when comm.compress actually narrowed the wire
-        if trainer.comm_compress_active:
-            hooks.append(CommCompressHook(writer,
-                                          cfg.train.summary_every_steps))
-        # measured per-bucket exchange timings (parallel/overlap.py
-        # probe) joined with the live step rate — rows appear once the
-        # probe has run; silent when the bucketed exchange is off
-        if trainer.comm_overlap_active and cfg.telemetry.comm_timing:
-            hooks.append(CommTimingHook(writer,
-                                        cfg.train.summary_every_steps))
-        # predicted-vs-measured drift sentinel (telemetry/planner.py,
-        # docs/planner.md): the what-if model's prediction for THIS run
-        # held against the heartbeat/probe/memory measurements; "auto"
-        # arms lazily once the bucketed exchange has traced
-        if cfg.telemetry.plan_drift != "off" \
-                and trainer.comm_overlap_active:
-            hooks.append(PlanDriftHook(writer, cfg, trainer,
                                        cfg.train.summary_every_steps))
     # per-host accounting exported by EVERY process (the chief's stream
     # alone would claim 1/N of the cluster): sharded-checkpoint bytes
@@ -933,8 +903,8 @@ def run_route(cfg: ExperimentConfig):
     With ``route.load_qps > 0`` the open-loop generator
     (``route.load_shape`` arrival schedule) drives the fleet, an
     in-flight canary is drained to a verdict on trickle traffic, then a
-    JSON report prints and the process exits — scripts/serve_fleet_smoke.sh
-    and bench's serving_fleet row. With ``load_qps = 0`` the router runs
+    JSON report prints and the process exits — scripts/serve_fleet_smoke.sh.
+    With ``load_qps = 0`` the router runs
     until SIGTERM/SIGINT (requests would come from in-process submit)."""
     import json as _json
     import time as _time
@@ -1086,26 +1056,12 @@ def run_train_and_eval(cfg: ExperimentConfig):
                     or cfg.train.summary_every_steps))
             hooks.append(CkptAsyncHook(writer,
                                        cfg.train.summary_every_steps))
-            if trainer.comm_overlap_active:
-                hooks.append(CommOverlapHook(
-                    writer, cfg.train.summary_every_steps))
             if trainer.zero1_active:
                 hooks.append(Zero1Hook(writer,
                                        cfg.train.summary_every_steps))
-            if trainer.precision_active or trainer.comm_compress_active:
+            if trainer.precision_active:
                 hooks.append(PrecisionHook(
                     writer, cfg.train.summary_every_steps))
-            if trainer.comm_compress_active:
-                hooks.append(CommCompressHook(
-                    writer, cfg.train.summary_every_steps))
-            if trainer.comm_overlap_active and cfg.telemetry.comm_timing:
-                hooks.append(CommTimingHook(
-                    writer, cfg.train.summary_every_steps))
-            # drift sentinel: see run_train
-            if cfg.telemetry.plan_drift != "off" \
-                    and trainer.comm_overlap_active:
-                hooks.append(PlanDriftHook(
-                    writer, cfg, trainer, cfg.train.summary_every_steps))
     # per-host sharded-ckpt + device-memory accounting: every process
     # exports, like run_train (the monitor's per-host rollup reads these)
     te_shard_writer = None
@@ -1218,18 +1174,12 @@ def main(argv=None):
         # filesystem reads, like monitor
         from .telemetry.merge import main_trace_merge
         sys.exit(main_trace_merge(argv[1:]))
-    if argv and argv[0] == "comm-report":
-        # per-collective runtime attribution (telemetry/comm_report.py):
-        # join the committed collective schedule with the measured
-        # per-bucket exchange timings into achieved bytes/sec per bucket
-        from .telemetry.comm_report import main_comm_report
-        sys.exit(main_comm_report(argv[1:]))
     if argv and argv[0] == "plan":
         # what-if performance planner (telemetry/planner.py,
         # docs/planner.md): predict step time / HBM watermark / comm
-        # fraction per layout × knob candidate from the committed
-        # collective schedules × the fabric's bandwidth catalog, rank
-        # them, RECOMMEND a layout — no cluster needed
+        # fraction per layout from the committed collective schedules ×
+        # the fabric's bandwidth catalog, rank them, RECOMMEND a layout
+        # — no cluster needed
         from .telemetry.planner import main_plan
         sys.exit(main_plan(argv[1:]))
     serve_cmd = False

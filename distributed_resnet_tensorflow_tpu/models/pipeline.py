@@ -232,42 +232,12 @@ class PipelinedEncoder(nn.Module):
     expert_capacity_factor: float = 1.25
     moe_top_k: int = 1
 
-    def _local_param_shape(self, name, full_shape):
-        """Declared shape of one stacked leaf: the FULL stacked shape
-        normally; inside the layout-aware exchange body (the enclosing
-        shard_map maps ``pipeline``/``expert`` manually —
-        parallel/overlap.py) each peer holds only its own slice, so the
-        declaration shrinks by the manual axis sizes along the leaf's
-        ``stacked_encoder_spec`` dims — flax's param shape check then
-        matches the local shards the body actually receives."""
-        from ..parallel.mesh import current_manual_axes
-        from ..parallel.sharding import stacked_encoder_spec
-        manual = current_manual_axes()
-        if not manual or self.mesh is None:
-            return full_shape
-        spec = stacked_encoder_spec(name, len(full_shape),
-                                    self.mesh.shape.get("tensor", 1))
-        out = list(full_shape)
-        for dim, names in enumerate(spec):
-            if names is None:
-                continue
-            tup = names if isinstance(names, tuple) else (names,)
-            div = 1
-            for n in tup:
-                if n in manual:
-                    div *= self.mesh.shape.get(n, 1)
-            if div > 1:
-                out[dim] //= div
-        return tuple(out)
-
     def _params(self, d):
         hd = d // self.num_heads
         f = self.mlp_ratio * d
         vs = jax.nn.initializers.variance_scaling
         def stacked(name, shape, init):
-            return self.param(name, init,
-                              self._local_param_shape(
-                                  name, (self.depth,) + shape),
+            return self.param(name, init, (self.depth,) + shape,
                               jnp.float32)
         ones = lambda key, shape, dtype: jnp.ones(shape, dtype)   # noqa: E731
         zeros = nn.initializers.zeros
@@ -404,13 +374,8 @@ class PipelinedEncoder(nn.Module):
                 f"interleave {v} requires microbatches ({m}) >= pipeline "
                 f"stages ({pstages})")
         # microbatching applies to the LOCAL batch: each data-parallel shard
-        # runs its own pipeline over its slice of the batch. Inside the
-        # layout-aware exchange body (parallel/overlap.py maps the batch
-        # axes manually) ``x`` already IS the per-shard slice — dividing
-        # again would halve every microbatch.
-        from ..parallel.mesh import current_manual_axes
-        inline = "pipeline" in current_manual_axes() and pstages > 1
-        if self.mesh is not None and not inline:
+        # runs its own pipeline over its slice of the batch
+        if self.mesh is not None:
             from ..parallel.mesh import batch_shard_count
             n_batch_shards = batch_shard_count(self.mesh)
         else:
@@ -441,8 +406,9 @@ class PipelinedEncoder(nn.Module):
                 f"batch shards) must be a multiple of microbatches {m}")
 
         mesh = self.mesh
-        from .transformer import _batch_axes
-        x_spec = P(_batch_axes(mesh) or None, "seq" if ring else None, None)
+        from ..parallel.mesh import present_batch_axes
+        batch_axes = present_batch_axes(mesh)
+        x_spec = P(batch_axes or None, "seq" if ring else None, None)
         # per-leaf specs MATCH param_sharding_rule's placement (pipeline on
         # the stacked depth axis, tensor on heads/hidden when tp is active)
         # so the shard_map consumes the training state's own shards with no
@@ -464,7 +430,7 @@ class PipelinedEncoder(nn.Module):
             scalars (the pp×ep MoE failure this comment documents; see
             analysis/elaborate.py which now catches the class)."""
             aux = lax.psum(aux_acc, "pipeline") / m
-            for ax in (_batch_axes(mesh) or ()):
+            for ax in batch_axes:
                 aux = lax.pmean(aux, ax)
             if ring:
                 aux = lax.pmean(aux, "seq")
@@ -572,20 +538,9 @@ class PipelinedEncoder(nn.Module):
 
         from ..parallel.mesh import shard_map_unchecked
         body = pipelined if v == 1 else pipelined_circular
-        if inline:
-            # the enclosing exchange shard_map (parallel/overlap.py)
-            # already maps pipeline/expert (and the batch axes) manually:
-            # params arrived as this peer's stage shards
-            # (_local_param_shape), x as its batch slice, and every axis
-            # name the body psums/ppermutes over is bound — run the
-            # schedule directly. Building the inner shard_map here would
-            # re-map consumed axes (the exchange docstring has the
-            # nested-shard_map failure seen on jax 0.4.37).
-            y, aux = body(params, x)
-        else:
-            fn = shard_map_unchecked(body, mesh, in_specs=(p_spec, x_spec),
-                                     out_specs=(x_spec, P(None)))
-            y, aux = fn(params, x)
+        fn = shard_map_unchecked(body, mesh, in_specs=(p_spec, x_spec),
+                                 out_specs=(x_spec, P(None)))
+        y, aux = fn(params, x)
         return finish(y, aux[0])
 
 

@@ -17,8 +17,7 @@ TPU-first design decisions (NOT in the reference):
     (resnet_model_official.py:244-248) existed for cuDNN and is dropped.
   * bfloat16 compute / float32 params & batch stats (MXU-native mixed precision).
   * Cross-replica batch norm: under ``jit`` over a sharded batch the moments are
-    global by construction (XLA inserts the all-reduce); under ``shard_map`` /
-    ``pmap`` pass ``axis_name`` to get an explicit ``lax.pmean`` of moments.
+    global by construction (XLA inserts the all-reduce).
     This fixes the per-replica-BN accuracy gap the reference documented
     (reference README.md:38,54).
   * Optional ``remat`` (jax.checkpoint) on residual stages to trade FLOPs for
@@ -27,7 +26,7 @@ TPU-first design decisions (NOT in the reference):
 from __future__ import annotations
 
 from functools import partial
-from typing import Any, Callable, Optional, Sequence, Tuple
+from typing import Any, Callable, Sequence, Tuple
 
 import flax.linen as nn
 import jax
@@ -150,8 +149,7 @@ class BatchNormRelu(nn.Module):
       * "batch"  — BN (momentum 0.997, eps 1e-5 — reference
         resnet_model_official.py:37-48). Stats in float32. ``groups=1`` →
         cross-replica BN (global moments); ``groups=G`` → per-replica/
-        reference BN numerics (ops/batch_norm.py). ``axis_name`` adds
-        explicit pmean under shard_map.
+        reference BN numerics (ops/batch_norm.py).
       * "frozen" — BN applied from the RUNNING statistics even in training
         (the trainable frozen-BN fine-tune contract): scale/bias still
         learn, the batch-moment passes and their cross-replica semantics
@@ -166,7 +164,6 @@ class BatchNormRelu(nn.Module):
     momentum: float = 0.997
     epsilon: float = 1e-5
     dtype: Any = jnp.bfloat16
-    axis_name: Optional[str] = None
     groups: int = 1
     relu: bool = True
     stat_subsample: int = 1
@@ -187,7 +184,6 @@ class BatchNormRelu(nn.Module):
                 epsilon=self.epsilon,
                 dtype=self.dtype,
                 groups=self.groups,
-                axis_name=self.axis_name,
                 stat_subsample=self.stat_subsample,
             )(x, train and self.norm != "frozen")
         else:
@@ -207,7 +203,6 @@ class BuildingBlock(nn.Module):
     strides: int
     use_projection: bool
     dtype: Any = jnp.bfloat16
-    axis_name: Optional[str] = None
     bn_groups: int = 1
     bn_momentum: float = 0.997
     bn_epsilon: float = 1e-5
@@ -219,7 +214,7 @@ class BuildingBlock(nn.Module):
     def __call__(self, x: jax.Array, train: bool) -> jax.Array:
         bn = partial(BatchNormRelu, momentum=self.bn_momentum,
                      epsilon=self.bn_epsilon, dtype=self.dtype,
-                     axis_name=self.axis_name, groups=self.bn_groups,
+                     groups=self.bn_groups,
                      stat_subsample=self.bn_stat_subsample,
                      norm=self.norm, norm_groups=self.norm_groups)
         conv = partial(ConvFixedPadding, dtype=self.dtype)
@@ -241,7 +236,6 @@ class BottleneckBlock(nn.Module):
     strides: int
     use_projection: bool
     dtype: Any = jnp.bfloat16
-    axis_name: Optional[str] = None
     bn_groups: int = 1
     bn_momentum: float = 0.997
     bn_epsilon: float = 1e-5
@@ -253,7 +247,7 @@ class BottleneckBlock(nn.Module):
     def __call__(self, x: jax.Array, train: bool) -> jax.Array:
         bn = partial(BatchNormRelu, momentum=self.bn_momentum,
                      epsilon=self.bn_epsilon, dtype=self.dtype,
-                     axis_name=self.axis_name, groups=self.bn_groups,
+                     groups=self.bn_groups,
                      stat_subsample=self.bn_stat_subsample,
                      norm=self.norm, norm_groups=self.norm_groups)
         conv = partial(ConvFixedPadding, dtype=self.dtype)
@@ -278,7 +272,6 @@ class BlockLayer(nn.Module):
     num_blocks: int
     strides: int
     dtype: Any = jnp.bfloat16
-    axis_name: Optional[str] = None
     bn_groups: int = 1
     remat: bool = False
     bn_momentum: float = 0.997
@@ -298,7 +291,6 @@ class BlockLayer(nn.Module):
                 strides=self.strides if i == 0 else 1,
                 use_projection=(i == 0),
                 dtype=self.dtype,
-                axis_name=self.axis_name,
                 bn_groups=self.bn_groups,
                 bn_momentum=self.bn_momentum,
                 bn_epsilon=self.bn_epsilon,
@@ -317,7 +309,6 @@ class CifarResNetV2(nn.Module):
     num_classes: int = 10
     width_multiplier: int = 1
     dtype: Any = jnp.bfloat16
-    axis_name: Optional[str] = None
     bn_groups: int = 1
     remat: bool = False
     bn_momentum: float = 0.997
@@ -343,14 +334,14 @@ class CifarResNetV2(nn.Module):
         for i, (filters, strides) in enumerate(((16 * k, 1), (32 * k, 2), (64 * k, 2))):
             x = BlockLayer(
                 block_cls=BuildingBlock, filters=filters, num_blocks=num_blocks,
-                strides=strides, dtype=self.dtype, axis_name=self.axis_name,
+                strides=strides, dtype=self.dtype,
                 bn_groups=self.bn_groups, remat=self.remat,
                 bn_momentum=self.bn_momentum, bn_epsilon=self.bn_epsilon,
                 bn_stat_subsample=self.bn_stat_subsample,
                 norm=self.norm, norm_groups=self.norm_groups,
             )(x, train)
         x = BatchNormRelu(momentum=self.bn_momentum, epsilon=self.bn_epsilon,
-                          dtype=self.dtype, axis_name=self.axis_name,
+                          dtype=self.dtype,
                           groups=self.bn_groups,
                           stat_subsample=self.bn_stat_subsample,
                           norm=self.norm,
@@ -369,7 +360,6 @@ class ImageNetResNetV2(nn.Module):
     resnet_size: int = 50
     num_classes: int = 1001
     dtype: Any = jnp.bfloat16
-    axis_name: Optional[str] = None
     bn_groups: int = 1
     remat: bool = False
     bn_momentum: float = 0.997
@@ -401,14 +391,14 @@ class ImageNetResNetV2(nn.Module):
             x = BlockLayer(
                 block_cls=block_cls, filters=64 * (2 ** i), num_blocks=num_blocks,
                 strides=1 if i == 0 else 2, dtype=self.dtype,
-                axis_name=self.axis_name, bn_groups=self.bn_groups,
+                bn_groups=self.bn_groups,
                 remat=self.remat, bn_momentum=self.bn_momentum,
                 bn_epsilon=self.bn_epsilon,
                 bn_stat_subsample=self.bn_stat_subsample,
                 norm=self.norm, norm_groups=self.norm_groups,
             )(x, train)
         x = BatchNormRelu(momentum=self.bn_momentum, epsilon=self.bn_epsilon,
-                          dtype=self.dtype, axis_name=self.axis_name,
+                          dtype=self.dtype,
                           groups=self.bn_groups,
                           stat_subsample=self.bn_stat_subsample,
                           norm=self.norm,
@@ -420,7 +410,7 @@ class ImageNetResNetV2(nn.Module):
                         dtype=jnp.float32)(x)
 
 
-def create_model(model_cfg, dataset: str, axis_name: Optional[str] = None,
+def create_model(model_cfg, dataset: str,
                  remat: bool = False, bn_groups: int = 1,
                  mesh=None, compute_dtype=None) -> nn.Module:
     """Model factory; replaces the dataset dispatch in reference
@@ -473,7 +463,7 @@ def create_model(model_cfg, dataset: str, axis_name: Optional[str] = None,
             resnet_size=model_cfg.resnet_size,
             num_classes=model_cfg.num_classes,
             width_multiplier=model_cfg.width_multiplier,
-            dtype=dtype, axis_name=axis_name, bn_groups=bn_groups, remat=remat,
+            dtype=dtype, bn_groups=bn_groups, remat=remat,
             bn_momentum=model_cfg.bn_momentum, bn_epsilon=model_cfg.bn_epsilon,
             bn_stat_subsample=model_cfg.bn_stat_subsample,
             norm=model_cfg.norm, norm_groups=model_cfg.gn_groups)
@@ -481,7 +471,7 @@ def create_model(model_cfg, dataset: str, axis_name: Optional[str] = None,
         return ImageNetResNetV2(
             resnet_size=model_cfg.resnet_size,
             num_classes=model_cfg.num_classes,
-            dtype=dtype, axis_name=axis_name, bn_groups=bn_groups, remat=remat,
+            dtype=dtype, bn_groups=bn_groups, remat=remat,
             bn_momentum=model_cfg.bn_momentum, bn_epsilon=model_cfg.bn_epsilon,
             bn_stat_subsample=model_cfg.bn_stat_subsample,
             norm=model_cfg.norm, norm_groups=model_cfg.gn_groups,

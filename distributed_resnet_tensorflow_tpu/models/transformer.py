@@ -23,27 +23,15 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-
-def _batch_axes(mesh) -> tuple:
-    """Present batch axes, MINUS any the enclosing exchange shard_map
-    already maps manually (parallel/overlap.py: inside its body the batch
-    is per-shard local — re-splitting or constraining over those axes
-    would be wrong/illegal)."""
-    from ..parallel.mesh import current_manual_axes, present_batch_axes
-    manual = current_manual_axes()
-    return tuple(a for a in present_batch_axes(mesh) if a not in manual)
+from ..parallel.mesh import present_batch_axes
 
 
 def _constrain(x: jax.Array, mesh, spec: "P") -> jax.Array:
     """with_sharding_constraint when a mesh is attached (no-op otherwise) —
-    pins GSPMD's layout choice at the block boundaries. Axes the
-    enclosing exchange body maps manually are filtered out of the spec
-    (only auto axes may be constrained there)."""
+    pins GSPMD's layout choice at the block boundaries."""
     if mesh is None:
         return x
     from jax.sharding import NamedSharding
-    from ..parallel.mesh import filter_manual_spec
-    spec = filter_manual_spec(spec)
     if not any(s is not None for s in spec):
         return x
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
@@ -66,14 +54,12 @@ def _per_shard(fn, mesh):
     shard_map"), and it only lowers where EVERY mesh axis is manual: so
     under a multi-device mesh the kernel runs inside a full-manual
     shard_map, batch dim split over the batch axes and heads over
-    ``tensor`` (attention is independent per example and per head).
-    Inside the gradient exchange's body (parallel/overlap.py) the axes are
-    already manual and the arrays already local — call through."""
-    from ..parallel.mesh import current_manual_axes, shard_map_unchecked
-    if mesh is None or mesh.size == 1 or current_manual_axes():
+    ``tensor`` (attention is independent per example and per head)."""
+    from ..parallel.mesh import shard_map_unchecked
+    if mesh is None or mesh.size == 1:
         return fn
     tensor = "tensor" if mesh.shape.get("tensor", 1) > 1 else None
-    spec = P(_batch_axes(mesh) or None, None, tensor, None)
+    spec = P(present_batch_axes(mesh) or None, None, tensor, None)
     return shard_map_unchecked(fn, mesh, in_specs=(spec, spec, spec),
                                out_specs=spec)
 
@@ -106,7 +92,7 @@ def _apply_attention(q, k, v, impl: str, mesh=None):
                 "attention_impl='ring' needs a mesh with a seq axis > 1 "
                 "(set mesh.sequence and pass the mesh to the model)")
         return ring_attention_sharded(q, k, v, mesh,
-                                      batch_axes=_batch_axes(mesh))
+                                      batch_axes=present_batch_axes(mesh))
     raise ValueError(f"unknown attention_impl {impl!r}")
 
 
@@ -171,8 +157,8 @@ class EncoderBlock(nn.Module):
             # replicating it here would all-gather the 4x-dim hidden, the
             # largest activation, defeating sequence parallelism
             seq_spec = "seq" if mesh.shape.get("seq", 1) > 1 else None
-            h = _constrain(h, mesh, P(_batch_axes(mesh) or None, seq_spec,
-                                      "tensor"))
+            h = _constrain(h, mesh, P(present_batch_axes(mesh) or None,
+                                      seq_spec, "tensor"))
         h = nn.Dense(d, dtype=self.dtype)(h)
         return x + h
 
@@ -224,7 +210,8 @@ class VisionTransformer(nn.Module):
                 raise ValueError(f"{t} tokens not divisible by seq axis {seq}")
             # tokens sharded over `seq`: LayerNorm/MLP are token-pointwise and
             # partition cleanly; attention runs the ppermute ring
-            x = _constrain(x, mesh, P(_batch_axes(mesh) or None, "seq", None))
+            x = _constrain(x, mesh, P(present_batch_axes(mesh) or None,
+                                      "seq", None))
         if pipeline > 1:
             # GPipe microbatch pipeline over stacked-parameter stages
             # (models/pipeline.py); parameterization differs from the

@@ -28,8 +28,7 @@ per-element application is a single fused multiply-add in the COMPUTE dtype —
 ``y = x * a + b`` with ``a = scale·rsqrt(var+eps)`` and ``b = bias − mean·a``
 — so the bandwidth-bound elementwise pass runs at bf16 VPU rate and XLA can
 fuse it into the surrounding conv. Momentum 0.997 / eps 1e-5 defaults mirror
-reference resnet_model_official.py:37-38. ``axis_name`` additionally pmean's
-moments across a named axis for ``shard_map``/``pmap`` callers.
+reference resnet_model_official.py:37-38.
 
 The BN training tax — ~38% of the ImageNet ResNet-50 step is per-channel
 reduction passes over the activations — was attacked four ways in round 3
@@ -50,7 +49,7 @@ gradient of the band-stat forward.
 from __future__ import annotations
 
 import math
-from typing import Any, Optional
+from typing import Any
 
 import flax.linen as nn
 import jax
@@ -73,7 +72,6 @@ class GroupedBatchNorm(nn.Module):
     epsilon: float = 1e-5
     dtype: Any = jnp.bfloat16
     groups: int = 1
-    axis_name: Optional[str] = None
     use_scale: bool = True
     use_bias: bool = True
     # >1: estimate batch moments from the center band of H/s rows (see
@@ -122,14 +120,6 @@ class GroupedBatchNorm(nn.Module):
             gaxes = tuple(range(1, xsg.ndim - 1))
             gmean = jnp.mean(xf, axis=gaxes)                       # (g, C)
             gsq = jnp.mean(jnp.square(xf), axis=gaxes)
-            if self.axis_name is not None:
-                # pmean the RAW moments (E[x], E[x²]), not the centered
-                # variance: averaging per-shard variances would drop the
-                # between-shard mean spread and understate var — the
-                # shard_map path (parallel/overlap.py) must match the jit
-                # path's global moments
-                gmean = jax.lax.pmean(gmean, self.axis_name)
-                gsq = jax.lax.pmean(gsq, self.axis_name)
             gvar = gsq - jnp.square(gmean)
             a, b = affine(gmean, gvar)                             # (g, C)
             bshape = (g,) + (1,) * (xg.ndim - 2) + (features,)
@@ -143,12 +133,6 @@ class GroupedBatchNorm(nn.Module):
             xf = xs.astype(jnp.float32)
             mean = jnp.mean(xf, axis=reduce_axes)
             msq = jnp.mean(jnp.square(xf), axis=reduce_axes)
-            if self.axis_name is not None:
-                # raw moments, not centered variance — see the grouped
-                # branch above; with axis_name=None the expression below
-                # is bit-identical to the previous var formula
-                mean = jax.lax.pmean(mean, self.axis_name)
-                msq = jax.lax.pmean(msq, self.axis_name)
             var = msq - jnp.square(mean)
             a, b = affine(mean, var)
             y = x * a.astype(x.dtype) + b.astype(x.dtype)

@@ -93,51 +93,11 @@ def create_mesh(mesh_cfg=None, devices: Optional[Sequence[jax.Device]] = None,
         # host-aware fallback order: group each host's devices
         # contiguously (stable by (process_index, id)) before the reshape,
         # so consecutive ``data`` coordinates land on one host whenever
-        # the axis sizes allow — the layout data_axis_host_factorization
-        # below detects and the hierarchical exchange
-        # (parallel/overlap.py, comm.hierarchy) exploits
+        # the axis sizes allow
         ordered = sorted(devices, key=lambda d: (
             getattr(d, "process_index", 0), getattr(d, "id", 0)))
         dev_array = np.asarray(ordered).reshape(axis_sizes)
     return Mesh(dev_array, AXES)
-
-
-def data_axis_host_factorization(mesh: Mesh) -> Optional[int]:
-    """The intra-host group size ``k`` along the ``data`` axis, or None.
-
-    Returns ``k`` (1 < k < data_size, k | data_size) when the data axis
-    splits into uniform blocks of ``k`` consecutive coordinates such
-    that, for every fixed coordinate on the other mesh axes, all ``k``
-    devices of a block live on ONE process (host) and different blocks
-    live on different hosts — the factorization the hierarchical
-    exchange (parallel/overlap.py, ``comm.hierarchy``) stages its
-    reduce-scatter / psum / all-gather tiers over. None when the axis is
-    trivial, single-host, or the device order interleaves hosts (no
-    honest fast/slow tier split exists; ``comm.intra_axis_size``
-    overrides for virtual meshes)."""
-    ax = {name: i for i, name in enumerate(mesh.axis_names)}
-    if "data" not in ax:
-        return None
-    dsize = mesh.shape.get("data", 1)
-    if dsize <= 1:
-        return None
-    # one row per data coordinate: the process index of every device at
-    # that coordinate, other-axis positions flattened in a fixed order
-    moved = np.moveaxis(mesh.devices, ax["data"], 0).reshape(dsize, -1)
-    rows = [tuple(getattr(d, "process_index", 0) for d in moved[i])
-            for i in range(dsize)]
-    k = 1
-    while k < dsize and rows[k] == rows[0]:
-        k += 1
-    if k <= 1 or k >= dsize or dsize % k:
-        return None
-    blocks = [rows[b * k:(b + 1) * k] for b in range(dsize // k)]
-    for blk in blocks:
-        if any(r != blk[0] for r in blk[1:]):
-            return None
-    if len({blk[0] for blk in blocks}) <= 1:
-        return None
-    return k
 
 
 def data_sharding(mesh: Mesh) -> NamedSharding:
@@ -161,89 +121,12 @@ def batch_shard_count(mesh: Mesh) -> int:
     return mesh.shape.get("data", 1) * mesh.shape.get("fsdp", 1)
 
 
-def shard_map_unchecked(fn, mesh: Mesh, in_specs, out_specs,
-                        auto: frozenset = frozenset()):
-    """``jax.shard_map`` with the varying-mesh-axes check off — our bodies
-    wrap collectives and ``pallas_call``, which don't declare that info.
-
-    ``auto``: mesh axes left AUTOMATIC (GSPMD propagation inside the
-    body, like under plain jit) while the rest go manual — the
-    partial-manual form the layout-aware gradient exchange uses for the
-    propagation-parallel ``tensor`` axis (parallel/overlap.py): specs may
-    only name manual axes; values keep their auto-axis sharding.
-    ``jax.shard_map`` names the MANUAL set (``axis_names``), so it is the
-    mesh's axes minus ``auto``."""
-    manual = frozenset(mesh.axis_names) - frozenset(auto)
+def shard_map_unchecked(fn, mesh: Mesh, in_specs, out_specs):
+    """``jax.shard_map`` over every mesh axis with the varying-mesh-axes
+    check off — our bodies wrap collectives and ``pallas_call``, which
+    don't declare that info."""
     return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, axis_names=manual,
-                         check_vma=False)
-
-
-# ---------------------------------------------------------------------------
-# Manual-axes trace context: how model code learns it is running INSIDE a
-# manually-mapped shard_map body (the layout-aware gradient exchange,
-# parallel/overlap.make_bucketed_grad) rather than under plain jit.
-# Sharding constraints naming a manual axis are illegal inside the body,
-# model-internal shard_maps must not re-map an already-manual axis (and
-# nested shard_map over auto axes mis-transposed when this was written,
-# on jax 0.4.37 — see overlap.py; not re-checked on 0.9), and per-shard
-# batch math must stop dividing by shards the
-# enclosing body already split. The context is TRACE-time only: the body
-# runs during jit tracing, so its dynamic extent covers exactly the model
-# code whose behavior must flip.
-# ---------------------------------------------------------------------------
-
-_MANUAL_AXES = threading.local()
-
-
-def current_manual_axes() -> frozenset:
-    """Mesh axes the innermost enclosing exchange shard_map maps manually
-    (empty outside one)."""
-    return getattr(_MANUAL_AXES, "axes", frozenset())
-
-
-class manual_axes:
-    """Context manager declaring ``axes`` manually mapped for the model
-    code traced inside it (parallel/overlap.py wraps the loss body)."""
-
-    def __init__(self, axes):
-        self.axes = frozenset(axes)
-
-    def __enter__(self):
-        self._prev = current_manual_axes()
-        _MANUAL_AXES.axes = self.axes
-        return self.axes
-
-    def __exit__(self, *exc):
-        _MANUAL_AXES.axes = self._prev
-        return False
-
-
-def filter_spec_axes(spec: P, keep) -> P:
-    """PartitionSpec entry filter: keep only axis names for which
-    ``keep(name)`` is True, collapsing entries back to
-    name / tuple / ``None`` — the ONE home of that normalization, shared
-    by the manual-context constraint filter below and the exchange's
-    manual/auto spec splits (parallel/overlap.py)."""
-    out = []
-    for names in spec:
-        if names is None:
-            out.append(None)
-            continue
-        tup = names if isinstance(names, tuple) else (names,)
-        kept = tuple(n for n in tup if keep(n))
-        out.append(kept if len(kept) > 1 else (kept[0] if kept else None))
-    return P(*out)
-
-
-def filter_manual_spec(spec: P) -> P:
-    """Drop manual-axis references from a PartitionSpec (constraints and
-    shard_map specs inside the exchange body may only name auto axes) —
-    axes already consumed by the enclosing manual map become ``None``."""
-    manual = current_manual_axes()
-    if not manual:
-        return spec
-    return filter_spec_axes(spec, lambda n: n not in manual)
+                         out_specs=out_specs, check_vma=False)
 
 
 # weak-key memo: an lru_cache here would pin up to maxsize Mesh objects

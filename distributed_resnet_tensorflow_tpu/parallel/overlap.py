@@ -1,198 +1,39 @@
-"""Bucketed gradient-communication overlap for the dp / dp_fsdp exchange.
+"""Compiler options that hide a one-host gradient exchange.
 
-The default data-parallel step leaves the gradient all-reduce to XLA's
-sharding propagation: one (often fused) collective materializes after the
-FULL backward pass, serializing communication behind compute — at
-multi-host scale that tail is a first-order step-time term
-(arXiv:1711.00705 measures bucketed allreduce interleaved with backprop
-hiding most of it; arXiv:1802.05799's tensor-fusion knob is the same
-idea).
+A gradient is exchanged one way: the data-parallel step is jitted over the
+mesh and XLA's sharding propagation puts the all-reduce (``fsdp``: the
+reduce-scatter and all-gather) into the step program. What this module
+decides is what that program is compiled under:
 
-Which exchange a run gets (``comm.overlap=auto``; decided from what the
-code observes, PERF.md §6 PR 30 has the chip numbers):
+  * one process on a TPU backend, a mesh of ``data`` alone with more than
+    one shard (one v5e host, pure data parallel): every train-step program
+    is compiled under :data:`EXCHANGE_COMPILER_OPTIONS` — the TPU compiler
+    then runs each kernel's all-reduce asynchronously inside a matmul
+    fusion of the backward pass beside it, as far as the scheduler places
+    it there (PERF.md §6, PR 30, has the chip numbers);
+  * everything else (the CPU backend, a single batch shard, more than one
+    process, an ``fsdp``, ``tensor``, ``pipeline``, ``expert`` or ``seq``
+    axis beside ``data``): the compiler's defaults. None of those was
+    measured.
 
-  * one process on a TPU backend, a mesh of ``data`` alone with more
-    than one shard (one v5e host, pure data parallel): the PROPAGATED
-    exchange, with every train-step program compiled under
-    :func:`exchange_compiler_options` — the TPU compiler then runs each
-    kernel's all-reduce asynchronously inside a matmul fusion of the
-    backward pass beside it, as far as the scheduler places it there. On
-    four chips the bucketed path below LOST to this (it sums float32
-    where propagation sums the bf16 weight gradients, twice the bytes,
-    and its psums were no easier to hide).
-  * more than one process (the multi-host DCN path), envelope supported:
-    the BUCKETED exchange of this module. Never measured on chips.
-  * everything else (the CPU backend, a single batch shard, one process
-    with an ``fsdp``, ``tensor``, ``pipeline``, ``expert`` or ``seq``
-    axis beside ``data``): the plain propagated exchange.
-
-This module rebuilds the exchange explicitly:
-
-  * the loss/grad computation runs inside a ``shard_map`` over the batch
-    axes (``data`` × ``fsdp``), so each device produces its LOCAL gradient
-    contribution with no implicit collective;
-  * gradient leaves are greedily grouped — in REVERSE parameter order,
-    approximating backprop availability (output-side layers finish first)
-    — into buckets of at most ``comm.bucket_mb`` MB;
-  * each bucket is exchanged with its own ``lax.psum`` (plus a
-    ``psum_scatter`` over ``fsdp`` for ZeRO-sharded leaves), and buckets
-    are chained through ``lax.optimization_barrier`` so they issue in
-    order and XLA's all-reduce combiner cannot re-merge them into one
-    end-of-step collective. Each bucket's psum depends only on that
-    bucket's grads, so the latency-hiding scheduler overlaps it with the
-    rest of the backward pass.
-
-Numerics: per leaf, the exchange is the same all-reduce over the same
-per-device operands regardless of bucketing, so bucketed and unbucketed
-(single-bucket) runs produce BIT-IDENTICAL gradients — pinned by
-tests/test_overlap.py on the virtual 8-device mesh. Against the default
-XLA-propagation path the result agrees to float rounding (the reduction
-tree differs), not bitwise.
-
-Compressed exchange (``comm.compress``, docs/precision.md): each bucket's
-payload is cast to bf16/fp16 BEFORE its collective and re-materialized
-f32 after — half the inter-host bytes on the SAME bucket plan
-(arXiv:1811.05233 trained ImageNet/ResNet-50 to reference accuracy with
-half-precision allreduce). The cast is per-leaf and bucketing-independent,
-so the bit-identical many-vs-one-bucket claim HOLDS under compression
-(pinned by tests/test_precision.py); against the uncompressed exchange the
-result is allclose at the compressed dtype's rounding, by design. Local
-gradient accumulation and the optimizer update stay f32 — only the wire
-format narrows.
-
-Hierarchical exchange (``comm.hierarchy``, arXiv:1811.05233's 2D-torus
-allreduce; arXiv:1711.04325's intra-node-reduce-then-inter-node): when
-the ``data`` axis factors into a fast intra-host tier of size k and a
-slow inter-host tier (host-aware device order — parallel/mesh.
-data_axis_host_factorization — or the explicit ``comm.intra_axis_size``
-override), each bucket's flat data-axis psum is restaged as
-reduce-scatter over the k intra-host peers → psum of the 1/k shard over
-the inter-host tier → all-gather back intra-host, all via
-``axis_index_groups`` on the ONE ``data`` axis (no mesh rebuild, no
-nested shard_map). The full payload crosses only the fast tier; the
-slow tier carries 1/k of it — the PR 10 fsdp-leaf trick generalized to
-every bucket. It composes with ``comm.compress`` (the cast precedes the
-staged collectives), zero1 (data-scattered leaves already move 1/N and
-stay on their flat scatter), and the accumulation scan (one staged
-exchange per optimizer step). Numerics: flat-vs-hierarchical is the
-same sum under a different association, so results agree to float
-rounding, not bitwise (tests pin bitwise equality on exactly-
-representable payloads, and bitwise determinism of the hierarchical
-plan against itself); many-vs-one-bucket stays bit-identical within
-either plan.
-
-Layout-aware exchange (the universal overlap envelope): the exchange is
-no longer batch-mesh-only. Per leaf, the reduce-axis set derives from
-the leaf's PartitionSpec — a tensor-/expert-/pipeline-sharded leaf keeps
-its shaping-axis placement and psums over the batch axes (plus any
-shaping axis it is REPLICATED over) only; leaves are bucketed BY
-reduce-axis set so one bucket's tuple-psum never mixes axis sets (the
-MoE expert leaves get their own buckets). Three mechanisms, one per
-parallelism style:
-
-  * ``tensor`` (Megatron via GSPMD propagation, dp_tp): left AUTO in a
-    partially-manual shard_map — constraints and the per-op collectives
-    keep riding propagation inside the body, exactly as under jit.
-  * ``pipeline`` (+``expert``: dp_pp, dp_pp_ep): mapped MANUALLY along
-    with the batch axes; the PipelinedEncoder detects the enclosing
-    manual map (parallel/mesh.manual_axes) and runs its schedule INLINE
-    — a nested shard_map over auto axes mis-transposed (garbage
-    cotangents) on jax 0.4.37, which this was written against; that has
-    not been re-checked on 0.9, so the model's own shard_map still does
-    not rebuild inside the body. The bucketed exchange then issues after
-    the pipeline's backward flush.
-  * gradient accumulation (``train.grad_accum_steps`` > 1): the
-    microbatch scan runs INSIDE the shard_map body accumulating LOCAL
-    f32 gradients, and ONE bucketed exchange fires after the final
-    microbatch — wire traffic per optimizer step drops from ``accum×``
-    (the per-microbatch exchange XLA propagation emits inside lax.scan)
-    to ``1×``, and the exchange overlaps the final microbatch's
-    backprop (the last microbatch is peeled out of the scan so its
-    backward is still in flight when the first buckets issue).
-
-Replicated-leaf calculus on shaped meshes: each peer's local loss
-contribution is scaled so the SUM over every manual peer equals the
-global loss (CE /R, decay/aux /(shards·R), R = product of non-batch
-manual axis sizes). Each leaf's local gradient is then the true partial
-derivative w.r.t. that peer's shard, and the exchange is uniformly
-"psum over the manual axes the leaf's spec does not name" — redundant
-compute (a head replicated across pipeline peers) and partial compute
-(a router fed through the expert all-to-all) need no case split.
-
-Support envelope (``overlap_unsupported_reason``): batch-parallel,
-tensor (unpipelined), pipeline and pipeline×expert meshes across the
-conv/logistic/transformer families, with or without gradient
-accumulation. Still refused, each with its precise reason: ``seq`` > 1
-(ring attention's shard_map nests), ``expert`` > 1 without a pipeline
-axis (SwitchMlp's a2a shard_map nests), ``tensor`` × ``pipeline``
-(auto axis inside a manual body), and per-replica BN on BatchNorm
-models. ``comm.overlap=auto`` quietly stays off outside the envelope;
-``=on`` raises with the reason.
+The names here are :data:`EXCHANGE_COMPILER_OPTIONS`,
+:func:`exchange_compiler_options` (what ``train/loop.py`` asks) and
+``_compiler_refusal`` (the one compile that asks the compiler that is
+there). There is no hand-written exchange: a bucketed ``shard_map`` one
+ran 10.8% slower than the propagated step on four chips (it summed float32
+where propagation sums the bf16 weight gradients, and its psums landed
+after the backward pass; PERF.md §6, PR 30).
 """
 from __future__ import annotations
 
 import functools
 import logging
-import threading
-from dataclasses import dataclass
-from typing import Any, Callable, List, Optional, Sequence
+from typing import Optional
 
 import jax
-import jax.numpy as jnp
-import numpy as np
-from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
-
-from ..telemetry.tracer import span
+from jax.sharding import Mesh
 
 log = logging.getLogger(__name__)
-
-#: the two batch axes every exchange reduces over (size-1 axes are
-#: no-ops; both always exist on a full mesh — parallel/mesh.AXES)
-BATCH_AXES = ("data", "fsdp")
-
-#: non-batch mesh axes, in the canonical parallel/mesh.AXES order —
-#: the candidates for manual shaping axes in the layout-aware exchange
-SHAPING_AXES = ("pipeline", "expert", "seq", "tensor")
-
-
-def overlap_auto_axes(mesh: Mesh) -> frozenset:
-    """Mesh axes the exchange shard_map leaves AUTOMATIC: ``tensor``,
-    whose Megatron placement rides GSPMD propagation +
-    with_sharding_constraint (models/transformer.py) rather than explicit
-    collectives — inside the body it keeps behaving exactly as under
-    jit. Everything else the envelope admits is manual."""
-    return frozenset({"tensor"}) if mesh.shape.get("tensor", 1) > 1 \
-        else frozenset()
-
-
-def overlap_shaping_axes(mesh: Mesh):
-    """Active (>1) non-batch axes the exchange maps MANUALLY, canonical
-    order — the axes whose redundancy factor scales the local loss and
-    whose names join replicated leaves' reduce sets."""
-    auto = overlap_auto_axes(mesh)
-    return tuple(a for a in SHAPING_AXES
-                 if a not in auto and mesh.shape.get(a, 1) > 1)
-
-
-def _spec_axis_names(spec: P) -> frozenset:
-    names = set()
-    for entry in spec:
-        if entry is None:
-            continue
-        tup = entry if isinstance(entry, tuple) else (entry,)
-        names.update(tup)
-    return frozenset(names)
-
-
-def leaf_reduce_axes(spec: P, shaping) -> tuple:
-    """The psum axis set for one gradient leaf: always the batch axes,
-    plus every active shaping axis the leaf's spec does NOT name (a leaf
-    sharded over ``pipeline``/``expert`` already holds a distinct shard
-    per peer there — summing would corrupt it; a leaf replicated over
-    them carries a 1/R-scaled partial that the psum reconstructs)."""
-    named = _spec_axis_names(spec)
-    return BATCH_AXES + tuple(a for a in shaping if a not in named)
 
 
 #: compiler options under which the TPU compiler hides part of a one-host
@@ -262,1249 +103,3 @@ def exchange_compiler_options(mesh: Mesh) -> Optional[dict]:
                     refusal)
         return None
     return dict(EXCHANGE_COMPILER_OPTIONS)
-
-
-#: dtypes the exchange payload may compress to (``comm.compress``) — the
-#: SAME name→dtype map the step policy uses (parallel/precision.py is
-#: the one resolution point for every low-precision knob)
-from .precision import POLICY_DTYPES as COMPRESS_DTYPES  # noqa: E402
-
-
-def compress_dtype(cfg) -> Optional[str]:
-    """``comm.compress`` → the payload dtype NAME ("bf16"/"fp16") or None
-    (off). Pure validation — whether compression actually applies is the
-    overlap plan's call (it rides the bucketed exchange; the Trainer
-    warns when compression is requested while the exchange is off)."""
-    mode = cfg.comm.compress
-    if mode == "off":
-        return None
-    if mode not in COMPRESS_DTYPES:
-        raise ValueError(f"unknown comm.compress setting {mode!r}; "
-                         f"supported: off, {sorted(COMPRESS_DTYPES)}")
-    return mode
-
-
-def hierarchy_groups(k_intra: int, k_inter: int):
-    """``axis_index_groups`` for the two tiers of a factored ``data`` axis
-    of size ``k_intra × k_inter``: host-aware device order places a
-    host's devices CONSECUTIVELY along the axis, so the intra-tier
-    groups are the consecutive blocks ``[b·k, …, b·k+k-1]`` and the
-    inter-tier groups are the stride-k columns ``[r, r+k, …]`` (one peer
-    per host, matched by intra-host rank)."""
-    gi = [[b * k_intra + r for r in range(k_intra)] for b in range(k_inter)]
-    ge = [[b * k_intra + r for b in range(k_inter)] for r in range(k_intra)]
-    return gi, ge
-
-
-def hierarchy_factor(cfg, mesh: Mesh) -> Optional[int]:
-    """The intra-tier group size k for (cfg, mesh): the explicit
-    ``comm.intra_axis_size`` override when set (validated — must be a
-    non-trivial divisor of the data axis), else the host-derived
-    factorization (parallel/mesh.data_axis_host_factorization). None
-    when no non-trivial factorization exists."""
-    dsize = int(mesh.shape.get("data", 1))
-    k = int(getattr(cfg.comm, "intra_axis_size", 0) or 0)
-    if k:
-        if dsize <= 1 or k <= 1 or k >= dsize or dsize % k:
-            raise ValueError(
-                f"comm.intra_axis_size={k} must satisfy 1 < k < data axis "
-                f"size ({dsize}) and divide it — the hierarchical exchange "
-                "needs a non-trivial uniform two-tier factorization")
-        return k
-    from .mesh import data_axis_host_factorization
-    return data_axis_host_factorization(mesh)
-
-
-def resolve_hierarchy(cfg, mesh: Mesh) -> Optional[int]:
-    """``comm.hierarchy`` → the intra-tier size k or None (flat).
-    ``auto`` quietly stays flat when the mesh gives no factorization;
-    ``on`` raises instead of silently training a different program."""
-    mode = cfg.comm.hierarchy
-    if mode not in ("off", "auto", "on"):
-        raise ValueError(f"unknown comm.hierarchy setting {mode!r}")
-    if mode == "off":
-        return None
-    k = hierarchy_factor(cfg, mesh)
-    if k is None:
-        reason = ("the data axis has no intra/inter-host factorization "
-                  "(single host, trivial axis, or interleaved device "
-                  "order) and no comm.intra_axis_size override")
-        if mode == "on":
-            raise ValueError(f"comm.hierarchy=on is unsupported here: "
-                             f"{reason}")
-        log.info("comm.hierarchy=auto resolved flat: %s", reason)
-    return k
-
-
-def autotune_mode(cfg) -> str:
-    """``comm.autotune`` validated — "off" or "startup". Whether the
-    startup pass actually runs is the Trainer's call (it needs the
-    telemetry.comm_timing probe; see train/loop.py)."""
-    mode = getattr(cfg.comm, "autotune", "off")
-    if mode not in ("off", "startup"):
-        raise ValueError(f"unknown comm.autotune setting {mode!r}; "
-                         "supported: off, startup")
-    return mode
-
-
-@dataclass(frozen=True)
-class OverlapPlan:
-    """Resolved overlap configuration for one (cfg, mesh).
-
-    ``compress`` names the exchange payload dtype ("bf16"/"fp16") or None
-    — carried on the plan because the gather leg (make_bucketed_gather)
-    and the exchange must agree, and both already receive the plan.
-
-    ``hierarchy`` is the intra-tier group size k of the two-tier data-axis
-    exchange (module docstring) or None (flat). ``autotune`` mirrors
-    ``comm.autotune``; ``tuned`` marks a plan REWRITTEN by the startup
-    autotune pass (telemetry/planner.tune_comm_plan) — the comm_overlap
-    row carries both so a tuned run is distinguishable from a hand-set
-    one."""
-
-    bucket_bytes: int
-    compress: Optional[str] = None
-    hierarchy: Optional[int] = None
-    autotune: str = "off"
-    tuned: bool = False
-
-
-class OverlapStats:
-    """Thread-safe record of the most recent bucket plan — what the
-    ``{"event": "comm_overlap"}`` metrics row (train/hooks.CommOverlapHook)
-    and bench.py's overlap row export. Written when the bucketed grad fn
-    TRACES (once per compiled step, not per step)."""
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self._plan: Optional[dict] = None
-
-    def record(self, bucket_bytes: int, bucket_sizes: Sequence[int],
-               bucket_leaves: Sequence[int], total_bytes: int,
-               n_leaves: int, compress: Optional[str] = None,
-               wire_bytes: Optional[Sequence[int]] = None,
-               declared: Optional[Sequence[Sequence[str]]] = None,
-               reduce_axes: Optional[Sequence[str]] = None,
-               accum_steps: int = 1,
-               hierarchy: Optional[int] = None,
-               autotune: str = "off", tuned: bool = False,
-               inter_wire: Optional[Sequence[int]] = None,
-               op_wire: Optional[Sequence[Sequence[int]]] = None) -> None:
-        with self._lock:
-            self._plan = {
-                "buckets": len(bucket_sizes),
-                "bucket_cap_bytes": int(bucket_bytes),
-                "bucket_bytes": [int(b) for b in bucket_sizes],
-                "bucket_leaves": [int(n) for n in bucket_leaves],
-                "grad_bytes": int(total_bytes),
-                "leaves": int(n_leaves),
-                # layout-aware exchange: per-bucket reduce-axis set (one
-                # set per bucket by construction — the grouped planner)
-                # and the accumulation factor. Under accumulation the
-                # plan fires ONCE per optimizer step, so wire_bytes below
-                # is already the per-step number: 1/accum of what a
-                # per-microbatch exchange would move.
-                "bucket_reduce_axes": ["+".join(a) for a in reduce_axes]
-                if reduce_axes is not None
-                else ["+".join(BATCH_AXES)] * len(bucket_sizes),
-                "accum_steps": int(accum_steps),
-                # compressed-exchange payload accounting (comm.compress):
-                # the SAME bucket plan, narrower wire format — what the
-                # comm_compress metrics row and bench's precision row read
-                "compress": compress or "off",
-                "bucket_wire_bytes": [int(b) for b in wire_bytes]
-                if wire_bytes is not None
-                else [int(b) for b in bucket_sizes],
-                "wire_bytes": int(sum(wire_bytes)) if wire_bytes is not None
-                else int(total_bytes),
-                # hierarchical exchange (comm.hierarchy): the resolved
-                # intra-tier size k (0 = flat), whether the autotune pass
-                # chose this plan, and the per-bucket bytes crossing the
-                # SLOW inter-host tier — the 1/k acceptance number (flat:
-                # the full wire payload crosses it)
-                "hierarchy": int(hierarchy) if hierarchy else 0,
-                "autotune": autotune or "off",
-                "tuned": bool(tuned),
-                "bucket_inter_wire_bytes": [int(b) for b in inter_wire]
-                if inter_wire is not None
-                else ([int(b) for b in wire_bytes] if wire_bytes is not None
-                      else [int(b) for b in bucket_sizes]),
-                # per-bucket per-OP wire bytes, aligned 1:1 with the
-                # declared collective sequence — the planner/comm-report
-                # match staged (RS→psum→AG) plans op-by-op with these
-                "bucket_op_wire_bytes": [[int(x) for x in b]
-                                         for b in op_wire]
-                if op_wire is not None else None,
-                # per-bucket declared collective sequences (bucket order =
-                # issue order): what analysis/collectives.py cross-checks
-                # the traced jaxpr schedule against
-                "declared_collectives": [list(b) for b in declared]
-                if declared is not None else None,
-            }
-
-    def reset(self) -> None:
-        with self._lock:
-            self._plan = None
-
-    def snapshot(self) -> Optional[dict]:
-        with self._lock:
-            return dict(self._plan) if self._plan is not None else None
-
-
-#: process-global plan record (one overlap step per training process)
-overlap_stats = OverlapStats()
-
-
-def overlap_unsupported_reason(cfg, mesh: Mesh) -> Optional[str]:
-    """None when the bucketed exchange applies to this (cfg, mesh); else a
-    one-line reason (``comm.overlap=on`` raises it, ``auto`` logs it)."""
-    from .mesh import batch_shard_count
-    n = batch_shard_count(mesh)
-    if n <= 1:
-        return "a single batch shard has no gradient exchange to bucket"
-    accum = max(1, cfg.train.grad_accum_steps)
-    if cfg.train.batch_size % (n * accum):
-        per = f"{n} batch shards" if accum == 1 else \
-            (f"{n} batch shards × {accum} accumulation microbatches")
-        return (f"train.batch_size={cfg.train.batch_size} does not divide "
-                f"over {per} — the shard_map'd exchange needs equal "
-                "per-shard (micro)batches")
-    if mesh.shape.get("seq", 1) > 1:
-        return ("mesh axis 'seq' > 1 runs ring attention's own shard_map "
-                "inside the blocks — the exchange body does not contain it "
-                "(nested shard_map over auto axes mis-transposed on jax "
-                "0.4.37; not re-checked since); sequence parallelism stays "
-                "on the XLA-propagation exchange")
-    if mesh.shape.get("expert", 1) > 1 and mesh.shape.get("pipeline", 1) <= 1:
-        return ("mesh axis 'expert' > 1 without a pipeline axis routes "
-                "tokens through SwitchMlp's own (data,fsdp,expert) "
-                "shard_map — only the pipelined MoE form (dp_pp_ep, "
-                "models/pipeline._moe_mlp) runs inline in the exchange "
-                "body")
-    if mesh.shape.get("tensor", 1) > 1 and mesh.shape.get("pipeline", 1) > 1:
-        return ("tensor × pipeline is not wired into the exchange: "
-                "'tensor' rides GSPMD propagation as an AUTO axis, which "
-                "the manually-mapped pipeline body cannot contain")
-    if cfg.model.name == "resnet" and cfg.model.norm == "batch" \
-            and not cfg.model.cross_replica_bn:
-        return ("per-replica BN (cross_replica_bn=false) is emulated with "
-                "grouped moments aligned to the GLOBAL batch layout; under "
-                "shard_map the groups would be local — enable "
-                "cross_replica_bn or use norm='group'/'frozen'")
-    return None
-
-
-def resolve_overlap(cfg, mesh: Mesh) -> Optional[OverlapPlan]:
-    """``comm.overlap`` → an :class:`OverlapPlan` or None (off).
-
-    ``auto`` = on iff the run has peers (jax.process_count() > 1 — the
-    multi-host DCN path where the exchange tail is worth hiding) and the
-    envelope supports it. One process stays off whatever the backend: on
-    the four chips of one TPU host the bucketed step ran 11% SLOWER than
-    the propagated one (ViT-L's widths, 8 blocks), and still 7% slower
-    under the compiler options that hid the propagated exchange; such a
-    mesh gets :func:`exchange_compiler_options` instead (module
-    docstring; PERF.md §6 PR 30). ``on`` forces and raises the
-    unsupported reason instead of silently training a different program
-    than requested."""
-    from .mesh import batch_shard_count
-    mode = cfg.comm.overlap
-    if mode not in ("auto", "on", "off"):
-        raise ValueError(f"unknown comm.overlap setting {mode!r}")
-    if mode == "off":
-        return None
-    reason = overlap_unsupported_reason(cfg, mesh)
-    if mode == "on":
-        if reason is not None:
-            if batch_shard_count(mesh) <= 1:
-                # a single-shard mesh has no exchange to bucket — and it
-                # is exactly what checkpoint CONSUMERS (the standalone
-                # evaluator, a 1-device serving replica) see when they
-                # build a Trainer from a training config that forced the
-                # knob. A train-step-only option must not crash processes
-                # that never run a train step: resolve off, loudly.
-                log.warning("comm.overlap=on resolved OFF: %s", reason)
-                return None
-            raise ValueError(f"comm.overlap=on is unsupported here: "
-                             f"{reason}")
-    else:
-        if reason is not None or jax.process_count() <= 1:
-            return None
-    if cfg.comm.bucket_mb <= 0:
-        raise ValueError(
-            f"comm.bucket_mb must be > 0, got {cfg.comm.bucket_mb}")
-    return OverlapPlan(bucket_bytes=int(cfg.comm.bucket_mb * 2 ** 20),
-                       compress=compress_dtype(cfg),
-                       hierarchy=resolve_hierarchy(cfg, mesh),
-                       autotune=autotune_mode(cfg))
-
-
-def plan_buckets(leaf_bytes: Sequence[int],
-                 bucket_bytes: int) -> List[List[int]]:
-    """Group leaf indices (greedy, REVERSE order) into buckets of at most
-    ``bucket_bytes`` each. Reverse order approximates gradient
-    availability during backprop — the output-side parameters' grads
-    finish first, so their bucket's collective can issue while earlier
-    layers are still differentiating (the DDP bucketing order). A leaf
-    larger than the cap gets its own bucket (never split)."""
-    buckets: List[List[int]] = []
-    cur: List[int] = []
-    cur_bytes = 0
-    for i in reversed(range(len(leaf_bytes))):
-        nb = leaf_bytes[i]
-        if cur and cur_bytes + nb > bucket_bytes:
-            buckets.append(cur)
-            cur, cur_bytes = [], 0
-        cur.append(i)
-        cur_bytes += nb
-    if cur:
-        buckets.append(cur)
-    return buckets
-
-
-def plan_buckets_grouped(leaf_bytes: Sequence[int],
-                         reduce_axes: Sequence[tuple],
-                         bucket_bytes: int):
-    """Greedy reverse-order bucketing, one open bucket PER reduce-axis
-    set: a bucket's replicated leaves ride a single tuple-psum over the
-    bucket's axes, so mixing sets in one bucket is ill-formed (the MoE
-    expert leaves — no ``expert`` in their reduce set — must not share a
-    tuple-psum with the router's ``…+expert`` set). Returns
-    ``[(axes, [leaf indices]), …]`` in ISSUE order: buckets sorted by the
-    reversed position of their first leaf, approximating backprop
-    availability exactly like :func:`plan_buckets` — to which this
-    degenerates (one group, same buckets, same order) on the batch-only
-    meshes, keeping their plans and artifacts unchanged."""
-    open_buckets: dict = {}
-    done: List[tuple] = []  # (first_leaf_reversed_pos, axes, [indices])
-    n = len(leaf_bytes)
-    for pos, i in enumerate(reversed(range(n))):
-        axes = tuple(reduce_axes[i])
-        cur = open_buckets.get(axes)
-        if cur is not None and cur[2] + leaf_bytes[i] > bucket_bytes:
-            done.append((cur[0], axes, cur[1]))
-            cur = None
-        if cur is None:
-            cur = [pos, [], 0]
-            open_buckets[axes] = cur
-        cur[1].append(i)
-        cur[2] += leaf_bytes[i]
-    for axes, cur in open_buckets.items():
-        done.append((cur[0], axes, cur[1]))
-    done.sort(key=lambda t: t[0])
-    return [(axes, idxs) for _, axes, idxs in done]
-
-
-def _fsdp_dim(spec: P) -> Optional[int]:
-    """The dimension a PartitionSpec shards over ``fsdp``, or None."""
-    return _axis_dim(spec, "fsdp")
-
-
-def _axis_dim(spec: P, axis: str) -> Optional[int]:
-    """The dimension a PartitionSpec shards over ``axis``, or None."""
-    for d, names in enumerate(spec):
-        if names is None:
-            continue
-        names = names if isinstance(names, tuple) else (names,)
-        if axis in names:
-            return d
-    return None
-
-
-def _param_specs(params: Any, mesh: Mesh):
-    """Per-leaf PartitionSpecs from the SAME rule the training state uses
-    (parallel/sharding.param_sharding_rule via tree_param_shardings), so
-    the shard_map in_specs match how jit actually lays the params out —
-    a drifted spec would force a per-step reshard."""
-    from .sharding import tree_param_shardings
-    shardings = tree_param_shardings(params, mesh)
-    return jax.tree_util.tree_map(lambda s: s.spec, shardings,
-                                  is_leaf=lambda x: hasattr(x, "spec"))
-
-
-def _resolve_hier(hierarchy, data_size, reduce_axes):
-    """(k_intra, k_inter) when the hierarchical staging applies to this
-    bucket — the bucket reduces over ``data`` and the factorization is
-    non-trivial — else None (flat). One resolution point shared by the
-    declared plan and the exchange so the two cannot disagree."""
-    if not hierarchy or "data" not in reduce_axes:
-        return None
-    k, dsize = int(hierarchy), int(data_size)
-    if dsize <= 1 or k <= 1 or k >= dsize or dsize % k:
-        return None
-    return k, dsize // k
-
-
-def _bucket_plan_ops(specs, out_specs=None, reduce_axes=BATCH_AXES,
-                     hierarchy=None, data_size=0, leaf_elems=None,
-                     wire_itemsize=4, fsdp_size=1) -> List[dict]:
-    """One bucket's collective-issue plan, op by op — the single source
-    both :func:`declared_bucket_collectives` (signature strings for the
-    hangcheck) and make_bucketed_grad's wire-byte accounting read, so the
-    declared schedule and the byte ledger cannot drift apart. Each op:
-
-      ``sig``   — ``"<kind>@<axis>[+<axis>…]"``, with a ``[k]`` suffix on
-                  grouped (two-tier) collectives naming the GROUP size —
-                  analysis/collectives.py tags traced ``axis_index_groups``
-                  ops the same way;
-      ``wire_bytes`` — that op's input payload in wire dtype bytes
-                  (0 when ``leaf_elems`` is not given);
-      ``inter`` — True when the payload crosses the slow data tier (a
-                  flat data psum/scatter moves the FULL payload across
-                  hosts; the staged plan's inter leg moves 1/k).
-
-    The op order is the issue order ``_exchange_bucket`` traces: the
-    replicated block first (tuple-psum, or its staged RS→psum→AG
-    restaging), then the per-leaf fsdp/zero1 ops, then the staged block
-    for fsdp-scattered remainders."""
-    if out_specs is None:
-        out_specs = specs
-    reduce_axes = tuple(reduce_axes)
-    hier = _resolve_hier(hierarchy, data_size, reduce_axes)
-    elems = list(leaf_elems) if leaf_elems is not None else [0] * len(specs)
-    ops: List[dict] = []
-
-    def add(sig, n_elems, inter=False):
-        ops.append({"sig": sig, "wire_bytes": int(n_elems) * wire_itemsize,
-                    "inter": inter})
-
-    def staged(total_elems, rest):
-        # the two-tier restaging of ``psum@data[+rest]``: RS over the k
-        # intra peers (payload padded to a multiple of k), psum of the
-        # 1/k shard across hosts (+ any non-data reduce axes, flat), AG
-        # the reduced shard back intra-host
-        k, k_inter = hier
-        padded = total_elems + (-total_elems) % k
-        shard = padded // k
-        add(f"psum_scatter@data[{k}]", padded)
-        add(f"psum@data[{k_inter}]", shard, inter=True)
-        if rest:
-            add("psum@" + "+".join(rest), shard)
-        add(f"all_gather@data[{k}]", shard)
-
-    z1_dims = [_axis_dim(o, "data") for o in out_specs]
-    rep_idx = [i for i, s in enumerate(specs)
-               if _fsdp_dim(s) is None and z1_dims[i] is None]
-    if rep_idx:
-        rep_elems = sum(elems[i] for i in rep_idx)
-        if hier is not None:
-            staged(rep_elems, tuple(a for a in reduce_axes if a != "data"))
-        else:
-            add("psum@" + "+".join(reduce_axes), rep_elems,
-                inter="data" in reduce_axes)
-    rem_axes = tuple(a for a in reduce_axes if a != "fsdp")
-    staged_elems = 0
-    staged_any = False
-    for i, spec in enumerate(specs):
-        d = _fsdp_dim(spec)
-        dz = z1_dims[i]
-        if d is None and dz is None:
-            continue
-        e = elems[i]
-        if d is not None:
-            add("psum_scatter@fsdp", e)
-            e = e // max(1, int(fsdp_size))
-        if dz is not None:
-            # zero1 leaves stay on the flat data scatter: they already
-            # move only 1/N and land in the shard layout — restaging
-            # would re-gather what the optimizer wants scattered
-            add("psum_scatter@data", e, inter=True)
-            if d is None:
-                add("psum@fsdp", e // max(1, int(data_size) or 1))
-        elif hier is not None:
-            staged_any = True
-            staged_elems += e
-        else:
-            add("psum@" + "+".join(rem_axes), e, inter="data" in rem_axes)
-    if hier is not None and staged_any:
-        staged(staged_elems, tuple(a for a in rem_axes if a != "data"))
-    return ops
-
-
-def declared_bucket_collectives(specs, out_specs=None,
-                                reduce_axes=BATCH_AXES,
-                                hierarchy=None, data_size=0) -> List[str]:
-    """The collective-issue sequence ``_exchange_bucket`` will emit for
-    one bucket, as ``"<kind>@<axis>[+<axis>…]"`` strings — the DECLARED
-    plan hangcheck's schedule extractor (analysis/collectives.py) checks
-    the traced jaxpr against: replicated leaves ride ONE tuple-psum over
-    the bucket's reduce-axis set (``reduce_axes`` — the batch axes plus
-    any shaping axes the leaves replicate over, parallel layouts); each
-    fsdp/ZeRO-sharded leaf reduce-scatters FIRST on its sharded axis,
-    then psums (or scatters) the remainder. Under ``hierarchy`` (the
-    intra-tier size k) the data-axis reductions restage as
-    ``psum_scatter@data[k] → psum@data[D/k] → all_gather@data[k]``
-    (module docstring). Must mirror ``_exchange_bucket`` exactly — a
-    drift between the two IS the gate finding."""
-    return [op["sig"] for op in _bucket_plan_ops(
-        specs, out_specs, reduce_axes, hierarchy, data_size)]
-
-
-def _hier_reduce(parts, k_intra, k_inter, rest_axes):
-    """All-reduce ``parts`` (a list of same-dtype leaves, summed over the
-    full ``data`` axis plus ``rest_axes``) via the two-tier staging:
-    flatten + concat into one vector, pad to a multiple of k, then
-    ``psum_scatter`` over the intra-tier groups (each of the k intra
-    peers ends holding a distinct 1/k shard, already host-locally
-    reduced), ``psum`` the shard across the inter-tier groups (the only
-    inter-host traffic — 1/k of the payload; ``rest_axes`` fold in here
-    too, on the shard), and ``all_gather`` the fully-reduced shards back
-    over the intra tier. Returns leaves in input order/shape."""
-    gi, ge = hierarchy_groups(k_intra, k_inter)
-    shapes = [np.shape(p) for p in parts]
-    sizes = [int(np.prod(s, dtype=np.int64)) for s in shapes]
-    flat = [p.reshape(-1) for p in parts]
-    vec = flat[0] if len(flat) == 1 else jnp.concatenate(flat)
-    total = int(vec.shape[0])
-    pad = (-total) % k_intra
-    if pad:
-        vec = jnp.pad(vec, (0, pad))
-    shard = lax.psum_scatter(vec, "data", scatter_dimension=0, tiled=True,
-                             axis_index_groups=gi)
-    shard = lax.psum(shard, "data", axis_index_groups=ge)
-    if rest_axes:
-        shard = lax.psum(shard, tuple(rest_axes))
-    full = lax.all_gather(shard, "data", axis=0, tiled=True,
-                          axis_index_groups=gi)
-    if pad:
-        full = full[:total]
-    out, off = [], 0
-    for shape, n in zip(shapes, sizes):
-        out.append(full[off:off + n].reshape(shape))
-        off += n
-    return out
-
-
-def _exchange_bucket(leaves, specs, out_specs=None, compress=None,
-                     reduce_axes=BATCH_AXES, hierarchy=None, data_size=0):
-    """One bucket's gradient exchange: replicated leaves ride a single
-    tuple-psum over the bucket's reduce-axis set (``reduce_axes`` — the
-    batch axes, plus the shaping axes the leaves replicate over on
-    pipeline/expert layouts; one collective issue); fsdp-sharded leaves
-    psum over the remaining axes and psum_scatter over ``fsdp`` on their
-    sharded dim (the ZeRO reduce-scatter), landing exactly in the leaf's
-    training-state layout. Returns leaves in input order.
-
-    ``out_specs`` (the ZeRO-1 path, arXiv:2004.13336) additionally names
-    a ``data`` dim per leaf: those leaves reduce-SCATTER over ``data``
-    instead of psumming, so each replica receives only its optimizer
-    shard's gradient slice — 1/N the data-axis payload, landing exactly
-    in the sharded weight-update layout.
-
-    ``compress`` ("bf16"/"fp16", comm.compress): the payload is cast to
-    the compressed dtype BEFORE its collectives and re-materialized f32
-    after — the wire carries half the bytes; every f32 accumulation
-    around the exchange (local grads, the optimizer) is untouched. The
-    cast is per-leaf, so it commutes with bucketing: many-vs-one-bucket
-    stays bit-identical under compression.
-
-    ``hierarchy``/``data_size`` (comm.hierarchy, module docstring): when
-    the bucket reduces over ``data`` and the k | data_size factorization
-    is non-trivial, the flat data-axis psums restage through
-    :func:`_hier_reduce` — replicated leaves as one staged block, fsdp-
-    scattered remainders as a second staged block after their scatters.
-    zero1 leaves keep their flat data scatter (they already move 1/N).
-    The issue order mirrors :func:`_bucket_plan_ops` op for op."""
-    if out_specs is None:
-        out_specs = specs
-    reduce_axes = tuple(reduce_axes)
-    hier = _resolve_hier(hierarchy, data_size, reduce_axes)
-    in_dt = leaves[0].dtype if leaves else jnp.float32
-    if compress is not None:
-        cdt = COMPRESS_DTYPES[compress]
-        leaves = [l.astype(cdt) for l in leaves]
-    z1_dims = [_axis_dim(o, "data") for o in out_specs]
-    rep_idx = [i for i, s in enumerate(specs)
-               if _fsdp_dim(s) is None and z1_dims[i] is None]
-    out: List[Any] = [None] * len(leaves)
-    if rep_idx:
-        if hier is not None:
-            reduced = _hier_reduce(
-                [leaves[i] for i in rep_idx], hier[0], hier[1],
-                tuple(a for a in reduce_axes if a != "data"))
-        else:
-            reduced = lax.psum(tuple(leaves[i] for i in rep_idx),
-                               reduce_axes)
-        for i, v in zip(rep_idx, reduced):
-            out[i] = v
-    rem_axes = tuple(a for a in reduce_axes if a != "fsdp")
-    staged_idx: List[int] = []
-    staged_vals: List[Any] = []
-    for i, (leaf, spec) in enumerate(zip(leaves, specs)):
-        d = _fsdp_dim(spec)
-        dz = z1_dims[i]
-        if d is None and dz is None:
-            continue
-        # reduce-scatter FIRST on every sharded axis: the remaining
-        # collective then carries the scattered shard instead of the full
-        # leaf — same sum (the axes reduce independently), N× less
-        # payload on the axis this path exists to relieve
-        if d is not None:
-            leaf = lax.psum_scatter(leaf, "fsdp", scatter_dimension=d,
-                                    tiled=True)
-        if dz is not None:
-            leaf = lax.psum_scatter(leaf, "data", scatter_dimension=dz,
-                                    tiled=True)
-            if d is None:
-                leaf = lax.psum(leaf, "fsdp")
-        elif hier is not None:
-            staged_idx.append(i)
-            staged_vals.append(leaf)
-            continue
-        else:
-            leaf = lax.psum(leaf, rem_axes)
-        out[i] = leaf
-    if staged_idx:
-        reduced = _hier_reduce(staged_vals, hier[0], hier[1],
-                               tuple(a for a in rem_axes if a != "data"))
-        for i, v in zip(staged_idx, reduced):
-            out[i] = v
-    if compress is not None:
-        # f32 re-materialization: everything downstream of the exchange
-        # (grad-norm metric, optimizer update) accumulates full-precision
-        out = [v.astype(in_dt) for v in out]
-    return out
-
-
-def make_bucketed_grad(plan: OverlapPlan, mesh: Mesh, *,
-                       weight_decay: float,
-                       decay_in_loss: bool = True,
-                       decay_all_params: bool = False,
-                       label_smoothing: float = 0.0,
-                       fused_xent: str = "off",
-                       aux_loss_weight: float = 0.01,
-                       zero1_min_size: Optional[int] = None,
-                       precision=None,
-                       grad_accum_steps: int = 1,
-                       augment_fn: Optional[Callable] = None,
-                       augment_seed: int = 0) -> Callable:
-    """Drop-in replacement for ``jax.value_and_grad(loss_fn, has_aux=True)``
-    in train/loop.make_train_step's single step:
-
-        grad_fn(params, batch_stats, images, labels, apply_fn, step=0)
-            -> ((loss, (ce, logits, new_batch_stats)), grads)
-
-    with the gradient exchange bucketed as described in the module
-    docstring. loss/ce come out as the GLOBAL batch mean (identical
-    semantics to the jit path); logits reassemble into the global array;
-    new_batch_stats is replicated by construction (the model's BN pmean's
-    its moments over the batch axes — Trainer builds the model with
-    ``axis_name=BATCH_AXES`` when overlap is active).
-
-    ``zero1_min_size`` (non-None = ZeRO-1 active, the value is the
-    replication floor in elements) switches the exchange to the ZeRO-1
-    form (``parallel.sharding.zero1_grad_specs``): leaves the rule table
-    assigns a ``data`` dim reduce-SCATTER over ``data`` and come out in
-    the sharded weight-update layout — the optimizer then updates only
-    each replica's shard, and the bucketed all-gather
-    (``make_bucketed_gather``) brings the param updates back.
-
-    ``precision`` (``parallel.precision.PrecisionPolicy``): the SAME
-    policy input cast the jit path's loss_fn applies
-    (train/loop.make_train_step) — the shard_map body must mirror it or
-    the overlap step would compute a different program than the step it
-    replaces.
-
-    ``grad_accum_steps`` > 1 runs the microbatch scan INSIDE the body
-    (module docstring): local f32 accumulation, the final microbatch
-    peeled out of the scan, ONE bucketed exchange after it — per-step
-    wire traffic is 1× the gradient bytes instead of accum×, and the
-    exchange overlaps the last microbatch's backprop. ``augment_fn`` /
-    ``augment_seed`` mirror make_train_step's per-microbatch prep with
-    per-(shard, step, microbatch) keys — draws stay i.i.d. per example
-    across shards, and both bucketing plans use the same keys so
-    bucketing stays a pure scheduling change; ``step`` feeds the RNG."""
-    from .mesh import batch_shard_count, manual_axes, shard_map_unchecked
-    from ..train.loop import make_ce_fn
-    from ..train.optimizers import loss_weight_decay
-    n_shards = batch_shard_count(mesh)
-    auto = overlap_auto_axes(mesh)
-    manual = frozenset(a for a in mesh.axis_names if a not in auto)
-    shaping = overlap_shaping_axes(mesh)
-    loss_axes = BATCH_AXES + shaping
-    r_scale = int(np.prod([mesh.shape[a] for a in shaping], dtype=np.int64)) \
-        if shaping else 1
-    n_total = n_shards * r_scale
-    accum = max(1, grad_accum_steps)
-    # the SAME mode/smoothing resolution the jit path uses, unreduced: the
-    # caller's shard_map body is already per-shard, so the Pallas kernel
-    # (fused_xent on/interpret) runs directly on the local (b/n, C) tile
-    per_example_ce = make_ce_fn(label_smoothing, fused_xent,
-                                per_example=True)
-    batch_spec = P(BATCH_AXES)
-
-    def grad_fn(params, batch_stats, images, labels, apply_fn, step=0):
-        n_global = images.shape[0]
-        pspecs = _param_specs(params, mesh)
-        if auto:
-            # shard_map specs may only name MANUAL axes — auto ("tensor")
-            # references are stripped; the auto-axis sharding rides GSPMD
-            # propagation through the body instead
-            mspecs = jax.tree_util.tree_map(
-                _strip_axes(auto), pspecs,
-                is_leaf=lambda x: isinstance(x, P))
-        else:
-            mspecs = pspecs
-        if zero1_min_size is not None:
-            from .sharding import zero1_grad_specs
-            gout_specs = zero1_grad_specs(params, mesh,
-                                          min_size=zero1_min_size)
-        else:
-            gout_specs = mspecs
-        bs_specs = jax.tree_util.tree_map(lambda _: P(), batch_stats)
-
-        def body(params_l, bstats, images_l, labels_l):
-            # reconstruct full params from fsdp shards (the explicit form
-            # of the all-gather XLA propagation inserts on the jit path)
-            def gather(leaf, spec):
-                d = _fsdp_dim(spec)
-                if d is None:
-                    return leaf
-                return lax.all_gather(leaf, "fsdp", axis=d, tiled=True)
-
-            pfull = jax.tree_util.tree_map(gather, params_l, mspecs)
-
-            def local_loss(pf, bs, images_mb, labels_mb, mb_global):
-                variables = {"params": pf, "batch_stats": bs}
-                imgs = images_mb if precision is None \
-                    else precision.cast_compute(images_mb)
-                logits, mutated = apply_fn(variables, imgs, train=True,
-                                           mutable=["batch_stats",
-                                                    "losses"])
-                # local CONTRIBUTION to the global mean loss: sum of this
-                # shard's per-example CE over the GLOBAL (micro)batch
-                # size; replicated terms (decay, aux) are pre-divided by
-                # the total manual peer count, and on shaped meshes the
-                # CE part by the redundancy factor R, so the psum over
-                # ``loss_axes`` reconstructs each exactly once — grads
-                # then exchange as a plain sum, no post-scaling (the
-                # module docstring's replicated-leaf calculus)
-                ce_part = per_example_ce(logits, labels_mb).sum() \
-                    / mb_global
-                if r_scale != 1:
-                    ce_part = ce_part / r_scale
-                loss_part = ce_part
-                if decay_in_loss:
-                    loss_part = loss_part + loss_weight_decay(
-                        pf, weight_decay, decay_all_params) / n_total
-                aux = jax.tree_util.tree_leaves(mutated.get("losses", {}))
-                if aux:
-                    loss_part = loss_part + aux_loss_weight * sum(
-                        jnp.sum(a) for a in aux) / n_total
-                return loss_part, (ce_part, logits,
-                                   mutated["batch_stats"])
-
-            def micro_grad(bs, images_mb, labels_mb, mb_global):
-                return jax.value_and_grad(
-                    local_loss, has_aux=True)(pfull, bs, images_mb,
-                                              labels_mb, mb_global)
-
-            if accum <= 1:
-                (loss_part, (ce_part, logits, new_bs)), grads = \
-                    micro_grad(bstats, images_l, labels_l, n_global)
-            else:
-                # the in-envelope accumulation scan: local f32 grads
-                # accumulate across the first accum-1 microbatches inside
-                # lax.scan; the LAST microbatch runs peeled so its
-                # backward is still in flight when the reverse-order
-                # buckets start issuing — the exchange hides behind it
-                local_b = images_l.shape[0]
-                mb = local_b // accum
-                mb_global = n_global // accum
-                im = images_l.reshape((accum, mb) + images_l.shape[1:])
-                lb = labels_l.reshape((accum, mb) + labels_l.shape[1:])
-
-                def prep_mb(images_mb, midx):
-                    if augment_fn is None:
-                        return images_mb
-                    # fold in this shard's batch coordinate: the body is
-                    # per-shard, so one shared key would give example i
-                    # on EVERY shard identical crop/flip draws — an N×
-                    # cut in augmentation diversity vs the jit path's
-                    # global-batch draws. Per-(shard, step, microbatch)
-                    # keys keep draws i.i.d. per example; bucketing stays
-                    # a pure scheduling change (same keys both plans).
-                    shard = lax.axis_index("data") * mesh.shape["fsdp"] \
-                        + lax.axis_index("fsdp")
-                    rng = jax.random.fold_in(
-                        jax.random.fold_in(
-                            jax.random.fold_in(
-                                jax.random.PRNGKey(augment_seed), step),
-                            midx), shard)
-                    return augment_fn(images_mb, rng)
-
-                def scan_body(carry, xs):
-                    grads_acc, bs = carry
-                    images_mb, labels_mb, midx = xs
-                    (lp, (cp, lg, nbs)), g = micro_grad(
-                        bs, prep_mb(images_mb, midx), labels_mb,
-                        mb_global)
-                    grads_acc = jax.tree_util.tree_map(jnp.add,
-                                                       grads_acc, g)
-                    return (grads_acc, nbs), (lp, cp, lg)
-
-                zero_grads = jax.tree_util.tree_map(
-                    lambda p: jnp.zeros(np.shape(p), jnp.float32), pfull)
-                (grads_acc, bs_carry), (lps, cps, lgs) = jax.lax.scan(
-                    scan_body, (zero_grads, bstats),
-                    (im[:-1], lb[:-1], jnp.arange(accum - 1)))
-                (lp_last, (cp_last, lg_last, new_bs)), g_last = \
-                    micro_grad(bs_carry, prep_mb(im[-1], accum - 1),
-                               lb[-1], mb_global)
-                grads = jax.tree_util.tree_map(
-                    lambda a, b: (a + b) / accum, grads_acc, g_last)
-                # metrics mirror the jit accumulation path: loss/ce are
-                # the MEAN over microbatches of the per-microbatch global
-                # values; logits reassemble in batch order
-                loss_part = (jnp.sum(lps) + lp_last) / accum
-                ce_part = (jnp.sum(cps) + cp_last) / accum
-                logits = jnp.concatenate(
-                    [lgs.reshape((-1,) + lgs.shape[2:]), lg_last], axis=0)
-
-            # bucketed exchange, reverse parameter order, grouped by
-            # reduce-axis set; buckets chained through
-            # optimization_barrier so they issue in order and the
-            # all-reduce combiner can't re-merge them (see module
-            # docstring)
-            leaves, treedef = jax.tree_util.tree_flatten(grads)
-            spec_leaves = treedef.flatten_up_to(mspecs)
-            z1_leaves = treedef.flatten_up_to(gout_specs)
-            reduce_sets = [leaf_reduce_axes(s, shaping)
-                           for s in spec_leaves]
-            leaf_bytes = [int(np.prod(np.shape(g)) *
-                              np.dtype(g.dtype).itemsize) for g in leaves]
-            buckets = plan_buckets_grouped(leaf_bytes, reduce_sets,
-                                           plan.bucket_bytes)
-            bucket_sizes = [sum(leaf_bytes[i] for i in b)
-                            for _, b in buckets]
-            # the bucket PLAN is computed from the uncompressed leaf
-            # bytes either way — compression narrows the wire format on
-            # the same plan, so A/B rows compare like for like
-            if plan.compress is not None:
-                wire_itemsize = int(
-                    np.dtype(COMPRESS_DTYPES[plan.compress]).itemsize)
-                ratio = wire_itemsize / np.dtype(np.float32).itemsize
-                wire_sizes = [int(b * ratio) for b in bucket_sizes]
-            else:
-                wire_itemsize = int(np.dtype(np.float32).itemsize)
-                wire_sizes = bucket_sizes
-            data_size = int(mesh.shape.get("data", 1))
-            leaf_elems = [int(np.prod(np.shape(g), dtype=np.int64))
-                          for g in leaves]
-            plan_ops = [_bucket_plan_ops(
-                [spec_leaves[i] for i in b], [z1_leaves[i] for i in b],
-                reduce_axes=axes, hierarchy=plan.hierarchy,
-                data_size=data_size,
-                leaf_elems=[leaf_elems[i] for i in b],
-                wire_itemsize=wire_itemsize,
-                fsdp_size=int(mesh.shape.get("fsdp", 1)))
-                for axes, b in buckets]
-            # declared sigs go through the module-level wrapper, NOT the
-            # plan_ops list above: declared_bucket_collectives is the
-            # drift seam hangcheck's seeded-mismatch test patches, and a
-            # plan that bypassed it could never be caught disagreeing
-            # with the trace.
-            declared = [declared_bucket_collectives(
-                [spec_leaves[i] for i in b], [z1_leaves[i] for i in b],
-                reduce_axes=axes, hierarchy=plan.hierarchy,
-                data_size=data_size)
-                for axes, b in buckets]
-            overlap_stats.record(plan.bucket_bytes, bucket_sizes,
-                                 [len(b) for _, b in buckets],
-                                 sum(leaf_bytes), len(leaves),
-                                 compress=plan.compress,
-                                 wire_bytes=wire_sizes,
-                                 declared=declared,
-                                 reduce_axes=[axes for axes, _ in buckets],
-                                 accum_steps=accum,
-                                 hierarchy=plan.hierarchy,
-                                 autotune=plan.autotune, tuned=plan.tuned,
-                                 inter_wire=[sum(op["wire_bytes"]
-                                                 for op in ops
-                                                 if op["inter"])
-                                             for ops in plan_ops],
-                                 op_wire=[[op["wire_bytes"] for op in ops]
-                                          for ops in plan_ops])
-            out_leaves: List[Any] = [None] * len(leaves)
-            anchor = None
-            for bi, ((axes, b), nbytes, wbytes) in enumerate(
-                    zip(buckets, bucket_sizes, wire_sizes)):
-                # flight recorder: one (trace-time) span per planned
-                # bucket — the plan is visible in trace.json without
-                # instrumenting the compiled program itself. The bucket
-                # index joins the span to the plan/comm_timing rows.
-                with span("comm.bucket", bucket=bi, bytes=int(nbytes),
-                          wire_bytes=int(wbytes), leaves=len(b)):
-                    vals = [leaves[i] for i in b]
-                    if anchor is not None:
-                        vals, _ = lax.optimization_barrier((vals, anchor))
-                    exchanged = _exchange_bucket(
-                        vals, [spec_leaves[i] for i in b],
-                        out_specs=[z1_leaves[i] for i in b],
-                        compress=plan.compress, reduce_axes=axes,
-                        hierarchy=plan.hierarchy, data_size=data_size)
-                    anchor = exchanged[0]
-                    for i, v in zip(b, exchanged):
-                        out_leaves[i] = v
-            if auto:
-                # pin the exchanged grads' auto-axis (tensor) placement
-                # so the optimizer update consumes them without a reshard
-                out_leaves = [
-                    _constrain_auto(v, s, mesh, auto)
-                    for v, s in zip(out_leaves,
-                                    treedef.flatten_up_to(pspecs))]
-            grads_out = jax.tree_util.tree_unflatten(treedef, out_leaves)
-            loss = lax.psum(loss_part, loss_axes)
-            ce = lax.psum(ce_part, loss_axes)
-            return loss, ce, logits, new_bs, grads_out
-
-        def ctx_body(params_l, bstats, images_l, labels_l):
-            # the manual-axes context (parallel/mesh.py) tells model code
-            # traced inside the body that these axes are already mapped:
-            # constraints drop them, the PipelinedEncoder runs inline
-            with manual_axes(manual):
-                return body(params_l, bstats, images_l, labels_l)
-
-        sharded = shard_map_unchecked(
-            ctx_body, mesh,
-            in_specs=(mspecs, bs_specs, batch_spec, batch_spec),
-            out_specs=(P(), P(), batch_spec, bs_specs, gout_specs),
-            auto=auto)
-        loss, ce, logits, new_bs, grads = sharded(params, batch_stats,
-                                                  images, labels)
-        return (loss, (ce, logits, new_bs)), grads
-
-    # the accumulation contract the step builder validates
-    # (train/loop.make_train_step): a grad fn built for a different
-    # accum factor than the step's would silently skip accumulation
-    grad_fn.grad_accum_steps = accum
-    return grad_fn
-
-
-def _strip_axes(drop: frozenset):
-    """PartitionSpec transformer removing ``drop``-axis references (the
-    shard_map-facing spec: manual specs may not name auto axes)."""
-    from .mesh import filter_spec_axes
-
-    def strip(spec: P) -> P:
-        return filter_spec_axes(spec, lambda n: n not in drop)
-    return strip
-
-
-def _constrain_auto(leaf, spec: P, mesh: Mesh, auto: frozenset):
-    """with_sharding_constraint on the AUTO axes of ``spec`` only — how
-    the exchanged gradients keep their tensor placement inside the
-    partially-manual body (constraints naming manual axes are illegal
-    there)."""
-    from .mesh import filter_spec_axes
-    aspec = filter_spec_axes(spec, lambda n: n in auto)
-    if not any(e is not None for e in aspec):
-        return leaf
-    from jax.sharding import NamedSharding
-    return lax.with_sharding_constraint(leaf, NamedSharding(mesh, aspec))
-
-
-def make_bucketed_gather(plan: OverlapPlan, mesh: Mesh,
-                         zero1_specs: Any) -> Callable:
-    """The ZeRO-1 return leg, bucketed: ``gather(updates) -> updates`` —
-    all-gather each data-sharded param-UPDATE leaf back to its base param
-    layout, one ``lax.all_gather`` issue per bucket (the SAME greedy
-    reverse-order plan the gradient exchange uses, ``plan_buckets``),
-    buckets chained through ``optimization_barrier`` so the scheduler can
-    overlap each gather with the optimizer arithmetic still producing
-    later buckets' updates. Leaves the rule table left replicated pass
-    through untouched. The gather payload plan is recorded into
-    ``parallel.sharding.zero1_stats`` (the ``zero1`` metrics row /
-    bench's payload accounting).
-
-    Under ``comm.compress`` (plan.compress) the gathered param-UPDATE
-    payload is cast to the compressed dtype for the all-gather and
-    re-materialized f32 after — the return leg halves like the exchange.
-    Every replica applies the SAME bf16-rounded update (the rounding
-    happens before the gather), so params stay replica-consistent; the
-    f32 masters accumulate the update in f32 as always."""
-    from .mesh import shard_map_unchecked
-    from .sharding import zero1_stats
-
-    def gather(updates):
-        flat, treedef = jax.tree_util.tree_flatten(updates)
-        specs = treedef.flatten_up_to(zero1_specs)
-        z1_dims = [_axis_dim(s, "data") for s in specs]
-        # only the GATHERED leaves ride the bucket chain — a replicated
-        # pass-through leaf in a bucket would contribute no collective,
-        # and anchoring the next barrier on it would let XLA re-merge
-        # adjacent buckets' gathers. Bucket by FULL-leaf bytes: that is
-        # the all-gather output payload.
-        gidx = [i for i, d in enumerate(z1_dims) if d is not None]
-        gbytes = [int(np.prod(np.shape(flat[i])) *
-                      np.dtype(flat[i].dtype).itemsize) for i in gidx]
-        buckets = [[gidx[j] for j in b]
-                   for b in plan_buckets(gbytes, plan.bucket_bytes)]
-        leaf_bytes = {i: nb for i, nb in zip(gidx, gbytes)}
-        gathered_sizes = [sum(leaf_bytes[i] for i in b) for b in buckets]
-        if plan.compress is not None:
-            cratio = np.dtype(COMPRESS_DTYPES[plan.compress]).itemsize \
-                / np.dtype(np.float32).itemsize
-            gathered_wire = [int(b * cratio) for b in gathered_sizes]
-        else:
-            gathered_wire = gathered_sizes
-        zero1_stats.record_gather(gathered_sizes,
-                                  [len(b) for b in buckets],
-                                  compress=plan.compress,
-                                  wire_bytes=gathered_wire)
-        base_specs = [P(*(None if n == "data" else n for n in s))
-                      if d is not None else s
-                      for s, d in zip(specs, z1_dims)]
-
-        def body(*leaves):
-            out: List[Any] = list(leaves)  # pass-throughs stay as-is
-            anchor = None
-            for bi, (b, nbytes, wbytes) in enumerate(
-                    zip(buckets, gathered_sizes, gathered_wire)):
-                with span("zero1.gather", bucket=bi, bytes=int(nbytes),
-                          wire_bytes=int(wbytes)):
-                    vals = [leaves[i] for i in b]
-                    if anchor is not None:
-                        vals, _ = lax.optimization_barrier((vals, anchor))
-                    for i, v in zip(b, vals):
-                        if plan.compress is not None:
-                            v = v.astype(COMPRESS_DTYPES[plan.compress])
-                        v = lax.all_gather(v, "data", axis=z1_dims[i],
-                                           tiled=True)
-                        if plan.compress is not None:
-                            v = v.astype(leaves[i].dtype)
-                        out[i] = v
-                    anchor = out[b[0]]
-            return tuple(out)
-
-        sharded = shard_map_unchecked(body, mesh,
-                                      in_specs=tuple(specs),
-                                      out_specs=tuple(base_specs))
-        return jax.tree_util.tree_unflatten(treedef, sharded(*flat))
-
-    return gather
-
-
-def probe_comm_plan(mesh: Mesh, reps: int = 3,
-                    hier_k: Optional[int] = None) -> Optional[dict]:
-    """Measure each planned exchange bucket's collective STANDALONE on the
-    live mesh — the runtime leg of per-collective attribution
-    (docs/observability.md; the static leg is the committed
-    collective_schedules.json from analysis/collectives.py).
-
-    For every bucket of the traced plan (``overlap_stats``) this compiles
-    and times one ``lax.psum`` over the batch axes whose payload matches
-    the bucket's WIRE bytes and dtype (``comm.compress`` narrows the
-    probe exactly like the exchange). The time is the bucket's collective
-    cost fully exposed — what the overlapped step HIDES when the
-    scheduling works — so ``wire_bytes / probe_secs`` is the achieved
-    standalone bandwidth and ``Σ probe_secs / step_secs`` is the overlap
-    headroom the comm_timing row reports.
-
-    SPMD contract: every process must call this at the same program
-    point (Trainer.train does, once, at the first loop boundary after
-    the plan traces) — the probe executes real collectives, so a process
-    bailing mid-sequence while peers sit inside a psum would be a
-    divergence hang (exactly the class docs/static_analysis.md's
-    hangcheck exists to prevent). The protocol therefore front-loads all
-    fallible LOCAL work (sizing + lowering + AOT compilation — no
-    collective issued) into phase 1, then runs ONE tiny agreement psum:
-    a process whose local prep failed still participates with a 0 flag,
-    and a non-unanimous total makes EVERY process abandon together
-    before any bucket collective launches. Phase 3 (payload allocation +
-    the timed collectives — coordinated executions by nature, so they
-    cannot precede the vote) then carries the same irreducible risk as
-    any training-step collective: a mid-execution failure there means
-    the mesh is already broken and the watchdog owns recovery. Results land in
-    ``utils.metrics.comm_timing_stats``; returns the recorded snapshot,
-    or None when no plan has traced / the probe was abandoned. Never
-    raises (observability must not kill training).
-
-    ``hier_k`` (the intra-tier size of a data-axis factorization —
-    comm.hierarchy / the autotune pass): additionally times, per
-    data-reducing axis set, one grouped psum over the INTRA tier (full
-    payload = that set's largest bucket wire) and one over the INTER
-    tier (1/k payload — the staged plan's cross-host leg). These land as
-    ``tiers`` entries in the comm_timing row and fold into the bandwidth
-    catalog as ``<axes>:intra`` / ``<axes>:inter`` rows — what
-    tune_comm_plan ranks flat-vs-hierarchical with."""
-    import math
-    import time as _time
-
-    from jax.sharding import NamedSharding
-
-    from ..utils.metrics import comm_timing_stats
-    from .mesh import shard_map_unchecked
-
-    snap = overlap_stats.snapshot()
-    if snap is None:
-        return None
-    compress = snap.get("compress", "off")
-    wire_dtype = np.dtype(np.float32) if compress == "off" \
-        else np.dtype(COMPRESS_DTYPES[compress])
-    axes = [a for a in BATCH_AXES if mesh.shape.get(a, 1) > 1] \
-        or list(BATCH_AXES)
-    # layout-aware plans carry one reduce-axis set per bucket (the
-    # grouped planner) — each bucket's probe psums over ITS set, so the
-    # timed collective matches what the step actually issues
-    bucket_axes = [tuple(s.split("+"))
-                   for s in snap.get("bucket_reduce_axes",
-                                     ["+".join(BATCH_AXES)]
-                                     * len(snap["bucket_bytes"]))]
-    replicated = NamedSharding(mesh, P())
-
-    # -- phase 1: LOCAL prep (deterministic; no collective issued) -------
-    programs = []
-    tier_programs = []
-    agree_c = None
-    ok = 1.0
-    try:
-        def _agree(x):
-            return lax.psum(x, tuple(mesh.axis_names))  # global, all axes
-
-        agree_c = jax.jit(shard_map_unchecked(
-            _agree, mesh, in_specs=P(), out_specs=P()))
-
-        for bi, (nbytes, wbytes, leaves, baxes) in enumerate(zip(
-                snap["bucket_bytes"], snap["bucket_wire_bytes"],
-                snap["bucket_leaves"], bucket_axes)):
-            elems = max(1, int(wbytes) // wire_dtype.itemsize)
-
-            def _psum(x, _axes=baxes):
-                return lax.psum(x, _axes)
-
-            # AOT-compile BOTH programs now — jax.jit alone is lazy and
-            # would push compilation past the vote into phase 3
-            fn = jax.jit(shard_map_unchecked(
-                _psum, mesh, in_specs=P(), out_specs=P())).lower(
-                    jax.ShapeDtypeStruct((elems,), wire_dtype,
-                                         sharding=replicated)).compile()
-            fill = jax.jit(lambda e=elems: jnp.zeros((e,), wire_dtype),
-                           out_shardings=replicated).lower().compile()
-            programs.append((bi, int(nbytes), int(wbytes), int(leaves),
-                             baxes, fn, fill))
-
-        # tier legs (hierarchical autotune): per data-reducing axis set,
-        # a grouped intra-tier psum at the set's max bucket wire and a
-        # grouped inter-tier psum at 1/k of it. Grouped psums of a
-        # replicated input are replica-consistent (equal group sizes),
-        # so P()→P() is sound.
-        dsize = int(mesh.shape.get("data", 1))
-        if hier_k and 1 < int(hier_k) < dsize and dsize % int(hier_k) == 0:
-            gi, ge = hierarchy_groups(int(hier_k), dsize // int(hier_k))
-            sig_payload: dict = {}
-            for wbytes, baxes in zip(snap["bucket_wire_bytes"],
-                                     bucket_axes):
-                if "data" in baxes:
-                    s = "+".join(baxes)
-                    sig_payload[s] = max(sig_payload.get(s, 0),
-                                         int(wbytes))
-            for sig in sorted(sig_payload):
-                for tier, groups, tbytes in (
-                        ("intra", gi, sig_payload[sig]),
-                        ("inter", ge,
-                         max(1, sig_payload[sig] // int(hier_k)))):
-                    elems = max(1, int(tbytes) // wire_dtype.itemsize)
-
-                    def _gpsum(x, _g=groups):
-                        return lax.psum(x, "data", axis_index_groups=_g)
-
-                    fn = jax.jit(shard_map_unchecked(
-                        _gpsum, mesh, in_specs=P(),
-                        out_specs=P())).lower(
-                            jax.ShapeDtypeStruct((elems,), wire_dtype,
-                                                 sharding=replicated)
-                        ).compile()
-                    fill = jax.jit(
-                        lambda e=elems: jnp.zeros((e,), wire_dtype),
-                        out_shardings=replicated).lower().compile()
-                    tier_programs.append(
-                        (sig, tier, elems * wire_dtype.itemsize, fn,
-                         fill))
-    except Exception:  # pragma: no cover - prep is best effort
-        log.exception("comm-plan probe prep failed; voting to abandon")
-        ok = 0.0
-
-    # -- phase 2: agreement (first coordinated execution) ----------------
-    if agree_c is None:  # can't even vote; peers' agreement psum will
-        return None      # surface it (irreducible — see the docstring)
-    try:
-        flag = jax.make_array_from_callback(
-            (), replicated, lambda idx: np.asarray(ok, np.float32))
-        total_ok = float(np.asarray(jax.device_get(agree_c(flag))))
-        n_devices = math.prod(mesh.shape.values())
-        if total_ok < n_devices - 0.5:  # a peer's prep failed: all bail
-            log.warning("comm-plan probe abandoned by agreement "
-                        "(%.0f/%d devices ready)", total_ok, n_devices)
-            return None
-    except Exception:  # pragma: no cover - mesh already compromised
-        log.exception("comm-plan probe agreement failed; comm_timing row "
-                      "will be absent")
-        return None
-
-    # -- phase 3: the timed collectives (all processes committed) --------
-    buckets = []
-    tiers = []
-    total = 0.0
-    try:
-        for bi, nbytes, wbytes, leaves, baxes, fn, fill in programs:
-            x = fill()
-            jax.block_until_ready(fn(x))  # compile + warm
-            best = None
-            for _ in range(max(1, reps)):
-                t0 = _time.perf_counter()
-                jax.block_until_ready(fn(x))
-                dt = _time.perf_counter() - t0
-                best = dt if best is None else min(best, dt)
-            with span("comm.probe", bucket=bi, bytes=nbytes,
-                      wire_bytes=wbytes):
-                pass  # the probe span marks the measurement in the trace
-            total += best
-            buckets.append({
-                "bucket": bi,
-                "bytes": nbytes,
-                "wire_bytes": wbytes,
-                "leaves": leaves,
-                "axes": "+".join(baxes),
-                "probe_secs": round(best, 6),
-                "wire_bytes_per_sec": round(wbytes / best, 1)
-                if best > 0 else 0.0,
-            })
-        # tier legs last: same timing discipline, but their times do NOT
-        # join comm_secs_total — they measure hypothetical staged legs,
-        # not the plan's standalone exchange cost
-        for sig, tier, tbytes, fn, fill in tier_programs:
-            x = fill()
-            jax.block_until_ready(fn(x))
-            best = None
-            for _ in range(max(1, reps)):
-                t0 = _time.perf_counter()
-                jax.block_until_ready(fn(x))
-                dt = _time.perf_counter() - t0
-                best = dt if best is None else min(best, dt)
-            tiers.append({
-                "axes": sig,
-                "tier": tier,
-                "wire_bytes": int(tbytes),
-                "probe_secs": round(best, 6),
-                "wire_bytes_per_sec": round(tbytes / best, 1)
-                if best > 0 else 0.0,
-            })
-    except Exception:  # pragma: no cover - the mesh is already broken
-        log.exception("comm-plan probe failed mid-measurement; "
-                      "comm_timing row will be absent")
-        return None
-    comm_timing_stats.record(buckets, total, max(1, reps), axes, compress,
-                             tiers=tiers)
-    log.info("comm probe: %d bucket(s), %.2f ms standalone exchange "
-             "(compress=%s)", len(buckets), total * 1e3, compress)
-    result = comm_timing_stats.snapshot()
-    # persist the measurement into the per-fabric bandwidth catalog
-    # (telemetry/bandwidth.py) so main.py comm-report and the what-if
-    # planner can cost layouts without a live mesh. Chief-only: the
-    # catalog file is one per fabric, and N processes racing the same
-    # atomic replace would keep only an arbitrary winner's fold
-    if jax.process_index() == 0:
-        from ..telemetry.bandwidth import update_from_probe
-        update_from_probe(result)
-    return result

@@ -2,7 +2,7 @@
 
 Compute-side MFU has been flat at ~0.35 on imagenet-rn50 since BENCH_r02
 because every hot path still ran f32 end to end. This module is the ONE
-resolution point for the three low-precision knobs (docs/precision.md):
+resolution point for the two low-precision knobs (docs/precision.md):
 
   * ``train.precision`` — the TRAINING STEP policy. ``bf16`` computes
     activations/matmuls in bfloat16 while the parameters (and the whole
@@ -17,15 +17,6 @@ resolution point for the three low-precision knobs (docs/precision.md):
     ``model.compute_dtype`` contract untouched — BIT-identical to the
     pre-policy step, the exactness oracle every cast path is tested
     against.
-  * ``comm.compress`` — the GRADIENT-EXCHANGE payload dtype
-    (parallel/overlap.py): each ``comm.bucket`` psum / reduce-scatter /
-    ZeRO-1 all-gather payload is cast to bf16/fp16 on the wire and
-    re-materialized f32 on arrival, halving inter-host bytes on the SAME
-    bucket plan (arXiv:1811.05233 trained ImageNet/ResNet-50 to
-    reference accuracy with half-precision allreduce). Resolved by
-    ``parallel.overlap.compress_dtype``; it rides the bucketed exchange,
-    so the Trainer warns loudly when compression is requested while
-    ``comm.overlap`` resolves off.
   * ``serve.variants`` — reduced-precision SERVING variants
     (serve/compile_cache.py buckets become (batch, variant)): a ``bf16``
     variant serves from a bf16-cast weight copy through a bf16-compute
@@ -38,10 +29,10 @@ Checkpoints are policy-agnostic by construction: the masters are f32, so
 save/restore and the serving hot swap never see a cast leaf —
 :func:`check_master_dtypes` is the guard that keeps that true.
 
-Why fp16 is exchange-only: an fp16 TRAINING step needs loss scaling to
-keep small gradients out of the subnormal range (bf16 shares f32's
-exponent and does not); until a scaler exists, ``train.precision=fp16``
-is refused with that reason rather than silently diverging.
+Why no fp16 step: an fp16 TRAINING step needs loss scaling to keep small
+gradients out of the subnormal range (bf16 shares f32's exponent and
+does not); until a scaler exists, ``train.precision=fp16`` is refused
+with that reason rather than silently diverging.
 """
 from __future__ import annotations
 
@@ -52,7 +43,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-#: dtypes a policy / compressed exchange / serving variant may name
+#: dtypes a policy may name
 POLICY_DTYPES = {"bf16": jnp.bfloat16, "fp16": jnp.float16}
 
 #: serving-variant names → COMPUTE dtype (``f32`` is the policy-native
@@ -141,9 +132,7 @@ def precision_unsupported_reason(cfg) -> Optional[str]:
     if mode == "fp16":
         return ("an fp16 TRAINING step needs loss scaling to keep small "
                 "gradients out of the subnormal range (bf16 shares f32's "
-                "exponent range and does not) — use train.precision=bf16; "
-                "fp16 is available for the exchange payload "
-                "(comm.compress=fp16)")
+                "exponent range and does not) — use train.precision=bf16")
     return f"unknown train.precision setting {mode!r}"
 
 
@@ -249,18 +238,16 @@ def check_master_dtypes(params, master_dtype=jnp.float32) -> None:
 
 
 class PrecisionStats:
-    """Process-global record of the resolved precision/compression
-    configuration — what the ``{"event": "precision"}`` metrics row
-    (train/hooks.PrecisionHook) and bench.py's ``precision`` row export.
-    Mirrors overlap_stats' contract: written at Trainer build /
-    state-init time (a property of the run, not of any step)."""
+    """Process-global record of the resolved precision policy — what
+    the ``{"event": "precision"}`` metrics row (train/hooks.PrecisionHook)
+    exports. Written at Trainer build / state-init time (a property of
+    the run, not of any step)."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self._snap: Optional[Dict[str, Any]] = None
 
-    def record_policy(self, policy: Optional[PrecisionPolicy],
-                      compress: Optional[str]) -> None:
+    def record_policy(self, policy: Optional[PrecisionPolicy]) -> None:
         with self._lock:
             base = self._snap or {}
             self._snap = {**base,
@@ -269,8 +256,7 @@ class PrecisionStats:
                           if policy else None,
                           "master_dtype": jnp.dtype(
                               policy.master_dtype).name if policy
-                          else None,
-                          "compress": compress or "off"}
+                          else None}
 
     def record_params(self, params) -> None:
         """Master-tree accounting from the LIVE state: leaf count and f32

@@ -363,10 +363,8 @@ class Zero1Report:
 
 
 class Zero1Stats:
-    """Process-global record of the most recent ZeRO-1 resolution +
-    exchange-payload accounting (reduce-scatter/all-gather bytes from the
-    bucket plan) — what the ``{"event": "zero1"}`` metrics row and
-    bench.py's ``zero1`` row export. Mirrors overlap_stats' contract."""
+    """Process-global record of the most recent ZeRO-1 resolution — what
+    the ``{"event": "zero1"}`` metrics row exports."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -376,25 +374,6 @@ class Zero1Stats:
         with self._lock:
             base = self._snap or {}
             self._snap = {**base, **report.snapshot()}
-
-    def record_gather(self, bucket_bytes, bucket_leaves,
-                      compress=None, wire_bytes=None) -> None:
-        """Bucketed param-update all-gather plan (parallel/overlap.py):
-        per-bucket FULL-leaf bytes in issue order. ``compress`` /
-        ``wire_bytes`` carry the comm.compress wire format (the SAME
-        plan, narrower payload — docs/precision.md)."""
-        bucket_bytes = [int(b) for b in bucket_bytes]
-        with self._lock:
-            base = self._snap or {}
-            self._snap = {**base,
-                          "gather_buckets": len(bucket_bytes),
-                          "gather_bucket_bytes": bucket_bytes,
-                          "gather_bucket_leaves": [int(n) for n in
-                                                   bucket_leaves],
-                          "gather_compress": compress or "off",
-                          "gather_wire_bytes":
-                              [int(b) for b in wire_bytes]
-                              if wire_bytes is not None else bucket_bytes}
 
     def reset(self) -> None:
         with self._lock:
@@ -411,10 +390,8 @@ zero1_stats = Zero1Stats()
 
 def zero1_unsupported_reason(cfg, mesh) -> Optional[str]:
     """None when the ZeRO-1 sharded weight update applies to this
-    (cfg, mesh); else a one-line reason. The envelope is wider than the
-    overlap path's (no BN/accum/model-family restrictions — the sharded
-    update is a layout transformation, not a step rewrite): it needs only
-    a >1 ``data`` axis and no program-shaping axes (those bake their own
+    (cfg, mesh); else a one-line reason. The sharded update is a layout
+    transformation, not a step rewrite: it needs only a >1 ``data`` axis and no program-shaping axes (those bake their own
     shard_maps and optimizer layouts into the model)."""
     if mesh.shape.get("data", 1) <= 1:
         return ("a single data shard holds the whole optimizer state "
@@ -434,7 +411,7 @@ def resolve_zero1(cfg, mesh) -> bool:
     single-data-shard mesh (what checkpoint CONSUMERS like the standalone
     evaluator and 1-device serving replicas see when they build a Trainer
     from a training config: a train-step-only knob must resolve off
-    loudly there, not crash them — the comm.overlap precedent)."""
+    loudly there, not crash them)."""
     import logging
     mode = cfg.optimizer.zero1
     if mode not in ("auto", "on", "off"):

@@ -98,8 +98,7 @@ def run_open_loop(server, qps: float, duration_secs: float,
     per request).
 
     ``variant`` targets one serving precision variant (docs/precision.md;
-    None = the replica's default) — bench's (batch, variant) serving row
-    drives one open loop per variant."""
+    None = the replica's default): one open loop per variant."""
     offsets = arrival_times(shape, qps, duration_secs)
     n = len(offsets)
     pool = synthetic_requests(server.image_shape, server.image_dtype,

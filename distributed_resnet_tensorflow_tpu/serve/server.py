@@ -276,7 +276,7 @@ class InferenceServer:
         t1 = time.perf_counter()
         step = self.serving_step
         # latency keys carry the variant past the default f32 — the
-        # (batch, variant) breakdown bench's serving row reports; plain
+        # (batch, variant) breakdown the serve report shows; plain
         # f32 keys keep their historical names
         key = f"bucket_{bucket}" if variant == "f32" \
             else f"bucket_{bucket}_{variant}"

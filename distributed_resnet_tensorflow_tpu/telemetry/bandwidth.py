@@ -1,13 +1,12 @@
 """Per-fabric achieved-bandwidth catalog (``results/bandwidth/<fabric>.json``).
 
-``parallel/overlap.probe_comm_plan`` measures what each planned exchange
-bucket's collective actually achieves on the live mesh — but until now
-that measurement died with the run: ``main.py comm-report`` needed a
-fresh probe and the what-if planner (telemetry/planner.py) had nothing
-measured to cost candidate layouts against. This module persists every
-probe into a small per-fabric catalog keyed by the reduce-axis set, so
-any later process on the same fabric can read achieved bytes/sec without
-holding a live mesh.
+A small per-fabric document of achieved collective bandwidth keyed by
+the reduce-axis set, which the what-if planner (telemetry/planner.py)
+costs candidate layouts against and the ``plan-drift`` gate phase holds
+to a live micro-probe. This module reads it; nothing in the tree writes
+one since the collective probe went with the bucketed exchange (PR 31):
+a catalog is measured outside and placed in the directory, and without
+one the planner uses its reference row.
 
 A *fabric* is the hardware the numbers are valid for: platform ×
 device kind × global device count (``fabric_id``) — a v4-32's ICI numbers
@@ -22,31 +21,23 @@ Catalog schema (``schema_version`` 2, documented in docs/planner.md)::
      "platform": "cpu",
      "device_kind": "cpu",
      "devices": 8,
-     "axes": {                     # keyed by the probe's reduce-axis set
+     "axes": {                     # keyed by the reduce-axis set
       "data+fsdp": {
        "bytes_per_sec": 4.1e8,     # best standalone WIRE bytes/sec seen
        "latency_secs": 2.3e-4,     # smallest per-collective cost seen
-       "samples": 12,              # probe buckets folded in, ever
+       "samples": 12,
        "min_wire_bytes": 20480,    # payload range the numbers came from
        "max_wire_bytes": 4194304
       },
-      "data+fsdp:intra": {         # hierarchical tier rows (v2): the
-       "tier": "intra",            # probe's grouped-psum legs over the
-       ...                         # fast intra-host / slow inter-host
-      }, ...                       # sub-groups of the data axis — what
-     }                             # tune_comm_plan ranks hierarchy with
+      "data+fsdp:intra": {         # tier rows (v2): the fast intra-host /
+       "tier": "intra",            # slow inter-host sub-groups of the
+       ...                         # data axis
+      }, ...
+     }
     }
 
 v1 documents (no tier rows, no ``tier`` field) load unchanged — every
-v1 key is a valid v2 flat key; the first probe fold on a factored mesh
-adds the tier rows and stamps the current schema_version.
-
-Merging is best-achieved: ``bytes_per_sec`` only ratchets up and
-``latency_secs`` only down — the probe times collectives standalone
-(best-of-reps), so the catalog is the fabric's demonstrated ceiling, the
-right operand for a planner that predicts what a layout *could* do.
-Writes are atomic (tmp + ``os.replace``) and never raise: losing one
-probe's persistence must not kill training.
+v1 key is a valid v2 flat key.
 """
 from __future__ import annotations
 
@@ -54,7 +45,7 @@ import json
 import logging
 import os
 import re
-from typing import Dict, List, Optional
+from typing import Optional
 
 log = logging.getLogger(__name__)
 
@@ -98,7 +89,7 @@ def catalog_path(fabric: Optional[str] = None) -> str:
 def load_catalog(path: Optional[str] = None,
                  fabric: Optional[str] = None) -> Optional[dict]:
     """The catalog document, or None when absent/unreadable (callers
-    fall back to the planner's reference table / a live probe)."""
+    fall back to the planner's reference table)."""
     path = path or catalog_path(fabric)
     try:
         with open(path) as f:
@@ -141,89 +132,3 @@ def lookup(catalog: Optional[dict], axes_sig: str) -> Optional[dict]:
         if best is None or key > best[0]:
             best = (key, axes[name])
     return best[1] if best else None
-
-
-def update_from_probe(snapshot: Optional[dict],
-                      path: Optional[str] = None,
-                      devices=None) -> Optional[str]:
-    """Fold one ``probe_comm_plan`` snapshot (``utils.metrics.
-    comm_timing_stats`` shape: per-bucket wire bytes / probe secs /
-    axes) into the fabric's catalog. Returns the path written, or None
-    when there was nothing to record / the write failed (logged, never
-    raised — persistence is observability, not correctness)."""
-    if not snapshot or not snapshot.get("buckets"):
-        return None
-    try:
-        if devices is None:
-            import jax
-            devices = jax.devices()
-        fabric = fabric_id(devices)
-        path = path or catalog_path(fabric)
-        doc = load_catalog(path) or {
-            "schema_version": SCHEMA_VERSION,
-            "fabric": fabric,
-            "platform": str(getattr(devices[0], "platform", "unknown")),
-            "device_kind": str(getattr(devices[0], "device_kind", "")),
-            "devices": len(devices),
-            "axes": {},
-        }
-        # folding under the current schema: v1 docs carry only flat keys,
-        # all valid under v2 — stamp the version forward on write
-        doc["schema_version"] = SCHEMA_VERSION
-        axes: Dict[str, dict] = doc.setdefault("axes", {})
-
-        def fold(sig, wire, bw, secs, tier=None):
-            if wire <= 0 or bw <= 0 or secs <= 0:
-                return
-            e = axes.get(sig)
-            if e is None:
-                e = axes[sig] = {"bytes_per_sec": bw,
-                                 "latency_secs": secs,
-                                 "samples": 1, "min_wire_bytes": wire,
-                                 "max_wire_bytes": wire}
-            else:
-                e["bytes_per_sec"] = max(float(e["bytes_per_sec"]), bw)
-                e["latency_secs"] = min(float(e["latency_secs"]), secs)
-                e["samples"] = int(e.get("samples", 0)) + 1
-                e["min_wire_bytes"] = min(int(e["min_wire_bytes"]), wire)
-                e["max_wire_bytes"] = max(int(e["max_wire_bytes"]), wire)
-            if tier:
-                e["tier"] = tier
-
-        for b in snapshot["buckets"]:
-            fold(b.get("axes") or "data", int(b.get("wire_bytes", 0)),
-                 float(b.get("wire_bytes_per_sec", 0.0)),
-                 float(b.get("probe_secs", 0.0)))
-        # hierarchical tier legs (probe hier_k) land under tiered keys
-        # ("<axes>:intra" / "<axes>:inter") with an explicit tier field
-        for t in snapshot.get("tiers") or []:
-            tier = t.get("tier", "intra")
-            fold(f"{t.get('axes') or 'data'}:{tier}",
-                 int(t.get("wire_bytes", 0)),
-                 float(t.get("wire_bytes_per_sec", 0.0)),
-                 float(t.get("probe_secs", 0.0)), tier=tier)
-        if not axes:
-            return None
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        tmp = path + ".tmp"
-        with open(tmp, "w", encoding="utf-8") as f:
-            json.dump(doc, f, indent=1, sort_keys=True)
-            f.write("\n")
-        os.replace(tmp, path)
-        log.info("bandwidth catalog: folded %d bucket(s) into %s",
-                 len(snapshot["buckets"]), path)
-        return path
-    except Exception:  # pragma: no cover - persistence is best effort
-        log.exception("bandwidth catalog update failed (probe results "
-                      "still live in comm_timing_stats)")
-        return None
-
-
-def list_catalogs() -> List[str]:
-    """Every fabric catalog present (for ``main.py plan`` discovery)."""
-    try:
-        d = catalog_dir()
-        return sorted(os.path.join(d, f) for f in os.listdir(d)
-                      if f.endswith(".json"))
-    except OSError:
-        return []

@@ -25,8 +25,7 @@ Categorized spans (telemetry/tracer.py) feed ``GoodputMeter.add``; the
 chief's ``GoodputHook`` (train/hooks.py) emits one registered
 ``{"event": "goodput"}`` metrics row per summary cadence with per-category
 seconds and percentages (summing to ~100% of the interval's wall by
-construction). ``bench.py``'s goodput row and ``main.py monitor`` consume
-the same numbers — ROADMAP open items 2 (input gap) and 5 (zero-stall
+construction). ``main.py monitor`` consumes the same numbers — ROADMAP open items 2 (input gap) and 5 (zero-stall
 persistence) are measured against exactly these buckets.
 """
 from __future__ import annotations

@@ -3,7 +3,7 @@
 Each process dumps its own flight-recorder ring as
 ``trace[.procN].json`` (telemetry/tracer.py) — useful alone, but a
 distributed incident is a RELATIVE story: a straggling host's late
-``comm.bucket`` span is only visibly late against its peers' lanes on
+``train.step`` span is only visibly late against its peers' lanes on
 ONE timeline. This module merges the per-process dumps into a single
 Perfetto/Chrome-trace file with one process lane per host:
 

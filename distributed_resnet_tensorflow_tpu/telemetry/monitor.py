@@ -552,28 +552,7 @@ def main_monitor(argv=None) -> int:
     ap.add_argument("--hbm-warn-frac", type=float, default=_HBM_WARN_FRAC,
                     help="flag hosts whose device watermark exceeds this "
                          "share of the reported bytes_limit")
-    ap.add_argument("--bench", action="store_true",
-                    help="render the cross-round bench trajectory "
-                         "(tools/bench_trajectory.py over the repo's "
-                         "BENCH_r*.json) instead of the live rollup")
     ns = ap.parse_args(argv)
-    if ns.bench:
-        # the joiner is a stdlib-only standalone script (it must run
-        # without jax); load it by path from the repo checkout
-        import importlib.util
-        repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        script = os.path.join(repo_root, "tools", "bench_trajectory.py")
-        if not os.path.exists(script):
-            print(f"monitor --bench: {script} not found (not running "
-                  "from a source checkout?)")
-            return 1
-        spec = importlib.util.spec_from_file_location(
-            "bench_trajectory", script)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        extra = ["--json"] if ns.json else []
-        return mod.main(extra)
     try:
         while True:
             agg = aggregate(ns.root, hbm_warn_frac=ns.hbm_warn_frac)

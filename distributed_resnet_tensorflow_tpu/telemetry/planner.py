@@ -1,43 +1,34 @@
-"""What-if performance planner: ``main.py plan`` + the drift sentinel.
+"""What-if performance planner: ``main.py plan``.
 
 The repo owns both halves of an analytic cost model and this module
 joins them (ROADMAP item 5): the committed static schedule
-(``analysis/collective_schedules.json`` — ordered collectives with true
-wire bytes per preset × layout × knob variant) says WHAT must move, and
-the per-fabric bandwidth catalog (telemetry/bandwidth.py, fed by
-``parallel/overlap.probe_comm_plan``) says how fast this fabric has
-demonstrably moved it. On top ride a catalogued roofline compute term
-and an abstract-state HBM occupancy model, so for any candidate the
-planner predicts, WITHOUT running it:
+(``analysis/collective_schedules.json`` — the collectives a step program
+writes out, in order, with true wire bytes per preset × layout) says
+WHAT must move, and a per-fabric bandwidth catalog
+(telemetry/bandwidth.py) says how fast a fabric moves it, where one
+exists. On top ride a catalogued roofline compute term and an
+abstract-state HBM occupancy model, so for any candidate the planner
+predicts, WITHOUT running it:
 
   * per-step wall time   — compute (step FLOPs over an assumed-MFU
-    roofline, or a measured step time when the caller has one) plus the
-    EXPOSED communication: every scheduled collective costed as
-    ``latency + bytes/bandwidth``, with the declared bucket plan's
-    exchange earning overlap credit (it hides behind backprop up to
-    ``OVERLAP_EFFICIENCY`` of the compute time — arXiv:1711.00705's
-    premise, bench.py's overlap row its measurement),
+    roofline) plus every scheduled collective costed as ``latency + bytes/bandwidth``.
+    The gradient exchange itself is XLA's (sharding propagation) and is
+    not in a jaxpr-level schedule: the planner does not cost it,
   * per-device HBM watermark — sharded abstract train state + a gradient
     copy + an activation estimate + staging-ring occupancy, the same
-    shapes ``analysis/elaborate.py`` validates (calibrated against the
-    live ``memory`` rows by the drift sentinel), and
-  * comm fraction        — exposed comm over the predicted step.
+    shapes ``analysis/elaborate.py`` validates, and
+  * comm fraction        — scheduled comm over the predicted step.
 
 ``main.py plan`` ranks the candidates and RECOMMENDS a layout; the
 ``plan-drift`` gate phase (analysis/plan_drift.py) re-runs the model
 over the committed schedules with the baked-in REFERENCE constants and
-commits the diffable ``analysis/plan_catalog.json``. Live runs arm a
-:class:`DriftSentinel` (train/hooks.py PlanDriftHook): predicted vs
-measured step time (heartbeat EWMA), comm seconds (``comm_timing``
-probe) and HBM (``memory`` rows) — sustained divergence beyond
-``telemetry.plan_tolerance`` emits a ``plan_drift`` row and a
-flight-recorder dump. docs/planner.md is the operator manual.
+commits the diffable ``analysis/plan_catalog.json``. docs/planner.md is
+the operator manual.
 
 Every number here is a MODEL, not a measurement: the constants below
 are order-of-magnitude anchors chosen once and kept stable so the
 committed catalog diffs only when a schedule or the model changes.
-Predictions carry their assumptions (``bandwidth_source``) and the
-sentinel exists precisely because models drift from reality.
+Predictions carry their assumptions (``bandwidth_source``).
 """
 from __future__ import annotations
 
@@ -45,8 +36,7 @@ import argparse
 import json
 import logging
 import math
-import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 log = logging.getLogger(__name__)
 
@@ -65,38 +55,11 @@ REFERENCE_PEAK_TFLOPS = 275.0
 #: assumed model FLOP utilization of that peak (a well-tuned ResNet/ViT
 #: lands 0.3-0.5; docs/planner.md discusses sensitivity)
 ASSUMED_MFU = 0.40
-#: fraction of compute time the bucketed exchange can hide behind
-#: (bench.py's overlap row measures the realized fraction)
-OVERLAP_EFFICIENCY = 0.7
 #: train-step FLOPs ≈ this × forward FLOPs (fwd + bwd ≈ 3×)
 TRAIN_FLOPS_MULTIPLIER = 3.0
 #: activation-footprint heuristic: fwd FLOPs per byte of live
 #: activation memory (conv/attention stacks land within a small factor)
 ACT_FLOPS_PER_BYTE = 50.0
-
-#: schedule ops that can carry a gradient-exchange bucket's payload
-#: (same set main.py comm-report matches on)
-_EXCHANGE_OPS = ("psum", "psum_scatter")
-
-#: staged (hierarchical) plans additionally issue an intra-tier
-#: all-gather leg; only op-wire-ledger matching admits it (a forward
-#: fsdp all-gather must never steal a flat bucket match)
-_EXCHANGE_OPS_HIER = _EXCHANGE_OPS + ("all_gather",)
-
-#: variants of the committed schedule the planner costs as knob
-#: candidates (serve_* and reshard_* variants are not train steps)
-PLAN_VARIANTS = ("train", "overlap", "overlap+zero1", "overlap+accum2",
-                 "overlap+accum4", "overlap+hier", "bf16+compress")
-
-#: bucket_mb candidates the startup autotune pass costs (the configured
-#: value always joins the set)
-TUNE_BUCKET_MB = (0.25, 1.0, 4.0, 16.0)
-
-#: a probed tier bandwidth this many × the flat row's is a measurement
-#: lie (the seeded-probe-lie tests): the tuner then distrusts the tier
-#: rows and falls back to the flat plan, loudly
-TUNE_SANITY_FACTOR = 100.0
-
 
 def layout_label(mesh_cfg) -> str:
     """The catalog-style layout name ("dp", "dp_fsdp", "dp_pp_ep", ...)
@@ -122,9 +85,9 @@ def _ring_scale(n: int) -> float:
 # -- bandwidth -----------------------------------------------------------
 class BandwidthTable:
     """Resolves a reduce-axis signature (``"data+fsdp"``) to
-    ``(bytes_per_sec, latency_secs)``. Three sources, in the order a
-    live prediction prefers them: a fresh probe snapshot, the fabric's
-    persisted catalog, the baked-in reference row."""
+    ``(bytes_per_sec, latency_secs)``. Two sources: the fabric's
+    persisted catalog where one exists, else the baked-in reference
+    row."""
 
     def __init__(self, source: str,
                  axes: Optional[Dict[str, Tuple[float, float]]] = None,
@@ -157,44 +120,6 @@ class BandwidthTable:
         return cls("catalog", axes,
                    default_bps=bps_all[len(bps_all) // 2],
                    default_latency=lat_all[len(lat_all) // 2])
-
-    @classmethod
-    def from_probe(cls, snapshot: Optional[dict]
-                   ) -> Optional["BandwidthTable"]:
-        """A ``comm_timing`` snapshot/row as a table (bench.py's A/B
-        legs predict against the probe they just ran)."""
-        if not snapshot or not snapshot.get("buckets"):
-            return None
-        by_sig: Dict[str, Tuple[float, float]] = {}
-        for b in snapshot["buckets"]:
-            bps = float(b.get("wire_bytes_per_sec", 0.0))
-            lat = float(b.get("probe_secs", 0.0))
-            if bps <= 0:
-                continue
-            sig = b.get("axes") or "data"
-            old = by_sig.get(sig)
-            by_sig[sig] = (max(bps, old[0]) if old else bps,
-                           min(lat, old[1]) if old else lat)
-        # hierarchical tier legs (probe hier_k) land under the catalog's
-        # tiered key form — "<axes>:intra" / "<axes>:inter"
-        for t in snapshot.get("tiers") or []:
-            bps = float(t.get("wire_bytes_per_sec", 0.0))
-            lat = float(t.get("probe_secs", 0.0))
-            if bps <= 0:
-                continue
-            sig = f"{t.get('axes') or 'data'}:{t.get('tier', 'intra')}"
-            old = by_sig.get(sig)
-            by_sig[sig] = (max(bps, old[0]) if old else bps,
-                           min(lat, old[1]) if old else lat)
-        if not by_sig:
-            return None
-        t = cls("probe", by_sig)
-        # defaults from the FLAT rows when any exist: a tier row's
-        # bandwidth describes a sub-group, not an unknown full axis set
-        flat = {k: v for k, v in by_sig.items() if ":" not in k} or by_sig
-        t.default_bps = max(v[0] for v in flat.values())
-        t.default_latency = min(v[1] for v in flat.values())
-        return t
 
     def lookup(self, axes_sig: str) -> Tuple[float, float]:
         hit = self.axes.get(axes_sig)
@@ -254,16 +179,13 @@ def flops_per_example(cfg) -> float:
         * (s / 224.0) ** 2
 
 
-def predict_compute_secs(cfg, n_devices: int, accum: int = 1,
-                         peak_tflops: Optional[float] = None) -> float:
-    """Roofline compute term for one OPTIMIZER step: global batch ×
-    accum microbatches of forward+backward FLOPs, spread ideally over
-    the devices, at ``ASSUMED_MFU`` of peak. ``peak_tflops=None`` is the
-    OFFLINE planner's reference constant (no device to ask); a live run
-    passes its own device's peak (predict_live)."""
-    peak = (peak_tflops or REFERENCE_PEAK_TFLOPS) * 1e12
-    examples = cfg.train.batch_size * max(1, accum)
-    step_flops = examples * flops_per_example(cfg) * TRAIN_FLOPS_MULTIPLIER
+def predict_compute_secs(cfg, n_devices: int) -> float:
+    """Roofline compute term for one OPTIMIZER step: the global batch's
+    forward+backward FLOPs, spread ideally over the devices, at
+    ``ASSUMED_MFU`` of the reference peak (no device to ask)."""
+    peak = REFERENCE_PEAK_TFLOPS * 1e12
+    step_flops = cfg.train.batch_size * flops_per_example(cfg) \
+        * TRAIN_FLOPS_MULTIPLIER
     return step_flops / max(1, n_devices) / (peak * ASSUMED_MFU)
 
 
@@ -281,159 +203,24 @@ def predict_from_signature(signature: dict, bandwidth: BandwidthTable,
                            devices: int = 8) -> dict:
     """Cost one committed schedule signature: every scheduled collective
     as ``latency + bytes/bandwidth`` (ring-scaled when predicting a
-    device count other than the canonical 8 the schedule traced at),
-    overlap credit for the declared bucket plan's exchange ops."""
-    plan = signature.get("plan") or {}
-    # staged (hierarchical) plans carry the per-op wire ledger, aligned
-    # 1:1 with the declared RS→psum→AG sequence — match op-by-op against
-    # it; flat plans keep the one-op-per-bucket match
-    op_wire = plan.get("bucket_op_wire_bytes")
-    if op_wire:
-        match_wire = [int(x) for b in op_wire for x in b]
-        exchange_ops = _EXCHANGE_OPS_HIER
-    else:
-        match_wire = [int(b) for b in plan.get("bucket_wire_bytes") or []]
-        exchange_ops = _EXCHANGE_OPS
+    device count other than the canonical 8 the schedule traced at).
+    All of it adds to the step: what the schedule holds (pipeline
+    hand-offs, expert all-to-alls) sits on the critical path."""
     scale = _ring_scale(devices) / _ring_scale(8)
     comm_secs = 0.0
-    exchange_secs = 0.0
     wire_bytes = 0
-    cursor = 0
     for op in _expanded_ops(signature):
         nbytes = int(op.get("bytes", 0)) * scale
-        sig = "+".join(op.get("axes") or [])
-        if op.get("tier"):
-            # grouped (two-tier) collectives cost against the tiered
-            # bandwidth row ("data+fsdp:intra" / ":inter")
-            sig = f"{sig}:{op['tier']}"
-        bps, lat = bandwidth.lookup(sig)
-        secs = lat + nbytes / bps
-        comm_secs += secs
+        bps, lat = bandwidth.lookup("+".join(op.get("axes") or []))
+        comm_secs += lat + nbytes / bps
         wire_bytes += int(nbytes)
-        # in-order subsequence match against the bucket plan (the
-        # comm-report discipline): matched ops are the overlappable
-        # gradient exchange
-        if op.get("op") in exchange_ops and cursor < len(match_wire) \
-                and int(op.get("bytes", -1)) == match_wire[cursor]:
-            cursor += 1
-            exchange_secs += secs
-    exposed = (comm_secs - exchange_secs) \
-        + max(0.0, exchange_secs - OVERLAP_EFFICIENCY * compute_secs)
-    step_secs = compute_secs + exposed
+    step_secs = compute_secs + comm_secs
     return {
         "step_secs": step_secs,
         "compute_secs": compute_secs,
         "comm_secs": comm_secs,
-        "comm_exposed_secs": exposed,
-        "comm_fraction": exposed / step_secs if step_secs > 0 else 0.0,
+        "comm_fraction": comm_secs / step_secs if step_secs > 0 else 0.0,
         "wire_bytes": wire_bytes,
-    }
-
-
-def tune_comm_plan(snapshot: dict, table: BandwidthTable, *,
-                   intra_k: Optional[int],
-                   bucket_mb: float,
-                   bucket_mb_candidates=TUNE_BUCKET_MB) -> dict:
-    """The startup autotune's chooser (comm.autotune=startup): given the
-    traced plan snapshot (parallel/overlap.overlap_stats — grad bytes,
-    per-bucket reduce-axis sets, the configured compress) and a
-    bandwidth table (ideally carrying the probe's tiered rows), cost
-    every (bucket_mb × flat-vs-hierarchical × compress) candidate with
-    the planner's collective model and return the cheapest. Pure and
-    deterministic given its inputs — the autotune-determinism contract
-    the tests pin.
-
-    First-order model, documented in docs/planner.md: the gradient is
-    one payload on its DOMINANT reduce-axis set (the set carrying the
-    most bucket bytes); a flat bucket costs ``lat + W/bps``; a staged
-    bucket costs the RS and AG legs on the intra tier plus the 1/k psum
-    on the inter tier. Compression candidates never introduce a lossy
-    wire dtype the operator didn't configure — options are "off" and
-    the snapshot's own compress.
-
-    Fallback discipline (the seeded-probe-lie tests): hierarchical
-    candidates are only costed when the table carries MEASURED tier rows
-    for the dominant set, and those rows pass the TUNE_SANITY_FACTOR
-    plausibility screen against the flat row — otherwise the tuner
-    stays flat and logs the reason loudly. Returns {bucket_mb,
-    hierarchy (k or 0), compress, predicted_secs, axes, source,
-    candidates, fallback}."""
-    grad_bytes = int(snapshot.get("grad_bytes") or 0)
-    sigs = snapshot.get("bucket_reduce_axes") or ["data+fsdp"]
-    sizes = snapshot.get("bucket_bytes") or [grad_bytes]
-    by_sig: Dict[str, int] = {}
-    for sig, nb in zip(sigs, sizes):
-        by_sig[sig] = by_sig.get(sig, 0) + int(nb)
-    # dominant reduce-axis set: most bytes, lexicographic tie-break
-    sig = sorted(by_sig, key=lambda s: (-by_sig[s], s))[0]
-    cur_compress = snapshot.get("compress", "off") or "off"
-    compress_opts = ["off"] if cur_compress == "off" \
-        else ["off", cur_compress]
-    itemsize = {"off": 4, "bf16": 2, "fp16": 2}
-
-    fallback = None
-    k = int(intra_k) if intra_k else 0
-    if k > 1 and "data" not in sig.split("+"):
-        k, fallback = 0, ("dominant reduce set %r has no data axis" % sig)
-    bps_f, lat_f = table.lookup(sig)
-    if k > 1:
-        if f"{sig}:intra" not in table.axes \
-                or f"{sig}:inter" not in table.axes:
-            k, fallback = 0, (
-                f"no measured tier rows for {sig!r} in the "
-                f"{table.source} table")
-        else:
-            bps_i, lat_i = table.lookup(f"{sig}:intra")
-            bps_e, lat_e = table.lookup(f"{sig}:inter")
-            implausible = [
-                f"{t}={bps:.3g} B/s vs flat {bps_f:.3g} B/s"
-                for t, bps in (("intra", bps_i), ("inter", bps_e))
-                if not (0 < bps <= TUNE_SANITY_FACTOR * bps_f)]
-            if implausible:
-                k, fallback = 0, (
-                    "tier bandwidth rows fail the plausibility screen "
-                    f"(×{TUNE_SANITY_FACTOR:g} of the flat row): "
-                    + "; ".join(implausible))
-    if fallback:
-        log.warning("comm autotune: hierarchical candidates DISABLED — "
-                    "%s; tuning flat only", fallback)
-
-    def cost(mb: float, hier: int, compress: str) -> float:
-        cap = max(1, int(mb * 2 ** 20))
-        n = max(1, -(-grad_bytes // cap))  # ceil
-        w = (grad_bytes / n) * itemsize[compress] / 4.0
-        if hier:
-            return n * (2 * (lat_i + w / bps_i)
-                        + (lat_e + (w / hier) / bps_e))
-        return n * (lat_f + w / bps_f)
-
-    mbs = sorted(set(float(m) for m in bucket_mb_candidates)
-                 | {float(bucket_mb)})
-    scored = []
-    for mb in mbs:
-        for hier in ([0, k] if k > 1 else [0]):
-            for compress in compress_opts:
-                scored.append((round(cost(mb, hier, compress), 9),
-                               mb != float(bucket_mb), hier == 0,
-                               mb, hier, compress))
-    # cheapest wins; ties prefer the configured bucket_mb, then the
-    # hierarchical form (it was only admitted with measured tier rows),
-    # then the smaller cap / plainer wire — fully deterministic
-    scored.sort(key=lambda t: (t[0], t[1], t[2], t[3], t[5]))
-    best = scored[0]
-    return {
-        "bucket_mb": best[3],
-        "hierarchy": best[4],
-        "compress": best[5],
-        "predicted_secs": best[0],
-        "axes": sig,
-        "source": table.source,
-        "fallback": fallback,
-        "candidates": {
-            f"bucket{mb:g}mb/"
-            + (f"hier{hier}" if hier else "flat")
-            + (f"/{compress}" if compress != "off" else ""):
-            secs for secs, _, _, mb, hier, compress in scored},
     }
 
 
@@ -475,9 +262,7 @@ def _sharded_bytes_per_device(shapes, shardings, mesh) -> int:
 def predict_hbm_bytes(cfg, trainer, devices: int = 8) -> Optional[dict]:
     """Per-device HBM watermark model: sharded train state (params +
     optimizer) + a gradient copy sized like the params + the activation
-    heuristic + two staging-ring slots of input batch. The live
-    calibration target is the ``memory`` rows' per-device
-    ``live_peak_bytes``."""
+    heuristic + two staging-ring slots of input batch."""
     try:
         from ..analysis.collectives import _abstract_state
         from ..parallel.mesh import batch_shard_count
@@ -511,30 +296,13 @@ def predict_hbm_bytes(cfg, trainer, devices: int = 8) -> Optional[dict]:
 
 
 # -- candidate enumeration (main.py plan / the gate phase) ---------------
-def _variant_knobs(cfg, variant: str) -> dict:
-    accum = 1
-    if "accum" in variant:
-        accum = int(variant.rsplit("accum", 1)[1])
-    return {
-        "precision": "bf16" if variant.startswith("bf16") else
-        cfg.train.precision,
-        "zero1": "zero1" in variant,
-        "compress": "bf16" if "compress" in variant else "off",
-        "bucket_mb": cfg.comm.bucket_mb,
-        "accum": accum,
-        "overlap": variant != "train",
-        "hierarchy": "hier" in variant,
-    }
-
-
 def plan_for_preset(preset: str, signatures: Dict[str, dict],
                     n_devices: int = 8,
                     bandwidth: Optional[BandwidthTable] = None,
-                    include_hbm: bool = True,
-                    measured_compute_secs: Optional[float] = None,
-                    peak_tflops: Optional[float] = None) -> dict:
-    """Cost every committed (layout, variant) candidate of one preset
-    and rank them. Pure given its inputs when ``bandwidth`` is the
+                    include_hbm: bool = True) -> dict:
+    """Cost the committed ``train`` schedule of every layout of one
+    preset (``serve_*`` and ``reshard_*`` variants are not a layout's
+    train step) and rank them. Pure given its inputs when ``bandwidth`` is the
     reference table — the plan-catalog byte-identity contract."""
     from ..utils.config import get_preset
     from ..analysis.elaborate import candidate_layouts
@@ -548,17 +316,15 @@ def plan_for_preset(preset: str, signatures: Dict[str, dict],
     for key in sorted(signatures):
         name, rest = key.split("@", 1)
         layout, variant = rest.split("/", 1)
-        if name != preset or variant not in PLAN_VARIANTS:
+        if name != preset or variant != "train":
             continue
         with recorder.span("plan.predict", preset=preset, layout=layout,
                            variant=variant):
-            knobs = _variant_knobs(cfg, variant)
-            compute = measured_compute_secs if measured_compute_secs \
-                else predict_compute_secs(cfg, n_devices,
-                                          accum=knobs["accum"],
-                                          peak_tflops=peak_tflops)
-            pred = predict_from_signature(signatures[key], bandwidth,
-                                          compute, devices=n_devices)
+            knobs = {"precision": cfg.train.precision,
+                     "zero1": cfg.optimizer.zero1 == "on"}
+            pred = predict_from_signature(
+                signatures[key], bandwidth,
+                predict_compute_secs(cfg, n_devices), devices=n_devices)
             if include_hbm and layout in layouts:
                 trainer = trainers.get(layout)
                 if trainer is None:
@@ -576,7 +342,7 @@ def plan_for_preset(preset: str, signatures: Dict[str, dict],
             "bandwidth_source": bandwidth.source,
             "candidates": candidates,
             "ranked": ranked,
-            "recommended": _recommend(candidates, ranked)}
+            "recommended": ranked[0] if ranked else None}
 
 
 def _trainer_for_layout(cfg, mesh_cfg):
@@ -625,15 +391,6 @@ def rank_candidates(candidates: Dict[str, dict]) -> List[str]:
                                  candidates[k].get("hbm_bytes", 0), k))
 
 
-def _recommend(candidates: Dict[str, dict],
-               ranked: List[str]) -> Optional[str]:
-    """The recommended LAYOUT choice compares like with like: the
-    fastest candidate among the plain ``overlap`` variants (every
-    layout traces one), falling back to the overall ranking."""
-    overlap_only = [k for k in ranked if k.endswith("/overlap")]
-    return (overlap_only or ranked or [None])[0]
-
-
 def recommend_layout(preset: str, n_devices: int = 8,
                      bandwidth: Optional[BandwidthTable] = None
                      ) -> Optional[Tuple[str, object]]:
@@ -642,7 +399,7 @@ def recommend_layout(preset: str, n_devices: int = 8,
     no committed schedules (a new preset must run the gate first)."""
     from ..utils.config import get_preset
     from ..analysis.elaborate import candidate_layouts
-    from .comm_report import load_schedules
+    from ..analysis.collectives import load_schedules
 
     signatures = load_schedules()
     if not any(k.startswith(preset + "@") for k in signatures):
@@ -660,120 +417,6 @@ def recommend_layout(preset: str, n_devices: int = 8,
         if name == layout:
             return name, mesh_cfg
     return None
-
-
-# -- live-run prediction (the drift sentinel's reference point) ----------
-def predict_live(cfg, trainer,
-                 bandwidth: Optional[BandwidthTable] = None
-                 ) -> Optional[dict]:
-    """Predict THIS run's step time / comm seconds / HBM from the live
-    traced bucket plan (parallel/overlap.overlap_stats) — no committed
-    schedule needed, so it works for any preset/override combination
-    actually running. Returns None until the exchange plan has traced
-    (the sentinel arms lazily) or when the run has no bucketed
-    exchange to model."""
-    import jax
-    from ..parallel.overlap import overlap_stats
-    from ..utils.profiling import detect_peak_tflops
-
-    snap = overlap_stats.snapshot()
-    if snap is None:
-        return None
-    if bandwidth is None:
-        bandwidth = measured_bandwidth_table() or BandwidthTable.reference()
-    n_devices = jax.device_count()
-    accum = max(1, int(snap.get("accum_steps", 1)))
-    # the attached accelerator's own peak; raises for a device_kind the
-    # peaks table does not know — a borrowed peak would make every
-    # plan_drift row on that machine a fiction
-    peak = detect_peak_tflops()
-    if peak is None:
-        # CPU rehearsal: the host has no peak, so the compute term keeps
-        # the catalog's reference constant and the step_secs drift this
-        # produces there is expected (docs/planner.md)
-        log.info("plan: cpu backend — compute term costed at the "
-                 "reference %.0f TFLOP/s, not at a device peak",
-                 REFERENCE_PEAK_TFLOPS)
-        peak = REFERENCE_PEAK_TFLOPS
-    compute = predict_compute_secs(cfg, n_devices, accum=accum,
-                                   peak_tflops=peak)
-    comm = 0.0
-    for wire, sig in zip(snap["bucket_wire_bytes"],
-                         snap.get("bucket_reduce_axes",
-                                  ["data"] * len(snap["bucket_wire_bytes"]))):
-        bps, lat = bandwidth.lookup(sig)
-        comm += lat + int(wire) / bps
-    exposed = max(0.0, comm - OVERLAP_EFFICIENCY * compute)
-    step = compute + exposed
-    pred = {
-        "step_secs": step,
-        "compute_secs": compute,
-        "comm_secs": comm,
-        "comm_exposed_secs": exposed,
-        "comm_fraction": exposed / step if step > 0 else 0.0,
-        "wire_bytes": int(snap.get("wire_bytes", 0)),
-    }
-    hbm = predict_hbm_bytes(cfg, trainer, devices=n_devices)
-    if hbm:
-        pred.update(hbm)
-    return _round_prediction(pred)
-
-
-# -- drift sentinel ------------------------------------------------------
-class DriftSentinel:
-    """Predicted-vs-measured divergence detector. Per metric: a check
-    whose ratio ``measured/predicted`` leaves ``[1/tolerance,
-    tolerance]`` grows a streak; ``window`` consecutive divergent
-    checks open an EPISODE, which fires exactly once; the episode ends
-    when a check lands back inside tolerance. A global cooldown gates
-    successive fires — a persistently mispredicted run must page once,
-    not once per cadence (the perf-anomaly sentinel's discipline,
-    resilience/watchdog.py)."""
-
-    METRICS = ("step_secs", "comm_secs", "hbm_bytes")
-
-    def __init__(self, predicted: dict, tolerance: float = 3.0,
-                 window: int = 8, cooldown_secs: float = 300.0,
-                 clock: Callable[[], float] = time.monotonic):
-        self.predicted = {m: float(predicted[m]) for m in self.METRICS
-                          if float(predicted.get(m) or 0.0) > 0.0}
-        self.tolerance = max(1.0 + 1e-9, float(tolerance))
-        self.window = max(1, int(window))
-        self.cooldown_secs = max(0.0, float(cooldown_secs))
-        self._clock = clock
-        self._streak: Dict[str, int] = {}
-        self._in_episode: Dict[str, bool] = {}
-        self._last_fire_t: Optional[float] = None
-
-    def check(self, metric: str, measured: Optional[float]
-              ) -> Optional[dict]:
-        """Feed one measurement; a dict (the ``plan_drift`` row body)
-        exactly when the sentinel fires, else None."""
-        predicted = self.predicted.get(metric)
-        if predicted is None or measured is None or measured <= 0:
-            return None
-        ratio = float(measured) / predicted
-        divergent = ratio > self.tolerance or ratio < 1.0 / self.tolerance
-        if not divergent:
-            self._streak[metric] = 0
-            self._in_episode[metric] = False
-            return None
-        self._streak[metric] = self._streak.get(metric, 0) + 1
-        if self._streak[metric] < self.window \
-                or self._in_episode.get(metric):
-            return None
-        now = self._clock()
-        if self._last_fire_t is not None \
-                and now - self._last_fire_t < self.cooldown_secs:
-            return None  # cooldown: keep the streak, fire later
-        self._last_fire_t = now
-        self._in_episode[metric] = True
-        return {"metric": metric,
-                "predicted": round(predicted, 9),
-                "measured": round(float(measured), 9),
-                "ratio": round(ratio, 4),
-                "tolerance": self.tolerance,
-                "windows": self._streak[metric]}
 
 
 # -- CLI -----------------------------------------------------------------
@@ -831,7 +474,7 @@ def main_plan(argv=None) -> int:
     if not ns.no_hbm:
         apply_virtual_cpu(max(8, ns.devices))
     from . import bandwidth as bw_mod
-    from .comm_report import load_schedules
+    from ..analysis.collectives import load_schedules
 
     signatures = load_schedules(ns.schedules or None)
     if not signatures:

@@ -10,8 +10,8 @@ because distributed step-time mysteries cannot be debugged from scalars
     last ~N events per process, like an aircraft flight recorder). The hot
     path is two ``perf_counter`` reads, one locked deque append, one
     ``(count, seconds)`` cell and one ``TraceMe`` — cheap enough to leave
-    on in production (the bench acceptance bar is <2% on the CIFAR
-    headline).
+    on in production (the acceptance bar was <2% on the CIFAR headline;
+    PERF.md, PR 24: nothing that shows on the chip).
   * ONE CLOCK: every span also enters a ``jax.profiler.TraceAnnotation``
     of its own name (a ``StepTraceAnnotation`` when it carries
     ``step_num``), so whenever a profiler session listens — the
@@ -162,17 +162,6 @@ SPAN_CATALOG = {
     "checkpoint.commit": "atomic rename + parent-dir fsync",
     "restore": "checkpoint restore into the live state (goodput: restart "
                "when on the NaN-rollback path)",
-    # gradient-communication overlap (parallel/overlap.py)
-    "comm.bucket": "one planned gradient-exchange bucket (recorded at "
-                   "step TRACE time with bytes/leaves args — the bucket "
-                   "plan, not a per-step event)",
-    "zero1.gather": "one planned ZeRO-1 param-update all-gather bucket "
-                    "(trace-time, like comm.bucket — the gather plan)",
-    "comm.probe": "one planned exchange bucket's collective timed "
-                  "STANDALONE on the live mesh (parallel/overlap."
-                  "probe_comm_plan; bucket/bytes/wire_bytes args — the "
-                  "runtime leg the comm_timing row and main.py "
-                  "comm-report attribute bandwidth from)",
     # serving (serve/server.py, serve/swap.py)
     "serve.batch": "one bucket dispatch: stage + AOT predict + resolve",
     "serve.swap_restore": "off-path host restore of a newer checkpoint",
@@ -207,12 +196,9 @@ SPAN_CATALOG = {
     "reshard.rebuild": "Trainer/mesh/sharding re-elaboration + input "
                        "source rebuild for the new generation",
     # what-if performance planner (telemetry/planner.py)
-    "plan.predict": "one layout × knob candidate costed by the analytic "
+    "plan.predict": "one layout candidate costed by the analytic "
                     "model (preset/layout args; main.py plan and the "
                     "plan-drift gate phase)",
-    "plan.drift_check": "one predicted-vs-measured comparison by the "
-                        "drift sentinel (train/hooks.py PlanDriftHook "
-                        "cadence firing)",
 }
 
 # unknown span names already warned about (warn once, like write_event)

@@ -58,7 +58,7 @@ class _CadenceHook:
 
 class _SnapshotExportHook(_CadenceHook):
     """Shared skeleton for the plan/summary exporters (Zero1Hook,
-    CommOverlapHook, PrecisionHook, CommCompressHook, CkptShardHook):
+    PrecisionHook, CkptShardHook, MemoryHook):
     at the cadence, pull a snapshot row and write it as ONE
     ``{"event": <event>}`` record per CHANGE — these rows describe a
     property of the run's compiled programs / writer state, not of any
@@ -79,13 +79,6 @@ class _SnapshotExportHook(_CadenceHook):
     def _snapshot(self) -> Optional[Dict[str, Any]]:
         raise NotImplementedError
 
-    def _gate(self, snap: Dict[str, Any]) -> Dict[str, Any]:
-        """The comparison key deciding re-export (default: the whole row).
-        Subclasses whose rows carry a live measurement override this to
-        quantize it — re-export when the measurement MOVES, without the
-        noise of re-exporting its every wiggle (CommTimingHook)."""
-        return snap
-
     def __call__(self, step: int, state, metrics: Dict[str, Any]) -> None:
         if not cadence_crossed(step, self.every_steps, self._last):
             return
@@ -93,9 +86,8 @@ class _SnapshotExportHook(_CadenceHook):
         snap = self._snapshot()
         if snap is None:
             return
-        key = self._gate(snap)
-        if key != self._exported:
-            self._exported = key
+        if snap != self._exported:
+            self._exported = snap
             self.writer.write_event(self.event, {"step": int(step),
                                                  **snap})
 
@@ -170,7 +162,7 @@ class InputStagesHook(_CadenceHook):
     """Export the input-pipeline stage counters (utils.metrics.input_stages:
     decode / stack / stage / transfer / dispatch_wait) to metrics.jsonl as a
     typed ``{"event": "input_stages", ...}`` record every N steps — the
-    attribution telemetry bench.py and docs/input_pipeline.md describe.
+    attribution telemetry docs/input_pipeline.md describes.
     Counters are cumulative since process start (or the last reset), so
     consumers can difference consecutive records for window rates."""
 
@@ -194,8 +186,7 @@ class InputEchoHook(_CadenceHook):
     """Export the data-echoing cache counters (utils.metrics.echo_stats:
     decoded/emitted/hits/evictions + cache bytes) to metrics.jsonl as
     typed ``{"event": "input_echo"}`` rows every N steps — the telemetry
-    bench.py's imagenet_input row and docs/input_pipeline.md read for the
-    echo hit rate. Counters are cumulative, like input_stages; rows are
+    docs/input_pipeline.md reads for the echo hit rate. Counters are cumulative, like input_stages; rows are
     only written once the echo path has actually served something (a run
     with echo_factor=1 emits nothing)."""
 
@@ -311,11 +302,9 @@ class CkptShardHook(_SnapshotExportHook):
 class Zero1Hook(_SnapshotExportHook):
     """Export the ZeRO-1 partition plan (parallel/sharding.zero1_stats:
     sharded/replicated leaf+byte counts, per-replica optimizer bytes,
-    fallback reasons, and — under comm.overlap — the bucketed param-
-    update all-gather plan) as ONE ``{"event": "zero1"}`` row per
-    resolved plan, the comm_overlap contract: the plan is a property of
-    the compiled step. Writes nothing when optimizer.zero1 resolved
-    off."""
+    fallback reasons) as ONE ``{"event": "zero1"}`` row per resolved
+    plan: the plan is a property of the compiled step. Writes nothing
+    when optimizer.zero1 resolved off."""
 
     event = "zero1"
 
@@ -326,253 +315,16 @@ class Zero1Hook(_SnapshotExportHook):
 
 class PrecisionHook(_SnapshotExportHook):
     """Export the resolved mixed-precision policy (parallel/precision.
-    precision_stats: policy/compute/master dtypes, effective compression,
-    master-tree accounting) as ONE ``{"event": "precision"}`` row per
-    resolved policy — the per-run precision summary (docs/precision.md).
-    Writes nothing when neither a policy nor compression resolved on."""
+    precision_stats: policy/compute/master dtypes, master-tree
+    accounting) as ONE ``{"event": "precision"}`` row per resolved
+    policy — the per-run precision summary (docs/precision.md). Writes
+    nothing when no policy resolved on."""
 
     event = "precision"
 
     def _snapshot(self):
         from ..parallel.precision import precision_stats
         return precision_stats.snapshot()
-
-
-class CommCompressHook(_SnapshotExportHook):
-    """Export the compressed-exchange payload accounting (parallel/
-    overlap.overlap_stats wire fields + the ZeRO-1 gather wire plan) as
-    ONE ``{"event": "comm_compress"}`` row per traced plan WHEN
-    ``comm.compress`` actually compressed something — the byte-halving
-    witness next to comm_overlap's bucket plan. Silent when the exchange
-    ran uncompressed (the comm_overlap row already carries wire_bytes ==
-    grad_bytes there)."""
-
-    event = "comm_compress"
-
-    def _snapshot(self):
-        from ..parallel.overlap import overlap_stats
-        from ..parallel.sharding import zero1_stats
-        snap = overlap_stats.snapshot()
-        if snap is None or snap.get("compress", "off") == "off":
-            return None
-        row = {"compress": snap["compress"],
-               "grad_bytes": snap["grad_bytes"],
-               "wire_bytes": snap["wire_bytes"],
-               "bucket_wire_bytes": snap["bucket_wire_bytes"],
-               "wire_ratio": round(snap["wire_bytes"] /
-                                   max(snap["grad_bytes"], 1), 4)}
-        z1 = zero1_stats.snapshot()
-        if z1 is not None and z1.get("gather_compress", "off") != "off":
-            row["gather_wire_bytes"] = z1["gather_wire_bytes"]
-        return row
-
-
-class CommOverlapHook(_SnapshotExportHook):
-    """Export the bucketed gradient-exchange plan (parallel/overlap.
-    overlap_stats) as ONE ``{"event": "comm_overlap"}`` row per traced
-    plan. Writes nothing when the overlap path never traced
-    (comm.overlap resolved off)."""
-
-    event = "comm_overlap"
-
-    def _snapshot(self):
-        from ..parallel.overlap import overlap_stats
-        snap = overlap_stats.snapshot()
-        if snap is not None:
-            # analysis-facing, unbounded (one op string per exchanged leaf
-            # per bucket) and not in EVENT_SCHEMAS["comm_overlap"]: the
-            # schedule cross-check reads it straight off overlap_stats
-            snap.pop("declared_collectives", None)
-            # same contract: per-op wire bytes mirror the declared
-            # sequence 1:1 — planner/comm-report inputs, not a row field
-            snap.pop("bucket_op_wire_bytes", None)
-        return snap
-
-
-class CommTimingHook(_SnapshotExportHook):
-    """Export the MEASURED per-bucket exchange timings (utils.metrics.
-    comm_timing_stats, fed once per process by parallel/overlap.
-    probe_comm_plan) as ``{"event": "comm_timing"}`` rows, JOINED with a
-    live per-step wall-time estimate measured between this hook's own
-    cadence firings — the runtime attribution ``main.py comm-report``
-    reduces against the static collective schedule
-    (docs/observability.md). The probe data is static per run, so the
-    ``_gate`` override quantizes the live rate to 2 significant digits:
-    rows re-export when the measured step time MOVES, not per wiggle."""
-
-    event = "comm_timing"
-
-    def __init__(self, writer: MetricsWriter, every_steps: int = 100):
-        super().__init__(writer, every_steps)
-        self._rate_prev: Optional[tuple] = None  # (monotonic, step)
-        self._pending_step = 0
-
-    def reset_window(self) -> None:
-        """Called by Trainer.train at segment start (the LoggingHook
-        protocol): a rate pair spanning the eval/checkpoint pause between
-        segments would inflate step_secs and understate the
-        comm_step_ratio headroom."""
-        self._rate_prev = None
-
-    def __call__(self, step: int, state, metrics: Dict[str, Any]) -> None:
-        self._pending_step = step  # _snapshot's rate-pair endpoint
-        super().__call__(step, state, metrics)
-
-    def _snapshot(self):
-        now = time.monotonic()
-        step = self._pending_step
-        prev, self._rate_prev = self._rate_prev, (now, step)
-        from ..utils.metrics import comm_timing_stats
-        snap = comm_timing_stats.snapshot()
-        if snap is None:
-            return None  # the probe has not run (overlap off / knob off)
-        if prev is not None and step > prev[1] and now > prev[0]:
-            step_secs = (now - prev[0]) / (step - prev[1])
-            snap["step_secs"] = round(step_secs, 6)
-            snap["comm_step_ratio"] = round(
-                snap["comm_secs_total"] / step_secs, 4)
-        return snap
-
-    def _gate(self, snap):
-        gate = dict(snap)
-        if "step_secs" in gate:
-            gate["step_secs"] = float(f"{gate['step_secs']:.2g}")
-            gate.pop("comm_step_ratio", None)
-        return gate
-
-
-class PlanDriftHook(_CadenceHook):
-    """The predicted-vs-measured drift sentinel (docs/planner.md). At the
-    first cadence after the bucketed exchange has traced, the chief
-    builds THIS run's analytic prediction (telemetry/planner.predict_live
-    — step time, comm seconds, per-device HBM, costed from the live
-    bucket plan × the fabric's bandwidth catalog), exports it as one
-    ``{"event": "plan"}`` row, and arms a planner.DriftSentinel. Every
-    cadence after that it compares the prediction against what the run
-    actually measures — step time from the heartbeat EWMA (falling back
-    to this hook's own rate pairs when no watchdog runs), comm seconds
-    from the comm_timing probe, HBM from the live memory sample — and a
-    sustained divergence beyond telemetry.plan_tolerance becomes a
-    ``{"event": "plan_drift"}`` row plus a flight-recorder dump: the
-    model said this run should cost X, the machine disagrees, go look.
-    Chief-only (the prediction and the measurements are per-run, not
-    per-process)."""
-
-    def __init__(self, writer: MetricsWriter, cfg, trainer,
-                 every_steps: int = 100):
-        self.writer = writer
-        self.cfg = cfg
-        self.trainer = trainer
-        self.every_steps = max(1, every_steps)
-        # main._arm_watchdog_hooks points this at the HeartbeatPublisher
-        # so the measured step time is the watchdog's own EWMA — one
-        # number, not two competing estimates
-        self.heartbeat = None
-        self._sentinel = None
-        self._predicted: Optional[dict] = None
-        self._rate_prev: Optional[tuple] = None  # (monotonic, step)
-        self._warned = False
-
-    def reset_window(self) -> None:
-        """LoggingHook protocol: a rate pair spanning the eval/checkpoint
-        pause between segments would read as a step-time regression."""
-        self._rate_prev = None
-
-    def _arm(self) -> bool:
-        from ..telemetry import planner
-        bw = planner.measured_bandwidth_table() \
-            or planner.BandwidthTable.reference()
-        pred = planner.predict_live(self.cfg, self.trainer, bandwidth=bw)
-        if pred is None:
-            if self.cfg.telemetry.plan_drift == "on" and not self._warned:
-                self._warned = True
-                log.warning(
-                    "telemetry.plan_drift=on but no prediction could be "
-                    "built yet (the bucketed exchange has not traced — "
-                    "comm.overlap off?); the sentinel stays disarmed")
-            return False
-        tcfg = self.cfg.telemetry
-        self._predicted = pred
-        self._sentinel = planner.DriftSentinel(
-            pred, tolerance=tcfg.plan_tolerance,
-            window=tcfg.plan_drift_window,
-            cooldown_secs=tcfg.plan_drift_cooldown_secs)
-        self.writer.write_event("plan", {
-            "preset": self.cfg.model.name,
-            "layout": planner.layout_label(self.cfg.mesh),
-            "devices": jax.device_count(),
-            "knobs": {
-                "precision": self.cfg.train.precision,
-                "zero1": self.cfg.optimizer.zero1,
-                "compress": self.cfg.comm.compress,
-                "bucket_mb": self.cfg.comm.bucket_mb,
-                "accum": self.cfg.train.grad_accum_steps,
-            },
-            "predicted": pred,
-            "bandwidth_source": bw.source,
-            "recommended": True,  # the layout actually running
-        })
-        log.info("plan-drift sentinel armed: predicted step %.3fms, "
-                 "comm %.3fms, HBM %s (bandwidth: %s)",
-                 pred["step_secs"] * 1e3, pred["comm_secs"] * 1e3,
-                 pred.get("hbm_bytes"), bw.source)
-        return True
-
-    def _measured(self, now: float, step: int) -> Dict[str, float]:
-        """The live values to hold against the prediction; only metrics
-        that actually have a measurement this cadence are checked."""
-        out: Dict[str, float] = {}
-        prev, self._rate_prev = self._rate_prev, (now, step)
-        if self.heartbeat is not None:
-            ewma = self.heartbeat.snapshot().get("ewma_step_secs")
-            if ewma:
-                out["step_secs"] = float(ewma)
-        if "step_secs" not in out and prev is not None \
-                and step > prev[1] and now > prev[0]:
-            out["step_secs"] = (now - prev[0]) / (step - prev[1])
-        from ..utils.metrics import comm_timing_stats
-        timing = comm_timing_stats.snapshot()
-        if timing is not None:
-            out["comm_secs"] = float(timing["comm_secs_total"])
-        if self._predicted and self._predicted.get("hbm_bytes"):
-            from ..telemetry.memory import sample_memory
-            sample = sample_memory()
-            peaks = [d.get("live_peak_bytes", 0)
-                     for d in sample.get("devices", {}).values()]
-            if peaks and max(peaks) > 0:
-                out["hbm_bytes"] = float(max(peaks))
-        return out
-
-    def __call__(self, step: int, state, metrics: Dict[str, Any]) -> None:
-        if not cadence_crossed(step, self.every_steps, self._last):
-            return
-        self._last = step
-        now = time.monotonic()
-        if self._sentinel is None:
-            if not self._arm():
-                self._rate_prev = (now, step)
-            return
-        from ..telemetry.tracer import recorder
-        with recorder.span("plan.drift_check", step=step):
-            for metric, measured in self._measured(now, step).items():
-                firing = self._sentinel.check(metric, measured)
-                if firing is None:
-                    continue
-                dump = recorder.dump_on_anomaly(
-                    "plan_drift",
-                    detail=f"{metric} predicted "
-                           f"{firing['predicted']:.6g} measured "
-                           f"{firing['measured']:.6g} at step {step}")
-                self.writer.write_event("plan_drift",
-                                        {"step": step, **firing,
-                                         "dump": dump})
-                self.writer.flush()
-                log.warning(
-                    "plan drift: %s measured %.6g vs predicted %.6g "
-                    "(ratio %.2f beyond tolerance %.1f for %d windows)",
-                    metric, firing["measured"], firing["predicted"],
-                    firing["ratio"], firing["tolerance"],
-                    firing["windows"])
 
 
 class MemoryHook(_SnapshotExportHook):

@@ -72,8 +72,7 @@ def resolve_fused_xent(fused_xent: str, label_smoothing: float = 0.0) -> str:
 
 
 def make_ce_fn(label_smoothing: float = 0.0, fused_xent: str = "off",
-               mesh: Optional[Mesh] = None,
-               per_example: bool = False) -> Callable:
+               mesh: Optional[Mesh] = None) -> Callable:
     """Resolve ``train.fused_xent`` into the batch CE function.
 
     Modes: "auto" (Pallas kernel iff running on TPU — the default),
@@ -85,28 +84,17 @@ def make_ce_fn(label_smoothing: float = 0.0, fused_xent: str = "off",
 
     When the mesh splits the batch over >1 shards, the kernel runs under
     ``shard_map`` so each device computes its local (b/n, C) tile — a plain
-    ``jit`` would have to replicate the custom call (all-gathering logits).
-
-    ``per_example=True`` returns the UNREDUCED (b,) CE with the same mode
-    resolution and no shard_map wrap — the inside-shard_map caller
-    (parallel/overlap.make_bucketed_grad) is already per-shard, so the
-    kernel runs directly on the local tile. One resolver for both paths:
-    the overlap loss cannot drift from the jit loss."""
+    ``jit`` would have to replicate the custom call (all-gathering logits)."""
     mode = resolve_fused_xent(fused_xent, label_smoothing)
     if mode == "off":
-        per_ex = lambda logits, labels: per_example_cross_entropy(  # noqa: E731
+        return lambda logits, labels: cross_entropy_loss(
             logits, labels, label_smoothing)
-        if per_example:
-            return per_ex
-        return lambda logits, labels: per_ex(logits, labels).mean()
     interpret = mode == "interpret"
     from ..ops.pallas import softmax_xent
 
     def per_ex(logits, labels):
         return softmax_xent(logits.astype(jnp.float32), labels, interpret)
 
-    if per_example:
-        return per_ex
     if mesh is not None and batch_shard_count(mesh) > 1:
         batch_axes = present_batch_axes(mesh)
         batch_spec = P(batch_axes)
@@ -127,7 +115,6 @@ def make_train_step(schedule: Callable, weight_decay: float,
                     augment_fn: Optional[Callable] = None,
                     augment_seed: int = 0,
                     aux_loss_weight: float = 0.01,
-                    value_and_grad_fn: Optional[Callable] = None,
                     apply_gradients_fn: Optional[Callable] = None,
                     precision=None):
     """Build the pure train_step(state, batch) -> (state, metrics).
@@ -136,16 +123,6 @@ def make_train_step(schedule: Callable, weight_decay: float,
     the top of the step (raw uint8 in, standardized f32 out — see
     ops/augment.py); RNG is fold_in(seed, step): deterministic and
     resume-stable.
-
-    ``value_and_grad_fn`` replaces ``jax.value_and_grad(loss_fn)`` with a
-    custom gradient strategy sharing its exact signature/aux contract —
-    the bucketed-overlap exchange (parallel/overlap.make_bucketed_grad)
-    plugs in here. With grad_accum_steps > 1 it OWNS the accumulation:
-    the microbatch scan runs inside its shard_map body (local f32
-    accumulation, one bucketed exchange after the final microbatch —
-    per-step wire traffic 1× instead of accum×), so the outer
-    ``accum_step`` below is bypassed and per-microbatch augmentation
-    (``prep``'s midx draws) moves into the body with it.
 
     ``apply_gradients_fn(state, grads) -> state`` replaces the default
     ``state.apply_gradients(grads)`` — the ZeRO-1 sharded weight update
@@ -160,19 +137,6 @@ def make_train_step(schedule: Callable, weight_decay: float,
     gradients/optimizer update run on the f32 masters."""
     if ce_fn is None:
         ce_fn = make_ce_fn(label_smoothing)
-    if value_and_grad_fn is not None:
-        # the overlap grad fn owns the accumulation scan — its built-in
-        # factor must match this step's, or the 'accumulated' run would
-        # silently train one giant microbatch (make_bucketed_grad stamps
-        # the attribute; a custom fn without one is assumed accum-free)
-        vag_accum = getattr(value_and_grad_fn, "grad_accum_steps", 1)
-        if vag_accum != max(1, grad_accum_steps):
-            raise ValueError(
-                f"value_and_grad_fn was built for grad_accum_steps="
-                f"{vag_accum} but the step is configured with "
-                f"{grad_accum_steps} — build the overlap grad fn with "
-                "the step's accumulation factor "
-                "(parallel/overlap.make_bucketed_grad)")
     if apply_gradients_fn is None:
         apply_gradients_fn = lambda state, grads: \
             state.apply_gradients(grads)  # noqa: E731
@@ -216,18 +180,10 @@ def make_train_step(schedule: Callable, weight_decay: float,
 
     def single_step(state: TrainState, batch) -> Tuple[TrainState, Dict[str, Any]]:
         images, labels = batch["images"], batch["labels"]
-        if value_and_grad_fn is None or grad_accum_steps <= 1:
-            # the overlap body preps per MICROBATCH itself when it owns
-            # the accumulation scan (distinct midx draws, like accum_step)
-            images = prep(images, state.step)
-        if value_and_grad_fn is not None:
-            (loss, (ce, logits, new_bs)), grads = value_and_grad_fn(
-                state.params, state.batch_stats, images, labels,
-                state.apply_fn, step=state.step)
-        else:
-            (loss, (ce, logits, new_bs)), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(state.params, state.batch_stats,
-                                       images, labels, state.apply_fn)
+        images = prep(images, state.step)
+        (loss, (ce, logits, new_bs)), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(state.params, state.batch_stats,
+                                   images, labels, state.apply_fn)
         new_state = apply_gradients_fn(state, grads).replace(
             batch_stats=new_bs)
         precision = jnp.mean(
@@ -239,9 +195,7 @@ def make_train_step(schedule: Callable, weight_decay: float,
         }
         return new_state, metrics
 
-    if grad_accum_steps <= 1 or value_and_grad_fn is not None:
-        # the overlap exchange owns the accumulation scan (one bucketed
-        # exchange per optimizer step, inside its shard_map body)
+    if grad_accum_steps <= 1:
         return single_step
 
     def accum_step(state: TrainState, batch) -> Tuple[TrainState, Dict[str, Any]]:
@@ -380,61 +334,11 @@ class Trainer:
         # contract BIT-identical (no policy code on that path)
         from ..parallel.precision import precision_stats, resolve_precision
         self._precision = resolve_precision(cfg)
-        # bucketed gradient-communication overlap (parallel/overlap.py):
-        # resolved BEFORE the model build because the shard_map'd step
-        # computes per-shard BN moments — the model must pmean them over
-        # the batch axes (GroupedBatchNorm axis_name) to keep the
-        # cross-replica-BN numerics. comm.overlap=on raises here when the
-        # (model, mesh, train) combination is outside the envelope.
-        from ..parallel.overlap import (BATCH_AXES, compress_dtype,
-                                        exchange_compiler_options,
-                                        resolve_overlap)
-        self._overlap = resolve_overlap(cfg, self.mesh)
         # what every train-step program is compiled under: on one TPU
         # host with a mesh of data shards alone, the options that let the
         # compiler hide the gradient all-reduces (None everywhere else)
+        from ..parallel.overlap import exchange_compiler_options
         self._step_compiler_options = exchange_compiler_options(self.mesh)
-        bn_axis_name = BATCH_AXES if self._overlap is not None else None
-        # compressed gradient exchange (comm.compress) rides the bucketed
-        # overlap — validate the knob even when the exchange is off, and
-        # warn LOUDLY when compression was requested but nothing will
-        # compress (the echo_transfer-warning contract: a silently
-        # unbucketed run would never halve a byte)
-        requested_compress = compress_dtype(cfg)
-        if requested_compress is not None and self._overlap is None:
-            import logging
-            logging.getLogger(__name__).warning(
-                "comm.compress=%s with comm.overlap resolved OFF: "
-                "compression rides the bucketed gradient exchange "
-                "(parallel/overlap.py), so this run exchanges FULL f32 "
-                "payloads — enable comm.overlap (or accept the "
-                "uncompressed exchange)", cfg.comm.compress)
-        # the hierarchical exchange and the startup autotune pass ride
-        # the same exchange — validate their knobs even when overlap is
-        # off, and warn loudly when they were requested but cannot act
-        # (the compress-warning contract above)
-        from ..parallel.overlap import autotune_mode, resolve_hierarchy
-        self._autotune = autotune_mode(cfg)
-        if self._overlap is None:
-            resolve_hierarchy(cfg, self.mesh)  # validate / raise on =on
-            if cfg.comm.hierarchy != "off" or self._autotune != "off":
-                import logging
-                logging.getLogger(__name__).warning(
-                    "comm.hierarchy=%s / comm.autotune=%s with "
-                    "comm.overlap resolved OFF: both ride the bucketed "
-                    "exchange (parallel/overlap.py), so neither can act "
-                    "— enable comm.overlap",
-                    cfg.comm.hierarchy, cfg.comm.autotune)
-        elif self._autotune == "startup" and not cfg.telemetry.comm_timing:
-            import logging
-            logging.getLogger(__name__).warning(
-                "comm.autotune=startup without telemetry.comm_timing: "
-                "the startup pass tunes FROM the comm probe's "
-                "measurements (parallel/overlap.probe_comm_plan) — "
-                "autotune degrades to off", )
-            self._autotune = "off"
-        self._comm_tuned = False
-        self._comm_retuned = False
         # ZeRO-1 sharded weight update (arXiv:2004.13336; parallel/
         # sharding.py rule table): optimizer state shards over `data`,
         # gradients reduce-scatter into the shard layout, the update runs
@@ -486,17 +390,13 @@ class Trainer:
         # model-resolution choices saved for the serving variant builder
         # (make_variant_predict_step): a variant must differ ONLY in
         # compute dtype, never in BN wiring or remat
-        self._bn_axis_name = bn_axis_name
         self._bn_groups = bn_groups
         self.model = create_model(cfg.model, cfg.data.dataset,
-                                  axis_name=bn_axis_name,
                                   remat=cfg.train.remat, bn_groups=bn_groups,
                                   mesh=self.mesh,
                                   compute_dtype=self._precision.compute_dtype
                                   if self._precision is not None else None)
-        precision_stats.record_policy(
-            self._precision,
-            self._overlap.compress if self._overlap is not None else None)
+        precision_stats.record_policy(self._precision)
         self.schedule = create_schedule(cfg.optimizer)
         decay_in_loss = not decoupled_decay(cfg.optimizer.name)
         if cfg.optimizer.decay_all_params and not decay_in_loss:
@@ -581,12 +481,6 @@ class Trainer:
         self._jitted_idx = None
         self._jitted_idx_multi = None
         self.state: Optional[TrainState] = None
-        # per-collective runtime attribution (telemetry.comm_timing):
-        # one standalone timing pass over the bucketed-exchange plan per
-        # process, fired at the first loop boundary after the plan traces
-        # (parallel/overlap.probe_comm_plan; every process participates —
-        # the probe runs collectives)
-        self._comm_probed = False
         # optional resilience/heartbeat.HeartbeatPublisher (set by
         # main.run_train when the watchdog is enabled): evaluate() ticks it
         # per eval batch so hang detection stays live outside the train
@@ -668,7 +562,6 @@ class Trainer:
             "device_augment": onoff(device_augment_enabled(cfg, "train")),
             "device_dataset": onoff(device_dataset_enabled(cfg, "train")),
             "attention": attention,
-            "comm.overlap": onoff(self.comm_overlap_active),
             "zero1": onoff(self.zero1_active),
             "step.compiler_options": ",".join(
                 f"{k}={v}" for k, v in
@@ -689,19 +582,16 @@ class Trainer:
 
     def _make_zero1_apply(self):
         """The ZeRO-1 weight update, ``(state, grads) -> state``:
-        gradients pinned to the rule-table shard layout (on the jit path
-        the ``with_sharding_constraint`` turns the all-reduce XLA would
-        emit into reduce-scatter — the arXiv:2004.13336 transformation;
-        on the overlap path the bucketed exchange already reduce-scattered
-        them), the optimizer transform then runs on each replica's 1/N
-        shard (cross-shard reductions like the LARS/LAMB trust-ratio
-        norms get their collectives from sharding propagation), and the
-        param updates return to the base layout — through the bucketed
-        all-gather when the overlap path is active, else through the jit
-        output sharding's gather."""
+        gradients pinned to the rule-table shard layout (the
+        ``with_sharding_constraint`` turns the all-reduce XLA would emit
+        into reduce-scatter — the arXiv:2004.13336 transformation), the
+        optimizer transform then runs on each replica's 1/N shard
+        (cross-shard reductions like the LARS/LAMB trust-ratio norms get
+        their collectives from sharding propagation), and the param
+        updates return to the base layout through the jit output
+        sharding's gather."""
         mesh = self.mesh
         min_size = self._zero1_min_size()
-        plan = self._overlap
 
         @jax.named_scope("optimizer")
         def apply_gradients_fn(state, grads):
@@ -716,9 +606,6 @@ class Trainer:
             updates, new_opt = state.tx.update(grads, state.opt_state,
                                                state.params)
             updates = with_sharding_constraint(updates, shard_tree)
-            if plan is not None:
-                from ..parallel.overlap import make_bucketed_gather
-                updates = make_bucketed_gather(plan, mesh, specs)(updates)
             import optax as _optax
             new_params = _optax.apply_updates(state.params, updates)
             return state.replace(step=state.step + 1, params=new_params,
@@ -728,26 +615,6 @@ class Trainer:
 
     def _build_train_step(self, aug_fn):
         cfg = self.cfg
-        vag = None
-        if self._overlap is not None:
-            # bucketed dp/dp_fsdp gradient exchange replaces the implicit
-            # XLA-propagation all-reduce (parallel/overlap.py): the CE /
-            # decay / aux-loss recipe is mirrored inside the shard_map
-            # body, so the loss semantics are identical to loss_fn's
-            from ..parallel.overlap import make_bucketed_grad
-            vag = make_bucketed_grad(
-                self._overlap, self.mesh,
-                weight_decay=cfg.optimizer.weight_decay,
-                decay_in_loss=not decoupled_decay(cfg.optimizer.name),
-                decay_all_params=cfg.optimizer.decay_all_params,
-                label_smoothing=cfg.optimizer.label_smoothing,
-                fused_xent=cfg.train.fused_xent,
-                aux_loss_weight=cfg.model.moe_aux_weight,
-                zero1_min_size=self._zero1_min_size()
-                if self._zero1 else None,
-                precision=self._precision,
-                grad_accum_steps=cfg.train.grad_accum_steps,
-                augment_fn=aug_fn, augment_seed=cfg.train.seed)
         return make_train_step(
             self.schedule, cfg.optimizer.weight_decay,
             cfg.optimizer.label_smoothing,
@@ -758,16 +625,9 @@ class Trainer:
                              cfg.train.fused_xent, self.mesh),
             augment_fn=aug_fn, augment_seed=cfg.train.seed,
             aux_loss_weight=cfg.model.moe_aux_weight,
-            value_and_grad_fn=vag,
             apply_gradients_fn=self._make_zero1_apply()
             if self._zero1 else None,
             precision=self._precision)
-
-    @property
-    def comm_overlap_active(self) -> bool:
-        """True when the train step exchanges gradients through the
-        bucketed overlap path (parallel/overlap.py)."""
-        return self._overlap is not None
 
     @property
     def zero1_active(self) -> bool:
@@ -781,13 +641,6 @@ class Trainer:
         the step: bf16 compute over f32 masters
         (parallel/precision.py)."""
         return self._precision is not None
-
-    @property
-    def comm_compress_active(self) -> bool:
-        """True when the gradient exchange actually compresses its
-        payloads (comm.compress riding an active bucketed overlap)."""
-        return self._overlap is not None and \
-            self._overlap.compress is not None
 
     def make_variant_predict_step(self, variant: str):
         """The serving VARIANT forward (serve/compile_cache.py buckets
@@ -809,7 +662,6 @@ class Trainer:
                                           WEIGHT_ONLY_VARIANTS,
                                           dequantize_params)
         model = create_model(self.cfg.model, self.cfg.data.dataset,
-                             axis_name=self._bn_axis_name,
                              remat=self.cfg.train.remat,
                              bn_groups=self._bn_groups, mesh=self.mesh,
                              compute_dtype=SERVE_VARIANT_DTYPES[variant])
@@ -866,8 +718,8 @@ class Trainer:
     def train_put_augments(self) -> bool:
         """True when the train put path's unpack program carries the fused
         device augmentation (so train batches come out float32 and the
-        step itself has no augment op) — bench and tests size their probe
-        batches by this."""
+        step itself has no augment op) — tests size their probe batches
+        by this."""
         return self._train_augment_spec is not None
 
     def jitted_multi_step(self, k: int = 0):
@@ -1116,98 +968,6 @@ class Trainer:
         if self.state is not None:
             self.state = self.state.replace(tx=self.tx)
 
-    def _maybe_probe_comm(self) -> None:
-        """Run the per-bucket collective timing probe ONCE per process,
-        the first time the bucketed exchange's plan is available
-        (parallel/overlap.probe_comm_plan → utils.metrics.
-        comm_timing_stats → the chief's comm_timing rows). Called at step
-        dispatch boundaries; every process reaches the same boundary in
-        the same order, so the probe's collectives are SPMD-safe. Must
-        never kill training — the probe itself swallows measurement
-        errors."""
-        if self._comm_probed or not self.comm_overlap_active \
-                or not self.cfg.telemetry.comm_timing:
-            return
-        from ..parallel.overlap import (hierarchy_factor, overlap_stats,
-                                        probe_comm_plan)
-        if overlap_stats.snapshot() is None:
-            return  # the step has not traced yet
-        self._comm_probed = True
-        # the tier legs probe whenever the mesh factors — a flat plan
-        # still measures intra/inter bandwidth so the autotune pass (and
-        # the offline planner, via the catalog) can rank hierarchy
-        hier_k = self._overlap.hierarchy
-        if hier_k is None and self._autotune == "startup":
-            try:
-                hier_k = hierarchy_factor(self.cfg, self.mesh)
-            except ValueError:
-                hier_k = None
-        result = probe_comm_plan(self.mesh,
-                                 reps=self.cfg.telemetry.comm_timing_reps,
-                                 hier_k=hier_k)
-        if result is not None and self._autotune == "startup" \
-                and not self._comm_tuned:
-            self._comm_tuned = True
-            self._retune_comm(result, hier_k)
-
-    def _retune_comm(self, probe_result: dict,
-                     hier_k: Optional[int]) -> None:
-        """The startup autotune pass (comm.autotune=startup): feed the
-        probe's measurements into the planner's cost model
-        (telemetry/planner.tune_comm_plan), and when the chosen plan
-        differs from the running one, REBUILD the train step around it —
-        the tuned plan re-traces, re-records its declared schedule, and
-        the next ``_maybe_probe_comm`` boundary re-probes it (guarded by
-        ``_comm_tuned`` against a tune loop). Never raises: a failed
-        tune keeps the configured plan."""
-        import logging
-        log = logging.getLogger(__name__)
-        try:
-            from ..parallel.overlap import overlap_stats
-            from ..telemetry.planner import BandwidthTable, tune_comm_plan
-            snap = overlap_stats.snapshot()
-            if snap is None:
-                return
-            table = BandwidthTable.from_probe(probe_result)
-            choice = tune_comm_plan(
-                snap, table,
-                intra_k=hier_k,
-                bucket_mb=self.cfg.comm.bucket_mb)
-        except Exception:
-            log.exception("comm autotune failed; keeping the configured "
-                          "plan")
-            return
-        plan = self._overlap
-        import dataclasses as _dc
-        tuned = _dc.replace(
-            plan,
-            bucket_bytes=int(choice["bucket_mb"] * 2 ** 20),
-            compress=None if choice["compress"] == "off"
-            else choice["compress"],
-            hierarchy=choice["hierarchy"] or None,
-            tuned=True)
-        log.info("comm autotune (startup): chose bucket_mb=%s compress=%s "
-                 "hierarchy=%s (%s)", choice["bucket_mb"],
-                 choice["compress"], choice["hierarchy"] or "flat",
-                 choice.get("fallback") or "cost model")
-        changed = (tuned.bucket_bytes, tuned.compress, tuned.hierarchy) \
-            != (plan.bucket_bytes, plan.compress, plan.hierarchy)
-        # rebuild even on a no-change choice: the re-traced plan records
-        # tuned=True into overlap_stats, so the comm_overlap row and the
-        # schedule artifact show the plan was CHOSEN, not just configured
-        self._overlap = tuned
-        self._train_step = self._build_train_step(self._aug_fn)
-        self._jitted_train = None
-        self._jitted_multi = None
-        self._jitted_idx = None
-        self._jitted_idx_multi = None
-        # the hot loops cache the jitted fn in a local — this flag tells
-        # them to re-fetch it so the tuned plan takes over MID-RUN (the
-        # startup pass must tune the very training it probed)
-        self._comm_retuned = True
-        if changed:
-            self._comm_probed = False  # re-probe the tuned plan's buckets
-
     # -- loops -------------------------------------------------------------
     def train(self, data_iter: Iterator, num_steps: Optional[int] = None,
               hooks: Tuple = (), start_step: int = 0,
@@ -1312,13 +1072,6 @@ class Trainer:
                 batch_uses -= 1
                 with span("train.step", step_num=step):
                     self.state, metrics = step_fn(self.state, batch)
-                self._maybe_probe_comm()
-                if self._comm_retuned:
-                    # the startup autotune rebuilt the step around its
-                    # chosen plan — swap the fresh jit in mid-run (the
-                    # accessor is a cached-attribute check afterwards)
-                    step_fn = self.jitted_index_step() if use_idx \
-                        else self.jitted_train_step()
                 with span("train.hooks"):
                     for h in hooks:
                         h(step + 1, self.state, metrics)
@@ -1365,9 +1118,6 @@ class Trainer:
                 b = jax.tree_util.tree_map(lambda x, i=i: x[i], stacked)
                 with span("train.step", step_num=step):
                     self.state, metrics = step_fn(self.state, b)
-                self._maybe_probe_comm()
-                if self._comm_retuned:
-                    step_fn = single_fn()  # autotuned rebuild — swap in
                 step += 1
                 with span("train.hooks"):
                     for h in hooks:
@@ -1402,11 +1152,6 @@ class Trainer:
                     break
                 with span("train.step", step_num=step):
                     self.state, metrics = multi_fn(self.state, stacked)
-                self._maybe_probe_comm()
-                if self._comm_retuned:
-                    # autotuned rebuild — swap the fused dispatch in too
-                    multi_fn = self.jitted_index_multi_step(k) if use_idx \
-                        else self.jitted_multi_step(k)
                 step += k
                 with span("train.hooks"):
                     for h in hooks:

@@ -1,6 +1,6 @@
 """Where JAX's persistent compilation cache lives — the one place that says.
 
-Every entry point that compiles (``main()``, ``bench.py``,
+Every entry point that compiles (``main()``, ``benchmark/run.py``,
 ``chip_smoke.py``'s children, ``__graft_entry__``, the ``tools/`` profilers)
 calls :func:`configure_compile_cache` before its first compile. The
 directory is part of the cache's lookup, so it must not move between

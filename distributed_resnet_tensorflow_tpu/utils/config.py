@@ -192,9 +192,9 @@ class OptimizerConfig:
     # shard the optimizer state and the weight update across the `data`
     # mesh axis: gradients reduce-scatter into each replica's optimizer
     # shard, the update runs on 1/N of the state per replica, and the
-    # parameter updates all-gather back (bucketed when comm.overlap is
-    # active). auto = on iff the run has >1 process (where per-replica
-    # optimizer memory is the binding constraint); on = force (raises the
+    # parameter updates all-gather back. auto = on iff the run has >1
+    # process (where per-replica optimizer memory is the binding
+    # constraint); on = force (raises the
     # unsupported reason outside the envelope); off = the replicated
     # update — the bit-identical exactness oracle the ZeRO-1 path is
     # tested against
@@ -271,8 +271,7 @@ class TrainConfig:
     # masters so save/restore and serve hot-swap are policy-agnostic.
     # "off" (default): the legacy model.compute_dtype contract, BIT-
     # identical to the pre-policy step — the exactness oracle the cast
-    # path is pinned against. fp16 is refused here (needs loss scaling;
-    # see comm.compress for the fp16 exchange payload).
+    # path is pinned against. fp16 is refused here (needs loss scaling).
     precision: str = "off"            # off | bf16
     # print MFU in the logging hook (XLA cost-analysis FLOPs / peak)
     log_mfu: bool = False
@@ -303,74 +302,6 @@ class CheckpointConfig:
     # how long a sharded save's finalize may wait on peer-host shard
     # markers (and peers on the chief's commit) before failing the save
     finalize_timeout_secs: float = 300.0
-
-
-@dataclass
-class CommConfig:
-    """Gradient-communication overlap (parallel/overlap.py; arXiv:1711.00705
-    bucketed allreduce interleaved with backprop). When enabled, the dp /
-    dp_fsdp gradient exchange is rebuilt as size-bucketed per-bucket psums
-    inside a ``shard_map``-wrapped step so XLA's latency-hiding scheduler
-    can overlap each bucket's collective with the remaining backward pass —
-    numerically identical leaf-by-leaf to the unbucketed exchange (same
-    per-leaf all-reduce over the same operands)."""
-
-    # auto = on iff the run has >1 process (the DCN multi-host dp path the
-    # bucketing exists for) AND the (model, mesh, train) combination
-    # supports it; on = force (raises with the reason when unsupported —
-    # tests and single-host bring-up); off = the default XLA-propagation
-    # exchange. One process on a TPU whose mesh is data shards alone
-    # (one host's chips, pure data parallel) stays on the propagated
-    # exchange under auto and gets its step programs compiled so that
-    # the all-reduces run asynchronously inside the backward pass's
-    # matmuls (parallel/overlap.exchange_compiler_options — from the
-    # mesh and the backend, no knob here): traced on four v5e chips,
-    # ViT-L's exposed all-reduce time fell from 10.6 to 6.4 ms a step,
-    # and the bucketed path ran slower than no overlap (PERF.md §6,
-    # PR 30)
-    overlap: str = "auto"             # auto | on | off
-    # target bucket size: gradient leaves are greedily grouped (in reverse
-    # parameter order, approximating backprop availability — output layers
-    # first) into buckets of at most this many MB; each bucket is one psum
-    # issue. Smaller buckets start communicating earlier but amortize less
-    # per-collective overhead (the DDP knob, arXiv:1711.00705 §4)
-    bucket_mb: float = 4.0
-    # compressed gradient exchange (docs/precision.md): cast each bucket's
-    # psum / reduce-scatter payload (and the ZeRO-1 param-update
-    # all-gather) to this dtype on the wire, re-materializing f32 on
-    # arrival — halves (bf16/fp16) the inter-host bytes the overlap
-    # machinery must hide, on the SAME bucket plan (arXiv:1811.05233:
-    # ImageNet/RN50 to reference accuracy with half-precision allreduce).
-    # Rides the bucketed exchange: with comm.overlap resolved off nothing
-    # compresses (the Trainer warns loudly). Local gradient accumulation
-    # and the optimizer update stay f32 either way.
-    compress: str = "off"             # off | bf16 | fp16
-    # hierarchical (two-tier) data-axis exchange (arXiv:1811.05233 2D-torus
-    # allreduce; arXiv:1711.04325 intra-node-reduce-then-inter-node): when
-    # the ``data`` mesh axis factors into intra-host × inter-host groups
-    # (host-aware device order, parallel/mesh.py), each bucket is
-    # reduce-scattered over the fast intra-host tier first, psummed as a
-    # 1/k shard over the slow inter-host tier, then all-gathered back
-    # intra-host — inter-host wire bytes drop to 1/intra_k per bucket.
-    # auto = on iff the bucketed exchange is on AND a non-trivial
-    # factorization exists; on = force (raises with the reason when no
-    # factorization exists); off = flat single-tier collectives
-    hierarchy: str = "off"            # off | auto | on
-    # explicit intra-tier group size override: 0 = derive from the mesh's
-    # host layout (jax.process_count / device process indices); a value
-    # k with 1 < k < data_axis_size and k | data_axis_size forces the
-    # factorization — the virtual-8 CPU test path ("2 hosts × 4 devices")
-    intra_axis_size: int = 0
-    # self-tuning comm plan (telemetry/planner.py tune_comm_plan): at the
-    # first step boundary a probe (probe_comm_plan, extended to time flat
-    # vs hierarchical legs per reduce-axis set) feeds the planner's cost
-    # model, which picks bucket_mb, compress (never introducing a lossy
-    # wire dtype the operator didn't opt into) and flat-vs-hierarchical
-    # per axis set; the chosen plan is recorded in the comm_overlap row
-    # and analysis/plan_catalog.json, and the step is rebuilt once.
-    # Requires telemetry.comm_timing (the probe) — startup warns and
-    # degrades to off without it.
-    autotune: str = "off"             # off | startup
 
 
 @dataclass
@@ -519,7 +450,7 @@ class TelemetryConfig:
     span tracer, its anomaly-triggered dumps, and the goodput export."""
 
     # record spans into the bounded in-memory ring (telemetry/tracer.py).
-    # Measured negligible (<2% on the CIFAR headline — the bench acceptance
+    # Measured negligible (<2% on the CIFAR headline, the acceptance
     # bar), so on by default; off = every span is a shared no-op.
     enabled: bool = True
     # ring capacity in span events — the flight recorder's memory bound
@@ -541,16 +472,6 @@ class TelemetryConfig:
     # week-long serve/monitor run must not fill the disk. 0 MB = unbounded
     metrics_max_mb: float = 256.0
     metrics_max_segments: int = 4
-    # -- per-collective runtime attribution (parallel/overlap.py probe) --
-    # once per process, after the bucketed exchange has traced, time each
-    # planned bucket's collective standalone on the live mesh (wire
-    # dtype/bytes) — the measured side of the comm_timing row and
-    # `main.py comm-report`. Cost: a handful of tiny collective programs
-    # at the first loop boundary; every process participates (the probe
-    # is SPMD), the chief records. Off = plan-only telemetry.
-    comm_timing: bool = True
-    # number of timed repetitions per bucket (best-of)
-    comm_timing_reps: int = 3
     # -- device-memory telemetry (telemetry/memory.py) -------------------
     # sample per-device live-array bytes (+ allocator stats where the
     # backend reports them), host RSS, echo-cache and staging-ring
@@ -581,24 +502,6 @@ class TelemetryConfig:
     # not dump a trace per detection tick); the episode also re-arms only
     # after a healthy sample
     anomaly_cooldown_secs: float = 60.0
-    # -- predicted-vs-measured drift sentinel (train/hooks.PlanDriftHook,
-    # telemetry/planner.py, docs/planner.md) ----------------------------
-    # arm the sentinel: at run start the chief predicts step time / comm
-    # seconds / HBM from the live bucket plan × the fabric's bandwidth
-    # catalog, emits one {"event": "plan"} row, then compares measured
-    # values (heartbeat EWMA step time, comm_timing probe, memory rows)
-    # each cadence. "auto" = on when the prediction can be built (overlap
-    # active), "on" forces a warning when it cannot, "off" disarms.
-    plan_drift: str = "auto"
-    # divergence band: fire when measured/predicted leaves
-    # [1/tol, tol] for plan_drift_window consecutive checks. The analytic
-    # model is a roofline, not a simulator — 3x either way means the
-    # model or the machine is wrong, not that the model is 20% off.
-    plan_tolerance: float = 3.0
-    plan_drift_window: int = 8
-    # minimum gap between plan_drift firings (each one dumps the flight
-    # recorder); an episode re-arms only after an in-tolerance check
-    plan_drift_cooldown_secs: float = 300.0
 
 
 @dataclass
@@ -610,9 +513,6 @@ class EvalConfig:
     # images: ceil(50000 / data.eval_batch_size) (=500 at the default 100);
     # the iterator masks the final partial batch, and a larger count just
     # stops at stream exhaustion, so overshooting is safe single-process.
-    # The measured full-pass wall time rides in bench.py's
-    # imagenet_input.eval_pass key (native decode + uint8 ship + device
-    # standardize, docs r4).
     eval_batch_count: int = 50
     eval_once: bool = False
     poll_interval_secs: float = 60.0  # reference sleeps 60s between polls
@@ -766,7 +666,6 @@ class ExperimentConfig:
     data: DataConfig = field(default_factory=DataConfig)
     optimizer: OptimizerConfig = field(default_factory=OptimizerConfig)
     mesh: MeshConfig = field(default_factory=MeshConfig)
-    comm: CommConfig = field(default_factory=CommConfig)
     train: TrainConfig = field(default_factory=TrainConfig)
     checkpoint: CheckpointConfig = field(default_factory=CheckpointConfig)
     resilience: ResilienceConfig = field(default_factory=ResilienceConfig)
@@ -795,13 +694,11 @@ class ExperimentConfig:
         """Apply one dotted-path override, e.g. ("train.batch_size", 256)."""
         obj = self
         parts = dotted.split(".")
-        for p in parts[:-1]:
-            obj = getattr(obj, p)
-        leaf = parts[-1]
-        if not hasattr(obj, leaf):
-            raise KeyError(f"unknown config key: {dotted}")
-        cur = getattr(obj, leaf)
-        setattr(obj, leaf, _coerce(value, cur))
+        for p in parts:
+            if not hasattr(obj, p):
+                raise KeyError(f"unknown config key: {dotted}")
+            parent, obj = obj, getattr(obj, p)
+        setattr(parent, parts[-1], _coerce(value, obj))
 
 
 def _coerce(value: Any, template: Any) -> Any:
@@ -916,11 +813,9 @@ def _imagenet_resnet50_lars32k() -> ExperimentConfig:
         warmup_steps=800, total_steps=3600, label_smoothing=0.1)
     cfg.train = TrainConfig(batch_size=32768, train_steps=3600,
                             log_every_steps=10,
-                            # the arXiv:1811.05233 recipe shape: bf16
-                            # step + half-precision gradient exchange
-                            # (docs/precision.md)
+                            # the arXiv:1811.05233 recipe shape: a
+                            # bf16 step (docs/precision.md)
                             precision="bf16")
-    cfg.comm.compress = "bf16"
     return cfg
 
 
@@ -955,7 +850,6 @@ def _imagenet_resnet50_lars4k() -> ExperimentConfig:
                             train_steps=large_batch_steps(bs, 90),
                             log_every_steps=20,
                             precision="bf16")  # arXiv:1811.05233 recipe
-    cfg.comm.compress = "bf16"
     return cfg
 
 
@@ -976,7 +870,6 @@ def _imagenet_resnet50_lamb4k() -> ExperimentConfig:
                             train_steps=large_batch_steps(bs, 90),
                             log_every_steps=20,
                             precision="bf16")  # arXiv:1811.05233 recipe
-    cfg.comm.compress = "bf16"
     return cfg
 
 
@@ -1023,8 +916,8 @@ def _vit_moe() -> ExperimentConfig:
     Sized so every transformer layout elaborates on the virtual 8-device
     gate mesh (dp / dp_fsdp / dp_pp / dp_tp / dp_pp_ep: depth 8 % 2
     stages, heads 4 % tensor 2, experts 4 % expert 2, bs 64 % shards ×
-    microbatches), giving the MoE/pipeline overlap + collective-schedule
-    families a shipped config instead of test-only ad-hoc ones."""
+    microbatches), giving the MoE/pipeline collective-schedule families
+    a shipped config instead of test-only ad-hoc ones."""
     cfg = ExperimentConfig()
     cfg.model = ModelConfig(
         name="vit", num_classes=10, vit_patch_size=4, vit_dim=128,

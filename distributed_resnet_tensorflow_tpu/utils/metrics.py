@@ -37,9 +37,9 @@ class StageStats:
     stage's throughput as items / busiest-thread-seconds — the number that
     stays honest for multi-worker stages (a 4-thread decode pool that spent
     40 thread-seconds decoding 1000 images over a 10 s wall ran at ~100
-    img/s, not 25). ``bench.py`` attributes the end-to-end input rate from
-    these counters instead of re-measuring each component in isolation, so
-    the attribution reflects the overlapped pipeline as it actually ran.
+    img/s, not 25). The end-to-end input rate is attributed from these
+    counters instead of re-measuring each component in isolation, so the
+    attribution reflects the overlapped pipeline as it actually ran.
     """
 
     def __init__(self):
@@ -100,7 +100,7 @@ class StageStats:
 # stacker, the echo cache, the staging/transfer thread and the dispatch
 # loop all feed this one registry through their spans (so does every
 # other span, under its own name); InputStagesHook exports it to
-# metrics.jsonl and bench.py reads it for end-to-end attribution. Decode
+# metrics.jsonl. Decode
 # worker PROCESSES (data.decode_processes > 0) accumulate in their own
 # process and ship counter snapshots back over the result queue; the
 # parent merges them here under per-worker keys (data/imagenet.py,
@@ -115,8 +115,7 @@ class EchoStats:
     past its first — the decodes echoing saved), evictions (samples
     dropped by the byte bound with echo uses still pending) and the lost
     uses those evictions cost. ``InputEchoHook`` exports snapshots to
-    metrics.jsonl as ``{"event": "input_echo"}`` rows and bench.py's
-    imagenet_input row reads the same registry."""
+    metrics.jsonl as ``{"event": "input_echo"}`` rows."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -233,50 +232,6 @@ class CkptAsyncStats:
 ckpt_async_stats = CkptAsyncStats()
 
 
-class CommTimingStats:
-    """Thread-safe record of the MEASURED per-bucket collective timings
-    (parallel/overlap.probe_comm_plan): the runtime companion to the
-    static bucket plan in ``overlap_stats``. The probe times each planned
-    gradient-exchange bucket's collective standalone (wire dtype, wire
-    bytes) once per process, so the ``{"event": "comm_timing"}`` row and
-    ``main.py comm-report`` can attribute achieved bytes/sec to
-    INDIVIDUAL buckets instead of one aggregate ratio
-    (docs/observability.md)."""
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self._probe: Optional[Dict[str, Any]] = None
-
-    def record(self, buckets, comm_secs_total: float, reps: int,
-               axes, compress: str, tiers=None) -> None:
-        with self._lock:
-            self._probe = {
-                "buckets": [dict(b) for b in buckets],
-                "comm_secs_total": round(float(comm_secs_total), 6),
-                "reps": int(reps),
-                "axes": list(axes),
-                "compress": compress,
-                # hierarchical tier legs (probe hier_k): standalone
-                # grouped-psum timings per (axes, intra|inter) — catalog
-                # inputs for tune_comm_plan, NOT part of comm_secs_total
-                "tiers": [dict(t) for t in tiers] if tiers else [],
-            }
-
-    def reset(self) -> None:
-        with self._lock:
-            self._probe = None
-
-    def snapshot(self) -> Optional[Dict[str, Any]]:
-        with self._lock:
-            return None if self._probe is None else {
-                k: (list(v) if isinstance(v, list) else v)
-                for k, v in self._probe.items()}
-
-
-# process-global measured bucket timings (one probed plan per process)
-comm_timing_stats = CommTimingStats()
-
-
 #: The metrics.jsonl event registry — the ONE source of truth for every
 #: typed ``{"event": <name>, ...}`` record any part of the framework may
 #: emit. Each entry: {"fields": {field: one-line description},
@@ -371,7 +326,7 @@ EVENT_SCHEMAS = {
     },
     "zero1": {
         "emitted_by": "train/hooks.py Zero1Hook (once per resolved "
-                      "partition plan, like comm_overlap)",
+                      "partition plan)",
         "fields": {
             "step": "step at export time",
             "data_shards": "data-axis size the optimizer state shards "
@@ -388,85 +343,6 @@ EVENT_SCHEMAS = {
                                            "denominator)",
             "reasons": "per-reason fallback counts (below-min-size, "
                        "no-divisible-dim, bookkeeping, ...)",
-            "gather_buckets": "param-update all-gather buckets "
-                              "(comm.overlap composition only)",
-            "gather_bucket_bytes": "per-bucket gathered bytes, issue "
-                                   "order",
-            "gather_bucket_leaves": "per-bucket gathered leaf counts",
-        },
-    },
-    "comm_overlap": {
-        "emitted_by": "train/hooks.py CommOverlapHook (once per run, "
-                      "when the bucketed exchange traced)",
-        "fields": {
-            "step": "step at export time",
-            "buckets": "gradient-exchange buckets in the compiled step",
-            "bucket_cap_bytes": "configured comm.bucket_mb in bytes",
-            "bucket_bytes": "per-bucket gradient bytes (reverse "
-                            "parameter order — issue order)",
-            "bucket_leaves": "per-bucket gradient leaf counts",
-            "grad_bytes": "total exchanged gradient bytes per step",
-            "leaves": "gradient leaves exchanged",
-            "compress": "comm.compress payload dtype (off = f32 wire)",
-            "bucket_wire_bytes": "per-bucket bytes actually on the wire "
-                                 "(= bucket_bytes when compress is off; "
-                                 "halved under bf16/fp16 on the SAME "
-                                 "bucket plan)",
-            "wire_bytes": "total wire bytes per step exchange (ONE "
-                          "exchange per optimizer step — under "
-                          "accumulation this is 1/accum of what a "
-                          "per-microbatch exchange would move)",
-            "bucket_reduce_axes": "per-bucket reduce-axis set "
-                                  "('data+fsdp', '…+pipeline+expert' on "
-                                  "shaped layouts) — one set per bucket "
-                                  "by construction (the grouped planner)",
-            "accum_steps": "train.grad_accum_steps the exchange "
-                           "accumulates over inside the body (1 = none)",
-            "hierarchy": "intra-tier group size k of the two-tier "
-                         "data-axis exchange (comm.hierarchy; 0 = flat)",
-            "autotune": "comm.autotune mode the plan resolved under "
-                        "(off | startup)",
-            "tuned": "true when the startup autotune pass REWROTE the "
-                     "plan (telemetry/planner.tune_comm_plan)",
-            "bucket_inter_wire_bytes": "per-bucket wire bytes crossing "
-                                       "the SLOW (inter-host) data tier "
-                                       "— the full wire payload when "
-                                       "flat, 1/k of it (+pad) when "
-                                       "hierarchical",
-        },
-    },
-    "comm_timing": {
-        "emitted_by": "train/hooks.py CommTimingHook (chief; once the "
-                      "per-bucket collective probe has run — "
-                      "parallel/overlap.probe_comm_plan)",
-        "fields": {
-            "step": "step at export time",
-            "buckets": "per-bucket measured attribution, issue order: "
-                       "{bucket, bytes, wire_bytes, leaves, axes, "
-                       "probe_secs, wire_bytes_per_sec} — probe_secs is "
-                       "the bucket's collective timed STANDALONE on the "
-                       "live mesh (wire dtype/bytes, the bucket's own "
-                       "reduce-axis set), not its in-step exposed time",
-            "comm_secs_total": "sum of the per-bucket standalone times — "
-                               "what the exchange would cost fully "
-                               "exposed",
-            "reps": "timed repetitions per bucket (best-of)",
-            "axes": "mesh axes the probed collective reduces over",
-            "compress": "wire dtype the probe used (comm.compress; off "
-                        "= f32)",
-            "tiers": "hierarchical tier legs, when probed with a "
-                     "factored data axis: {axes, tier: intra|inter, "
-                     "wire_bytes, probe_secs, wire_bytes_per_sec} per "
-                     "data-reducing axis set — catalog inputs for the "
-                     "autotune cost model, NOT included in "
-                     "comm_secs_total",
-            "step_secs": "measured wall seconds per optimizer step over "
-                         "the hook's window (loop-boundary cadence "
-                         "pairs)",
-            "comm_step_ratio": "comm_secs_total / step_secs — the share "
-                               "of each step the exchange would cost if "
-                               "NOTHING were overlapped (the overlap "
-                               "headroom; docs/observability.md)",
         },
     },
     "memory": {
@@ -517,8 +393,8 @@ EVENT_SCHEMAS = {
     },
     "precision": {
         "emitted_by": "train/hooks.py PrecisionHook (once per resolved "
-                      "policy, like comm_overlap — a property of the "
-                      "run, not of any step)",
+                      "policy — a property of the run, not of any "
+                      "step)",
         "fields": {
             "step": "step at export time",
             "policy": "resolved train.precision (off | bf16)",
@@ -526,31 +402,10 @@ EVENT_SCHEMAS = {
                              "(null when off)",
             "master_dtype": "persisted parameter/optimizer dtype "
                             "(float32 — the checkpoint contract)",
-            "compress": "effective comm.compress (off when the bucketed "
-                        "exchange resolved off — see the Trainer "
-                        "warning)",
             "param_leaves": "parameter leaves in the master tree",
             "master_param_bytes": "f32 master parameter bytes (what "
                                   "checkpoints persist regardless of "
                                   "policy)",
-        },
-    },
-    "comm_compress": {
-        "emitted_by": "train/hooks.py CommCompressHook (once per traced "
-                      "plan WHEN compression is active; silent "
-                      "otherwise)",
-        "fields": {
-            "step": "step at export time",
-            "compress": "payload dtype on the wire (bf16 | fp16)",
-            "grad_bytes": "f32 gradient bytes the exchange covers",
-            "wire_bytes": "bytes actually exchanged after the cast",
-            "bucket_wire_bytes": "per-bucket wire bytes, issue order "
-                                 "(same bucket plan as comm_overlap's "
-                                 "bucket_bytes)",
-            "wire_ratio": "wire_bytes / grad_bytes (0.5 for bf16/fp16)",
-            "gather_wire_bytes": "ZeRO-1 param-update all-gather wire "
-                                 "bytes per bucket (comm.overlap + "
-                                 "zero1 composition only)",
         },
     },
     "corrupt_record": {
@@ -806,47 +661,24 @@ EVENT_SCHEMAS = {
         },
     },
     "plan": {
-        "emitted_by": "telemetry/planner.py (main.py plan, and the chief "
-                      "at run start when the drift sentinel arms; "
+        "emitted_by": "telemetry/planner.py (main.py plan --root; "
                       "docs/planner.md)",
         "fields": {
             "preset": "preset the prediction is for",
             "layout": "layout name (dp | dp_fsdp | dp_tp | dp_pp | "
                       "dp_pp_ep)",
             "devices": "global device count the prediction assumes",
-            "knobs": "knob dict {precision, zero1, compress, bucket_mb, "
-                     "accum} the prediction assumes",
+            "knobs": "knob dict {precision, zero1} the prediction "
+                     "assumes",
             "predicted": "{step_secs, compute_secs, comm_secs, "
-                         "comm_exposed_secs, comm_fraction, "
+                         "comm_fraction, "
                          "hbm_bytes, wire_bytes} — the cost model's "
                          "output (telemetry/planner.py)",
             "bandwidth_source": "'catalog' (results/bandwidth/"
-                                "<fabric>.json), 'reference' (baked-in "
-                                "table) or 'probe' (live comm_timing)",
+                                "<fabric>.json) or 'reference' (baked-in "
+                                "table)",
             "recommended": "true on the row for the layout main.py plan "
-                           "ranked first (plan rows from a live run "
-                           "describe the running layout and omit this)",
-        },
-    },
-    "plan_drift": {
-        "emitted_by": "train/hooks.py PlanDriftHook (sustained "
-                      "predicted-vs-measured divergence beyond "
-                      "telemetry.plan_tolerance; docs/planner.md)",
-        "fields": {
-            "step": "step at detection",
-            "metric": "which observable diverged (step_secs | comm_secs "
-                      "| hbm_bytes)",
-            "predicted": "the cost model's value for this run's layout",
-            "measured": "the live value (heartbeat step EWMA / "
-                        "comm_timing probe total / memory row peak)",
-            "ratio": "measured / predicted (>= 1: slower/bigger than "
-                     "predicted; the sentinel fires on either side of "
-                     "tolerance)",
-            "tolerance": "telemetry.plan_tolerance the ratio exceeded",
-            "windows": "consecutive divergent checks before firing "
-                       "(telemetry.plan_drift_window)",
-            "dump": "trace.json path when the flight recorder dumped "
-                    "(absent when tracing is off)",
+                           "ranked first",
         },
     },
 }
@@ -986,9 +818,8 @@ class LatencyStats:
 
     The serving path (serve/server.py) records one sample per request keyed
     by its dispatch bucket; ``summary_ms`` is what the ``serve_request``
-    metrics rows, ``bench.py``'s serving row and the ``main.py serve``
-    report all read — one implementation so p50/p99 can't be computed three
-    different ways. Samples are capped (default 200k ≈ hours of smoke-load
+    metrics rows and the ``main.py serve`` report both read — one
+    implementation so p50/p99 can't be computed two different ways. Samples are capped (default 200k ≈ hours of smoke-load
     serving) to bound memory on long-lived servers; past the cap each new
     sample overwrites a deterministic pseudo-random slot, so the buffer
     becomes a RECENCY-WEIGHTED window (~the last cap samples; older ones
@@ -1107,7 +938,7 @@ def metric_stream_dirs(root: str, filename: str = "metrics.jsonl"):
 def iter_metric_streams(root: str, filename: str = "metrics.jsonl"):
     """Yield the rows of every metrics stream under ``root``, tolerant
     of torn lines and vanished files — the offline reducers' read path
-    (`main.py trace-merge` / `comm-report`)."""
+    (`main.py trace-merge`)."""
     for d in metric_stream_dirs(root, filename):
         try:
             yield read_metrics(d, filename=filename, tolerant=True)

@@ -38,15 +38,14 @@
 # later. Scoped runs (--lint-only, --preset, --no-*) enforce the same
 # ceiling trivially.
 #
-# Budget history: the original <120 s contract was measured against the
-# pre-universal-envelope gate (~105 s) and TRIPPED at HEAD on a loaded
-# container (129 s, 0 findings — wall time on this box drifts ~2x under
-# concurrent load for identical code). The universal overlap envelope
-# (ISSUE 15) legitimately grew coverage — transformer-family overlap /
-# compress traces, the vit_moe preset, accumulation schedules, int8
-# variant traces — to a measured ~160-200 s full gate. 300 s = measured
-# unloaded time + the observed load drift; raise it only with a matching
-# measurement, and look at the per-phase echo before blaming the budget.
+# Budget history: the original <120 s contract TRIPPED at HEAD on a
+# loaded container (129 s, 0 findings — wall time on this box drifts ~2x
+# under concurrent load for identical code); with the bucketed-exchange
+# traces the full gate measured ~160-260 s and the budget became 300 s.
+# Those traces went in PR 31 (the gate measured 168 s after it: elaborate
+# 107 s, the schedule phase about 45 s).
+# Raise the budget only with a matching measurement, and look at the
+# per-phase echo before blaming it.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 

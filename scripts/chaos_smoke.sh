@@ -35,49 +35,11 @@ if [[ "${1:-}" == "--fast" ]]; then
   # passes through (e.g. --no-hangcheck, mirroring --no-zero1-sweep)
   scripts/analysis_gate.sh ${ANALYSIS_GATE_ARGS:-}
   # opt-in observability stage (OBS_SMOKE=1): the slow-peer perf-anomaly
-  # + trace-merge + comm-report end-to-end (scripts/obs_smoke.sh, ~2 min
+  # + trace-merge end-to-end (scripts/obs_smoke.sh, ~2 min
   # of live 2-process training — too heavy for the default seconds-fast
   # gate, which is why it is opt-in)
   if [[ "${OBS_SMOKE:-0}" == "1" ]]; then
     scripts/obs_smoke.sh
-  fi
-  # gate-adjacent overlap family sweep (OVERLAP_SWEEP=0 opts out): the
-  # bench --overlap-ab family legs (conv dp / vit dp_tp / moe dp_pp_ep /
-  # conv accum=4) on the virtual 8-device mesh — a regression in any
-  # newly in-envelope exchange (a leg erroring, wire bytes no longer 1×
-  # per step under accumulation) surfaces pre-submit instead of on a
-  # cluster. ~2-3 min CPU; the result JSON is printed for the log.
-  if [[ "${OVERLAP_SWEEP:-1}" == "1" ]]; then
-    env JAX_PLATFORMS=cpu \
-        XLA_FLAGS="${XLA_FLAGS:-} --xla_force_host_platform_device_count=8" \
-        python bench.py --overlap-ab | tail -1 | python -c '
-import json, sys
-d = json.loads(sys.stdin.read())
-fams = d["families"]
-bad = [k for k, v in fams.items()
-       if "error" in v.get("on", {}) or "error" in v.get("off", {})]
-accum = fams["conv_dp_accum4"]["on"]
-assert not bad, f"overlap family legs failed: {bad}: {fams}"
-# wire per optimizer step must equal the gradient bytes ONCE (grad_bytes
-# is recorded independently from the leaf sizes) — the 1x-per-step
-# contract; the static witness that no per-microbatch exchange sneaks
-# back in is the overlap+accumN hangcheck schedule in the gate above
-assert accum["accum_steps"] == 4 and \
-    accum["wire_bytes_per_step"] == accum["grad_bytes"], accum
-# hierarchical A/B leg (ISSUE 18): the staged exchange must trace on the
-# factored virtual mesh (2 "hosts" x 4 devices) and its inter-tier wire
-# must drop to ~1/4 of the flat leg (pad-tolerant 3x bound)
-hier = d["hierarchy"]
-assert "error" not in hier, f"hierarchy leg failed: {hier}"
-assert hier["intra_k"] == 4, hier
-assert hier["inter_wire_bytes"] * 3 < hier["flat_inter_wire_bytes"], hier
-print("overlap family sweep OK:",
-      {k: v.get("on_vs_off") for k, v in fams.items()})
-print("hierarchy leg OK:",
-      {k: hier[k] for k in ("intra_k", "inter_wire_bytes",
-                            "flat_inter_wire_bytes", "hier_vs_flat_steps")})
-print(json.dumps(fams))
-'
   fi
 fi
 

@@ -10,7 +10,7 @@
 #     dispatch sanitizer ARMED,
 #   * asserts from metrics.jsonl that the {"event": "input_stages"} rows
 #     show more than one busy decode worker and the {"event": "input_echo"}
-#     rows show echo hits > 0 — the telemetry contract bench.py's
+#     rows show echo hits > 0 — the telemetry contract the input
 #     attribution is built on.
 #
 #   scripts/input_smoke.sh            # full smoke

@@ -16,9 +16,8 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# tests must not write into the tracked tree: the bandwidth catalog
-# (telemetry/bandwidth.py) defaults to results/bandwidth/ in the checkout,
-# and every train run with the comm probe on folds its samples in.
+# tests must not read or write the tracked tree: the bandwidth catalog
+# (telemetry/bandwidth.py) defaults to results/bandwidth/ in the checkout.
 # Environment, not a fixture: subprocess tests inherit it.
 _BANDWIDTH_TMP = tempfile.TemporaryDirectory(prefix="drt-bandwidth-")
 os.environ["DRT_BANDWIDTH_DIR"] = _BANDWIDTH_TMP.name

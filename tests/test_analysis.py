@@ -492,28 +492,6 @@ def test_ckpt_io_rule_scopes_manager_to_writer_fn(tmp_path):
     assert (rel, 5) not in hits
 
 
-def test_elaborator_traces_bucketed_overlap_step(devices):
-    """The gate traces the comm.overlap=on variant of every in-envelope
-    preset × layout (elab-overlap-step): a clean conv preset elaborates
-    without findings, and the trace actually ran (the plan registry is
-    populated by the shard_map trace)."""
-    from distributed_resnet_tensorflow_tpu.analysis.elaborate import (
-        elaborate_config)
-    from distributed_resnet_tensorflow_tpu.parallel.overlap import (
-        overlap_stats)
-    from distributed_resnet_tensorflow_tpu.utils.config import (
-        MeshConfig, get_preset)
-    cfg = get_preset("cifar10_resnet50")
-    cfg.model.resnet_size = 8
-    cfg.data.image_size = 8
-    cfg.train.batch_size = 16
-    overlap_stats.reset()
-    findings = elaborate_config(cfg, MeshConfig(data=4, fsdp=2),
-                                "fixture@dp_fsdp")
-    assert [f for f in findings if f.rule == "elab-overlap-step"] == [], \
-        [f.message for f in findings]
-    assert overlap_stats.snapshot() is not None
-
 # ---------------------------------------------------------------------------
 # unsharded-opt-state rule + elab-zero1 big-mesh sweep (ISSUE 11)
 # ---------------------------------------------------------------------------

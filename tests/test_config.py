@@ -95,3 +95,25 @@ def test_bs512_throughput_preset():
     # epoch budget preserved: steps x batch equal
     assert cfg.train.train_steps * cfg.train.batch_size == \
         base.train.train_steps * base.train.batch_size
+
+
+@pytest.mark.parametrize("key", [
+    "comm.overlap", "comm.bucket_mb", "comm.compress", "comm.hierarchy",
+    "comm.intra_axis_size", "comm.autotune",
+    "telemetry.comm_timing", "telemetry.comm_timing_reps",
+    "telemetry.plan_drift", "telemetry.plan_tolerance",
+    "telemetry.plan_drift_window", "telemetry.plan_drift_cooldown_secs",
+])
+def test_a_removed_key_is_refused(key):
+    """The bucketed gradient exchange went with its six ``comm.*`` knobs
+    and the probe and drift sentinel that read its plan: an old command
+    line or a saved ``--config_json`` that still sets one fails with the
+    loader's own unknown-key error, as any misspelt key does."""
+    section, name = key.split(".")
+    with pytest.raises(KeyError, match="unknown config key"):
+        parse_args(["--preset", "smoke", "--set", f"{key}=1"])
+    d = get_preset("smoke").to_dict()
+    assert name not in d.get(section, {})
+    d.setdefault(section, {})[name] = 1
+    with pytest.raises(KeyError, match="unknown config key"):
+        ExperimentConfig.from_dict(d)

@@ -1,8 +1,8 @@
 """Input-pipeline stage telemetry tests (fast, `-m 'not slow'` CI smoke).
 
-The overlapped input pipeline's attribution (bench.py imagenet_input,
+The overlapped input pipeline's attribution (the benchmark's stage_ms,
 docs/input_pipeline.md) is computed FROM the stage counters in
-utils.metrics.input_stages — if those counters silently rot, the bench
+utils.metrics.input_stages — if those counters silently rot, the benchmark
 would keep printing an attribution built on nothing. This suite pins the
 contract: counters populate during real training, are monotone, and export
 through MetricsWriter/InputStagesHook to metrics.jsonl.
@@ -56,8 +56,8 @@ def test_stage_stats_per_thread_rate_estimate():
 def test_pipeline_counters_populated_and_monotone():
     """The CI tripwire for attribution telemetry: a real (tiny) training
     run must populate the staging counters, and they must be monotone in
-    work done — so bench.py's counter-based attribution can't silently
-    read an empty registry."""
+    work done — so a counter-based attribution can't silently read an
+    empty registry."""
     from distributed_resnet_tensorflow_tpu.data import (
         learnable_synthetic_iterator)
     from distributed_resnet_tensorflow_tpu.train import Trainer

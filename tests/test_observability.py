@@ -1,6 +1,5 @@
 """Performance-observability plane (ISSUE 14): cluster trace merge with
-heartbeat-estimated clock offsets, per-collective runtime attribution
-(comm-report's static↔runtime join), device-memory telemetry rows, the
+heartbeat-estimated clock offsets, device-memory telemetry rows, the
 watchdog's perf-anomaly sentinel, and the monitor's windowed steps/s +
 per-host HBM watermark rollup. The live 2-process leg is
 scripts/obs_smoke.sh; everything here is deterministic and fast."""
@@ -13,14 +12,13 @@ import pytest
 
 from distributed_resnet_tensorflow_tpu.resilience.heartbeat import (
     BeatTransport)
-from distributed_resnet_tensorflow_tpu.telemetry import comm_report, merge
+from distributed_resnet_tensorflow_tpu.telemetry import merge
 from distributed_resnet_tensorflow_tpu.telemetry.memory import (
     MemoryWatermarks, sample_memory, watermarks)
 from distributed_resnet_tensorflow_tpu.telemetry.tracer import recorder
 from distributed_resnet_tensorflow_tpu.utils.config import (
     TelemetryConfig, WatchdogConfig)
-from distributed_resnet_tensorflow_tpu.utils.metrics import (
-    LatencyStats, comm_timing_stats)
+from distributed_resnet_tensorflow_tpu.utils.metrics import LatencyStats
 
 
 class FakeWriter:
@@ -135,16 +133,15 @@ def test_trace_merge_cli_writes_valid_perfetto_json(tmp_path, capsys):
         0, 1000.0, [{"name": "train.step", "ph": "X", "pid": 1,
                      "tid": 1, "ts": 10.0, "dur": 5.0}])))
     (t_dir / "trace.proc1.json").write_text(json.dumps(_trace_doc(
-        1, 1001.0, [{"name": "comm.bucket", "ph": "X", "pid": 1,
-                     "tid": 1, "ts": 10.0, "dur": 5.0,
-                     "args": {"bucket": 0}}])))
+        1, 1001.0, [{"name": "train.hooks", "ph": "X", "pid": 1,
+                     "tid": 1, "ts": 10.0, "dur": 5.0}])))
     rc = merge.main_trace_merge(["--root", str(tmp_path)])
     assert rc == 0
     out_path = tmp_path / "telemetry" / "trace.merged.json"
     doc = json.load(open(out_path))  # valid Perfetto/Chrome-trace JSON
     assert doc["otherData"]["merged"] is True
     assert {e["name"] for e in doc["traceEvents"] if e.get("ph") == "X"} \
-        == {"train.step", "comm.bucket"}
+        == {"train.step", "train.hooks"}
     # re-merge is idempotent: the merged output is not a merge input
     assert str(out_path) not in merge.find_traces(str(tmp_path))
     assert merge.main_trace_merge(["--root", str(tmp_path)]) == 0
@@ -153,121 +150,6 @@ def test_trace_merge_cli_writes_valid_perfetto_json(tmp_path, capsys):
 
 def test_trace_merge_cli_fails_loudly_on_empty_root(tmp_path):
     assert merge.main_trace_merge(["--root", str(tmp_path)]) == 1
-
-
-# ---------------------------------------------------------------------------
-# comm-report: the static↔runtime join
-# ---------------------------------------------------------------------------
-
-def _timing_row(step_secs=0.01):
-    return {
-        "buckets": [
-            {"bucket": 0, "bytes": 100, "wire_bytes": 100, "leaves": 5,
-             "probe_secs": 0.002, "wire_bytes_per_sec": 50000.0},
-            {"bucket": 1, "bytes": 50, "wire_bytes": 50, "leaves": 3,
-             "probe_secs": 0.001, "wire_bytes_per_sec": 50000.0},
-        ],
-        "comm_secs_total": 0.003, "reps": 3, "axes": ["data", "fsdp"],
-        "compress": "off", "step_secs": step_secs,
-    }
-
-
-def _signatures():
-    return {"p@dp_fsdp/overlap": {"ops": [
-        {"op": "psum", "axes": ["data", "fsdp"], "bytes": 100,
-         "count": 1, "operands": 5},
-        {"op": "psum", "axes": ["data", "fsdp"], "bytes": 50,
-         "count": 1, "operands": 3},
-        {"op": "psum", "axes": ["data", "fsdp"], "bytes": 4,
-         "count": 2, "operands": 1},
-    ]}}
-
-
-def test_comm_report_joins_static_schedule_with_measured_buckets():
-    report = comm_report.build_report(
-        _timing_row(), signatures=_signatures(),
-        step_secs_off=0.0085)
-    assert report["schedule_key"] == "p@dp_fsdp/overlap"
-    assert report["schedule_matched"] == 2
-    for b in report["buckets"]:
-        assert b["static"]["kind"] == "psum"
-        assert b["static"]["axes"] == ["data", "fsdp"]
-    assert report["buckets"][0]["pct_of_comm"] == pytest.approx(66.67,
-                                                               abs=0.1)
-    assert report["buckets"][1]["pct_of_comm"] == pytest.approx(33.33,
-                                                               abs=0.1)
-    assert report["bottleneck_bucket"] == 0
-    assert report["comm_step_ratio"] == pytest.approx(0.3)
-    # exposed = 10ms − 8.5ms = 1.5ms of the 3ms exchange → half hidden
-    assert report["overlap_fraction"] == pytest.approx(0.5)
-    text = comm_report.render(report)
-    assert "psum@data,fsdp" in text and "bottleneck: bucket 0" in text
-
-
-def test_comm_report_measured_only_without_matching_schedule():
-    timing = _timing_row()
-    timing["buckets"][0]["wire_bytes"] = 999  # no schedule op matches
-    report = comm_report.build_report(timing, signatures=_signatures())
-    assert report["schedule_key"] is None
-    assert report["buckets"][0].get("static") is None
-    assert "measured-only" in comm_report.render(report)
-
-
-def test_comm_report_ambiguous_schedule_reports_candidates():
-    sigs = _signatures()
-    sigs["q@dp_fsdp/overlap"] = sigs["p@dp_fsdp/overlap"]
-    key, candidates = comm_report.select_schedule_key(
-        sigs, _timing_row()["buckets"])
-    assert key is None and sorted(candidates) == \
-        ["p@dp_fsdp/overlap", "q@dp_fsdp/overlap"]
-    # an explicit key disambiguates; a bogus one fails loudly
-    report = comm_report.build_report(_timing_row(), signatures=sigs,
-                                      key="q@dp_fsdp/overlap")
-    assert report["schedule_key"] == "q@dp_fsdp/overlap"
-    with pytest.raises(KeyError):
-        comm_report.build_report(_timing_row(), signatures=sigs,
-                                 key="nope")
-
-
-def test_comm_report_selects_compressed_schedule_variant():
-    """comm.compress halves the measured wire bytes, which only the
-    committed ``.../bf16+compress`` signature carries — the candidate
-    filter must not exclude compressed-exchange variants."""
-    sigs = {"p@dp_fsdp/bf16+compress": {"ops": [
-        {"op": "psum", "axes": ["data", "fsdp"], "bytes": 50,
-         "count": 1, "operands": 5}]}}
-    timing = {"buckets": [
-        {"bucket": 0, "bytes": 100, "wire_bytes": 50, "leaves": 5,
-         "probe_secs": 0.001, "wire_bytes_per_sec": 50000.0}],
-        "comm_secs_total": 0.001, "reps": 3, "axes": ["data"],
-        "compress": "bf16"}
-    report = comm_report.build_report(timing, signatures=sigs)
-    assert report["schedule_key"] == "p@dp_fsdp/bf16+compress"
-    assert report["schedule_matched"] == 1
-    assert report["buckets"][0]["static"]["kind"] == "psum"
-
-
-def test_comm_report_cli_end_to_end(tmp_path, capsys):
-    _write_stream(str(tmp_path / "train"), [
-        {"event": "comm_overlap", "time": 10.0, "step": 100,
-         "buckets": 2, "bucket_cap_bytes": 262144, "grad_bytes": 150,
-         "wire_bytes": 150, "leaves": 8},
-        {"event": "comm_timing", "time": 11.0, "step": 100,
-         **_timing_row()},
-    ])
-    sched = tmp_path / "schedules.json"
-    sched.write_text(json.dumps({"signatures": _signatures()}))
-    rc = comm_report.main_comm_report(
-        ["--root", str(tmp_path), "--schedules", str(sched),
-         "--step-secs-off", "0.0085"])
-    assert rc == 0
-    out = capsys.readouterr().out
-    assert "p@dp_fsdp/overlap" in out and "overlap fraction" in out
-
-
-def test_comm_report_cli_without_rows_exits_nonzero(tmp_path, capsys):
-    assert comm_report.main_comm_report(["--root", str(tmp_path)]) == 1
-    assert "no comm_timing row" in capsys.readouterr().out
 
 
 # ---------------------------------------------------------------------------
@@ -304,40 +186,6 @@ def test_memory_hook_exports_registered_rows():
     assert w.events and w.events[0]["event"] == "memory"
     assert "live_bytes_total" in w.events[0]
     assert w.events[0]["step"] == 1
-
-
-# ---------------------------------------------------------------------------
-# comm-timing hook (the probe's exporter)
-# ---------------------------------------------------------------------------
-
-def test_comm_timing_hook_exports_once_per_rate_change(monkeypatch):
-    from distributed_resnet_tensorflow_tpu.train import hooks as hooks_mod
-    clock = FakeClock(t=100.0)
-    monkeypatch.setattr(hooks_mod.time, "monotonic", clock)
-    comm_timing_stats.reset()
-    try:
-        w = FakeWriter()
-        hook = hooks_mod.CommTimingHook(w, every_steps=1)
-        hook(1, None, {})
-        assert w.events == []  # the probe has not run yet
-        comm_timing_stats.record(
-            _timing_row()["buckets"], 0.003, 3, ["data"], "off")
-        clock.t += 1.0
-        hook(2, None, {})  # probe data + the first measured rate pair
-        assert len(w.events) == 1
-        assert w.events[0]["event"] == "comm_timing"
-        assert w.events[0]["comm_secs_total"] == pytest.approx(0.003)
-        assert w.events[0]["step_secs"] == pytest.approx(1.0)
-        assert w.events[0]["comm_step_ratio"] == pytest.approx(0.003)
-        clock.t += 1.0
-        hook(3, None, {})  # same quantized rate → the change gate holds
-        assert len(w.events) == 1
-        clock.t += 2.0
-        hook(4, None, {})  # the rate MOVED → re-export
-        assert len(w.events) == 2
-        assert w.events[1]["step_secs"] == pytest.approx(2.0)
-    finally:
-        comm_timing_stats.reset()
 
 
 # ---------------------------------------------------------------------------
@@ -632,10 +480,10 @@ def test_latency_stats_under_cap_keeps_every_sample():
 
 
 # ---------------------------------------------------------------------------
-# CLI dispatch (main.py trace-merge / comm-report)
+# CLI dispatch (main.py trace-merge)
 # ---------------------------------------------------------------------------
 
-def test_main_dispatches_trace_merge_and_comm_report(tmp_path):
+def test_main_dispatches_trace_merge(tmp_path):
     from distributed_resnet_tensorflow_tpu import main as main_mod
     t_dir = tmp_path / "telemetry"
     t_dir.mkdir()
@@ -645,6 +493,3 @@ def test_main_dispatches_trace_merge_and_comm_report(tmp_path):
     with pytest.raises(SystemExit) as e:
         main_mod.main(["trace-merge", "--root", str(tmp_path)])
     assert e.value.code == 0
-    with pytest.raises(SystemExit) as e:
-        main_mod.main(["comm-report", "--root", str(tmp_path)])
-    assert e.value.code == 1  # no comm_timing rows in this root

@@ -1,5 +1,5 @@
 """Pallas kernel tests (interpret mode on CPU; the same kernels compile for
-TPU where bench.py exercises them)."""
+TPU where chip_smoke.py exercises them)."""
 import jax
 import jax.numpy as jnp
 import numpy as np

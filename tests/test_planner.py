@@ -3,21 +3,14 @@
 The load-bearing claims, pinned here:
 
 * the analytic cost model is internally consistent over the COMMITTED
-  collective schedules (step = compute + exposed, accumulation scales
-  compute, compression narrows wire bytes, rankings sort), and the
-  probe-fed prediction of a LIVE virtual-8 bucketed leg lands inside
-  the documented ``telemetry.plan_tolerance`` band of the measured
-  step — the same band the drift sentinel enforces;
+  collective schedules (step = compute + scheduled comm, rankings sort);
 * ``analysis/plan_catalog.json`` is byte-identical across consecutive
   gate runs AND matches the committed file (the artifact must only
   ever diff on a real model/schedule change);
-* a seeded bandwidth-table lie is caught twice over: statically by the
-  gate's catalog-vs-micro-probe cross-check, and live by the
-  DriftSentinel — which fires exactly ONCE per divergence episode,
-  with a cooldown;
-* the bandwidth catalog round-trips probe measurements (merge-best),
-  and ``tools/bench_trajectory.py`` joins the BENCH rounds with
-  correct per-key deltas.
+* a seeded bandwidth-table lie is caught by the gate's
+  catalog-vs-micro-probe cross-check;
+* a bandwidth catalog placed for a fabric loads, tier rows and version-1
+  documents included, and its lookups fall back as documented.
 """
 import json
 import os
@@ -25,11 +18,9 @@ import os
 import numpy as np
 import pytest
 
-import jax
-
-from distributed_resnet_tensorflow_tpu.telemetry import planner
-from distributed_resnet_tensorflow_tpu.telemetry.comm_report import (
+from distributed_resnet_tensorflow_tpu.analysis.collectives import (
     load_schedules)
+from distributed_resnet_tensorflow_tpu.telemetry import planner
 from distributed_resnet_tensorflow_tpu.utils.config import (MeshConfig,
                                                             get_preset)
 
@@ -98,42 +89,24 @@ def test_plan_consistency_over_committed_schedules(signatures):
         assert cands, preset
         for key, c in cands.items():
             assert np.isfinite(c["step_secs"]) and c["step_secs"] > 0
-            assert c["comm_exposed_secs"] <= c["comm_secs"] + 1e-12
             assert c["step_secs"] == pytest.approx(
-                c["compute_secs"] + c["comm_exposed_secs"], rel=1e-6)
+                c["compute_secs"] + c["comm_secs"], rel=1e-6)
             assert 0.0 <= c["comm_fraction"] <= 1.0
         # ranking is by predicted step time
         steps = [cands[k]["step_secs"] for k in plan["ranked"]]
         assert steps == sorted(steps)
-        # the recommendation compares overlap variants with each other
-        assert plan["recommended"].endswith("/overlap")
-
-
-def test_accum_and_compress_variants_scale_the_model(signatures):
-    plan = planner.plan_for_preset("cifar10_resnet50", signatures,
-                                   include_hbm=False)
-    c = plan["candidates"]
-    # accumulation multiplies the compute term, not the exchange
-    assert c["dp/overlap+accum4"]["compute_secs"] == pytest.approx(
-        4 * c["dp/overlap"]["compute_secs"], rel=1e-6)
-    assert c["dp/overlap+accum4"]["comm_secs"] == pytest.approx(
-        c["dp/overlap"]["comm_secs"], rel=1e-6)
-    # bf16 compression halves the exchange payload on the wire
-    assert c["dp_fsdp/bf16+compress"]["wire_bytes"] == pytest.approx(
-        c["dp/overlap"]["wire_bytes"] / 2, rel=0.1)
-    # the zero1 variant exists for the preset that pins the knob
-    lamb = planner.plan_for_preset("imagenet_resnet50_lamb4k",
-                                   signatures, include_hbm=False)
-    zero1 = [k for k in lamb["candidates"] if k.endswith("overlap+zero1")]
-    assert zero1 and all(
-        lamb["candidates"][k]["comm_secs"] > 0 for k in zero1)
+        assert plan["recommended"] == plan["ranked"][0]
 
 
 def test_vit_moe_plan_covers_transformer_layouts(signatures):
     plan = planner.plan_for_preset("vit_moe", signatures,
                                    include_hbm=False)
     layouts = {k.split("/", 1)[0] for k in plan["candidates"]}
-    assert {"dp", "dp_fsdp", "dp_tp", "dp_pp", "dp_pp_ep"} <= layouts
+    assert {"dp", "dp_tp", "dp_pp", "dp_pp_ep"} <= layouts
+    # what a pipelined step writes out (stage hand-offs, the expert
+    # all-to-all) is scheduled comm; the batch layout's exchange is XLA's
+    c = plan["candidates"]
+    assert c["dp_pp_ep/train"]["comm_secs"] > c["dp/train"]["comm_secs"] == 0
 
 
 def test_recommend_layout_returns_mesh(signatures):
@@ -142,180 +115,6 @@ def test_recommend_layout_returns_mesh(signatures):
     layout, mesh_cfg = rec
     assert hasattr(mesh_cfg, "data")
     assert planner.recommend_layout("no_such_preset") is None
-
-
-# ---------------------------------------------------------------------------
-# live virtual-8 leg: probe-fed prediction vs measured step
-# ---------------------------------------------------------------------------
-
-def _tiny_overlap_cfg():
-    cfg = get_preset("smoke")
-    cfg.model.compute_dtype = "float32"
-    cfg.model.resnet_size = 8
-    cfg.model.num_classes = 4
-    cfg.data.image_size = 8
-    cfg.train.batch_size = 16
-    cfg.comm.overlap = "on"
-    cfg.comm.bucket_mb = 0.05
-    cfg.optimizer.schedule = "constant"
-    cfg.checkpoint.save_every_secs = 0.0
-    return cfg
-
-
-@pytest.fixture(scope="module")
-def tiny_overlap_trainer(devices):
-    from distributed_resnet_tensorflow_tpu.parallel import create_mesh
-    from distributed_resnet_tensorflow_tpu.train import Trainer
-    cfg = _tiny_overlap_cfg()
-    tr = Trainer(cfg, mesh=create_mesh(MeshConfig(data=8)))
-    tr.init_state()
-    return cfg, tr
-
-
-def _batches(n, bs=16, size=8, classes=4):
-    rng = np.random.RandomState(7)
-    return [{"images": rng.randn(bs, size, size, 3).astype(np.float32),
-             "labels": rng.randint(0, classes, (bs,)).astype(np.int32)}
-            for _ in range(n)]
-
-
-def test_probe_fed_prediction_within_documented_tolerance(
-        tiny_overlap_trainer):
-    """The bench.py discipline (docs/planner.md 'Tolerances'): measured
-    compute + probe-fed bandwidths must predict the bucketed leg's step
-    inside the plan_tolerance band the live sentinel enforces."""
-    import time as _time
-    from distributed_resnet_tensorflow_tpu.parallel.overlap import (
-        overlap_stats, probe_comm_plan)
-    cfg, tr = tiny_overlap_trainer
-    state, _ = tr.train(iter(_batches(2)), num_steps=2)  # compile+warm
-    n = 6
-    t0 = _time.perf_counter()
-    state, _ = tr.train(iter(_batches(n)), num_steps=n)
-    jax.block_until_ready(state.params)
-    measured_step = (_time.perf_counter() - t0) / n
-
-    timing = probe_comm_plan(tr.mesh)
-    assert timing is not None and timing["buckets"]
-    bw = planner.BandwidthTable.from_probe(timing)
-    assert bw is not None and bw.source == "probe"
-    snap = overlap_stats.snapshot()
-    comm = 0.0
-    for wire, sig in zip(snap["bucket_wire_bytes"],
-                         snap["bucket_reduce_axes"]):
-        bps, lat = bw.lookup(sig)
-        comm += lat + int(wire) / bps
-    # CPU "compute" is the measured step itself net of the probed
-    # exchange — the off-leg substitution bench.py records
-    compute = max(measured_step - timing["comm_secs_total"], 1e-9)
-    exposed = max(0.0, comm - planner.OVERLAP_EFFICIENCY * compute)
-    predicted = compute + exposed
-    tol = cfg.telemetry.plan_tolerance
-    assert predicted / measured_step < tol
-    assert measured_step / predicted < tol
-
-
-def test_predict_live_builds_after_trace(tiny_overlap_trainer):
-    cfg, tr = tiny_overlap_trainer
-    pred = planner.predict_live(cfg, tr,
-                                bandwidth=planner.BandwidthTable
-                                .reference())
-    assert pred is not None
-    for k in ("step_secs", "compute_secs", "comm_secs",
-              "comm_exposed_secs", "comm_fraction", "wire_bytes",
-              "hbm_bytes"):
-        assert k in pred, k
-    assert pred["hbm_bytes"] >= pred["state_bytes"] > 0
-
-
-def test_plan_drift_hook_fires_once_on_seeded_bandwidth_lie(
-        tiny_overlap_trainer, tmp_path, monkeypatch):
-    """Satellite contract: a lying bandwidth table (comm predicted as
-    ~free, so the whole step is predicted orders of magnitude faster
-    than a CPU can step) must arm the sentinel and produce exactly ONE
-    plan_drift row per episode — plus the arming plan row."""
-    from distributed_resnet_tensorflow_tpu.train.hooks import (
-        PlanDriftHook)
-    from distributed_resnet_tensorflow_tpu.utils.metrics import (
-        MetricsWriter)
-    cfg, tr = tiny_overlap_trainer
-    monkeypatch.setattr(
-        planner, "measured_bandwidth_table",
-        lambda: planner.BandwidthTable(source="catalog",
-                                       axes={}, default_bps=1e18,
-                                       default_latency=0.0))
-    cfg.telemetry.plan_drift_window = 2
-    cfg.telemetry.plan_drift_cooldown_secs = 0.0
-    w = MetricsWriter(str(tmp_path), enable_tensorboard=False)
-    hook = PlanDriftHook(w, cfg, tr, every_steps=1)
-    n = 8
-    tr.train(iter(_batches(n)), num_steps=n, hooks=[hook])
-    w.flush()
-    w.close()
-    rows = [json.loads(l) for l in
-            open(os.path.join(str(tmp_path), "metrics.jsonl"))]
-    plan_rows = [r for r in rows if r.get("event") == "plan"]
-    drift_rows = [r for r in rows if r.get("event") == "plan_drift"]
-    assert len(plan_rows) == 1
-    assert plan_rows[0]["layout"] == "dp"
-    assert plan_rows[0]["bandwidth_source"] == "catalog"
-    # one episode, one firing — step_secs stays divergent the whole run
-    step_firings = [r for r in drift_rows if r["metric"] == "step_secs"]
-    assert len(step_firings) == 1
-    assert step_firings[0]["ratio"] > cfg.telemetry.plan_tolerance
-    assert step_firings[0]["windows"] >= cfg.telemetry.plan_drift_window
-
-
-# ---------------------------------------------------------------------------
-# DriftSentinel episode/cooldown semantics (fake clock)
-# ---------------------------------------------------------------------------
-
-def _sentinel(**kw):
-    clock = {"t": 0.0}
-    kw.setdefault("tolerance", 3.0)
-    kw.setdefault("window", 3)
-    kw.setdefault("cooldown_secs", 100.0)
-    s = planner.DriftSentinel({"step_secs": 1.0, "comm_secs": 0.01},
-                              clock=lambda: clock["t"], **kw)
-    return s, clock
-
-
-def test_sentinel_fires_exactly_once_per_episode():
-    s, _clock = _sentinel()
-    assert s.check("step_secs", 1.1) is None          # in tolerance
-    for _ in range(2):
-        assert s.check("step_secs", 10.0) is None     # streak building
-    firing = s.check("step_secs", 10.0)               # window reached
-    assert firing and firing["metric"] == "step_secs"
-    assert firing["ratio"] == pytest.approx(10.0)
-    for _ in range(20):                               # still divergent
-        assert s.check("step_secs", 10.0) is None     # episode: silent
-    assert s.check("step_secs", 1.0) is None          # episode ends
-    for _ in range(2):
-        assert s.check("step_secs", 10.0) is None
-
-
-def test_sentinel_cooldown_defers_but_does_not_lose_the_fire():
-    s, clock = _sentinel()
-    for _ in range(2):
-        s.check("step_secs", 10.0)
-    assert s.check("step_secs", 10.0)                 # fires at t=0
-    s.check("step_secs", 1.0)                         # episode ends
-    # new episode inside the cooldown: suppressed, streak kept
-    for _ in range(5):
-        assert s.check("step_secs", 10.0) is None
-    clock["t"] = 101.0                                # cooldown elapsed
-    assert s.check("step_secs", 10.0) is not None
-
-
-def test_sentinel_metrics_are_independent():
-    s, _clock = _sentinel(window=2)
-    s.check("comm_secs", 0.5)
-    assert s.check("comm_secs", 0.5)["metric"] == "comm_secs"
-    # step_secs' streak is untouched by comm's episode
-    s.check("step_secs", 10.0)
-    assert s.check("step_secs", 10.0) is None         # cooldown gates it
-    assert s.check("hbm_bytes", 1e12) is None         # not predicted
 
 
 # ---------------------------------------------------------------------------
@@ -376,57 +175,64 @@ def test_seeded_bandwidth_lie_is_a_gate_finding(tmp_path, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# bandwidth catalog round-trip
+# bandwidth catalog: version-2 tier rows load, version-1 documents still do
 # ---------------------------------------------------------------------------
 
-def test_catalog_roundtrip_and_merge_best(tmp_path, monkeypatch):
+def _row(bps, tier=None):
+    row = {"bytes_per_sec": bps, "latency_secs": 2e-4, "samples": 3,
+           "min_wire_bytes": 1024, "max_wire_bytes": 4096}
+    return {**row, "tier": tier} if tier else row
+
+
+def _place_catalog(tmp_path, monkeypatch, version, axes):
     from distributed_resnet_tensorflow_tpu.telemetry import bandwidth
     monkeypatch.setenv(bandwidth.DIR_ENV, str(tmp_path))
-    snap = {"buckets": [
-        {"bucket": 0, "bytes": 100, "wire_bytes": 100, "leaves": 1,
-         "axes": "data", "probe_secs": 2e-4,
-         "wire_bytes_per_sec": 5e5}],
-        "comm_secs_total": 2e-4, "reps": 3, "axes": ["data"],
-        "compress": "off"}
-    path = bandwidth.update_from_probe(snap)
-    assert path and os.path.exists(path)
-    doc = bandwidth.load_catalog(path)
-    assert doc["axes"]["data"]["bytes_per_sec"] == 5e5
-    assert doc["axes"]["data"]["samples"] == 1
-    # a better later probe wins; a worse one does not regress the entry
-    snap["buckets"][0]["wire_bytes_per_sec"] = 9e5
-    snap["buckets"][0]["probe_secs"] = 1e-4
-    bandwidth.update_from_probe(snap)
-    snap["buckets"][0]["wire_bytes_per_sec"] = 1e5
-    snap["buckets"][0]["probe_secs"] = 9e-4
-    bandwidth.update_from_probe(snap)
-    doc = bandwidth.load_catalog(path)
-    assert doc["axes"]["data"]["bytes_per_sec"] == 9e5
-    assert doc["axes"]["data"]["latency_secs"] == 1e-4
-    assert doc["axes"]["data"]["samples"] == 3
+    path = bandwidth.catalog_path("cpu-8")
+    assert os.path.dirname(path) == str(tmp_path)
+    with open(path, "w") as f:
+        json.dump({"schema_version": version, "fabric": "cpu-8",
+                   "platform": "cpu", "device_kind": "cpu", "devices": 8,
+                   "axes": axes}, f)
+    return bandwidth, bandwidth.load_catalog(fabric="cpu-8")
 
 
-def test_comm_report_synthesizes_from_catalog():
-    from distributed_resnet_tensorflow_tpu.telemetry.comm_report import (
-        synthesize_timing)
-    overlap_row = {"bucket_wire_bytes": [1000, 2000],
-                   "bucket_bytes": [1000, 2000],
-                   "bucket_leaves": [3, 4],
-                   "bucket_reduce_axes": ["data", "data+fsdp"],
-                   "compress": "off"}
-    catalog = {"schema_version": 1, "fabric": "cpu-8",
-               "axes": {"data": {"bytes_per_sec": 1e6,
-                                 "latency_secs": 1e-4}}}
-    timing = synthesize_timing(overlap_row, catalog)
-    assert timing["modeled_from_catalog"] == "cpu-8"
-    assert len(timing["buckets"]) == 2
-    assert all(b["modeled"] for b in timing["buckets"])
-    assert timing["comm_secs_total"] == pytest.approx(
-        2e-4 + 3000 / 1e6, rel=1e-6)
+def test_bandwidth_catalog_v2_tier_rows_roundtrip(tmp_path, monkeypatch):
+    bandwidth, doc = _place_catalog(tmp_path, monkeypatch, 2, {
+        "data+fsdp": _row(5e8),
+        "data+fsdp:intra": _row(1e9, "intra"),
+        "data+fsdp:inter": _row(6e7, "inter")})
+    assert doc["schema_version"] == bandwidth.SCHEMA_VERSION == 2
+    axes = doc["axes"]
+    assert axes["data+fsdp:intra"]["tier"] == "intra"
+    # tier-aware lookup: exact tier row; a tiered query without a tier
+    # row falls back to the flat base entry
+    assert bandwidth.lookup(doc, "data+fsdp:intra") is \
+        axes["data+fsdp:intra"]
+    assert bandwidth.lookup(doc, "data+expert:intra") is not None
+    del axes["data+fsdp:inter"]
+    assert bandwidth.lookup(doc, "data+fsdp:inter") is axes["data+fsdp"]
+    # the planner's table reads the same rows
+    table = planner.BandwidthTable.from_catalog(doc)
+    assert table.source == "catalog"
+    assert table.lookup("data+fsdp:intra") == (1e9, 2e-4)
+    assert table.lookup("data+fsdp:inter") == (5e8, 2e-4)
+
+
+def test_bandwidth_catalog_v1_document_still_loads(tmp_path, monkeypatch):
+    bandwidth, doc = _place_catalog(tmp_path, monkeypatch, 1,
+                                    {"data+fsdp": _row(5e8)})
+    assert doc is not None
+    assert bandwidth.lookup(doc, "data+fsdp")["bytes_per_sec"] == 5e8
+    # a tiered query on a v1 document answers with the flat row
+    assert bandwidth.lookup(doc, "data+fsdp:intra")["bytes_per_sec"] == 5e8
+    # an unreadable document is no catalog, not an error
+    with open(bandwidth.catalog_path("cpu-8"), "w") as f:
+        f.write("{")
+    assert bandwidth.load_catalog(fabric="cpu-8") is None
 
 
 # ---------------------------------------------------------------------------
-# main.py plan CLI + bench trajectory
+# main.py plan CLI
 # ---------------------------------------------------------------------------
 
 def test_main_plan_cli_ranks_three_presets(capsys):
@@ -456,41 +262,3 @@ def test_main_plan_writes_registered_rows(tmp_path, capsys):
     for r in plan_rows:
         assert {"preset", "layout", "devices", "knobs", "predicted",
                 "bandwidth_source", "recommended"} <= set(r)
-
-
-def _repo_root():
-    return os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def test_bench_trajectory_joins_rounds(tmp_path):
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "bench_trajectory",
-        os.path.join(_repo_root(), "tools", "bench_trajectory.py"))
-    bt = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bt)
-    for name, parsed in (
-            ("BENCH_r01.json", {"a": {"x": 10.0}, "ok": True}),
-            ("BENCH_r02.json", {}),                      # the r05 shape
-            ("BENCH_r03.json", {"a": {"x": 15.0}, "b": 2})):
-        with open(tmp_path / name, "w") as f:
-            json.dump({"n": 1, "rc": 0, "cmd": "x", "parsed": parsed}, f)
-    traj = bt.build_trajectory(bt.discover_rounds(str(tmp_path)))
-    rows = traj["rounds"]
-    assert [r["round"] for r in rows] == ["r01", "r02", "r03"]
-    assert rows[1]["parsed_empty"] is True
-    # the delta bridges the empty round to the last value seen
-    assert rows[2]["deltas"]["a.x"] == {"abs": 5.0, "pct": 50.0}
-    assert "ok" not in rows[0]["metrics"]  # bools are not magnitudes
-    # the one record left in the tree joins too: BENCH_r05, the older
-    # chip record (its payload was truncated, so nothing parsed)
-    real = bt.build_trajectory(bt.discover_rounds(_repo_root()))
-    assert [r["round"] for r in real["rounds"]] == ["r05"]
-    assert real["rounds"][0]["parsed_empty"] is True
-
-
-def test_monitor_bench_flag(capsys):
-    from distributed_resnet_tensorflow_tpu.telemetry.monitor import (
-        main_monitor)
-    assert main_monitor(["--bench"]) == 0
-    assert "bench trajectory" in capsys.readouterr().out

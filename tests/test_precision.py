@@ -1,21 +1,15 @@
-"""Low-precision hot paths (parallel/precision.py + comm.compress +
-serve variants; docs/precision.md).
+"""Low-precision hot paths (parallel/precision.py + serve variants;
+docs/precision.md).
 
 The load-bearing claims, pinned on the virtual 8-device mesh:
 
-  * with ``train.precision=off`` and ``comm.compress=off`` NOTHING
-    changes: the policy resolves to None, the model keeps its configured
-    compute dtype, the exchange carries f32 — and runs are bitwise
-    deterministic (the off path is byte-for-byte the pre-policy step; no
+  * with ``train.precision=off`` NOTHING changes: the policy resolves
+    to None, the model keeps its configured compute dtype — and runs are
+    bitwise deterministic (the off path is byte-for-byte the pre-policy step; no
     policy code touches it);
   * the bf16 step is allclose to the f32 oracle at the documented
     tolerances on dp AND dp_fsdp, for momentum and LAMB, with and
     without ZeRO-1 — while every persisted leaf stays an f32 MASTER;
-  * the compressed exchange is a pure WIRE change: many-vs-one-bucket
-    stays BIT-identical under compression (for both the gradient psum
-    leg and the ZeRO-1 scatter/gather composition), wire bytes halve on
-    the SAME bucket plan, and the result is allclose to the uncompressed
-    exchange;
   * checkpoints are policy-agnostic: an f32-master checkpoint written
     under a bf16 policy restores bit-exactly into an off-policy trainer
     (and vice versa), including the per-host sharded layout and the
@@ -33,12 +27,9 @@ import jax
 import jax.numpy as jnp
 
 from distributed_resnet_tensorflow_tpu.parallel import create_mesh
-from distributed_resnet_tensorflow_tpu.parallel.overlap import (
-    compress_dtype, overlap_stats)
 from distributed_resnet_tensorflow_tpu.parallel.precision import (
     check_master_dtypes, precision_stats, resolve_precision,
     resolve_serve_variants)
-from distributed_resnet_tensorflow_tpu.parallel.sharding import zero1_stats
 from distributed_resnet_tensorflow_tpu.train import Trainer
 from distributed_resnet_tensorflow_tpu.utils.config import (MeshConfig,
                                                             get_preset)
@@ -113,11 +104,11 @@ def test_precision_off_is_policy_free_and_deterministic(devices):
     resolver being the only entry point, this pins the off path to the
     pre-policy (PR 11) step."""
     cfg = _tiny_cfg()
-    assert cfg.train.precision == "off" and cfg.comm.compress == "off"
+    assert cfg.train.precision == "off"
     assert resolve_precision(cfg) is None
     batches = _fixed_batches()
     tr, _, a, m1 = _train(MeshConfig(data=8), batches)
-    assert not tr.precision_active and not tr.comm_compress_active
+    assert not tr.precision_active
     assert tr.model.dtype == jnp.float32  # configured dtype untouched
     _, _, b, m2 = _train(MeshConfig(data=8), batches)
     np.testing.assert_array_equal(a, b)
@@ -140,12 +131,7 @@ def test_fp16_step_refused_with_reason():
 
 @pytest.mark.parametrize("mesh_cfg,opt,zero1", [
     (MeshConfig(data=8), "momentum", "off"),
-    # momentum-dp_fsdp re-tiered out of the 870s tier-1 (ISSUE 19,
-    # ~11s): momentum-dp keeps the bf16-vs-f32 oracle claim in tier-1
-    # and the fsdp layout stays pinned by the overlap/zero1 exactness
-    # tests; the full (unfiltered) suite runs the layout cross
-    pytest.param(MeshConfig(data=4, fsdp=2), "momentum", "off",
-                 marks=pytest.mark.slow),
+    (MeshConfig(data=4, fsdp=2), "momentum", "off"),
     # lamb_zero1 legs re-tiered out of the 870s tier-1 (ISSUE 13): the
     # momentum legs pin the bf16-vs-f32 oracle; the LAMB×ZeRO-1
     # composition re-runs it with the heaviest optimizer and stays in
@@ -207,146 +193,7 @@ def test_bf16_step_allclose_remaining_matrix_dp(opt, zero1):
 
 
 # ---------------------------------------------------------------------------
-# compressed gradient exchange
-# ---------------------------------------------------------------------------
-
-@pytest.mark.slow  # re-tiered out of the 870s tier-1 (ISSUE 20, ~11s: two
-# full trainings under compress+overlap on dp_fsdp); tier-1 keeps the
-# compressed-wire contract via test_precision_and_compress_event_rows and
-# the bf16 numerics via the f32-oracle allclose tests; the full
-# (unfiltered) suite still runs this bucketing composition
-def test_compressed_exchange_bucketing_is_bit_identical(devices):
-    """The compression cast is per-leaf and commutes with bucketing:
-    many tiny buckets vs one giant bucket under comm.compress=bf16 must
-    produce BITWISE-equal params — compression narrows the wire, never
-    the scheduling-invariance contract. Runs on dp_fsdp so the
-    fsdp reduce-scatter leg compresses too; plain dp rides the zero1
-    composition test below."""
-    batches = _fixed_batches()
-    kw = {"comm.overlap": "on", "comm.compress": "bf16"}
-    mesh_cfg = MeshConfig(data=4, fsdp=2)
-    _, _, many, _ = _train(mesh_cfg, batches, **kw,
-                           **{"comm.bucket_mb": "0.05"})
-    plan = overlap_stats.snapshot()
-    assert plan["buckets"] > 1 and plan["compress"] == "bf16"
-    _, _, one, _ = _train(mesh_cfg, batches, **kw,
-                          **{"comm.bucket_mb": "4096"})
-    assert overlap_stats.snapshot()["buckets"] == 1
-    np.testing.assert_array_equal(many, one)
-
-
-# re-tiered out of the 870s tier-1 (ISSUE 19, ~14s: two full zero1
-# trainings). Each composed half stays pinned in tier-1 —
-# test_compressed_exchange_bucketing_is_bit_identical (compression ×
-# bucketing, fsdp leg) and test_zero1.py's overlap-bucketing bitwise
-# test (zero1 × bucketing, uncompressed); the full (unfiltered) suite
-# runs the triple composition.
-@pytest.mark.slow
-def test_compressed_exchange_zero1_composition_bit_identical(devices):
-    """Compression composed with the ZeRO-1 reduce-scatter AND the
-    bucketed param-update all-gather: still bitwise bucket-invariant."""
-    batches = _fixed_batches()
-    kw = {"comm.overlap": "on", "comm.compress": "bf16",
-          "optimizer.zero1": "on", "optimizer.zero1_min_size": "16"}
-    _, _, many, _ = _train(MeshConfig(data=8), batches, **kw,
-                           **{"comm.bucket_mb": "0.05"})
-    z1 = zero1_stats.snapshot()
-    assert z1["gather_compress"] == "bf16"
-    assert sum(z1["gather_wire_bytes"]) * 2 == \
-        sum(z1["gather_bucket_bytes"])
-    _, _, one, _ = _train(MeshConfig(data=8), batches, **kw,
-                          **{"comm.bucket_mb": "4096"})
-    np.testing.assert_array_equal(many, one)
-
-
-@pytest.mark.slow  # re-tiered out of the 870s tier-1 (~17s: three full bucketed-exchange trainings over one plan); runs in the full (unfiltered) suite
-def test_compressed_exchange_halves_wire_bytes_same_plan(devices):
-    """The acceptance claim, three runs over ONE bucket plan: (a) the
-    compressed exchange halves per-bucket wire bytes on the SAME plan
-    and stays allclose to the uncompressed exchange (bf16 wire rounding
-    only); (b) the bf16 POLICY composed with the bucketed exchange (the
-    shard_map body mirrors the jit path's policy cast) stays allclose to
-    the composed f32 step at the policy tolerance."""
-    batches = _fixed_batches()
-    kw = {"comm.overlap": "on", "comm.bucket_mb": "0.05"}
-    _, _, plain, m0 = _train(MeshConfig(data=8), batches, **kw)
-    base = overlap_stats.snapshot()
-    assert base["compress"] == "off"
-    assert base["wire_bytes"] == base["grad_bytes"]
-    _, _, comp, _ = _train(MeshConfig(data=8), batches, **kw,
-                           **{"comm.compress": "bf16"})
-    snap = overlap_stats.snapshot()
-    # same plan…
-    assert snap["bucket_bytes"] == base["bucket_bytes"]
-    assert snap["bucket_leaves"] == base["bucket_leaves"]
-    # …half the wire
-    assert snap["wire_bytes"] * 2 == snap["grad_bytes"]
-    assert all(w * 2 == b for w, b in zip(snap["bucket_wire_bytes"],
-                                          snap["bucket_bytes"]))
-    np.testing.assert_allclose(comp, plain, rtol=2e-2, atol=5e-3)
-    # (b) bf16 policy over the same bucketed exchange
-    tr, _, on, m1 = _train(MeshConfig(data=8), batches, **kw,
-                           **{"train.precision": "bf16"})
-    assert tr.precision_active and tr.comm_overlap_active
-    _assert_bf16_close(on, plain, "momentum", m1, m0)
-
-
-def test_compress_requires_overlap_warns_loudly(caplog, devices):
-    """The satellite fix: comm.compress with comm.overlap resolved off
-    must warn (compression rides the bucketed exchange — a silently
-    unbucketed run would never compress a byte)."""
-    import logging
-    cfg = _tiny_cfg(**{"comm.compress": "bf16"})  # overlap auto→off (1 proc)
-    with caplog.at_level(logging.WARNING,
-                         logger="distributed_resnet_tensorflow_tpu.train.loop"):
-        tr = Trainer(cfg, mesh=create_mesh(MeshConfig(data=8)))
-    assert not tr.comm_compress_active
-    assert any("comm.compress" in r.message and "overlap" in r.message
-               for r in caplog.records)
-    # unknown compress values are refused even with the exchange off
-    with pytest.raises(ValueError, match="comm.compress"):
-        compress_dtype(_tiny_cfg(**{"comm.compress": "int4"}))
-
-
-# re-tiered out of the 870s tier-1 (ISSUE 17, ~13s: the triple
-# composition). Each pairwise leg stays pinned in tier-1
-# (test_compressed_exchange_zero1_composition_bit_identical,
-# test_compressed_exchange_bucketing_is_bit_identical, the accum
-# bit-identity leg in test_overlap); the full (unfiltered) suite runs
-# compress×zero1×accum together.
-@pytest.mark.slow
-def test_compress_and_zero1_compose_with_accumulation(caplog, devices):
-    """The converted warning branch: gradient accumulation used to force
-    the exchange off (comm.compress/optimizer.zero1 then warned and ran
-    full-f32 replicated) — it is IN-envelope now, so the composition must
-    build silently, compress the ONE per-step exchange (wire = grad/2),
-    scatter into the ZeRO-1 shard update and gather back bucketed, with
-    many-vs-one-bucket still bitwise equal."""
-    import logging
-    batches = _fixed_batches()
-    kw = {"comm.overlap": "on", "comm.compress": "bf16",
-          "optimizer.zero1": "on", "train.grad_accum_steps": "2"}
-    with caplog.at_level(logging.WARNING,
-                         logger="distributed_resnet_tensorflow_tpu.train.loop"):
-        tr, _, many, m1 = _train(MeshConfig(data=8), batches, **kw,
-                                 **{"comm.bucket_mb": "0.05"})
-    assert tr.comm_overlap_active and tr.comm_compress_active \
-        and tr.zero1_active
-    assert not any("comm.compress" in r.message and "overlap" in
-                   r.message for r in caplog.records)
-    plan = overlap_stats.snapshot()
-    assert plan["accum_steps"] == 2 and plan["compress"] == "bf16"
-    assert plan["wire_bytes"] * 2 == plan["grad_bytes"]  # halved, 1×/step
-    z1 = zero1_stats.snapshot()
-    assert z1["gather_compress"] == "bf16" and z1["gather_buckets"] >= 1
-    _, _, one, m2 = _train(MeshConfig(data=8), batches, **kw,
-                           **{"comm.bucket_mb": "4096"})
-    np.testing.assert_array_equal(many, one)
-    assert float(m1["loss"]) == float(m2["loss"])
-
-
-# ---------------------------------------------------------------------------
-# checkpoints: f32 masters, policy-agnostic
+# checkpoints stay policy-agnostic
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("sharded", ["off", "on"], ids=["single", "sharded"])
@@ -577,39 +424,30 @@ def test_f32_variant_stays_full_precision_under_bf16_policy(tmp_path,
 
 
 # ---------------------------------------------------------------------------
-# telemetry: precision + comm_compress rows
+# telemetry: the precision row
 # ---------------------------------------------------------------------------
 
-def test_precision_and_compress_event_rows(tmp_path, devices):
-    from distributed_resnet_tensorflow_tpu.train.hooks import (
-        CommCompressHook, PrecisionHook)
+def test_precision_event_row(tmp_path, devices):
+    from distributed_resnet_tensorflow_tpu.train.hooks import PrecisionHook
     from distributed_resnet_tensorflow_tpu.utils.metrics import (
         MetricsWriter, read_metrics)
     precision_stats.reset()
-    overlap_stats.reset()
     batches = _fixed_batches(n=2)
-    cfg = _tiny_cfg(**{"train.precision": "bf16", "comm.overlap": "on",
-                       "comm.bucket_mb": "0.05", "comm.compress": "bf16"})
+    cfg = _tiny_cfg(**{"train.precision": "bf16"})
     tr = Trainer(cfg, mesh=create_mesh(MeshConfig(data=8)))
-    assert tr.precision_active and tr.comm_compress_active
+    assert tr.precision_active
     tr.init_state()
     w = MetricsWriter(str(tmp_path), enable_tensorboard=False)
-    hooks = (PrecisionHook(w, every_steps=1),
-             CommCompressHook(w, every_steps=1))
-    tr.train(iter(batches), num_steps=2, hooks=hooks)
+    tr.train(iter(batches), num_steps=2,
+             hooks=(PrecisionHook(w, every_steps=1),))
     w.close()
-    rows = read_metrics(str(tmp_path))
-    prows = [r for r in rows if r.get("event") == "precision"]
-    crows = [r for r in rows if r.get("event") == "comm_compress"]
+    prows = [r for r in read_metrics(str(tmp_path))
+             if r.get("event") == "precision"]
     assert len(prows) == 1        # one row per resolved policy
     assert prows[0]["policy"] == "bf16"
     assert prows[0]["compute_dtype"] == "bfloat16"
     assert prows[0]["master_dtype"] == "float32"
-    assert prows[0]["compress"] == "bf16"
     assert prows[0]["master_param_bytes"] > 0
-    assert len(crows) == 1        # one row per traced plan
-    assert crows[0]["wire_ratio"] == 0.5
-    assert crows[0]["wire_bytes"] * 2 == crows[0]["grad_bytes"]
 
 
 def test_precision_events_registered():
@@ -617,8 +455,7 @@ def test_precision_events_registered():
         SPAN_CATALOG)
     from distributed_resnet_tensorflow_tpu.utils.metrics import (
         EVENT_SCHEMAS)
-    for name in ("precision", "comm_compress"):
-        assert name in EVENT_SCHEMAS and EVENT_SCHEMAS[name]["fields"]
+    assert EVENT_SCHEMAS["precision"]["fields"]
     assert "serve.variant_build" in SPAN_CATALOG
 
 
@@ -628,15 +465,12 @@ def test_precision_events_registered():
 
 def test_large_batch_presets_carry_the_bf16_recipe():
     """The arXiv:1811.05233 recipe shape rides the large-batch presets:
-    bf16 step + compressed exchange; the accuracy-replay presets stay
-    f32-off (the oracle)."""
+    a bf16 step; the accuracy-replay presets stay f32-off (the oracle)."""
     for name in ("imagenet_resnet50_lars32k", "imagenet_resnet50_lars4k",
                  "imagenet_resnet50_lamb4k"):
         cfg = get_preset(name)
         assert cfg.train.precision == "bf16", name
-        assert cfg.comm.compress == "bf16", name
         assert resolve_precision(cfg) is not None
     for name in ("cifar10_resnet50", "imagenet_resnet50", "smoke"):
         cfg = get_preset(name)
         assert cfg.train.precision == "off", name
-        assert cfg.comm.compress == "off", name

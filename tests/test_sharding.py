@@ -2,6 +2,8 @@
 that replaces the reference's grpc PS and Horovod backends (SURVEY.md
 §2.8-2.9). Verifies the sharded step equals the single-device step: sync
 data parallelism by construction (what SyncReplicasOptimizer promised)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -81,52 +83,171 @@ def test_param_sharding_rule(mesh_dp_fsdp):
     assert param_sharding_rule("odd", (513, 1023), mesh_dp_fsdp) == P()
 
 
-@pytest.mark.heavy
-# re-tiered out of the 870s tier-1 (ISSUE 17, ~21s: the slowest single
-# test — a full sharded-vs-single-device training oracle). The sharded
-# step's numerics stay pinned in tier-1 by test_overlap's bucketed-vs-
-# default allclose legs and test_fsdp_state_sharding's spec checks; the
-# full (unfiltered) suite runs this oracle.
-@pytest.mark.slow
-def test_sharded_step_matches_single_device(mesh8):
-    """The crux: dp-sharded training step == serial step (sync DP exactness).
-    The reference could only approximate this promise through
-    SyncReplicasOptimizer's token machinery (reference resnet_model.py:102-135)."""
-    from distributed_resnet_tensorflow_tpu.train import Trainer
-    from distributed_resnet_tensorflow_tpu.data import learnable_synthetic_iterator
-
-    def build(mesh_cfg):
-        cfg = get_preset("smoke")
-        cfg.model.compute_dtype = "float32"
+def _oracle_cfg(family: str, accum: int):
+    """The smallest model of a family that still has every kind of leaf
+    its sharding rules name, float32, one optimizer step of 16 examples.
+    The ResNets are wide enough and the ViTs deep enough in the MLP that
+    one kernel crosses the fsdp rule's 2**16-element floor."""
+    cfg = get_preset("smoke")
+    cfg.model.compute_dtype = "float32"
+    cfg.model.num_classes = 4
+    cfg.data.image_size = 8
+    cfg.train.batch_size = 16
+    cfg.train.grad_accum_steps = accum
+    cfg.optimizer.schedule = "constant"
+    cfg.optimizer.learning_rate = 0.05
+    if family in ("resnet_bn", "resnet_gn"):
         cfg.model.resnet_size = 8
-        cfg.model.num_classes = 4
-        cfg.data.image_size = 8
-        cfg.train.batch_size = 16
-        cfg.optimizer.schedule = "constant"
-        cfg.mesh = mesh_cfg
-        return cfg
+        cfg.model.width_multiplier = 4
+        if family == "resnet_gn":
+            cfg.model.norm = "group"
+            cfg.model.gn_groups = 4
+    elif family == "logistic":
+        cfg.model.name = "logistic"
+        cfg.model.input_size = 8 * 8 * 3
+        cfg.model.hidden_units = 32
+    else:
+        cfg.model.name = "vit"
+        cfg.model.vit_patch_size = 4
+        cfg.model.vit_dim = 128
+        cfg.model.vit_depth = 2
+        cfg.model.vit_heads = 2
+        cfg.optimizer.name = "adam"
+        cfg.optimizer.learning_rate = 1e-3
+        cfg.optimizer.weight_decay = 0.0
+        if family == "vit_moe":
+            cfg.model.vit_num_experts = 2
+            # room for every token: the dispatch on an expert axis drops by
+            # device-local group, one device by the whole batch, and only a
+            # run without drops is the same function of the batch
+            cfg.model.vit_expert_capacity_factor = 4.0
+    return cfg
 
-    it = learnable_synthetic_iterator(16, 8, 4, seed=11)
-    batch = next(it)
 
-    tr1 = Trainer(build(MeshConfig(data=1)),
-                  mesh=create_mesh(MeshConfig(data=1),
-                                   devices=jax.devices()[:1]))
-    tr8 = Trainer(build(MeshConfig(data=8)), mesh=mesh8)
-    tr1.init_state(seed=0)
-    tr8.init_state(seed=0)
+_ORACLE_LAYOUTS = {
+    "dp": dict(data=8),
+    "dp_fsdp": dict(data=4, fsdp=2),
+    "dp_tp": dict(data=4, tensor=2),
+    "dp_pp": dict(data=2, pipeline=2),
+    "dp_pp_ep": dict(data=2, pipeline=2, expert=2),
+    "dp_ep": dict(data=4, expert=2),
+    "dp_sp": dict(data=4, sequence=2),
+    "dp_tp_pp": dict(data=2, tensor=2, pipeline=2),
+}
 
-    s1, m1 = tr1.jitted_train_step()(tr1.state, shard_batch(batch, tr1.mesh))
-    s8, m8 = tr8.jitted_train_step()(tr8.state, shard_batch(batch, tr8.mesh))
 
-    # forward/loss agree to fp exactness; parameters after one update agree
-    # up to gradient all-reduce reassociation noise (partial sums over 8
-    # devices reduce in a different order than one device — inherent fp32)
-    assert np.isclose(float(m1["loss"]), float(m8["loss"]), rtol=1e-6)
-    for a, b in zip(jax.tree_util.tree_leaves(s1.params),
-                    jax.tree_util.tree_leaves(s8.params)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-3, atol=5e-4)
+def _oracle_batch(cfg):
+    rng = np.random.RandomState(11)
+    shape = (16, cfg.model.input_size) if cfg.model.name == "logistic" \
+        else (16, 8, 8, 3)
+    return {"images": rng.randn(*shape).astype(np.float32),
+            "labels": rng.randint(0, 4, (16,)).astype(np.int32)}
+
+
+def _one_step(cfg, mesh, params=None):
+    """One optimizer step through ``jitted_train_step`` on ``mesh``:
+    (loss, params before, params after). ``params`` replaces the freshly
+    drawn ones (the optimizer state of step 0 holds no trace of them)."""
+    from distributed_resnet_tensorflow_tpu.train import Trainer
+    tr = Trainer(cfg, mesh=mesh)
+    tr.init_state(seed=0)
+    named = set()
+    for leaf in jax.tree_util.tree_leaves(tr.state.params):
+        for entry in leaf.sharding.spec:
+            named.update(entry if isinstance(entry, tuple) else (entry,))
+    # every axis of the layout but `data` shards some parameter: the
+    # comparison is of the layout the case names, not of replicas
+    assert {a for a, n in mesh.shape.items() if n > 1} - {"data", "seq"} \
+        <= named  # (a sequence axis shards activations alone)
+    if params is not None:
+        placed = jax.tree_util.tree_map(
+            lambda new, old: jax.device_put(np.asarray(new), old.sharding),
+            params, tr.state.params)
+        tr.state = tr.state.replace(params=placed)
+    before = jax.tree_util.tree_map(np.asarray, tr.state.params)
+    state, m = tr.jitted_train_step()(
+        tr.state, shard_batch(_oracle_batch(cfg), tr.mesh))
+    return float(m["loss"]), before, \
+        jax.tree_util.tree_map(np.asarray, state.params)
+
+
+@functools.lru_cache(maxsize=None)
+def _single_device_step(family, accum, moe_aux_weight):
+    """The oracle's side, once for the layouts that share it."""
+    cfg = _oracle_cfg(family, accum)
+    cfg.model.moe_aux_weight = moe_aux_weight
+    return _one_step(cfg, create_mesh(MeshConfig(data=1),
+                                      devices=jax.devices()[:1]))
+
+
+@pytest.mark.parametrize("family,layout,accum", [
+    ("resnet_bn", "dp", 1), ("resnet_bn", "dp", 2),
+    ("resnet_bn", "dp_fsdp", 1), ("resnet_bn", "dp_fsdp", 2),
+    ("resnet_gn", "dp", 1), ("resnet_gn", "dp", 2),
+    ("resnet_gn", "dp_fsdp", 1),
+    ("logistic", "dp", 1), ("logistic", "dp", 2),
+    ("vit", "dp", 1), ("vit", "dp", 2),
+    ("vit", "dp_fsdp", 1), ("vit", "dp_fsdp", 2),
+    ("vit", "dp_tp", 1), ("vit", "dp_tp", 2),
+    ("vit", "dp_pp", 1), ("vit", "dp_pp", 2),
+    # layouts the bucketed step's envelope refused, so that nothing ever
+    # compared them with anything: a sequence axis (ring attention),
+    # tensor beside pipeline, an expert axis without a pipeline
+    ("vit", "dp_sp", 1), ("vit", "dp_sp", 2),
+    ("vit", "dp_tp_pp", 1), ("vit", "dp_tp_pp", 2),
+    ("vit_moe", "dp", 1),
+    ("vit_moe", "dp_tp", 1), ("vit_moe", "dp_tp", 2),
+    ("vit_moe", "dp_pp_ep", 1), ("vit_moe", "dp_pp_ep", 2),
+    ("vit_moe", "dp_ep", 1), ("vit_moe", "dp_ep", 2),
+], ids=lambda v: str(v))
+def test_sharded_step_matches_single_device(devices, family, layout, accum):
+    """The crux: one training step on a mesh == the same step on one device
+    (sync data parallelism by construction; the reference could only
+    approximate it through SyncReplicasOptimizer's token machinery,
+    reference resnet_model.py:102-135). Every family, over every layout its
+    sharding rules serve, with and without gradient accumulation: the
+    loss to float rounding, the updated parameters up to the
+    reassociation of the gradient sum (eight partial sums reduce in
+    another order than one device adds)."""
+    axes = _ORACLE_LAYOUTS[layout]
+    count = int(np.prod(list(axes.values())))
+    mesh = create_mesh(MeshConfig(**axes), devices=jax.devices()[:count])
+    cfg = _oracle_cfg(family, accum)
+    if "pipeline" in axes:
+        cfg.model.vit_pipeline_microbatches = 2
+        if family == "vit_moe":
+            # a pipelined stage balances its experts over one microbatch
+            # (models/pipeline.py), one device over the whole batch: a
+            # different auxiliary loss by design, so it is left out here
+            cfg.model.moe_aux_weight = 0.0
+    loss1, before1, after1 = _single_device_step(
+        family, accum, cfg.model.moe_aux_weight)
+    if "pipeline" in axes:
+        # the pipelined encoder stacks its blocks' parameters
+        from distributed_resnet_tensorflow_tpu.models.pipeline import (
+            pack_encoder_params)
+
+        def packed(tree):
+            out = {k: v for k, v in tree.items()
+                   if not k.startswith("EncoderBlock_")}
+            out["encoder"] = jax.tree_util.tree_map(
+                np.asarray,
+                pack_encoder_params(tree, cfg.model.vit_depth))
+            return out
+
+        before1, after1 = packed(before1), packed(after1)
+    loss_n, _, after_n = _one_step(cfg, mesh, params=before1)
+    assert np.isclose(loss1, loss_n, rtol=1e-5), (loss1, loss_n)
+    flat1 = jax.tree_util.tree_leaves_with_path(after1)
+    flat_n = jax.tree_util.tree_leaves_with_path(after_n)
+    assert [p for p, _ in flat1] == [p for p, _ in flat_n]
+    moved = 0.0
+    for (path, a), (_, b), start in zip(
+            flat1, flat_n, jax.tree_util.tree_leaves(before1)):
+        np.testing.assert_allclose(b, a, rtol=1e-3, atol=5e-4,
+                                   err_msg=jax.tree_util.keystr(path))
+        moved = max(moved, float(np.abs(a - start).max()))
+    assert moved > 1e-4  # the step updated something to compare
 
 
 @pytest.mark.heavy

@@ -611,3 +611,85 @@ def test_evaluate_closes_staging_thread():
             break
         time.sleep(0.05)
     assert not leaked, leaked
+
+
+def _step_program_cfg(family):
+    cfg = _tiny_cfg()
+    if family == "vit":
+        cfg.model.name = "vit"
+        cfg.model.vit_patch_size = 4
+        cfg.model.vit_dim = 32
+        cfg.model.vit_depth = 2
+        cfg.model.vit_heads = 2
+        cfg.optimizer.name = "adam"
+        cfg.optimizer.learning_rate = 1e-3
+        cfg.optimizer.weight_decay = 0.0
+    return cfg
+
+
+def _run_step_program(program, cfg, mesh):
+    """Four optimizer steps through one of the Trainer's four train-step
+    programs: the single-step programs dispatched four times, the fused
+    ones once over the stacked group. Returns (last loss, params)."""
+    from distributed_resnet_tensorflow_tpu.parallel.sharding import (
+        shard_batch, shard_stacked_batch)
+    k = 4
+    rng = np.random.RandomState(5)
+    tr = Trainer(cfg, mesh=mesh)
+    tr.init_state(seed=0)
+    if program.startswith("jitted_index"):
+        tr.attach_device_dataset(
+            rng.randint(0, 256, (64, 8, 8, 3)).astype(np.uint8),
+            rng.randint(0, 4, (64,)).astype(np.int32))
+        group = {"idx": rng.randint(0, 64, (k, 16)).astype(np.int32)}
+        put_one, put_group = tr._put_idx, tr._put_idx_multi
+    else:
+        group = {"images": rng.randn(k, 16, 8, 8, 3).astype(np.float32),
+                 "labels": rng.randint(0, 4, (k, 16)).astype(np.int32)}
+        put_one = lambda b: shard_batch(b, mesh)  # noqa: E731
+        put_group = lambda b: shard_stacked_batch(b, mesh)  # noqa: E731
+    fn = getattr(tr, program)()
+    if "multi" in program:
+        state, m = fn(tr.state, put_group(group))
+    else:
+        state = tr.state
+        for i in range(k):
+            state, m = fn(state, put_one({n: v[i] for n, v in group.items()}))
+    assert int(state.step) == k
+    return float(m["loss"]), jax.tree_util.tree_map(np.asarray, state.params)
+
+
+@pytest.mark.parametrize("program,family", [
+    ("jitted_train_step", "resnet"), ("jitted_train_step", "vit"),
+    ("jitted_multi_step", "resnet"), ("jitted_multi_step", "vit"),
+    ("jitted_index_step", "resnet"), ("jitted_index_step", "vit"),
+    # found by this case when it was written (PR 31), on the CPU's eight
+    # virtual devices: from the second iteration of the rolled scan on, the
+    # gradient lacks the loss's L2 term (grad_norm 3.19652 where one device
+    # and the same mesh under train.scan_unroll=4 read 3.19875, and
+    # optimizer.weight_decay=0 reads 3.19651 everywhere). The streamed
+    # fused step, the single index step and AdamW's ViT all agree with one
+    # device, and so does this program over 2 and 4 shards: only 8 shards
+    # of two examples each show it. No benchmark cell fuses index steps;
+    # ROADMAP has the debt.
+    pytest.param("jitted_index_multi_step", "resnet",
+                 marks=pytest.mark.xfail(strict=True, reason=(
+                     "the rolled index scan on a mesh drops the L2 "
+                     "gradient after its first iteration"))),
+    ("jitted_index_multi_step", "vit"),
+])
+def test_step_program_on_a_mesh_matches_one_device(mesh8, program, family):
+    """Each of the four programs a training run can dispatch (a streamed
+    batch or a device dataset's indices, one step or a fused group of
+    four: the four jit sites ``exchange_compiler_options`` reaches) trains
+    the same parameters over eight data shards as on one device."""
+    from distributed_resnet_tensorflow_tpu.parallel import create_mesh
+    from distributed_resnet_tensorflow_tpu.utils.config import MeshConfig
+    one = create_mesh(MeshConfig(data=1), devices=jax.devices()[:1])
+    loss1, params1 = _run_step_program(program, _step_program_cfg(family), one)
+    loss8, params8 = _run_step_program(program, _step_program_cfg(family),
+                                       mesh8)
+    assert np.isclose(loss1, loss8, rtol=1e-4), (loss1, loss8)
+    for a, b in zip(jax.tree_util.tree_leaves(params1),
+                    jax.tree_util.tree_leaves(params8)):
+        np.testing.assert_allclose(b, a, rtol=2e-3, atol=5e-4)

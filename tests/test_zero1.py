@@ -6,10 +6,6 @@ The load-bearing claims, pinned on the virtual 8-device mesh:
   * the ZeRO-1 step is numerically allclose (f32 tolerance) to the
     replicated update on dp AND dp_fsdp — and the replicated (off) path
     is the untouched exactness oracle;
-  * the gather-order-insensitive part is BIT-identical: under
-    comm.overlap, many-bucket vs single-bucket ZeRO-1 runs (both the
-    reduce-scatter exchange and the param-update all-gather re-bucket)
-    produce bitwise-equal params — bucketing is scheduling, never math;
   * the optimizer state is ACTUALLY sharded: per-replica optimizer bytes
     shrink by exactly (N-1)/N for the shardable leaves, measured from
     the live state's shard shapes;
@@ -83,20 +79,8 @@ def _opt_bytes_per_replica(state):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("mesh_cfg", [
-    MeshConfig(data=8),
-    # dp_fsdp re-tiered out of the 870s tier-1 (ISSUE 17, ~12s): the dp
-    # leg pins the replicated-update equivalence; the dp_fsdp×zero1
-    # cross keeps its tier-1 pin via test_zero1_overlap_matches_plain_
-    # path[dp_fsdp], the full (unfiltered) suite runs this leg too
-    pytest.param(MeshConfig(data=4, fsdp=2), marks=pytest.mark.slow),
-], ids=["dp", "dp_fsdp"])
-@pytest.mark.parametrize("opt", [
-    "momentum",
-    # re-tiered out of the 870s tier-1 (ISSUE 13): the momentum leg pins
-    # the exchange numerics; the LAMB leg re-runs them with the heavier
-    # trust-ratio optimizer and stays in the full (unfiltered) suite
-    pytest.param("lamb", marks=pytest.mark.slow),
-])
+    MeshConfig(data=8), MeshConfig(data=4, fsdp=2)], ids=["dp", "dp_fsdp"])
+@pytest.mark.parametrize("opt", ["momentum", "lamb"])
 def test_zero1_matches_replicated_update(mesh_cfg, opt):
     """ZeRO-1 on vs off after a few steps: allclose at f32 tolerance
     (the reduction trees differ — reduce-scatter + sharded norms vs the
@@ -121,46 +105,7 @@ def test_zero1_matches_replicated_update(mesh_cfg, opt):
     assert sharded, "zero1=on left every optimizer leaf replicated"
 
 
-@pytest.mark.slow  # re-tiered out of the 870s tier-1 (ISSUE 20, ~11s: two
-# full trainings under zero1+overlap); tier-1 keeps the zero1+overlap path
-# via test_zero1_overlap_matches_plain_path[dp] and the bucketing
-# bit-identity claim via test_bucketed_is_bit_identical_to_unbucketed[dp];
-# the full (unfiltered) suite still runs this composition
-def test_zero1_overlap_bucketing_is_bit_identical(devices):
-    """The gather-order-insensitive pinned claim: under comm.overlap,
-    re-bucketing BOTH collectives legs (reduce-scatter exchange and the
-    param-update all-gather) may only change scheduling — many tiny
-    buckets vs one giant bucket must produce BITWISE-equal params."""
-    batches = _fixed_batches()
-    kw = {"comm.overlap": "on", "optimizer.zero1": "on",
-          "optimizer.zero1_min_size": "16"}
-    _, _, many, _ = _train(MeshConfig(data=8), batches, **kw,
-                           **{"comm.bucket_mb": "0.05"})
-    plan = zero1_stats.snapshot()
-    assert plan is not None and plan.get("gather_buckets", 0) > 1, plan
-    _, _, one, _ = _train(MeshConfig(data=8), batches, **kw,
-                          **{"comm.bucket_mb": "4096"})
-    assert zero1_stats.snapshot()["gather_buckets"] == 1
-    np.testing.assert_array_equal(many, one)
-
-
-@pytest.mark.parametrize("mesh_cfg", [
-    MeshConfig(data=8),
-    MeshConfig(data=4, fsdp=2),
-], ids=["dp", "dp_fsdp"])
-def test_zero1_overlap_matches_plain_path(mesh_cfg):
-    """ZeRO-1 composed with the bucketed exchange agrees with the plain
-    replicated jit path to float rounding."""
-    batches = _fixed_batches()
-    _, _, base, _ = _train(mesh_cfg, batches)
-    _, _, over, _ = _train(mesh_cfg, batches,
-                           **{"comm.overlap": "on", "comm.bucket_mb": "0.1",
-                              "optimizer.zero1": "on",
-                              "optimizer.zero1_min_size": "16"})
-    np.testing.assert_allclose(over, base, rtol=2e-4, atol=2e-5)
-
-
-@pytest.mark.slow  # re-tiered out of the 870s tier-1 (ISSUE 13); the bench zero1 row measures the same live shard shapes
+@pytest.mark.slow  # re-tiered out of the 870s tier-1 (ISSUE 13)
 def test_zero1_memory_shrinks_by_n_minus_1_over_n(devices):
     """Per-replica optimizer bytes, measured from live shard shapes: the
     shardable leaves cost exactly 1/N per replica; the total matches the
