@@ -69,7 +69,11 @@ log = logging.getLogger(__name__)
 
 #: bump when the trace.json event shape changes (consumers key on it via
 #: the ``trace_dump`` metrics row and the file's otherData block)
-SPAN_SCHEMA_VERSION = 10  # 10: + train.hooks/train.build/
+SPAN_SCHEMA_VERSION = 11  # 11: + train.hook_read (a cadence hook's
+#                               late read of device metrics) and
+#                               train.lead_wait (the fused loop's bound
+#                               on dispatches in flight), PR 32
+#                          10: + train.hooks/train.build/
 #                               train.init_state/input.finalize/
 #                               input.issue; input.stage narrowed to the
 #                               pack; every span is also a profiler
@@ -134,6 +138,17 @@ SPAN_CATALOG = {
                   "(step_num = first step of the dispatch)",
     "train.hooks": "the hook loop after one dispatch (all hooks, one "
                    "span)",
+    "train.hook_read": "a cadence hook's late read of device metrics "
+                       "(train/hooks.py): the values kept at its last "
+                       "cadence step pulled to the host, one dispatch "
+                       "later (inside train.hooks, or after the loop at "
+                       "Trainer.train's flush); count = late reads, "
+                       "seconds = the loop's wait for the device",
+    "train.lead_wait": "the fused loop's wait, after sending a dispatch, "
+                       "for the one FUSED_DISPATCH_LEAD back "
+                       "(train/loop.py): bounds the dispatches in flight "
+                       "and with them the groups and outputs the runtime "
+                       "holds for them",
     "eval.round": "one full evaluation round (goodput: eval)",
     "eval.batch": "one eval batch: stage wait + step dispatch",
     # checkpointing (checkpoint/manager.py)

@@ -6,24 +6,42 @@ Successor of the reference's session-hook stack (SURVEY.md §2.11-2.15):
 ``_LearningRateSetterHook`` → gone (the LR schedule is computed inside the
 jitted step, no per-step host feed).
 
-Hooks receive device metrics WITHOUT forcing a sync: values are jax.Arrays;
-hooks that print/serialize pull them at their own cadence, so the hot loop
-stays async-dispatch bound, not host bound.
+Hooks receive the step's metrics as they left the jitted call: jax.Arrays,
+futures of a dispatch the device may not have reached yet. Off their
+cadence hooks touch none of them. At a cadence step the hooks that turn
+metrics into host numbers (NanGuardHook, LoggingHook, SummaryHook) keep
+the step number and the arrays, and read them at their NEXT call, one
+dispatch later: ``Trainer.train`` has enqueued the following dispatch by
+then, so the wait for the kept step's values ends with work still queued
+on the device (a read of the dispatch just sent ends with the device
+drained, and the host's whole next turn is then the chips' idle time:
+6.7 of every 119 ms a step in ``vit_l16_dp4``, PERF.md §6 PR 32). Every
+line, row and check carries the kept step's number and values;
+``Trainer.train`` flushes a kept reading before it returns. What decides
+is the value's type and nothing else: a host number (Python float, NumPy
+scalar) is read at once. CheckpointHook reads the CURRENT metrics, at
+once, when its cadence fires: the save gate never runs late.
 """
 from __future__ import annotations
 
 import logging
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 
+from ..telemetry.tracer import span
 from ..utils.metrics import MetricsWriter, Throughput
 
 log = logging.getLogger(__name__)
 
 
 from ..utils import cadence_crossed  # noqa: F401  (re-export; shared impl)
+
+
+#: the divergence indicators: an exploding gradient shows in grad_norm a
+#: step before the loss goes non-finite
+DIVERGENCE_KEYS = ("loss", "grad_norm")
 
 
 def nonfinite_metric(metrics: Optional[Dict[str, Any]]) -> Optional[str]:
@@ -35,7 +53,7 @@ def nonfinite_metric(metrics: Optional[Dict[str, Any]]) -> Optional[str]:
     import math
     if not metrics:
         return None
-    for key in ("loss", "grad_norm"):
+    for key in DIVERGENCE_KEYS:
         value = metrics.get(key)
         if value is not None and not math.isfinite(float(value)):
             return key
@@ -43,17 +61,67 @@ def nonfinite_metric(metrics: Optional[Dict[str, Any]]) -> Optional[str]:
 
 
 class _CadenceHook:
-    """Shared cadence cursor for hooks gating on ``cadence_crossed``."""
+    """Shared cadence cursor for hooks gating on ``cadence_crossed``, and
+    the one late read of device metrics (module docstring): a subclass
+    that turns metrics into host numbers states which (``_reads``) and
+    what it does with them (``_emit``) and inherits ``__call__``; the
+    exporters of host-side counters override ``__call__`` and keep
+    nothing."""
 
     _last = 0
+    #: (step, metrics, the entries to read) of a cadence step whose device
+    #: values are unread
+    _kept: Optional[Tuple[int, Dict[str, Any], Dict[str, Any]]] = None
 
     def rollback_to(self, step: int) -> None:
         """Rewind the cadence after a checkpoint rollback
         (resilience/sentinel.py): a cursor still pointing at the trip step
         would treat every replayed step as already-handled — for the NaN
         guard that is a blind window in which a cadence save could commit
-        NaN params; for logging/summaries the replayed span would vanish."""
+        NaN params; for logging/summaries the replayed span would vanish.
+        A kept reading belongs to the abandoned timeline and goes."""
         self._last = min(self._last, step)
+        self._kept = None
+
+    def _reads(self, metrics: Dict[str, Any]) -> Dict[str, Any]:
+        """The entries of ``metrics`` this hook turns into host numbers."""
+        raise NotImplementedError
+
+    def _emit(self, step: int, metrics: Dict[str, Any],
+              values: Dict[str, Any]) -> None:
+        """Print, write or check ``values`` (``_reads(metrics)`` as host
+        numbers) under ``step``."""
+        raise NotImplementedError
+
+    def __call__(self, step: int, state, metrics: Dict[str, Any]) -> None:
+        self.flush()
+        if not cadence_crossed(step, self.every_steps, self._last):
+            return
+        self._last = step
+        wanted = self._reads(metrics)
+        futures = [v for v in wanted.values() if isinstance(v, jax.Array)]
+        if not futures:  # host numbers: nothing to wait for
+            self._emit(step, metrics, wanted)
+            return
+        for v in futures:  # the scalars travel as soon as they exist
+            v.copy_to_host_async()
+        self._kept = (step, metrics, wanted)
+
+    def flush(self) -> None:
+        """Read a kept reading now and emit it under its own step: the
+        hook's next call does this first, and ``Trainer.train`` before it
+        returns, so no line is lost and a non-finite loss of the last
+        cadence step still raises out of ``train``."""
+        if self._kept is None:
+            return
+        (step, metrics, wanted), self._kept = self._kept, None
+        # its cell: count = late reads, seconds = the loop's wait for the
+        # device
+        with span("train.hook_read"):
+            host = jax.device_get(list(wanted.values()))
+        # zipped back by hand: a dict through device_get comes back with
+        # its keys sorted, and the line keeps the order of its columns
+        self._emit(step, metrics, dict(zip(wanted, host)))
 
 
 class _SnapshotExportHook(_CadenceHook):
@@ -119,15 +187,16 @@ class LoggingHook(_CadenceHook):
         of each segment)."""
         self.throughput.reset()
 
-    def __call__(self, step: int, state, metrics: Dict[str, Any]) -> None:
-        if not cadence_crossed(step, self.every_steps, self._last):
-            return
-        self._last = step
+    def _reads(self, metrics):
+        return {k: metrics[k] for k in ("loss", "cross_entropy", "precision",
+                                        "learning_rate") if k in metrics}
+
+    def _emit(self, step, metrics, values):
+        # stamped with the values on the host: the device has finished
+        # ``step``, so img/s is its rate and not the host's enqueue rate
         tp = self.throughput.update(step)
         parts = [f"step {step}"]
-        for k in ("loss", "cross_entropy", "precision", "learning_rate"):
-            if k in metrics:
-                parts.append(f"{k} {float(metrics[k]):.4f}")
+        parts += [f"{k} {float(v):.4f}" for k, v in values.items()]
         if tp:
             parts.append(f"{tp['steps_per_sec']:.2f} stp/s")
             if self.throughput.batch_size:
@@ -149,13 +218,13 @@ class SummaryHook(_CadenceHook):
         self.every_steps = max(1, every_steps)
         self._last = 0
 
-    def __call__(self, step: int, state, metrics: Dict[str, Any]) -> None:
-        if not cadence_crossed(step, self.every_steps, self._last):
-            return
-        self._last = step
-        scalars = {k: float(v) for k, v in metrics.items()
-                   if hasattr(v, "__float__") or isinstance(v, (int, float))}
-        self.writer.write_scalars(step, scalars)
+    def _reads(self, metrics):
+        return {k: v for k, v in metrics.items()
+                if hasattr(v, "__float__") or isinstance(v, (int, float))}
+
+    def _emit(self, step, metrics, values):
+        self.writer.write_scalars(step,
+                                  {k: float(v) for k, v in values.items()})
 
 
 class InputStagesHook(_CadenceHook):
@@ -446,19 +515,20 @@ class NanGuardHook(_CadenceHook):
         self.on_nan = on_nan
         self._last = 0
 
-    def __call__(self, step: int, state, metrics: Dict[str, Any]) -> None:
-        if not cadence_crossed(step, self.every_steps, self._last):
-            return
-        self._last = step
+    def _reads(self, metrics):
+        return {k: v for k in DIVERGENCE_KEYS
+                if (v := (metrics or {}).get(k)) is not None}
+
+    def _emit(self, step, metrics, values):
         # loss AND grad_norm (nonfinite_metric): an exploding gradient
         # shows up in grad_norm a step before the loss goes non-finite
         # (the optimizer has already eaten the inf update by then) —
         # catching either is the trigger for the rollback policy in
         # resilience/sentinel.py
-        bad = nonfinite_metric(metrics)
+        bad = nonfinite_metric(values)
         if bad is not None:
             if self.on_nan is not None:
                 self.on_nan(step, metrics)
                 return
             raise self.NanLossError(
-                f"non-finite {bad} {float(metrics[bad])} at step {step}")
+                f"non-finite {bad} {float(values[bad])} at step {step}")

@@ -20,6 +20,7 @@ stands in for very large global batches on small meshes.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import time
 from functools import partial
@@ -39,6 +40,10 @@ from .optimizers import (create_optimizer, decoupled_decay,
                          loss_weight_decay)
 from .schedules import create_schedule
 from .state import TrainState, create_train_state, state_shardings
+
+
+#: fused dispatches queued behind the one the loop waits for (Trainer.train)
+FUSED_DISPATCH_LEAD = 2
 
 
 def per_example_cross_entropy(logits: jax.Array, labels: jax.Array,
@@ -983,7 +988,26 @@ class Trainer:
         last finished step — the preemption listener's entry point
         (resilience/preemption.py). The poll is one Event check; it does not
         force a device sync.
+
+        Hooks that read device metrics at a cadence read them one call
+        late (train/hooks.py), so every normal return — end of
+        ``num_steps``, ``stop_fn``, an exhausted stream — first flushes
+        what they still hold: the last cadence line is printed and a
+        non-finite loss of the last cadence dispatch raises out of here.
+        Not while an exception propagates: that one is the news.
         """
+        out = self._dispatch_loop(data_iter, num_steps, hooks, start_step,
+                                  stop_fn)
+        for h in hooks:
+            flush = getattr(h, "flush", None)
+            if flush is not None:
+                flush()
+        return out
+
+    def _dispatch_loop(self, data_iter, num_steps, hooks, start_step,
+                       stop_fn):
+        """``train`` up to its return; every ``return`` in here is one of
+        its normal ends."""
         if self.state is None:
             self.init_state()
         for h in hooks:
@@ -1099,6 +1123,17 @@ class Trainer:
                 None]
         entry = self._multi_prefetch
         stacked_iter = entry[1]
+        # metrics of the fused dispatches sent and not yet waited for. The
+        # runtime allocates a dispatch's buffers when it is enqueued, and
+        # its staged group (k batches) lives until it has run, so the
+        # loop's lead is device memory (168 MiB a dispatch in ViT-L's
+        # cells, PERF.md §6 PR 32). Nothing else bounds it: the hooks read
+        # late (train/hooks.py) and the runtime stops the host only at 32
+        # programs in flight. So the loop waits for the dispatch
+        # FUSED_DISPATCH_LEAD back: one runs, that many are queued behind
+        # it, and a host turn as long as a whole dispatch costs the device
+        # nothing.
+        sent = collections.deque()
         fetch_cm = self.heartbeat.data_fetch \
             if self.heartbeat is not None else contextlib.nullcontext
 
@@ -1153,6 +1188,10 @@ class Trainer:
                 with span("train.step", step_num=step):
                     self.state, metrics = multi_fn(self.state, stacked)
                 step += k
+                sent.append(metrics)
+                if len(sent) > FUSED_DISPATCH_LEAD:
+                    with span("train.lead_wait"):
+                        jax.block_until_ready(sent.popleft())
                 with span("train.hooks"):
                     for h in hooks:
                         h(step, self.state, metrics)

@@ -693,3 +693,129 @@ def test_step_program_on_a_mesh_matches_one_device(mesh8, program, family):
     for a, b in zip(jax.tree_util.tree_leaves(params1),
                     jax.tree_util.tree_leaves(params8)):
         np.testing.assert_allclose(b, a, rtol=2e-3, atol=5e-4)
+
+
+# ---------------------------------------------------------------------------
+# cadence hooks read device metrics one call late; train() flushes (PR 32)
+# ---------------------------------------------------------------------------
+
+def _logistic_trainer(k):
+    cfg = _tiny_cfg()
+    cfg.model.name = "logistic"
+    cfg.model.input_size = 8 * 8 * 3
+    cfg.train.steps_per_loop = k
+    tr = Trainer(cfg)
+    tr.init_state(seed=0)
+    return tr
+
+
+def _stream(batches=None):
+    """The learnable stream, endless or cut after ``batches``."""
+    src = learnable_synthetic_iterator(16, 8, 4, seed=5)
+    return src if batches is None else iter([next(src)
+                                             for _ in range(batches)])
+
+
+# how train() comes to its return -> (batches in the stream, num_steps, stop
+# after which hook call, the steps whose line must be there on return)
+_ENDS = {
+    1: {"num_steps": (None, 4, None, [2, 4]),
+        "stop_fn": (None, 50, 2, [2]),
+        "exhausted": (4, 100, None, [2, 4])},
+    3: {"num_steps": (None, 6, None, [3, 6]),
+        "stop_fn": (None, 60, 1, [3]),
+        "exhausted": (7, 100, None, [3, 6]),
+        # 2 fused groups, then one unfused step of a third
+        "tail": (None, 7, None, [3, 6, 7])},
+}
+
+
+@pytest.mark.parametrize("k,end", [(k, end) for k in _ENDS for end in _ENDS[k]])
+def test_train_flushes_late_hooks_on_every_normal_return(k, end):
+    """The last cadence line is printed before train() returns, under its
+    own step and with that step's loss, however the loop ends."""
+    from distributed_resnet_tensorflow_tpu.train.hooks import LoggingHook
+    batches, num_steps, stop_after, want = _ENDS[k][end]
+    tr = _logistic_trainer(k)
+    lines, calls = [], []
+    hooks = (LoggingHook(every_steps={1: 2, 3: 3}[k] if end != "tail" else 1,
+                         print_fn=lines.append),
+             lambda s, st, m: calls.append((s, m["loss"])))
+    state, m = tr.train(
+        _stream(batches), num_steps=num_steps, hooks=hooks,
+        stop_fn=(lambda: len(calls) >= stop_after) if stop_after else None)
+    assert int(state.step) == want[-1]
+    loss_at = {s: f"{float(v):.4f}" for s, v in calls}
+    assert [ln.split()[:4] for ln in lines] == [
+        ["step", str(s), "loss", loss_at[s]] for s in want]
+
+
+@pytest.mark.parametrize("k", [1, 3])
+def test_nan_in_the_last_cadence_dispatch_raises_out_of_train(k):
+    """No later hook call would read it: the flush does, before train()
+    returns a state nobody checked."""
+    from distributed_resnet_tensorflow_tpu.resilience import faultinject
+    from distributed_resnet_tensorflow_tpu.train.hooks import NanGuardHook
+    tr = _logistic_trainer(k)
+    n = 2 * k
+    stream = faultinject.inject_nan(_stream(), at_batch=n)
+    with pytest.raises(NanGuardHook.NanLossError, match=f"at step {n}$"):
+        tr.train(stream, num_steps=n, hooks=(NanGuardHook(every_steps=k),))
+
+
+@pytest.mark.parametrize("k", [1, 3])
+def test_late_read_comes_after_the_next_dispatch_was_entered(k):
+    """The point of reading late: when the loop waits for the kept step's
+    values, the following train.step has been enqueued. A recording step
+    in the compiled one's place shows the order; the span counts the
+    reads."""
+    from distributed_resnet_tensorflow_tpu.train.hooks import (
+        LoggingHook, NanGuardHook)
+    from distributed_resnet_tensorflow_tpu.utils.metrics import input_stages
+    tr = _logistic_trainer(k)
+    events = []
+
+    def fake_step(state, batch):
+        events.append("dispatch")
+        return state, {"loss": jnp.asarray(float(len(events)))}
+    tr._jitted_train = tr._jitted_multi = fake_step
+    hooks = (NanGuardHook(every_steps=2 * k),
+             LoggingHook(every_steps=2 * k,
+                         print_fn=lambda s: events.append(s.split()[1])))
+    input_stages.reset()
+    tr.train(_stream(), num_steps=4 * k, hooks=hooks)
+    # dispatches end at k, 2k, 3k, 4k; 2k is read after the third was
+    # entered, 4k by the flush
+    assert events == ["dispatch", "dispatch", "dispatch", str(2 * k),
+                      "dispatch", str(4 * k)]
+    assert input_stages.snapshot()["train.hook_read"]["count"] == 4
+
+
+def test_fused_loop_waits_for_the_dispatch_two_back():
+    """The loop's lead is device memory (a dispatch's group and outputs are
+    allocated when it is enqueued): after sending dispatch n the fused loop
+    waits for n - 2, so never more than three are in flight, with or
+    without a hook that pulls."""
+    from distributed_resnet_tensorflow_tpu.train import loop
+    from distributed_resnet_tensorflow_tpu.utils.metrics import input_stages
+    assert loop.FUSED_DISPATCH_LEAD == 2
+    tr = _logistic_trainer(3)
+    events = []
+
+    class Sent:  # a leaf jax.block_until_ready asks to block
+        def __init__(self, n):
+            self.n = n
+
+        def block_until_ready(self):
+            events.append(f"waited {self.n}")
+
+    def fake_step(state, batch):
+        n = sum(e.startswith("sent") for e in events) + 1
+        events.append(f"sent {n}")
+        return state, {"loss": Sent(n)}
+    tr._jitted_multi = fake_step
+    input_stages.reset()
+    tr.train(_stream(), num_steps=15)
+    assert events == ["sent 1", "sent 2", "sent 3", "waited 1", "sent 4",
+                      "waited 2", "sent 5", "waited 3"]
+    assert input_stages.snapshot()["train.lead_wait"]["count"] == 3
