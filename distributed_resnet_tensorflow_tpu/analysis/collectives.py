@@ -144,7 +144,7 @@ _STATE_MEMO: dict = {}
 
 def _abstract_state(trainer, cfg):
     import dataclasses
-    from ..train.state import abstract_train_state
+    from ..train.state import abstract_train_state, init_input
     from ..parallel.mesh import batch_shard_count
     nb = batch_shard_count(trainer.mesh)
     # the memoized state embeds apply_fn — a module bound to ITS mesh.
@@ -158,10 +158,7 @@ def _abstract_state(trainer, cfg):
     state = _STATE_MEMO.get(key)
     if state is None:
         state = abstract_train_state(
-            trainer.model, trainer.tx,
-            (nb, cfg.data.image_size, cfg.data.image_size, 3)
-            if cfg.model.name != "logistic"
-            else (nb, cfg.model.input_size))
+            trainer.model, trainer.tx, init_input(trainer.model, cfg, nb))
         _STATE_MEMO[key] = state
     return state
 
@@ -177,8 +174,8 @@ def run_collectives(preset_names: Optional[Sequence[str]] = None,
     import jax
     from ..parallel.mesh import create_mesh
     from ..utils.config import MeshConfig, PRESETS, get_preset
-    from .elaborate import candidate_layouts, _abstract_batch, \
-        _axis_product
+    from .elaborate import candidate_layouts, has_classifier_forward, \
+        _abstract_batch, _axis_product
 
     findings: List[Finding] = []
     signatures: Dict[str, dict] = {}
@@ -293,8 +290,9 @@ def run_collectives(preset_names: Optional[Sequence[str]] = None,
         # (3) serve/predict step: smallest + largest AOT bucket on the
         # first layout — forward-only, so the signature pins that serving
         # carries NO hidden collectives on the batch-parallel meshes
-        if layouts and not dedupe("serve", cfg, layouts[0][0],
-                                  (cfg.serve.max_batch,)):
+        if layouts and has_classifier_forward(cfg) and \
+                not dedupe("serve", cfg, layouts[0][0],
+                           (cfg.serve.max_batch,)):
             label, mesh_cfg = layouts[0]
             try:
                 import jax as _jax

@@ -94,10 +94,20 @@ def _axis_product(mesh_cfg) -> int:
         mesh_cfg.sequence, mesh_cfg.expert))
 
 
+def has_classifier_forward(cfg) -> bool:
+    """False for a token model: it has no eval or serve forward yet (token
+    serving waits, ROADMAP Queue 2), so its train step is what there is to
+    trace."""
+    return cfg.data.dataset != "tokens"
+
+
 def _abstract_batch(cfg, batch_size: int):
     """Shape/dtype skeleton of one host batch as the input pipeline would
     deliver it on this backend (float32 images after host-side prep)."""
     import jax
+    if cfg.data.dataset == "tokens":  # inputs and next-token targets
+        return {"tokens": jax.ShapeDtypeStruct(
+            (batch_size, cfg.data.seq_len + 1), np.int32)}
     if cfg.model.name == "logistic":
         img = jax.ShapeDtypeStruct((batch_size, cfg.model.input_size),
                                    np.float32)
@@ -170,7 +180,8 @@ def elaborate_config(cfg, mesh_cfg, locus: str,
     import jax
     from ..parallel.mesh import batch_shard_count, create_mesh
     from ..train.loop import Trainer
-    from ..train.state import abstract_train_state, state_shardings
+    from ..train.state import (abstract_train_state, init_input,
+                               state_shardings)
     from ..utils.config import stacked_layout_stamp
 
     findings: List[Finding] = []
@@ -197,9 +208,7 @@ def elaborate_config(cfg, mesh_cfg, locus: str,
         if state_shapes is None:
             state_shapes = abstract_train_state(
                 trainer.model, trainer.tx,
-                (nb, cfg.data.image_size, cfg.data.image_size, 3)
-                if cfg.model.name != "logistic"
-                else (nb, cfg.model.input_size))
+                init_input(trainer.model, cfg, nb))
             if _state_cache is not None:
                 _state_cache[cache_key] = state_shapes
     except Exception as e:
@@ -421,7 +430,7 @@ def run_elaborate_zero1(preset_names: Optional[Sequence[str]] = None,
                                      zero1_state_shardings,
                                      zero1_unsupported_reason)
     from ..train.loop import Trainer
-    from ..train.state import abstract_train_state
+    from ..train.state import abstract_train_state, init_input
     from ..utils.config import MeshConfig, PRESETS, get_preset
 
     import dataclasses
@@ -470,11 +479,7 @@ def run_elaborate_zero1(preset_names: Optional[Sequence[str]] = None,
                         # (preset, size, layout)
                         t = Trainer(copy.deepcopy(cfg), mesh=mesh)
                         state_shapes = abstract_train_state(
-                            t.model, t.tx,
-                            (1, cfg.data.image_size,
-                             cfg.data.image_size, 3)
-                            if cfg.model.name != "logistic"
-                            else (1, cfg.model.input_size))
+                            t.model, t.tx, init_input(t.model, cfg, 1))
                         shared_states[state_key] = state_shapes
                 except Exception as e:
                     findings.append(_findings_from_exc(
@@ -553,7 +558,7 @@ def run_elaborate(preset_names: Optional[Sequence[str]] = None,
         fwd_key = repr((dataclasses.asdict(cfg.model),
                         dataclasses.asdict(cfg.data),
                         dataclasses.asdict(cfg.serve)))
-        fwd = fwd_key not in seen_forward
+        fwd = fwd_key not in seen_forward and has_classifier_forward(cfg)
         seen_forward.add(fwd_key)
         for label, mesh_cfg in candidate_layouts(cfg, n_devices):
             # the step graph only changes with PROGRAM-SHAPING axes

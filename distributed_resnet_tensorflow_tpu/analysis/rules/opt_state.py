@@ -43,13 +43,11 @@ def _zero1_resolves_sharded(cfg) -> bool:
                                       zero1_rules)
     from ...train.optimizers import create_optimizer
     from ...train.schedules import create_schedule
-    from ...train.state import abstract_train_state
+    from ...train.state import abstract_train_state, init_input
 
     model = create_model(cfg.model, cfg.data.dataset)
     tx = create_optimizer(cfg.optimizer, create_schedule(cfg.optimizer))
-    shape = (1, cfg.data.image_size, cfg.data.image_size, 3) \
-        if cfg.model.name != "logistic" else (1, cfg.model.input_size)
-    state = abstract_train_state(model, tx, shape)
+    state = abstract_train_state(model, tx, init_input(model, cfg, 1))
     report = Zero1Report(CANONICAL_DATA_SHARDS)
     match_partition_rules(
         zero1_rules(_SizesMesh({"data": CANONICAL_DATA_SHARDS}),

@@ -76,6 +76,10 @@ def create_input_iterator(cfg, mode: str = "train", shard_index: int = 0,
     if d.dataset == "synthetic":
         it = synthetic_iterator(bs, d.image_size, cfg.model.num_classes,
                                 seed=cfg.train.seed)
+    elif d.dataset == "tokens":
+        from .tokens import token_stream_iterator
+        it = token_stream_iterator(bs, d.seq_len, cfg.model.vocab_held,
+                                   seed=cfg.train.seed)
     elif d.dataset in ("cifar10", "cifar100"):
         it = cifar_iterator(d.dataset, d.data_dir, bs, mode,
                             seed=cfg.train.seed, shard_index=shard_index,

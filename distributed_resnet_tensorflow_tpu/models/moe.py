@@ -51,7 +51,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Any
+from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
@@ -402,3 +402,237 @@ class SwitchMlp(nn.Module):
         from jax.sharding import NamedSharding
         return jax.lax.with_sharding_constraint(
             arr, NamedSharding(mesh, P("expert", None, None)))
+
+
+# ---------------------------------------------------------------------------
+# Dropless routed experts over a held range (the decoder family,
+# models/transformer.py CausalDecoder). Where the Switch layer above gives
+# every expert a fixed capacity and drops what overflows, this one keeps
+# every assignment: the assignments that land on the experts held here are
+# sorted by expert and run through grouped products, whatever the imbalance.
+# The layer is TOLD which contiguous range of the published experts it
+# holds: it routes over all of them, computes its own experts' part of the
+# routed sum and adds the shared expert; what the absent experts would add
+# belongs to the chips that hold them. On one chip it runs without its
+# exchange, and nothing here stands in for the absent chips.
+# ---------------------------------------------------------------------------
+
+
+@jax.custom_vjp
+def _dispatch(x, tok, slot):
+    """Tokens' rows laid out by sorted assignment: row i of the result is
+    token ``tok[i]`` of ``x`` (n, d). ``slot`` (n, k) says where each of a
+    token's k assignments sits in that layout, so the transpose is
+    ``_combine``: neither direction scatters."""
+    return x[tok]
+
+
+def _dispatch_fwd(x, tok, slot):
+    return x[tok], (tok, slot)
+
+
+def _dispatch_bwd(res, g):
+    tok, slot = res
+    return _combine(g, tok, slot), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(y, tok, slot):
+    """Sorted assignments' rows (n·k, d) summed back per token: (n, d)."""
+    return y[slot].sum(axis=1)
+
+
+def _combine_fwd(y, tok, slot):
+    return _combine(y, tok, slot), (tok, slot)
+
+
+def _combine_bwd(res, g):
+    tok, slot = res
+    return _dispatch(g, tok, slot), None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _live_rows(a, sizes):
+    """``a`` with the rows past the groups' end zeroed."""
+    live = jnp.arange(a.shape[0]) < jnp.sum(sizes)
+    return jnp.where(live[:, None], a, jnp.zeros((), a.dtype))
+
+
+@jax.custom_vjp
+def grouped_dot(x, w, sizes):
+    """``jax.lax.ragged_dot`` (x (m, k) sorted by group, w (g, k, n), sizes
+    (g,)) in float32, with the rows that belong to no group ZERO in the
+    result and in the gradient. The TPU compiler lowers the ragged product
+    to a kernel that visits the groups' tiles only: rows past their end
+    are not written, in the product and in its transpose alike, and hold
+    whatever the buffer held (the CPU's reference lowering writes zeros,
+    so only the chip shows it). A dropless layer's sorted buffer is mostly
+    such rows — the assignments of absent experts — and their gradient
+    rows are summed into the tokens' (``_dispatch``'s transpose)."""
+    return _live_rows(jax.lax.ragged_dot(
+        x, w, sizes, preferred_element_type=jnp.float32), sizes)
+
+
+def _grouped_dot_fwd(x, w, sizes):
+    return grouped_dot(x, w, sizes), (x, w, sizes)
+
+
+def _grouped_dot_bwd(res, g):
+    x, w, sizes = res
+    _, vjp = jax.vjp(lambda x, w: jax.lax.ragged_dot(
+        x, w, sizes, preferred_element_type=jnp.float32), x, w)
+    dx, dw = vjp(_live_rows(g, sizes))
+    return _live_rows(dx, sizes), dw, None
+
+
+grouped_dot.defvjp(_grouped_dot_fwd, _grouped_dot_bwd)
+
+
+def biased_topk_route(x, router, bias, top_k: int, route_scale: float):
+    """Sigmoid scores over every published expert, the ``top_k`` by score
+    plus bias (the bias takes part in the choice only and has no gradient),
+    weights renormalised over the chosen and scaled. All in float32: where
+    two biased scores lie within a lower precision's rounding the choice
+    itself would differ, and that gap is no rounding. Returns (chosen
+    (n, k) int32, weights (n, k) f32, counts (E,) f32 of assignments)."""
+    scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                                    router.astype(jnp.float32),
+                                    precision=jax.lax.Precision.HIGHEST))
+    _, sel = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+    picked = jnp.take_along_axis(scores, sel, axis=-1)
+    w = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20) \
+        * route_scale
+    counts = jnp.zeros((router.shape[-1],), jnp.float32).at[
+        sel.reshape(-1)].add(1.0)
+    return sel, w, counts
+
+
+def held_experts_sum(x, sel, w, gate, up, down, lo: int, dtype):
+    """Σ over a token's chosen experts that lie in [lo, lo + E_held) of
+    weight × SwiGLU expert, for one chunk of tokens. x (n, d); sel, w
+    (n, k); gate/up (E_held, d, m); down (E_held, m, d).
+
+    Dropless: the sorted buffer holds every assignment (n·k rows: all of
+    them may land here), so no imbalance can overflow it; the held
+    experts' assignments come first, grouped by expert, and the rows past
+    their end belong to absent experts and read zero (``grouped_dot``).
+    One buffer size whatever the load: a smaller buffer for the common
+    case behind a ``lax.cond`` was measured (PR 33: −5% of the step) and
+    taken out, because which chunks overflowed it depended on the seed and
+    the step's time with them (runs 1.4% apart where the bound holds half
+    of 1.5%)."""
+    n, k = sel.shape
+    e_held = gate.shape[0]
+    local = sel - lo
+    held = jnp.logical_and(local >= 0, local < e_held)
+    local = jnp.where(held, local, e_held).reshape(-1)  # absent: sorts last
+    order = jnp.argsort(local, stable=True).astype(jnp.int32)
+    slot = jnp.zeros_like(order).at[order].set(
+        jnp.arange(n * k, dtype=jnp.int32)).reshape(n, k)
+    sizes = jnp.zeros((e_held + 1,), jnp.int32).at[local].add(1)[:e_held]
+    ws = jnp.where(held, w, 0.0).reshape(-1)[order]
+    tok = order // k
+    xs = _dispatch(x.astype(dtype), tok, slot)
+    h = jax.nn.silu(grouped_dot(xs, gate.astype(dtype), sizes)) \
+        * grouped_dot(xs, up.astype(dtype), sizes)
+    y = grouped_dot(h.astype(dtype), down.astype(dtype), sizes)
+    return _combine(y * ws[:, None], tok, slot)
+
+
+class SwiGLU(nn.Module):
+    """(silu(x W_gate) ⊙ (x W_up)) W_down, no biases."""
+    hidden: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        d = x.shape[-1]
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        h = nn.silu(dense(self.hidden, name="gate")(x)) \
+            * dense(self.hidden, name="up")(x)
+        return dense(d, name="down")(h)
+
+
+class Kernel(nn.Module):
+    """A ``kernel`` (d, features) and nothing else: for a product its owner
+    forms itself."""
+    features: int
+
+    @nn.compact
+    def __call__(self, d: int):
+        return self.param("kernel", nn.initializers.lecun_normal(),
+                          (d, self.features))
+
+
+#: tokens to a sorted buffer of assignments (HeldExperts)
+TOKEN_CHUNK = 4096
+
+
+class HeldExperts(nn.Module):
+    """The stacked experts held here and their part of the routed sum
+    (``held_experts_sum``), a chunk of tokens at a time: a chunk's sorted
+    buffer is recomputed in the backward pass, so the buffer's worst case
+    (every assignment of every token lands here) is a chunk's and not the
+    batch's."""
+    lo: int
+    held: int
+    hidden: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x, sel, w):
+        n, d = x.shape
+        stack = nn.initializers.variance_scaling(
+            1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=0)
+        gate = self.param("gate", stack, (self.held, d, self.hidden))
+        up = self.param("up", stack, (self.held, d, self.hidden))
+        down = self.param("down", stack, (self.held, self.hidden, d))
+        chunk = math.gcd(n, TOKEN_CHUNK)
+
+        @jax.checkpoint
+        def one(args):
+            return held_experts_sum(*args, gate, up, down, self.lo, self.dtype)
+        split = lambda a: a.reshape((n // chunk, chunk) + a.shape[1:])  # noqa: E731
+        if chunk == n:
+            return one((x, sel, w))
+        return jax.lax.map(one, (split(x), split(sel), split(w))).reshape(n, d)
+
+
+class DroplessMoe(nn.Module):
+    """Routed experts over the held range + a shared expert (module
+    comment above). ``__call__(x (n, d) f32) -> (out (n, d) f32, counts
+    (E,))``; ``counts`` are the batch's assignments to each of the PUBLISHED
+    experts (what the router-bias rule reads, train/loop.py)."""
+    num_experts: int                 # the router's published width
+    experts_held: Tuple[int, int]    # [lo, hi) of them live here
+    top_k: int
+    hidden: int                      # a routed expert's width
+    shared_hidden: int               # the shared expert's (0: none)
+    route_scale: float = 1.0
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jax.Array):
+        lo, hi = self.experts_held
+        if not 0 <= lo < hi <= self.num_experts:
+            raise ValueError(f"experts_held {self.experts_held} is no range "
+                             f"of {self.num_experts} experts")
+        with jax.named_scope("route"):
+            # the kernel alone lives in the module: the product, the
+            # sigmoid and the choice are biased_topk_route's, in float32
+            kernel = Kernel(self.num_experts, name="router")(x.shape[-1])
+            bias = self.param("router_bias", nn.initializers.zeros,
+                              (self.num_experts,), jnp.float32)
+            sel, w, counts = biased_topk_route(
+                x, kernel, bias, self.top_k, self.route_scale)
+        out = HeldExperts(lo, hi - lo, self.hidden, self.dtype,
+                          name="experts")(x, sel, w)
+        if self.shared_hidden:
+            out = out + SwiGLU(self.shared_hidden, self.dtype,
+                               name="shared")(x.astype(self.dtype))
+        return out.astype(jnp.float32), counts

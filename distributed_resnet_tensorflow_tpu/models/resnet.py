@@ -432,6 +432,11 @@ def create_model(model_cfg, dataset: str,
                            hidden_units=model_cfg.hidden_units,
                            dtype=dtype if compute_dtype is not None
                            else jnp.float32)
+    if model_cfg.name == "afmoe":
+        from .transformer import CausalDecoder
+        return CausalDecoder(cfg=model_cfg, dtype=dtype,
+                             attention_impl=model_cfg.attention_impl,
+                             remat=remat, mesh=mesh)
     if model_cfg.name == "vit":
         from .transformer import VisionTransformer
         attn = model_cfg.attention_impl

@@ -16,6 +16,8 @@ bf16 compute / f32 params as elsewhere.
 """
 from __future__ import annotations
 
+import math
+from functools import partial
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -260,3 +262,282 @@ class VisionTransformer(nn.Module):
         x = nn.LayerNorm(dtype=self.dtype)(x)
         x = x.mean(axis=1).astype(jnp.float32)
         return nn.Dense(self.num_classes, dtype=jnp.float32, name="head")(x)
+
+
+# ---------------------------------------------------------------------------
+# Causal decoder: the token-model family (model.name="afmoe"). Built from a
+# list of layer kinds (``sliding_attention`` / ``full_attention``): RMS
+# norms before AND after each part, grouped query heads with per-head RMS
+# norms on queries and keys, rotary positions on the window layers only, a
+# sigmoid gate on the attention's output, SwiGLU feed-forward in the leading
+# dense layers and models/moe.DroplessMoe in the rest, an untied head over
+# the vocabulary rows held here. The residual stream and every norm are
+# float32; products run in ``dtype``.
+# ---------------------------------------------------------------------------
+
+LAYER_KINDS = ("sliding_attention", "full_attention")
+
+
+def causal_flash_or_dense(impl: str) -> str:
+    """The decoder's auto rule: the Pallas kernels on a TPU (nothing of
+    size [T, T] exists there at any length), their jax.numpy twin elsewhere."""
+    if impl != "auto":
+        return impl
+    return "flash" if jax.default_backend() == "tpu" else "dense"
+
+
+def causal_attention(q, k, v, window, impl: str, mesh=None):
+    """softmax(q kᵀ/√d) v under the causal mask and, with ``window``, the
+    band i − window < j ≤ i; k and v may carry fewer heads than q."""
+    impl = causal_flash_or_dense(impl)
+    if impl == "dense":
+        from ..ops.attention import attention
+        return attention(q, k, v, True, window)
+    if impl in ("flash", "flash_interpret"):
+        from ..ops.pallas import flash_attention
+        interpret = impl == "flash_interpret"
+        return _per_shard(
+            lambda q, k, v: flash_attention(q, k, v, True, interpret,
+                                            window=window), mesh)(q, k, v)
+    raise ValueError(f"the decoder's attention_impl is auto | dense | flash "
+                     f"| flash_interpret, not {impl!r}")
+
+
+class RMSNorm(nn.Module):
+    """x / sqrt(mean(x²) + eps) · scale over the last axis, in float32."""
+    eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        x = x.astype(jnp.float32)
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        return x * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps) * scale
+
+
+def rotary(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary positions 0..T-1 over the whole last axis of x (B, T, H, hd),
+    the rotate-half convention, in float32."""
+    t, hd = x.shape[1], x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    angles = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)[None, :, None]
+    sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)[None, :, None]
+    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
+    return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
+
+
+class GroupedAttention(nn.Module):
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    kind: str                        # one of LAYER_KINDS
+    window: int
+    rope_theta: float
+    eps: float
+    dtype: Any = jnp.bfloat16
+    attention_impl: str = "auto"
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, a: jax.Array) -> jax.Array:
+        b, t, d = a.shape
+        h, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        a = a.astype(self.dtype)
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        q = dense(h * hd, name="q_proj")(a).reshape(b, t, h, hd)
+        k = dense(kv * hd, name="k_proj")(a).reshape(b, t, kv, hd)
+        v = dense(kv * hd, name="v_proj")(a).reshape(b, t, kv, hd)
+        q = RMSNorm(self.eps, name="q_norm")(q)
+        k = RMSNorm(self.eps, name="k_norm")(k)
+        sliding = self.kind == "sliding_attention"
+        if sliding:  # the full layers carry no positional term at all
+            q, k = rotary(q, self.rope_theta), rotary(k, self.rope_theta)
+        o = causal_attention(q.astype(self.dtype), k.astype(self.dtype), v,
+                             self.window if sliding else None,
+                             self.attention_impl, self.mesh)
+        o = o.reshape(b, t, h * hd) \
+            * nn.sigmoid(dense(h * hd, name="gate_proj")(a))
+        return dense(d, name="o_proj")(o)
+
+
+class DecoderBlock(nn.Module):
+    cfg: Any                         # the ModelConfig (utils/config.py)
+    index: int
+    dtype: Any = jnp.bfloat16
+    attention_impl: str = "auto"
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, x: jax.Array):
+        """x (B, T, d) float32 -> (x, counts): counts (E,) of this layer's
+        assignments where it routes, a scalar 0 where it is dense."""
+        c = self.cfg
+        kind = c.layer_types[self.index]
+        if kind not in LAYER_KINDS:
+            raise ValueError(f"layer kind {kind!r} is none of {LAYER_KINDS}")
+        norm = partial(RMSNorm, c.rms_norm_eps)
+        with jax.named_scope("attention"):
+            a = GroupedAttention(
+                c.num_attention_heads, c.num_key_value_heads, c.head_dim,
+                kind, c.sliding_window, c.rope_theta, c.rms_norm_eps,
+                self.dtype, self.attention_impl, self.mesh,
+                name="attn")(norm(name="input_norm")(x))
+            x = x + norm(name="post_attn_norm")(a)
+        m = norm(name="pre_mlp_norm")(x)
+        if self.index < c.num_dense_layers:
+            from .moe import SwiGLU
+            f = SwiGLU(c.intermediate_size, self.dtype,
+                       name="mlp")(m.astype(self.dtype))
+            counts = jnp.zeros((), jnp.float32)
+        else:
+            from .moe import DroplessMoe
+            b, t, d = m.shape
+            f, counts = DroplessMoe(
+                c.num_experts, tuple(c.experts_held),
+                c.num_experts_per_tok, c.moe_intermediate_size,
+                c.moe_intermediate_size * c.num_shared_experts,
+                c.route_scale, self.dtype, name="moe")(m.reshape(b * t, d))
+            f = f.reshape(b, t, d)
+        return x + norm(name="post_mlp_norm")(f), counts
+
+
+class CausalDecoder(nn.Module):
+    """tokens (B, T) int32 -> logits (B, T, V) float32; with ``targets``
+    (B, T) the training outputs instead: ``{"loss", "correct", "counts"}``,
+    the mean next-token cross-entropy and the share of positions whose
+    largest logit is the target, computed a chunk of positions at a time
+    (the logits of a whole batch never exist), and per routing layer the
+    counts of assignments (``layer<i>`` -> (E,))."""
+    cfg: Any
+    dtype: Any = jnp.bfloat16
+    attention_impl: str = "auto"
+    remat: bool = True
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, tokens: jax.Array, train: bool = True, targets=None):
+        del train  # no dropout, no batch statistics
+        c = self.cfg
+        d, v = c.hidden_size, c.vocab_held
+        embedding = _Embedding(v, d, name="embed")()
+        x = embedding[tokens].astype(jnp.float32)
+        if c.mup_enabled:
+            x = x * math.sqrt(d)
+        block = DecoderBlock
+        if self.remat:
+            # a block's activations are recomputed in the backward pass,
+            # but for the flash kernel's output and logsumexp (134 MB and
+            # 2 MB a layer at 16,384 tokens): the forward kernel then runs
+            # once a step, not twice
+            from ..ops.pallas.flash_attention import SAVEABLE
+            block = nn.remat(DecoderBlock, policy=jax.checkpoint_policies
+                             .save_only_these_names(*SAVEABLE))
+        counts = {}
+        for i in range(len(c.layer_types)):
+            x, got = block(c, i, self.dtype, self.attention_impl, self.mesh,
+                           name=f"layer{i}")(x)
+            if i >= c.num_dense_layers:
+                counts[f"layer{i}"] = got
+        x = RMSNorm(c.rms_norm_eps, name="final_norm")(x)
+        from .moe import Kernel
+        head = Kernel(v, name="lm_head")(d)
+        with jax.named_scope("lm_head"):
+            if targets is None:
+                return jnp.dot(x.astype(self.dtype), head.astype(self.dtype),
+                               preferred_element_type=jnp.float32)
+            loss, correct = chunked_next_token_loss(
+                x, head, targets, self.dtype)
+        return {"loss": loss, "correct": correct, "counts": counts}
+
+    def objective(self) -> "NextTokenObjective":
+        """The family's loss over its own batch (train/loop.py)."""
+        return NextTokenObjective(self.cfg)
+
+    def init_input(self, rows: int, data_cfg) -> jax.ShapeDtypeStruct:
+        """What ``init`` is traced on: parameters do not depend on the
+        sequence's length, so a short one."""
+        return jax.ShapeDtypeStruct((rows, min(data_cfg.seq_len, 128)),
+                                    jnp.int32)
+
+
+class NextTokenObjective:
+    """``{"tokens": int32 (B, T + 1)}``: inputs are all but the last id of
+    a row, targets all but the first; the loss is the mean next-token
+    cross-entropy over every position of the batch, with no auxiliary term.
+    ``after_update`` is the router-bias rule (arXiv:2408.15664): after the
+    optimizer's update, ``b += load_balance_coeff · sign(mean(c) − c)`` from
+    the step's counts ``c`` of assignments to each published expert; the
+    bias has no gradient (models/moe.biased_topk_route stops it) and no
+    decay (train/optimizers._non_bn_mask)."""
+    batch_keys = ("tokens",)
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+
+    def prepare(self, batch, step, midx=None):
+        del step, midx
+        return batch
+
+    def forward(self, apply_fn, variables, batch):
+        tokens = batch["tokens"]
+        out = apply_fn({"params": variables["params"]}, tokens[:, :-1],
+                       train=True, targets=tokens[:, 1:])
+        metrics = {"precision": out["correct"]}
+        if out["counts"]:
+            lo, hi = self.cfg.experts_held
+            held = jnp.stack([c[lo:hi] for c in out["counts"].values()])
+            per_layer = jnp.sum(held, axis=-1)
+            # expectation: tokens × top-k × held / published, each layer
+            metrics["moe_assignments_held"] = jnp.mean(per_layer)
+            metrics["moe_load_max_over_mean"] = jnp.max(
+                jnp.max(held, axis=-1) * (hi - lo)
+                / jnp.maximum(per_layer, 1.0))
+        return (out["loss"], metrics, variables["batch_stats"], [],
+                out["counts"])
+
+    def after_update(self, params, counts):
+        params = dict(params)
+        for layer, c in counts.items():
+            moe = dict(params[layer]["moe"])
+            moe["router_bias"] = moe["router_bias"] \
+                + self.cfg.load_balance_coeff * jnp.sign(jnp.mean(c) - c)
+            params[layer] = dict(params[layer], moe=moe)
+        return params
+
+
+class _Embedding(nn.Module):
+    rows: int
+    features: int
+
+    @nn.compact
+    def __call__(self):
+        return self.param("embedding", nn.initializers.normal(0.02),
+                          (self.rows, self.features))
+
+
+#: positions to a block of logits (chunked_next_token_loss)
+LOSS_CHUNK = 2048
+
+
+def chunked_next_token_loss(x, head, targets, dtype):
+    """Mean over all positions of logsumexp(x W) − (x W)[target], and the
+    share of positions whose arg-max is the target; ``LOSS_CHUNK`` positions
+    at a time, each chunk's logits recomputed in the backward pass."""
+    b, t, d = x.shape
+    n = b * t
+    chunk = math.gcd(n, LOSS_CHUNK)
+    w = head.astype(dtype)
+
+    @jax.checkpoint
+    def one(args):
+        xc, yc = args
+        logits = jnp.dot(xc.astype(dtype), w,
+                         preferred_element_type=jnp.float32)
+        picked = jnp.take_along_axis(logits, yc[:, None], axis=-1)[:, 0]
+        nll = jax.nn.logsumexp(logits, axis=-1) - picked
+        hit = (jnp.argmax(logits, axis=-1) == yc).astype(jnp.float32)
+        return jnp.sum(nll), jnp.sum(hit)
+    nll, hit = jax.lax.map(one, (x.reshape(n // chunk, chunk, d),
+                                 targets.reshape(n // chunk, chunk)))
+    return jnp.sum(nll) / n, jnp.sum(hit) / n

@@ -34,17 +34,34 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array,
-              causal: bool = False) -> jax.Array:
-    """Dense reference attention. Shapes: (B, T, H, D) — batch, time, heads,
-    head_dim. fp32 softmax regardless of input dtype."""
+              causal: bool = False, window=None) -> jax.Array:
+    """Dense reference attention, and the ``jax.numpy`` twin of the Pallas
+    kernels (ops/pallas/flash_attention.py takes the same arguments).
+    Shapes: q (B, T, H, D) — batch, time, heads, head_dim; k and v
+    (B, T, KV, D) with H a multiple of KV (query head h reads key/value head
+    h // (H/KV)). ``window`` (with ``causal``): query i sees keys
+    i − window < j ≤ i. fp32 softmax regardless of input dtype."""
     b, tq, h, d = q.shape
+    tk, kvh = k.shape[1], k.shape[2]
+    if h % kvh or (window is not None and not causal):
+        raise ValueError(f"heads {h} over {kvh}, window {window} with "
+                         f"causal={causal}: not an attention this computes")
     scale = 1.0 / math.sqrt(d)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
+    if h != kvh:  # grouped heads: one key/value head to each group
+        q = q.reshape(b, tq, kvh, h // kvh, d)
+        s = jnp.einsum("bqhgd,bkhd->bhgqk", q, k).astype(jnp.float32)
+    else:
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+    s = s * scale
     if causal:
-        tk = k.shape[1]
         mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
-        s = jnp.where(mask[None, None], s, -jnp.inf)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq - window)
+        s = jnp.where(mask, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
+    if h != kvh:
+        return jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype),
+                          v).reshape(b, tq, h, d)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 
 

@@ -16,7 +16,17 @@ fused elementwise pass.
 Layout: (B, T, H, D). The wrapper pads T up to lcm(block_q, block_k) and D to
 the 128-lane width; padded keys are masked via ``valid_len``, padded queries
 are sliced off. Causal masking uses the dense-attention convention: with
-tq == tk the diagonal, i.e. query i attends keys ≤ i.
+tq == tk the diagonal, i.e. query i attends keys ≤ i. ``window`` (with
+``causal``) narrows that to the band i − window < j ≤ i: blocks outside the
+band are skipped like blocks above the diagonal, in the compute (``pl.when``)
+and in the copy (their block index is clamped to the nearest live block, and
+a block index that repeats is not fetched again).
+
+Grouped heads: ``k`` and ``v`` may carry fewer heads than ``q`` (H a multiple
+of KV; query head h reads key/value head h // (H/KV)). Keys and values are
+never copied per query head: the index maps send a group's query heads to
+one (B·KV, T, D) block, and the dK/dV pass sums over the group's heads in
+its innermost grid dimension.
 """
 from __future__ import annotations
 
@@ -58,8 +68,57 @@ def _pick_blocks(t: int, d: int) -> tuple:
     return table[-1][1]
 
 
+def _live(qi, kj, causal, window, block_q, block_k):
+    """Does the (q-block, k-block) tile hold any unmasked pair?"""
+    if not causal:
+        return True
+    live = kj * block_k <= qi * block_q + block_q - 1
+    if window is not None:  # its last key is inside the first query's band
+        live = jnp.logical_and(
+            live, kj * block_k + block_k - 1 > qi * block_q - window)
+    return live
+
+
+def _tile_mask(qi, kj, causal, window, valid_len, block_q, block_k):
+    """(block_q, block_k) bool of the pairs that count, or None for all."""
+    k_pos = kj * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (1, block_k), 1)
+    mask = None
+    if valid_len is not None:
+        mask = k_pos < valid_len
+    if causal:
+        q_pos = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, 1), 0)
+        cm = q_pos >= k_pos
+        if window is not None:
+            cm = jnp.logical_and(cm, k_pos > q_pos - window)
+        mask = cm if mask is None else jnp.logical_and(mask, cm)
+    return mask
+
+
+def _live_k(qi, kj, causal, window, block_q, block_k):
+    """The k-block to hold while q-block ``qi`` meets k-block ``kj``: ``kj``
+    itself where the tile is live, else the nearest live one."""
+    if not causal:
+        return kj
+    hi = (qi * block_q + block_q - 1) // block_k
+    lo = 0 if window is None else \
+        jnp.maximum(qi * block_q - window + 1, 0) // block_k
+    return jnp.clip(kj, lo, hi)
+
+
+def _live_q(kj, qi, causal, window, block_q, block_k, nq):
+    """The q-block to hold while k-block ``kj`` meets q-block ``qi``."""
+    if not causal:
+        return qi
+    lo = jnp.minimum((kj * block_k) // block_q, nq - 1)
+    hi = nq - 1 if window is None else jnp.minimum(
+        (kj * block_k + block_k - 2 + window) // block_q, nq - 1)
+    return jnp.clip(qi, lo, hi)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
-                  *, scale, causal, valid_len, block_q, block_k, nk):
+                  *, scale, causal, window, valid_len, block_q, block_k, nk):
     """One (q-block, k-block) tile. Scratch m/l/acc persist across the
     innermost (k-block) grid dimension."""
     qi = pl.program_id(1)
@@ -71,11 +130,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # blocks strictly above the causal diagonal contribute nothing
-    live = jnp.logical_or(not causal,
-                          kj * block_k <= qi * block_q + block_q - 1)
-
-    @pl.when(live)
+    # blocks strictly above the causal diagonal, or wholly outside the
+    # window's band, contribute nothing
+    @pl.when(_live(qi, kj, causal, window, block_q, block_k))
     def _accumulate():
         # keep matmul OPERANDS in the input dtype (bf16 on the MXU's native
         # rate — an f32 cast would halve/quarter throughput); accumulate f32
@@ -83,14 +140,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         k = k_ref[0]                                      # (bk, d)
         v = v_ref[0]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        k_pos = kj * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        if valid_len is not None:
-            s = jnp.where(k_pos < valid_len, s, _NEG_INF)
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        mask = _tile_mask(qi, kj, causal, window, valid_len, block_q,
+                          block_k)
+        if mask is not None:
+            s = jnp.where(mask, s, _NEG_INF)
         m_prev, l_prev, acc_prev = m_ref[:], l_ref[:], acc_ref[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -133,13 +186,29 @@ def _unfold(x, b, h, t, d):  # (B·H, T, D) → (B,T,H,D)
         .transpose(0, 2, 1, 3)
 
 
+def _group(q, k, window, causal):
+    """Query heads to a key/value head; refuses what the kernels cannot do."""
+    h, kvh = q.shape[2], k.shape[2]
+    if h % kvh:
+        raise ValueError(f"{h} query heads are no multiple of {kvh} "
+                         "key/value heads")
+    if window is not None and not causal:
+        raise ValueError("a window needs causal=True")
+    if window is not None and q.shape[1] != k.shape[1]:
+        raise ValueError("a window needs as many queries as keys")
+    return h // kvh
+
+
 def _flash_forward(q, k, v, causal=False, interpret=False,
-                   block_q=BLOCK_Q, block_k=BLOCK_K, return_residuals=False):
+                   block_q=BLOCK_Q, block_k=BLOCK_K, return_residuals=False,
+                   window=None):
     b, t, h, d = q.shape
+    g = _group(q, k, window, causal)
     scale = 1.0 / math.sqrt(d)
     block_q, block_k, tpad, dpad = _geometry(t, d, block_q, block_k)
 
-    qf, kf, vf = (_fold(x, b, h, d) for x in (q, k, v))
+    qf = _fold(q, b, h, d)
+    kf, vf = (_fold(x, b, h // g, d) for x in (k, v))
     if tpad or dpad:
         pad = ((0, 0), (0, tpad), (0, dpad))
         qf, kf, vf = (jnp.pad(x, pad) for x in (qf, kf, vf))
@@ -148,9 +217,12 @@ def _flash_forward(q, k, v, causal=False, interpret=False,
     grid = (b * h, nq, nk)
 
     kernel = functools.partial(
-        _flash_kernel, scale=scale, causal=causal,
+        _flash_kernel, scale=scale, causal=causal, window=window,
         valid_len=(t if tpad else None), block_q=block_q, block_k=block_k,
         nk=nk)
+
+    def k_at(bh, i, j):  # a group's query heads read one key/value head
+        return (bh // g, _live_k(i, j, causal, window, block_q, block_k), 0)
 
     scratch = [pltpu.VMEM((block_q, 1), jnp.float32),
                pltpu.VMEM((block_q, 1), jnp.float32),
@@ -166,10 +238,8 @@ def _flash_forward(q, k, v, causal=False, interpret=False,
         in_specs=[
             pl.BlockSpec((1, block_q, dp), lambda bh, i, j: (bh, i, 0),
                          memory_space=_VMEM),
-            pl.BlockSpec((1, block_k, dp), lambda bh, i, j: (bh, j, 0),
-                         memory_space=_VMEM),
-            pl.BlockSpec((1, block_k, dp), lambda bh, i, j: (bh, j, 0),
-                         memory_space=_VMEM),
+            pl.BlockSpec((1, block_k, dp), k_at, memory_space=_VMEM),
+            pl.BlockSpec((1, block_k, dp), k_at, memory_space=_VMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, dp), lambda bh, i, j: (bh, i, 0),
@@ -195,7 +265,8 @@ def _flash_forward(q, k, v, causal=False, interpret=False,
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_acc, *, scale, causal, valid_len, block_q, block_k, nk):
+                   dq_acc, *, scale, causal, window, valid_len, block_q,
+                   block_k, nk):
     """dQ pass: grid (B·H, nq, nk), k-blocks innermost/sequential.
     dS = P ∘ (dO·Vᵀ − Δ); dQ = scale · dS·K   (flash-attention-2 backward)."""
     qi = pl.program_id(1)
@@ -205,25 +276,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    live = jnp.logical_or(not causal,
-                          kj * block_k <= qi * block_q + block_q - 1)
-
-    @pl.when(live)
+    @pl.when(_live(qi, kj, causal, window, block_q, block_k))
     def _accumulate():
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         lse = lse_ref[0]                                   # (bq, 1)
         delta = delta_ref[0]                               # (bq, 1)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        k_pos = kj * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        mask = None
-        if valid_len is not None:
-            mask = k_pos < valid_len
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0)
-            cm = q_pos >= k_pos
-            mask = cm if mask is None else jnp.logical_and(mask, cm)
+        mask = _tile_mask(qi, kj, causal, window, valid_len, block_q,
+                          block_k)
         p = jnp.exp(s - lse)
         if mask is not None:
             p = jnp.where(mask, p, 0.0)
@@ -238,36 +298,29 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *,
-                    scale, causal, valid_len, block_q, block_k, nq):
-    """dK/dV pass: grid (B·H, nk, nq), q-blocks innermost/sequential.
+                    scale, causal, window, valid_len, block_q, block_k, nq,
+                    group):
+    """dK/dV pass: grid (B·KV, nk, group·nq): the q-blocks of every query
+    head of the group innermost/sequential, so one key/value head's
+    gradient sums over its group without leaving VMEM.
     dV = Pᵀ·dO;  dK = scale · dSᵀ·Q."""
     kj = pl.program_id(1)
-    qi = pl.program_id(2)
+    r = pl.program_id(2)
+    qi = r % nq
 
-    @pl.when(qi == 0)
+    @pl.when(r == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    live = jnp.logical_or(not causal,
-                          kj * block_k <= qi * block_q + block_q - 1)
-
-    @pl.when(live)
+    @pl.when(_live(qi, kj, causal, window, block_q, block_k))
     def _accumulate():
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         lse = lse_ref[0]
         delta = delta_ref[0]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        k_pos = kj * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        mask = None
-        if valid_len is not None:
-            mask = k_pos < valid_len
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0)
-            cm = q_pos >= k_pos
-            mask = cm if mask is None else jnp.logical_and(mask, cm)
+        mask = _tile_mask(qi, kj, causal, window, valid_len, block_q,
+                          block_k)
         p = jnp.exp(s - lse)
         if mask is not None:
             p = jnp.where(mask, p, 0.0)
@@ -277,21 +330,24 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         ds = (p * (dp - delta)).astype(q.dtype)
         dk_acc[:] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32) * scale
 
-    @pl.when(qi == nq - 1)
+    @pl.when(r == group * nq - 1)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _flash_backward(q, k, v, out, lse, g, causal=False, interpret=False,
-                    block_q=BLOCK_Q, block_k=BLOCK_K):
+                    block_q=BLOCK_Q, block_k=BLOCK_K, window=None):
     """Fused Pallas backward: recomputes P per tile from (q, k, lse) — no
     O(T²) residuals, two passes over the kv/q grids."""
     b, t, h, d = q.shape
+    group = _group(q, k, window, causal)
+    kvh = h // group
     scale = 1.0 / math.sqrt(d)
     block_q, block_k, tpad, dpad = _geometry(t, d, block_q, block_k)
 
-    qf, kf, vf, dof, of = (_fold(x, b, h, d) for x in (q, k, v, g, out))
+    qf, dof, of = (_fold(x, b, h, d) for x in (q, g, out))
+    kf, vf = (_fold(x, b, kvh, d) for x in (k, v))
     if tpad or dpad:
         pad = ((0, 0), (0, tpad), (0, dpad))
         qf, kf, vf, dof, of = (jnp.pad(x, pad)
@@ -302,7 +358,7 @@ def _flash_backward(q, k, v, out, lse, g, causal=False, interpret=False,
     delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32),
                     axis=-1, keepdims=True)                # (B·H, tp, 1)
 
-    common = dict(scale=scale, causal=causal,
+    common = dict(scale=scale, causal=causal, window=window,
                   valid_len=(t if tpad else None),
                   block_q=block_q, block_k=block_k)
 
@@ -317,10 +373,14 @@ def _flash_backward(q, k, v, out, lse, g, causal=False, interpret=False,
     def rb(im):
         return pl.BlockSpec((1, block_q, 1), im, memory_space=_VMEM)
 
+    live = (causal, window, block_q, block_k)
     q_at = lambda bh, i, j: (bh, i, 0)    # noqa: E731
-    k_at = lambda bh, i, j: (bh, j, 0)    # noqa: E731
-    q_at2 = lambda bh, j, i: (bh, i, 0)   # noqa: E731
-    k_at2 = lambda bh, j, i: (bh, j, 0)   # noqa: E731
+    k_at = lambda bh, i, j: (bh // group, _live_k(i, j, *live), 0)  # noqa: E731
+    # the dK/dV grid runs over key/value heads; r walks the group's query
+    # heads and, within each, its q-blocks
+    q_at2 = lambda bkv, j, r: (bkv * group + r // nq,   # noqa: E731
+                               _live_q(j, r % nq, *live, nq), 0)
+    k_at2 = lambda bkv, j, r: (bkv, j, 0)   # noqa: E731
 
     extra = {}
     if not interpret:
@@ -340,14 +400,14 @@ def _flash_backward(q, k, v, out, lse, g, causal=False, interpret=False,
     )(qf, kf, vf, dof, lse, delta)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, nq=nq, **common),
-        grid=(b * h, nk, nq),
+        functools.partial(_bwd_dkv_kernel, nq=nq, group=group, **common),
+        grid=(b * kvh, nk, group * nq),
         in_specs=[qb(q_at2), kb(k_at2), kb(k_at2), qb(q_at2), rb(q_at2),
                   rb(q_at2)],
         out_specs=[kb(k_at2), kb(k_at2)],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, tp, dp), k.dtype),
-            jax.ShapeDtypeStruct((b * h, tp, dp), v.dtype),
+            jax.ShapeDtypeStruct((b * kvh, tp, dp), k.dtype),
+            jax.ShapeDtypeStruct((b * kvh, tp, dp), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, dp), jnp.float32),
                         pltpu.VMEM((block_k, dp), jnp.float32)],
@@ -356,22 +416,27 @@ def _flash_backward(q, k, v, out, lse, g, causal=False, interpret=False,
         **extra,
     )(qf, kf, vf, dof, lse, delta)
 
-    return (_unfold(dq, b, h, t, d), _unfold(dk, b, h, t, d),
-            _unfold(dv, b, h, t, d))
+    return (_unfold(dq, b, h, t, d), _unfold(dk, b, kvh, t, d),
+            _unfold(dv, b, kvh, t, d))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False, interpret: bool = False,
-                    block_q: int = 0, block_k: int = 0) -> jax.Array:
-    """Pallas flash attention, (B, T, H, D). Differentiable with a FUSED
-    Pallas backward (dq + dk/dv kernels recomputing P from the lse
-    residual — O(T) memory, no extra full forward). ``block_q``/``block_k``
-    of 0 pick the measured-optimal tile for the sequence length and head
-    dim (_BLOCK_TABLES; tools/tune_flash_attention.py re-derives them)."""
+                    block_q: int = 0, block_k: int = 0,
+                    window=None) -> jax.Array:
+    """Pallas flash attention, q (B, T, H, D), k and v (B, T, KV, D) with H
+    a multiple of KV. Differentiable with a FUSED Pallas backward (dq +
+    dk/dv kernels recomputing P from the lse residual — O(T) memory, no
+    extra full forward). ``window`` (with ``causal``): query i sees keys
+    i − window < j ≤ i. ``block_q``/``block_k`` of 0 pick the
+    measured-optimal tile for the sequence length and head dim
+    (_BLOCK_TABLES; tools/tune_flash_attention.py re-derives them).
+    ``ops.attention.attention`` takes the same arguments and is the
+    kernels' ``jax.numpy`` twin."""
     bq, bk = _resolve_blocks(q, block_q, block_k)
     return _flash_forward(q, k, v, causal, interpret,
-                          block_q=bq, block_k=bk)
+                          block_q=bq, block_k=bk, window=window)
 
 
 def _resolve_blocks(q, block_q, block_k):
@@ -379,18 +444,30 @@ def _resolve_blocks(q, block_q, block_k):
     return block_q or auto_q, block_k or auto_k
 
 
-def _fa_fwd(q, k, v, causal, interpret, block_q, block_k):
+#: names a recomputation policy can keep (``jax.checkpoint_policies.
+#: save_only_these_names``): with the kernel's output and its logsumexp
+#: saved, a recomputed block does not run the forward kernel a second time
+SAVEABLE = ("flash_out", "flash_lse")
+
+
+def _fa_fwd(q, k, v, causal, interpret, block_q, block_k, window):
+    from jax.ad_checkpoint import checkpoint_name
     bq, bk = _resolve_blocks(q, block_q, block_k)
     out, lse = _flash_forward(q, k, v, causal, interpret,
-                              block_q=bq, block_k=bk, return_residuals=True)
+                              block_q=bq, block_k=bk, return_residuals=True,
+                              window=window)
+    out = checkpoint_name(out, SAVEABLE[0])
+    # kept without its trailing axis of 1, which a tiled layout pads to a
+    # lane's 128
+    lse = checkpoint_name(lse[..., 0], SAVEABLE[1])
     return out, (q, k, v, out, lse)
 
 
-def _fa_bwd(causal, interpret, block_q, block_k, res, g):
+def _fa_bwd(causal, interpret, block_q, block_k, window, res, g):
     q, k, v, out, lse = res
     bq, bk = _resolve_blocks(q, block_q, block_k)
-    return _flash_backward(q, k, v, out, lse, g, causal, interpret,
-                           block_q=bq, block_k=bk)
+    return _flash_backward(q, k, v, out, lse[..., None], g, causal,
+                           interpret, block_q=bq, block_k=bk, window=window)
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)

@@ -69,7 +69,9 @@ log = logging.getLogger(__name__)
 
 #: bump when the trace.json event shape changes (consumers key on it via
 #: the ``trace_dump`` metrics row and the file's otherData block)
-SPAN_SCHEMA_VERSION = 11  # 11: + train.hook_read (a cadence hook's
+SPAN_SCHEMA_VERSION = 12  # 12: + input.tokens (data/tokens.py: one packed
+#                              batch of a token stream)
+#                              11: + train.hook_read (a cadence hook's
 #                               late read of device metrics) and
 #                               train.lead_wait (the fused loop's bound
 #                               on dispatches in flight), PR 32
@@ -107,6 +109,8 @@ SPAN_CATALOG = {
     "input.decode": "one image decoded + cropped (imagenet decode worker) "
                     "or one batch gathered + augmented (cifar iterator); "
                     "charges the 'decode' stage",
+    "input.tokens": "one batch of a token stream drawn and packed to "
+                    "fixed sequences (data/tokens.py)",
     "input.stack": "K host batches np.stack'ed (stacker thread; charges "
                    "the 'stack' stage)",
     "input.echo": "one source batch absorbed into the decoded-sample echo "
@@ -144,11 +148,13 @@ SPAN_CATALOG = {
                        "later (inside train.hooks, or after the loop at "
                        "Trainer.train's flush); count = late reads, "
                        "seconds = the loop's wait for the device",
-    "train.lead_wait": "the fused loop's wait, after sending a dispatch, "
-                       "for the one FUSED_DISPATCH_LEAD back "
-                       "(train/loop.py): bounds the dispatches in flight "
-                       "and with them the groups and outputs the runtime "
-                       "holds for them",
+    "train.lead_wait": "the loop's wait, after sending a dispatch, for "
+                       "the one FUSED_DISPATCH_LEAD back (fused loop) or "
+                       "the step STEP_LEAD_SECONDS of device work back "
+                       "(one-step loop) (train/loop.py): bounds the "
+                       "dispatches in flight, the groups and outputs the "
+                       "runtime holds for them, and what a stop_fn waits "
+                       "behind",
     "eval.round": "one full evaluation round (goodput: eval)",
     "eval.batch": "one eval batch: stage wait + step dispatch",
     # checkpointing (checkpoint/manager.py)

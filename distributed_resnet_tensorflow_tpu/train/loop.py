@@ -39,11 +39,15 @@ from ..parallel.sharding import (finalize_staged, make_global_batch,
 from .optimizers import (create_optimizer, decoupled_decay,
                          loss_weight_decay)
 from .schedules import create_schedule
-from .state import TrainState, create_train_state, state_shardings
+from .state import (TrainState, create_train_state, init_input,
+                    state_shardings)
 
 
 #: fused dispatches queued behind the one the loop waits for (Trainer.train)
 FUSED_DISPATCH_LEAD = 2
+#: the one-step loop's lead: seconds of device work it lets stand behind the
+#: step that runs, at least two steps (Trainer.train)
+STEP_LEAD_SECONDS = 2.0
 
 
 def per_example_cross_entropy(logits: jax.Array, labels: jax.Array,
@@ -111,6 +115,56 @@ def make_ce_fn(label_smoothing: float = 0.0, fused_xent: str = "off",
     return lambda logits, labels: per_ex(logits, labels).mean()
 
 
+class ClassifierObjective:
+    """What an image classifier asks of the step: ``{"images", "labels"}``
+    batches, device-side augmentation at the top of the step, logits into
+    the batch cross-entropy, the top-1 share as ``precision``.
+
+    An *objective* is how a model family supplies its loss over its own
+    batch (``make_train_step``): ``batch_keys``; ``prepare(batch, step,
+    midx)``; ``forward(apply_fn, variables, batch) -> (ce, metrics,
+    batch_stats, sown_losses, aux)``; and ``after_update(params, aux) ->
+    params`` or None, a rule that moves leaves after the optimizer's update
+    from what the forward pass returned beside its loss. A model whose
+    class has an ``objective()`` brings its own
+    (models/transformer.CausalDecoder); every other gets this one."""
+    batch_keys = ("images", "labels")
+    after_update = None
+
+    def __init__(self, ce_fn, augment_fn=None, augment_seed: int = 0,
+                 precision=None):
+        self.ce_fn, self.augment_fn = ce_fn, augment_fn
+        self.augment_seed, self.precision = augment_seed, precision
+
+    def prepare(self, batch, step, midx=None):
+        if self.augment_fn is None:
+            return batch
+        with jax.named_scope("input_prep"):
+            rng = jax.random.fold_in(jax.random.PRNGKey(self.augment_seed),
+                                     step)
+            if midx is not None:  # distinct draws per accumulation microbatch
+                rng = jax.random.fold_in(rng, midx)
+            return dict(batch, images=self.augment_fn(batch["images"], rng))
+
+    def forward(self, apply_fn, variables, batch):
+        images, labels = batch["images"], batch["labels"]
+        if self.precision is not None:
+            # the policy cast wraps model apply (parallel/precision.py):
+            # activations enter in the compute dtype; params stay f32
+            # masters (flax casts them per-op, and the cast's transpose
+            # re-accumulates the gradient into the f32 cotangent)
+            images = self.precision.cast_compute(images)
+        logits, mutated = apply_fn(variables, images, train=True,
+                                   mutable=["batch_stats", "losses"])
+        top1 = jnp.mean(
+            (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32))
+        # auxiliary losses sown by modules (e.g. the Switch MoE
+        # load-balancing term, models/moe.py)
+        sown = jax.tree_util.tree_leaves(mutated.get("losses", {}))
+        return (self.ce_fn(logits, labels), {"precision": top1},
+                mutated["batch_stats"], sown, None)
+
+
 def make_train_step(schedule: Callable, weight_decay: float,
                     label_smoothing: float = 0.0,
                     decay_in_loss: bool = True,
@@ -121,8 +175,13 @@ def make_train_step(schedule: Callable, weight_decay: float,
                     augment_seed: int = 0,
                     aux_loss_weight: float = 0.01,
                     apply_gradients_fn: Optional[Callable] = None,
-                    precision=None):
+                    precision=None, objective=None):
     """Build the pure train_step(state, batch) -> (state, metrics).
+
+    ``objective`` is the model family's loss over its own batch
+    (``ClassifierObjective`` documents the contract; None builds that one
+    from ``ce_fn``/``augment_fn``/``precision``). The step is one path: what
+    differs between an image classifier and a token model is the objective.
 
     ``augment_fn(images, rng) -> images`` runs device-side augmentation at
     the top of the step (raw uint8 in, standardized f32 out — see
@@ -140,8 +199,10 @@ def make_train_step(schedule: Callable, weight_decay: float,
     (bf16), while the loss/CE/metric arithmetic around the apply stays
     f32 (make_ce_fn casts logits up before the softmax) and the
     gradients/optimizer update run on the f32 masters."""
-    if ce_fn is None:
-        ce_fn = make_ce_fn(label_smoothing)
+    if objective is None:
+        objective = ClassifierObjective(
+            ce_fn if ce_fn is not None else make_ce_fn(label_smoothing),
+            augment_fn, augment_seed, precision)
     if apply_gradients_fn is None:
         apply_gradients_fn = lambda state, grads: \
             state.apply_gradients(grads)  # noqa: E731
@@ -149,56 +210,43 @@ def make_train_step(schedule: Callable, weight_decay: float,
     # the named scopes are metadata only: they name the device ops of a
     # profiler trace (input_prep / forward / transpose(jvp(forward)) = the
     # backward pass / optimizer), so the step's device time splits by phase
-    def prep(images, step, midx=None):
-        if augment_fn is None:
-            return images
-        with jax.named_scope("input_prep"):
-            rng = jax.random.fold_in(jax.random.PRNGKey(augment_seed), step)
-            if midx is not None:  # distinct draws per accumulation microbatch
-                rng = jax.random.fold_in(rng, midx)
-            return augment_fn(images, rng)
-
     @jax.named_scope("forward")
-    def loss_fn(params, batch_stats, images, labels, apply_fn):
+    def loss_fn(params, batch_stats, batch, apply_fn):
         variables = {"params": params, "batch_stats": batch_stats}
-        if precision is not None:
-            # the policy cast wraps model apply (parallel/precision.py):
-            # activations enter in the compute dtype; params stay f32
-            # masters (flax casts them per-op, and the cast's transpose
-            # re-accumulates the gradient into the f32 cotangent)
-            images = precision.cast_compute(images)
-        logits, mutated = apply_fn(variables, images, train=True,
-                                   mutable=["batch_stats", "losses"])
-        ce = ce_fn(logits, labels)
+        ce, extra, new_bs, sown, aux = objective.forward(apply_fn, variables,
+                                                         batch)
         loss = ce
         if decay_in_loss:
             # L2 in the loss like the reference (resnet_model.py:78-86);
             # decay_all_params toggles kernels-only vs all-trainables
             loss = loss + loss_weight_decay(params, weight_decay,
                                             decay_all_params)
-        # auxiliary losses sown by modules (e.g. the Switch MoE
-        # load-balancing term, models/moe.py)
-        aux = jax.tree_util.tree_leaves(mutated.get("losses", {}))
-        if aux:
-            loss = loss + aux_loss_weight * sum(jnp.sum(a) for a in aux)
-        return loss, (ce, logits, mutated["batch_stats"])
+        if sown:
+            loss = loss + aux_loss_weight * sum(jnp.sum(a) for a in sown)
+        return loss, (ce, extra, new_bs, aux)
 
-    def single_step(state: TrainState, batch) -> Tuple[TrainState, Dict[str, Any]]:
-        images, labels = batch["images"], batch["labels"]
-        images = prep(images, state.step)
-        (loss, (ce, logits, new_bs)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(state.params, state.batch_stats,
-                                   images, labels, state.apply_fn)
+    def update(state, grads, new_bs, aux):
+        """The optimizer's update, then the family's rule (if it has one)
+        inside the same program."""
         new_state = apply_gradients_fn(state, grads).replace(
             batch_stats=new_bs)
-        precision = jnp.mean(
-            (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32))
+        if objective.after_update is not None:
+            with jax.named_scope("after_update"):
+                new_state = new_state.replace(
+                    params=objective.after_update(new_state.params, aux))
+        return new_state
+
+    def single_step(state: TrainState, batch) -> Tuple[TrainState, Dict[str, Any]]:
+        batch = objective.prepare(batch, state.step)
+        (loss, (ce, extra, new_bs, aux)), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(state.params, state.batch_stats,
+                                   batch, state.apply_fn)
         metrics = {
-            "loss": loss, "cross_entropy": ce, "precision": precision,
+            "loss": loss, "cross_entropy": ce, **extra,
             "learning_rate": schedule(state.step),
             "grad_norm": optax.global_norm(grads),
         }
-        return new_state, metrics
+        return update(state, grads, new_bs, aux), metrics
 
     if grad_accum_steps <= 1:
         return single_step
@@ -206,43 +254,41 @@ def make_train_step(schedule: Callable, weight_decay: float,
     def accum_step(state: TrainState, batch) -> Tuple[TrainState, Dict[str, Any]]:
         """lax.scan over microbatches: grads averaged, BN stats from the last
         microbatch (the reference had no accumulation; this enables reference
-        global-batch parity on few chips).
+        global-batch parity on few chips); the objective's metrics averaged
+        and what its rule reads (``aux``) summed.
 
         Augmentation/standardization runs INSIDE the scan body, one
         microbatch at a time — prepping the whole global batch up front
         would materialize it in float32 (at gbs 32k × 224² that is ~20 GB,
         more than a chip's HBM; the uint8 input is 4×-8× smaller)."""
-        images, labels = batch["images"], batch["labels"]
         n = grad_accum_steps
-        mb = images.shape[0] // n
-        images = images.reshape((n, mb) + images.shape[1:])
-        labels = labels.reshape((n, mb) + labels.shape[1:])
+        batch = jax.tree_util.tree_map(
+            lambda x: x.reshape((n, x.shape[0] // n) + x.shape[1:]), batch)
+        grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
         def body(carry, xs):
-            grads_acc, ce_acc, prec_acc, bs = carry
-            im, lb, midx = xs
-            im = prep(im, state.step, midx)
-            grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
-            (loss, (ce, logits, new_bs)), grads = grad_fn(
-                state.params, bs, im, lb, state.apply_fn)
-            prec = jnp.mean((jnp.argmax(logits, -1) == lb).astype(jnp.float32))
+            grads_acc, bs = carry
+            mb, midx = xs
+            mb = objective.prepare(mb, state.step, midx)
+            (loss, (ce, extra, new_bs, aux)), grads = grad_fn(
+                state.params, bs, mb, state.apply_fn)
             grads_acc = jax.tree_util.tree_map(jnp.add, grads_acc, grads)
-            return (grads_acc, ce_acc + ce, prec_acc + prec, new_bs), loss
+            # scalars and the rule's small sums: stacked, reduced below
+            return (grads_acc, new_bs), (
+                {"loss": loss, "cross_entropy": ce, **extra}, aux)
 
         zero_grads = jax.tree_util.tree_map(
             lambda p: jnp.zeros_like(p, jnp.float32), state.params)
-        (grads, ce_sum, prec_sum, new_bs), losses = jax.lax.scan(
-            body, (zero_grads, 0.0, 0.0, state.batch_stats),
-            (images, labels, jnp.arange(n)))
+        (grads, new_bs), (per_micro, aux) = jax.lax.scan(
+            body, (zero_grads, state.batch_stats), (batch, jnp.arange(n)))
         grads = jax.tree_util.tree_map(lambda g: g / n, grads)
-        new_state = apply_gradients_fn(state, grads).replace(
-            batch_stats=new_bs)
+        aux = jax.tree_util.tree_map(lambda a: a.sum(axis=0), aux)
         metrics = {
-            "loss": losses.mean(), "cross_entropy": ce_sum / n,
-            "precision": prec_sum / n, "learning_rate": schedule(state.step),
+            **{k: v.mean() for k, v in per_micro.items()},
+            "learning_rate": schedule(state.step),
             "grad_norm": optax.global_norm(grads),
         }
-        return new_state, metrics
+        return update(state, grads, new_bs, aux), metrics
 
     return accum_step
 
@@ -551,6 +597,9 @@ class Trainer:
             return "on" if flag else "off"
 
         attention = "n/a"
+        if cfg.model.name == "afmoe":
+            from ..models.transformer import causal_flash_or_dense
+            attention = causal_flash_or_dense(self.model.attention_impl)
         if cfg.model.name == "vit":
             attention = self.model.attention_impl
             if attention == "auto":  # no seq axis (create_model resolves it)
@@ -620,6 +669,12 @@ class Trainer:
 
     def _build_train_step(self, aug_fn):
         cfg = self.cfg
+        # a model family that is no image classifier brings its own loss
+        # over its own batch (ClassifierObjective documents the contract)
+        make = getattr(self.model, "objective", None)
+        objective = make() if make is not None else None
+        self._batch_keys = objective.batch_keys if objective is not None \
+            else ClassifierObjective.batch_keys
         return make_train_step(
             self.schedule, cfg.optimizer.weight_decay,
             cfg.optimizer.label_smoothing,
@@ -632,7 +687,7 @@ class Trainer:
             aux_loss_weight=cfg.model.moe_aux_weight,
             apply_gradients_fn=self._make_zero1_apply()
             if self._zero1 else None,
-            precision=self._precision)
+            precision=self._precision, objective=objective)
 
     @property
     def zero1_active(self) -> bool:
@@ -689,10 +744,9 @@ class Trainer:
             # attention) need the init dummy batch divisible by the batch
             # mesh axes
             nb = batch_shard_count(self.mesh)
-            shape = (nb, c.data.image_size, c.data.image_size, 3) \
-                if c.model.name != "logistic" else (nb, c.model.input_size)
             self.state = create_train_state(
-                rng, self.model, self.tx, shape, mesh=self.mesh,
+                rng, self.model, self.tx, init_input(self.model, c, nb),
+                mesh=self.mesh,
                 zero1=self._zero1, zero1_min_size=self._zero1_min_size())
             if self._precision is not None:
                 # the policy's checkpoint contract: f32 MASTERS only — a
@@ -713,7 +767,7 @@ class Trainer:
             b_sh = data_sharding(self.mesh)
             self._jitted_train = jax.jit(
                 self._train_step,
-                in_shardings=(st_sh, {"images": b_sh, "labels": b_sh}),
+                in_shardings=(st_sh, {k: b_sh for k in self._batch_keys}),
                 out_shardings=(st_sh, None),
                 donate_argnums=(0,),
                 compiler_options=self._step_compiler_options)
@@ -748,7 +802,7 @@ class Trainer:
 
             def multi(state, batches):
                 if reshuffle:
-                    lead = batches["labels"].shape
+                    lead = batches[self._batch_keys[-1]].shape
                     kb = lead[0] * lead[1]
                     perm = jax.random.permutation(
                         jax.random.fold_in(jax.random.PRNGKey(perm_seed),
@@ -774,7 +828,7 @@ class Trainer:
                 self.mesh, P(None, *data_sharding(self.mesh).spec))
             self._jitted_multi = jax.jit(
                 multi,
-                in_shardings=(st_sh, {"images": b_sh, "labels": b_sh}),
+                in_shardings=(st_sh, {k: b_sh for k in self._batch_keys}),
                 out_shardings=(st_sh, None),
                 donate_argnums=(0,),
                 compiler_options=self._step_compiler_options)
@@ -1075,6 +1129,21 @@ class Trainer:
                 if self.heartbeat is not None else contextlib.nullcontext
             batch = None
             batch_uses = 0
+            # metrics of the steps sent and not yet waited for. The
+            # runtime stops the host only at 32 programs in flight (16
+            # steps of an unpack and a step program): with steps of a
+            # second that is 16 s of work queued behind a stop_fn (a
+            # preemption waits that long for its state), and a profile of
+            # 12 dispatches holds one execution of the step. So the lead
+            # is STEP_LEAD_SECONDS of device work: the loop waits for the
+            # step `lead` back, and `lead` is that many seconds over the
+            # time between two waits that follow each other, a step's
+            # time while the device is what the loop waits for. Where
+            # that is more than the runtime allows (89 ms a step: 22
+            # steps, the ResNet cell) the step waited for has long run,
+            # the wait returns at once and the runtime's bound holds.
+            sent = collections.deque()
+            lead, waited_at = 2, None
             for step in range(start_step, num_steps):
                 if batch_uses <= 0:
                     try:
@@ -1096,6 +1165,17 @@ class Trainer:
                 batch_uses -= 1
                 with span("train.step", step_num=step):
                     self.state, metrics = step_fn(self.state, batch)
+                sent.append(metrics)
+                if len(sent) > lead:
+                    with span("train.lead_wait"):
+                        jax.block_until_ready(sent.popleft())
+                    now = time.perf_counter()
+                    if waited_at is not None and now > waited_at:
+                        lead = max(2, int(STEP_LEAD_SECONDS
+                                          / (now - waited_at)))
+                    waited_at = now
+                else:
+                    waited_at = None
                 with span("train.hooks"):
                     for h in hooks:
                         h(step + 1, self.state, metrics)

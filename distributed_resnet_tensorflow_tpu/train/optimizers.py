@@ -84,9 +84,11 @@ def decoupled_decay(name: str) -> bool:
 
 def _non_bn_mask(params):
     """True for params that should get weight decay / trust-ratio scaling:
-    exclude BatchNorm scale/bias, all 1-D params (biases), and position
-    embeddings (`pos_embed`, (1, T, D) — ndim>1 but not a matmul kernel;
-    ViT recipes conventionally exempt it from decay)."""
+    exclude BatchNorm scale/bias, all 1-D params (biases, norm scales, a
+    router's rule-moved bias), position embeddings (`pos_embed`, (1, T, D) —
+    ndim>1 but not a matmul kernel; ViT recipes conventionally exempt it
+    from decay) and a token model's `embedding` table (a lookup, not a
+    product: decaying it shrinks rare ids towards each other)."""
     import jax
 
     def keep(path, leaf):
@@ -95,7 +97,8 @@ def _non_bn_mask(params):
             return False
         # expert-stacked MoE biases are 2-D; exclude biases (and the ViT
         # pos_embed) by name too
-        if names and ("bias" in names[-1] or "pos_embed" in names[-1]):
+        if names and any(kind in names[-1] for kind in
+                         ("bias", "pos_embed", "embedding")):
             return False
         return leaf.ndim > 1
 

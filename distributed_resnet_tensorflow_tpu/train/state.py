@@ -44,7 +44,10 @@ class TrainState(struct.PyTreeNode):
 
 
 def _make_init_fn(model, tx, input_shape):
-    dummy = jnp.zeros(input_shape, jnp.float32)
+    # a shape of float32, or a ShapeDtypeStruct where the model's input is
+    # no float image (token ids)
+    dummy = jnp.zeros(getattr(input_shape, "shape", input_shape),
+                      getattr(input_shape, "dtype", jnp.float32))
 
     def init_fn(rng):
         variables = model.init(rng, dummy, train=False)
@@ -56,6 +59,18 @@ def _make_init_fn(model, tx, input_shape):
                           apply_fn=model.apply, tx=tx)
 
     return init_fn
+
+
+def init_input(model, cfg, rows: int):
+    """What ``rows`` examples of a model's ``init`` input look like: the
+    family's own (token ids: models/transformer.CausalDecoder.init_input),
+    else the float32 vector or image the config sizes. The ONE place the
+    trainer and the static elaborators (analysis/) size an init batch."""
+    if hasattr(model, "init_input"):
+        return model.init_input(rows, cfg.data)
+    if cfg.model.name == "logistic":
+        return (rows, cfg.model.input_size)
+    return (rows, cfg.data.image_size, cfg.data.image_size, 3)
 
 
 def abstract_train_state(model, tx, input_shape) -> TrainState:

@@ -22,7 +22,7 @@ class ModelConfig:
     the size/width axes the reference hard-coded (resnet_model.py:71-74 pins
     resnet_size=50 for both datasets)."""
 
-    name: str = "resnet"              # resnet | logistic | vit
+    name: str = "resnet"              # resnet | logistic | vit | afmoe
     resnet_size: int = 50             # cifar: 6n+2 ∈ {20,32,44,50,56,110,...}; imagenet: 18/34/50/101/152/200
     width_multiplier: int = 1         # Wide-ResNet (e.g. 28-10 → resnet_size=28, width=10)
     num_classes: int = 10
@@ -79,7 +79,36 @@ class ModelConfig:
     vit_moe_dispatch: str = "auto"    # auto | einsum | gather | a2a
     moe_aux_weight: float = 0.01      # Switch load-balancing loss weight
     # auto = ring if mesh.sequence>1; flash on TPU at >=2048 tokens; else dense
+    # (afmoe: auto = flash on TPU at any length, dense elsewhere)
     attention_impl: str = "auto"      # auto | dense | blockwise | flash | ring
+    # -- causal decoder family (name="afmoe": models/transformer.py
+    # CausalDecoder + models/moe.py DroplessMoe). Keys are the published
+    # config.json's where it has one; the sequence length is data.seq_len.
+    hidden_size: int = 256
+    num_attention_heads: int = 8      # query heads ...
+    num_key_value_heads: int = 2      # ... over this many key/value heads
+    head_dim: int = 32
+    # one entry a layer: sliding_attention (window + rotary positions) |
+    # full_attention (causal, no positional term)
+    layer_types: Tuple[str, ...] = ("sliding_attention", "full_attention")
+    sliding_window: int = 64
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-5
+    num_dense_layers: int = 1         # leading layers with a dense SwiGLU
+    intermediate_size: int = 512      # ... of this width
+    moe_intermediate_size: int = 128  # a routed (and the shared) expert's
+    num_experts: int = 16             # the router's width: ALL the experts
+    # [lo, hi) of the published experts live on this chip; the layer
+    # routes over all of them and computes its own experts' part only
+    experts_held: Tuple[int, int] = (0, 16)
+    num_experts_per_tok: int = 4
+    num_shared_experts: int = 1
+    route_scale: float = 1.0
+    # rate of the router-bias rule (arXiv:2408.15664): after each update
+    # b += coeff * sign(mean(load) - load); no auxiliary loss term
+    load_balance_coeff: float = 0.001
+    mup_enabled: bool = True          # embedding output x sqrt(hidden_size)
+    vocab_held: int = 512             # vocabulary rows held here (untied head)
 
 
 @dataclass
@@ -171,6 +200,9 @@ class DataConfig:
     verify_crc: bool = False
     # eval pipeline
     eval_batch_size: int = 100        # reference resnet_cifar_eval.py batch of 100
+    # token models (dataset="tokens", data/tokens.py): a batch is
+    # {"tokens": int32 [B, seq_len + 1]}, inputs and next-token targets
+    seq_len: int = 128
 
 
 @dataclass
@@ -931,6 +963,36 @@ def _vit_moe() -> ExperimentConfig:
     return cfg
 
 
+def _trinity_mini_share8() -> ExperimentConfig:
+    """Trinity-Mini (arcee-ai, model_type afmoe) at its published widths:
+    ONE chip's share of a deployment in which eight chips share each layer
+    — experts 0-15 of 128, 25,024 of 200,192 vocabulary rows, attention,
+    router and shared expert whole — and five of its 32 layers (one leading
+    dense layer and one whole period window, window, window, full; the
+    rest would lie on further chips as pipeline stages). 705,474,304
+    parameters, 11.3 GB of state at 16 bytes each; sequences of 8,192
+    tokens under per-block recomputation and a chunked loss."""
+    cfg = ExperimentConfig()
+    cfg.model = ModelConfig(
+        name="afmoe", compute_dtype="bfloat16", attention_impl="auto",
+        hidden_size=2048, num_attention_heads=32, num_key_value_heads=4,
+        head_dim=128,
+        layer_types=("sliding_attention",) * 4 + ("full_attention",),
+        sliding_window=2048, rope_theta=10000.0, rms_norm_eps=1e-5,
+        num_dense_layers=1, intermediate_size=6144,
+        moe_intermediate_size=1024, num_experts=128,
+        experts_held=(0, 16), num_experts_per_tok=8, num_shared_experts=1,
+        route_scale=2.826, load_balance_coeff=0.001, mup_enabled=True,
+        vocab_held=25024)
+    cfg.data = DataConfig(dataset="tokens", seq_len=8192)
+    cfg.optimizer = OptimizerConfig(
+        name="adamw", learning_rate=3e-4, weight_decay=0.1,
+        schedule="cosine", warmup_steps=2000, total_steps=100000)
+    cfg.train = TrainConfig(batch_size=2, train_steps=100000,
+                            steps_per_loop=1, remat=True)
+    return cfg
+
+
 def _cifar10_smoke() -> ExperimentConfig:
     """Local smoke test analog of reference scripts/submit_mac_dist.sh
     (1ps+2wk, bs=10, 100 steps on CPU — SURVEY.md §4.1)."""
@@ -954,6 +1016,7 @@ PRESETS = {
     "vit_long_context": _vit_long_context,
     "vit_large_224": _vit_large_224,
     "vit_moe": _vit_moe,
+    "trinity_mini_share8": _trinity_mini_share8,
     "smoke": _cifar10_smoke,
 }
 

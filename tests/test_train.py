@@ -791,31 +791,40 @@ def test_late_read_comes_after_the_next_dispatch_was_entered(k):
     assert input_stages.snapshot()["train.hook_read"]["count"] == 4
 
 
-def test_fused_loop_waits_for_the_dispatch_two_back():
+@pytest.mark.parametrize("k,step_s,waits", [(3, 0.8, 4), (1, 1.0, 4), (1, 0.05, 2)])
+def test_the_loop_waits_for_the_dispatch_its_lead_back(k, step_s, waits, monkeypatch):
     """The loop's lead is device memory (a dispatch's group and outputs are
-    allocated when it is enqueued): after sending dispatch n the fused loop
-    waits for n - 2, so never more than three are in flight, with or
-    without a hook that pulls."""
+    allocated when it is enqueued) and what a stop_fn waits behind: after
+    sending dispatch n the fused loop waits for n - 2, and so does the
+    one-step loop until it has waited twice; from the time between it
+    takes its lead as STEP_LEAD_SECONDS of device work: 2 steps of a
+    second, 40 of 50 ms (more than are ever sent here: it waits no more)."""
     from distributed_resnet_tensorflow_tpu.train import loop
     from distributed_resnet_tensorflow_tpu.utils.metrics import input_stages
-    assert loop.FUSED_DISPATCH_LEAD == 2
-    tr = _logistic_trainer(3)
+    assert (loop.FUSED_DISPATCH_LEAD, loop.STEP_LEAD_SECONDS) == (2, 2.0)
+    lead = 2
+    tr = _logistic_trainer(k)
     events = []
+    clock = [100.0]
+    monkeypatch.setattr(loop.time, "perf_counter", lambda: clock[0])
 
     class Sent:  # a leaf jax.block_until_ready asks to block
         def __init__(self, n):
             self.n = n
 
         def block_until_ready(self):
+            clock[0] += step_s
             events.append(f"waited {self.n}")
 
     def fake_step(state, batch):
         n = sum(e.startswith("sent") for e in events) + 1
         events.append(f"sent {n}")
         return state, {"loss": Sent(n)}
-    tr._jitted_multi = fake_step
+    tr._jitted_multi = tr._jitted_train = fake_step
     input_stages.reset()
-    tr.train(_stream(), num_steps=15)
-    assert events == ["sent 1", "sent 2", "sent 3", "waited 1", "sent 4",
-                      "waited 2", "sent 5", "waited 3"]
-    assert input_stages.snapshot()["train.lead_wait"]["count"] == 3
+    tr.train(_stream(), num_steps=(lead + 4) * k)
+    want = [f"sent {n}" for n in range(1, lead + 1)]
+    for n in range(1, 5):  # from dispatch lead + 1 on, one wait a dispatch
+        want += [f"sent {lead + n}"] + [f"waited {n}"] * (n <= waits)
+    assert events == want
+    assert input_stages.snapshot()["train.lead_wait"]["count"] == waits
