@@ -1,26 +1,44 @@
 """Flash attention — Pallas TPU kernels, fused forward AND backward.
 
-Canonical TPU tiling: grid (batch·heads, q_blocks, k_blocks) with the k-block
-dimension innermost and sequential ("arbitrary" semantics); online-softmax
-accumulators (m, l, acc) live in VMEM scratch and persist across the k-block
-iterations, so VMEM holds only one (block_q, d) query tile and one
-(block_k, d) key/value tile at a time — O(block) VMEM, any sequence length.
-Output (+ the logsumexp residual) is written on the last k iteration.
+Tiling: ``flash_fwd`` runs on the grid (batch·heads, q_blocks, k-walk): the
+innermost dimension, sequential ("arbitrary" semantics), is a WALK over the
+live k-blocks of the q-block, not over all of them. A tile is live when it
+holds a pair that counts; the live k-blocks of a q-block are one unbroken
+run (all of them without ``causal``, up to the diagonal with it, the band's
+width with ``window``), the walk is as long as the longest run and starts at
+each q-block's first live block (a shorter run ends on steps that compute
+nothing and fetch nothing: their block index repeats the last live one).
+Online-softmax accumulators (m, l, acc) live in VMEM scratch across the
+walk, so VMEM holds one (block_q, d) query tile and one (block_k, d)
+key/value tile at a time — O(block) VMEM, any sequence length. Output and
+the logsumexp residual are written on the walk's last step.
+
+``_Tiles`` holds the arithmetic (the live runs, a tile's mask) that the index
+maps, the kernels' predicates and ``tile_census`` all read. Every live tile
+of a call that masks at all builds its mask: leaving it off the tiles whose
+every pair counts was measured and gained nothing (PERF.md §6, PR 34).
+
+Row statistics are lane-dense. The logsumexp and Δ = rowsum(dO ∘ O) cross
+HBM as (B·H, 1, T) rows, the queries in the lanes (a trailing axis of 1
+pads every number to a 128-lane register row, in HBM and in VMEM). Inside
+``flash_fwd`` and ``flash_bwd_dq`` a q-block's statistics are (block_q, 128)
+with every lane of a row the same number, so they meet the score tile as
+whole registers; they are turned from and into rows once a q-block.
 
 The backward is the flash-attention-2 formulation in two Pallas passes that
 recompute P per tile from (q, k, lse) — no O(T²) residuals and no extra full
-forward: a dQ kernel marching k-blocks innermost, and a dK/dV kernel
-marching q-blocks innermost, with Δ = rowsum(dO ∘ O) precomputed as one
-fused elementwise pass.
+forward: ``flash_bwd_dq`` walking k-blocks innermost, and ``flash_bwd_dkv``
+walking, for each k-block, the live q-blocks of every query head of its
+group. ``flash_bwd_dkv`` computes its tile keys first (Sᵀ = K·Qᵀ): Pᵀ and
+dSᵀ come out as the operands dV = Pᵀ·dO and dK = dSᵀ·Q take (no tile is
+transposed), and the statistics subtract as the rows they arrive as.
 
 Layout: (B, T, H, D). The wrapper pads T up to lcm(block_q, block_k) and D to
-the 128-lane width; padded keys are masked via ``valid_len``, padded queries
-are sliced off. Causal masking uses the dense-attention convention: with
-tq == tk the diagonal, i.e. query i attends keys ≤ i. ``window`` (with
-``causal``) narrows that to the band i − window < j ≤ i: blocks outside the
-band are skipped like blocks above the diagonal, in the compute (``pl.when``)
-and in the copy (their block index is clamped to the nearest live block, and
-a block index that repeats is not fetched again).
+the 128-lane width; padded keys are masked via
+``valid_len``, padded queries are sliced off. Causal masking uses the
+dense-attention convention: with tq == tk the diagonal, i.e. query i attends
+keys ≤ i. ``window`` (with ``causal``) narrows that to the band
+i − window < j ≤ i.
 
 Grouped heads: ``k`` and ``v`` may carry fewer heads than ``q`` (H a multiple
 of KV; query head h reads key/value head h // (H/KV)). Keys and values are
@@ -32,6 +50,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,99 +59,228 @@ from jax.experimental.pallas import tpu as pltpu
 
 _VMEM = pltpu.VMEM
 _NEG_INF = -1e30
-BLOCK_Q = 256
-BLOCK_K = 256
+_LANES = 128
 
-# block tables from tools/tune_flash_attention.py on TPU v5e (bf16, causal,
-# fwd+bwd grad time over the full {128,256,512}² grid at T ∈ 1k..8k for
-# head dims 64 AND 128 — docs/flash_tune_r3.json): each bucket carries its
-# measured winner (e.g. T=4096 d=64: 512×512 at 11.9 ms vs 14.9 for the
-# old 256×256 guess; T=8192: 12.5 ms vs dense 126.7 → 10.1×). The winners
-# shift with head dim (wider heads → smaller tiles; the VMEM working set
-# per tile scales with d). Entries must come from the tuner, never
-# intuition — an early guessed 256×512 row measured 1.8× slower than what
-# it replaced.
+# Block tables: every entry is a winner of tools/tune_flash_attention.py on a
+# TPU v5e (bf16, causal), never intuition — an early guessed 256×512 row
+# measured 1.8× slower than what it replaced. Keyed by what decides the
+# winner: the head size (≤ 96 reads the 64 table), whether the call has a
+# window, and T (a row serves every T up to its own). An entry is the
+# (block_q, block_k) with the least summed device time of the three kernels
+# in docs/flash_tune_v5e_gqa_window.json (PR 34, the kernels as they are
+# since: {128,256,512,1024}² with each point's tile_census). Head size 128:
+# B=2 with 32 query heads on 4, window 2048 and none, T 1k..8k, and equal
+# heads without a window, which agree on every row; head size 64: 16 equal
+# heads, no window (a windowed call there reads the row without). With the
+# band walked alone and the statistics lane-dense, what is left to save is
+# a tile's fixed cost (the statistics' update, the accumulator's rescale,
+# the grid step), so the winners are 2 to 4 times the tiles of the table
+# before (docs/flash_tune_r3.json: {128,256,512}² on the kernels of that
+# day; where its rows differ from these they now lose by 1.1× to 3.7×).
+# A second run of every call's four best pairs
+# (docs/flash_tune_v5e_gqa_window_repeat.json) read each kernel within 0.02%
+# of the first and the same winners; the narrowest win by 0.29% (T = 1,024,
+# (512, 512) over (1024, 1024)) and by 0.8% and 1.2% (T = 4,096).
+# The sweeps ran powers of two alone. T is padded up to lcm(block_q,
+# block_k), so a T just over a multiple of its blocks pays dead work that
+# grows with them (2,100 tokens at head size 128 run as 3,072). No caller on
+# the chip sends one today (the encoder's 197 fit one tile): sweep such a T
+# before trusting its row.
 _BLOCK_TABLES = {
-    64: ((1024, (512, 512)), (2048, (128, 512)),
-         (4096, (512, 512)), (8192, (512, 512))),
-    128: ((1024, (128, 128)), (2048, (256, 256)),
-          (4096, (256, 256)), (8192, (256, 512))),
+    (64, False): ((4096, (512, 512)), (8192, (1024, 1024))),
+    (128, False): ((2048, (512, 512)), (8192, (1024, 1024))),
+    (128, True): ((8192, (512, 512)),),
 }
 
 
-def _pick_blocks(t: int, d: int) -> tuple:
-    table = _BLOCK_TABLES[64 if d <= 96 else 128]
+def _pick_blocks(t: int, d: int, window=None) -> tuple:
+    """The table's (block_q, block_k) for the call."""
+    head = 64 if d <= 96 else 128
+    table = _BLOCK_TABLES.get((head, window is not None),
+                              _BLOCK_TABLES[head, False])
     for upper, blocks in table:
         if t <= upper:
             return blocks
     return table[-1][1]
 
 
-def _live(qi, kj, causal, window, block_q, block_k):
-    """Does the (q-block, k-block) tile hold any unmasked pair?"""
-    if not causal:
-        return True
-    live = kj * block_k <= qi * block_q + block_q - 1
-    if window is not None:  # its last key is inside the first query's band
-        live = jnp.logical_and(
-            live, kj * block_k + block_k - 1 > qi * block_q - window)
-    return live
+class _Plan(NamedTuple):
+    """The blocks of a call's three kernels and the padding of T and D."""
+    block_q: int
+    block_k: int
+    tpad: int
+    dpad: int
 
 
-def _tile_mask(qi, kj, causal, window, valid_len, block_q, block_k):
-    """(block_q, block_k) bool of the pairs that count, or None for all."""
-    k_pos = kj * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_k), 1)
-    mask = None
-    if valid_len is not None:
-        mask = k_pos < valid_len
-    if causal:
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0)
-        cm = q_pos >= k_pos
-        if window is not None:
-            cm = jnp.logical_and(cm, k_pos > q_pos - window)
-        mask = cm if mask is None else jnp.logical_and(mask, cm)
-    return mask
+def _plan(t: int, d: int, window=None, block_q: int = 0,
+          block_k: int = 0) -> _Plan:
+    """Blocks as given, the table's where 0, clamped to the (padded)
+    sequence, keeping them a multiple of the TPU sublane tile (16 covers
+    bf16's (16,128) and f32's (8,128)) so Mosaic accepts shapes like t=196
+    (ViT-224/16). T is padded to their least common multiple, D to 128."""
+    table_q, table_k = _pick_blocks(t, d, window)
+    t16 = -(-t // 16) * 16
+    block_q = min(block_q or table_q, t16)
+    block_k = min(block_k or table_k, t16)
+    return _Plan(block_q, block_k, tpad=(-t) % math.lcm(block_q, block_k),
+                 dpad=(-d) % 128)
 
 
-def _live_k(qi, kj, causal, window, block_q, block_k):
-    """The k-block to hold while q-block ``qi`` meets k-block ``kj``: ``kj``
-    itself where the tile is live, else the nearest live one."""
-    if not causal:
-        return kj
-    hi = (qi * block_q + block_q - 1) // block_k
-    lo = 0 if window is None else \
-        jnp.maximum(qi * block_q - window + 1, 0) // block_k
-    return jnp.clip(kj, lo, hi)
+def _max(a, b):
+    both = isinstance(a, int) and isinstance(b, int)
+    return max(a, b) if both else jnp.maximum(a, b)
 
 
-def _live_q(kj, qi, causal, window, block_q, block_k, nq):
-    """The q-block to hold while k-block ``kj`` meets q-block ``qi``."""
-    if not causal:
-        return qi
-    lo = jnp.minimum((kj * block_k) // block_q, nq - 1)
-    hi = nq - 1 if window is None else jnp.minimum(
-        (kj * block_k + block_k - 2 + window) // block_q, nq - 1)
-    return jnp.clip(qi, lo, hi)
+def _min(a, b):
+    both = isinstance(a, int) and isinstance(b, int)
+    return min(a, b) if both else jnp.minimum(a, b)
+
+
+class _Tiles(NamedTuple):
+    """The (q-block, k-block) tiles of one call: which hold a pair that
+    counts (live), the order in which a kernel walks them, and a tile's
+    mask. The runs are integer arithmetic on block indices, so the index
+    maps and predicates of the kernels (on traced indices) and
+    ``tile_census`` (on Python ints) read one source.
+
+    ``valid_len`` is the number of real keys where the sequence was padded,
+    else None. The live k-blocks of a q-block, and the live q-blocks of a
+    k-block, are one unbroken run (a band), so a kernel's innermost grid
+    dimension is the longest run, started at each row's first live block."""
+    causal: bool
+    window: Optional[int]
+    valid_len: Optional[int]
+    block_q: int
+    block_k: int
+    nq: int
+    nk: int
+
+    def k_range(self, qi):
+        """First and last live k-block of q-block ``qi``."""
+        if not self.causal:
+            return 0, self.nk - 1
+        bq, bk = self.block_q, self.block_k
+        lo = 0 if self.window is None else \
+            _max(qi * bq - self.window + 1, 0) // bk
+        return lo, (qi * bq + bq - 1) // bk
+
+    def q_range(self, kj):
+        """First and last live q-block of k-block ``kj``."""
+        if not self.causal:
+            return 0, self.nq - 1
+        bq, bk = self.block_q, self.block_k
+        hi = self.nq - 1 if self.window is None else _min(
+            (kj * bk + bk - 2 + self.window) // bq, self.nq - 1)
+        return (kj * bk) // bq, hi
+
+    @property
+    def k_steps(self) -> int:
+        """Length of the k-walk: the longest run of live k-blocks."""
+        runs = (self.k_range(qi) for qi in range(self.nq))
+        return max(hi - lo + 1 for lo, hi in runs)
+
+    @property
+    def q_steps(self) -> int:
+        runs = (self.q_range(kj) for kj in range(self.nk))
+        return max(hi - lo + 1 for lo, hi in runs)
+
+    @property
+    def masks(self) -> bool:
+        """Does the call mask any pair at all?"""
+        return self.causal or self.valid_len is not None
+
+    def mask(self, qi, kj, keys_first=False):
+        """(block_q, block_k) bool of the tile's pairs that count;
+        (block_k, block_q) with ``keys_first``."""
+        q_axis, k_axis = (1, 0) if keys_first else (0, 1)
+
+        def across(n, axis):  # positions 0..n-1 laid along ``axis``
+            shape = (n, 1) if axis == 0 else (1, n)
+            return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+        k_pos = kj * self.block_k + across(self.block_k, k_axis)
+        mask = None
+        if self.valid_len is not None:
+            mask = k_pos < self.valid_len
+        if self.causal:
+            q_pos = qi * self.block_q + across(self.block_q, q_axis)
+            cm = q_pos >= k_pos
+            if self.window is not None:
+                cm = jnp.logical_and(cm, k_pos > q_pos - self.window)
+            mask = cm if mask is None else jnp.logical_and(mask, cm)
+        return mask
+
+    def census(self) -> dict:
+        runs = (self.k_range(qi) for qi in range(self.nq))
+        live = sum(hi - lo + 1 for lo, hi in runs)
+        return {"grid_steps": self.nq * self.k_steps,
+                "grid_steps_dkv": self.nk * self.q_steps,
+                "live": live, "masks": self.masks}
+
+
+def _tiles(t, causal, window, plan: _Plan) -> _Tiles:
+    tp = t + plan.tpad
+    return _Tiles(causal, window, t if plan.tpad else None, plan.block_q,
+                  plan.block_k, tp // plan.block_q, tp // plan.block_k)
+
+
+def tile_census(t: int, d: int, causal: bool, window, block_q: int,
+                block_k: int) -> dict:
+    """What one head of one kernel call walks at these sizes: ``grid_steps``
+    (the grid of ``flash_fwd`` and ``flash_bwd_dq``: q-blocks × the k-walk;
+    ``grid_steps_dkv`` is ``flash_bwd_dkv``'s, k-blocks × the q-walk of one
+    query head), ``live`` (tiles computed: they hold a pair that counts)
+    and ``masks`` (does every live tile build and apply a mask: all do or
+    none does; masking the edge tiles alone was measured and gained
+    nothing, PERF.md §6, PR 34). Built from the ``_Tiles`` the kernels'
+    index maps and predicates use; blocks of 0 are the table's."""
+    plan = _plan(t, d, window, block_q, block_k)
+    return _tiles(t, causal, window, plan).census()
+
+
+def _walk(run, step):
+    """The block held at ``step`` of a walk over the live run (first, last):
+    past its end the last one, and a block index that repeats is not
+    fetched again."""
+    lo, hi = run
+    return jnp.minimum(lo + step, hi)
+
+
+def _lanes(x, n):
+    """A per-row statistic kept as (rows, 128), every lane of a row the same
+    number, against a tile ``n`` lanes wide: whole registers side by side,
+    where a (rows, 1) column would be spread over the lanes each time."""
+    if n % _LANES:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+    return x if n == _LANES else jnp.tile(x, (1, n // _LANES))
+
+
+def _to_row(x):
+    """(rows, 128) lane-replicated → (1, rows): the form that crosses HBM."""
+    return x.T[:1]
+
+
+def _to_lanes(row):
+    """(1, rows) → (rows, 128) lane-replicated."""
+    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
-                  *, scale, causal, window, valid_len, block_q, block_k, nk):
+                  *, scale, tiles):
     """One (q-block, k-block) tile. Scratch m/l/acc persist across the
-    innermost (k-block) grid dimension."""
+    innermost grid dimension, the walk over the q-block's live k-blocks."""
     qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    j = pl.program_id(2)
+    lo, hi = tiles.k_range(qi)
+    kj = lo + j
 
-    @pl.when(kj == 0)
+    @pl.when(j == 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # blocks strictly above the causal diagonal, or wholly outside the
-    # window's band, contribute nothing
-    @pl.when(_live(qi, kj, causal, window, block_q, block_k))
+    # a walk shorter than the longest ends on steps past the last live block
+    @pl.when(kj <= hi)
     def _accumulate():
         # keep matmul OPERANDS in the input dtype (bf16 on the MXU's native
         # rate — an f32 cast would halve/quarter throughput); accumulate f32
@@ -140,41 +288,28 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         k = k_ref[0]                                      # (bk, d)
         v = v_ref[0]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        mask = _tile_mask(qi, kj, causal, window, valid_len, block_q,
-                          block_k)
-        if mask is not None:
-            s = jnp.where(mask, s, _NEG_INF)
+        if tiles.masks:
+            s = jnp.where(tiles.mask(qi, kj), s, _NEG_INF)
         m_prev, l_prev, acc_prev = m_ref[:], l_ref[:], acc_ref[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        p = jnp.exp(s - _lanes(m_new, s.shape[1]))
         corr = jnp.exp(m_prev - m_new)
         m_ref[:] = m_new
         l_ref[:] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_prev * corr + jnp.dot(
+        acc_ref[:] = acc_prev * _lanes(corr, acc_prev.shape[1]) + jnp.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
-    @pl.when(kj == nk - 1)
+    @pl.when(j == tiles.k_steps - 1)
     def _finalize():
         l = l_ref[:]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        acc = acc_ref[:]
+        o_ref[0] = (acc / _lanes(l, acc.shape[1])).astype(o_ref.dtype)
         # logsumexp residual for the fused backward: lse = m + log(l);
         # guard fully-masked rows (m = -inf) to keep exp(s - lse) finite
         m = m_ref[:]
-        lse_ref[0] = jnp.where(m <= _NEG_INF / 2, 0.0, m + jnp.log(l))
-
-
-def _geometry(t, d, block_q, block_k):
-    """Common fwd/bwd tiling: clamp blocks to the (padded) sequence, keeping
-    them a multiple of the TPU sublane tile (16 covers bf16's (16,128) and
-    f32's (8,128)) so Mosaic accepts shapes like t=196 (ViT-224/16)."""
-    t16 = -(-t // 16) * 16
-    block_q = min(block_q, t16)
-    block_k = min(block_k, t16)
-    step = math.lcm(block_q, block_k)
-    tpad = (-t) % step
-    dpad = (-d) % 128
-    return block_q, block_k, tpad, dpad
+        lse_ref[0] = _to_row(
+            jnp.where(m <= _NEG_INF / 2, 0.0, m + jnp.log(l)))
 
 
 def _fold(x, b, h, d):  # (B,T,H,D) → (B·H, T, D)
@@ -199,13 +334,14 @@ def _group(q, k, window, causal):
     return h // kvh
 
 
-def _flash_forward(q, k, v, causal=False, interpret=False,
-                   block_q=BLOCK_Q, block_k=BLOCK_K, return_residuals=False,
+def _flash_forward(q, k, v, plan: _Plan, causal=False, interpret=False,
                    window=None):
+    """(out (B, T, H, D), lse (B·H, 1, Tp) float32: the queries in the
+    lanes, the padded ones included)."""
     b, t, h, d = q.shape
     g = _group(q, k, window, causal)
     scale = 1.0 / math.sqrt(d)
-    block_q, block_k, tpad, dpad = _geometry(t, d, block_q, block_k)
+    block_q, block_k, tpad, dpad = plan
 
     qf = _fold(q, b, h, d)
     kf, vf = (_fold(x, b, h // g, d) for x in (k, v))
@@ -213,19 +349,15 @@ def _flash_forward(q, k, v, causal=False, interpret=False,
         pad = ((0, 0), (0, tpad), (0, dpad))
         qf, kf, vf = (jnp.pad(x, pad) for x in (qf, kf, vf))
     tp, dp = qf.shape[1], qf.shape[2]
-    nq, nk = tp // block_q, tp // block_k
-    grid = (b * h, nq, nk)
-
-    kernel = functools.partial(
-        _flash_kernel, scale=scale, causal=causal, window=window,
-        valid_len=(t if tpad else None), block_q=block_q, block_k=block_k,
-        nk=nk)
+    tiles = _tiles(t, causal, window, plan)
+    grid = (b * h, tiles.nq, tiles.k_steps)
+    kernel = functools.partial(_flash_kernel, scale=scale, tiles=tiles)
 
     def k_at(bh, i, j):  # a group's query heads read one key/value head
-        return (bh // g, _live_k(i, j, causal, window, block_q, block_k), 0)
+        return (bh // g, _walk(tiles.k_range(i), j), 0)
 
-    scratch = [pltpu.VMEM((block_q, 1), jnp.float32),
-               pltpu.VMEM((block_q, 1), jnp.float32),
+    scratch = [pltpu.VMEM((block_q, _LANES), jnp.float32),
+               pltpu.VMEM((block_q, _LANES), jnp.float32),
                pltpu.VMEM((block_q, dp), jnp.float32)]
     extra = {}
     if not interpret:
@@ -244,12 +376,12 @@ def _flash_forward(q, k, v, causal=False, interpret=False,
         out_specs=[
             pl.BlockSpec((1, block_q, dp), lambda bh, i, j: (bh, i, 0),
                          memory_space=_VMEM),
-            pl.BlockSpec((1, block_q, 1), lambda bh, i, j: (bh, i, 0),
+            pl.BlockSpec((1, 1, block_q), lambda bh, i, j: (bh, 0, i),
                          memory_space=_VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, tp, dp), q.dtype),
-            jax.ShapeDtypeStruct((b * h, tp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, 1, tp), jnp.float32),
         ],
         scratch_shapes=scratch,
         interpret=interpret,
@@ -257,94 +389,90 @@ def _flash_forward(q, k, v, causal=False, interpret=False,
         **extra,
     )(qf, kf, vf)
     # the lse output is computed even when discarded (no-grad path): a
-    # second kernel variant isn't worth the (B·H, Tp, 1) f32 write it saves
-    out_bthd = _unfold(out, b, h, t, d)
-    if return_residuals:
-        return out_bthd, lse
-    return out_bthd
+    # second kernel variant isn't worth the (B·H, 1, Tp) f32 write it saves
+    return _unfold(out, b, h, t, d), lse
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_acc, *, scale, causal, window, valid_len, block_q,
-                   block_k, nk):
-    """dQ pass: grid (B·H, nq, nk), k-blocks innermost/sequential.
+                   dq_acc, lse_s, delta_s, *, scale, tiles):
+    """dQ pass: grid (B·H, nq, k-walk), k-blocks innermost/sequential.
     dS = P ∘ (dO·Vᵀ − Δ); dQ = scale · dS·K   (flash-attention-2 backward)."""
     qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    j = pl.program_id(2)
+    lo, hi = tiles.k_range(qi)
+    kj = lo + j
 
-    @pl.when(kj == 0)
+    @pl.when(j == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
+        # the q-block's statistics arrive as rows and stay for the whole
+        # walk: turned once into the form the tiles subtract
+        lse_s[:] = _to_lanes(lse_ref[0])
+        delta_s[:] = _to_lanes(delta_ref[0])
 
-    @pl.when(_live(qi, kj, causal, window, block_q, block_k))
+    @pl.when(kj <= hi)
     def _accumulate():
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        lse = lse_ref[0]                                   # (bq, 1)
-        delta = delta_ref[0]                               # (bq, 1)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        mask = _tile_mask(qi, kj, causal, window, valid_len, block_q,
-                          block_k)
-        p = jnp.exp(s - lse)
-        if mask is not None:
-            p = jnp.where(mask, p, 0.0)
+        p = jnp.exp(s - _lanes(lse_s[:], s.shape[1]))
+        if tiles.masks:
+            p = jnp.where(tiles.mask(qi, kj), p, 0.0)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta)).astype(k.dtype)
+        ds = (p * (dp - _lanes(delta_s[:], s.shape[1]))).astype(k.dtype)
         dq_acc[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32) * scale
 
-    @pl.when(kj == nk - 1)
+    @pl.when(j == tiles.k_steps - 1)
     def _finalize():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *,
-                    scale, causal, window, valid_len, block_q, block_k, nq,
-                    group):
-    """dK/dV pass: grid (B·KV, nk, group·nq): the q-blocks of every query
-    head of the group innermost/sequential, so one key/value head's
+                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale, tiles, group):
+    """dK/dV pass: grid (B·KV, nk, group·q-walk): the live q-blocks of every
+    query head of the group innermost/sequential, so one key/value head's
     gradient sums over its group without leaving VMEM.
-    dV = Pᵀ·dO;  dK = scale · dSᵀ·Q."""
+    dV = Pᵀ·dO;  dK = scale · dSᵀ·Q, on the tile computed keys first
+    (Sᵀ = K·Qᵀ): Pᵀ and dSᵀ come out in the orientation the two products
+    take, and the q-block's statistics subtract as the rows they arrive as."""
     kj = pl.program_id(1)
     r = pl.program_id(2)
-    qi = r % nq
+    lo, hi = tiles.q_range(kj)
+    qi = lo + r % tiles.q_steps
 
     @pl.when(r == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(_live(qi, kj, causal, window, block_q, block_k))
+    @pl.when(qi <= hi)
     def _accumulate():
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        lse = lse_ref[0]
-        delta = delta_ref[0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        mask = _tile_mask(qi, kj, causal, window, valid_len, block_q,
-                          block_k)
-        p = jnp.exp(s - lse)
-        if mask is not None:
-            p = jnp.where(mask, p, 0.0)
-        dv_acc[:] += jnp.dot(p.astype(do.dtype).T, do,
+        st = jnp.dot(k, q.T, preferred_element_type=jnp.float32) * scale
+        pt = jnp.exp(st - lse_ref[0])                      # (bk, bq) − (1, bq)
+        if tiles.masks:
+            pt = jnp.where(tiles.mask(qi, kj, keys_first=True), pt, 0.0)
+        dv_acc[:] += jnp.dot(pt.astype(do.dtype), do,
                              preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta)).astype(q.dtype)
-        dk_acc[:] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32) * scale
+        dpt = jnp.dot(v, do.T, preferred_element_type=jnp.float32)
+        dst = (pt * (dpt - delta_ref[0])).astype(q.dtype)
+        dk_acc[:] += jnp.dot(dst, q, preferred_element_type=jnp.float32) * scale
 
-    @pl.when(r == group * nq - 1)
+    @pl.when(r == group * tiles.q_steps - 1)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _flash_backward(q, k, v, out, lse, g, causal=False, interpret=False,
-                    block_q=BLOCK_Q, block_k=BLOCK_K, window=None):
+def _flash_backward(q, k, v, out, lse, g, plan: _Plan, causal=False,
+                    interpret=False, window=None):
     """Fused Pallas backward: recomputes P per tile from (q, k, lse) — no
-    O(T²) residuals, two passes over the kv/q grids."""
+    O(T²) residuals, two passes over the kv/q grids. ``lse`` as
+    ``_flash_forward`` returns it."""
     b, t, h, d = q.shape
     group = _group(q, k, window, causal)
     kvh = h // group
     scale = 1.0 / math.sqrt(d)
-    block_q, block_k, tpad, dpad = _geometry(t, d, block_q, block_k)
+    block_q, block_k, tpad, dpad = plan
 
     qf, dof, of = (_fold(x, b, h, d) for x in (q, g, out))
     kf, vf = (_fold(x, b, kvh, d) for x in (k, v))
@@ -353,33 +481,36 @@ def _flash_backward(q, k, v, out, lse, g, causal=False, interpret=False,
         qf, kf, vf, dof, of = (jnp.pad(x, pad)
                                for x in (qf, kf, vf, dof, of))
     tp, dp = qf.shape[1], qf.shape[2]
-    nq, nk = tp // block_q, tp // block_k
+    tiles = _tiles(t, causal, window, plan)
     # Δ = rowsum(dO ∘ O): tiny elementwise pass, let XLA fuse it
     delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32),
-                    axis=-1, keepdims=True)                # (B·H, tp, 1)
+                    axis=-1)[:, None]                      # (B·H, 1, tp)
 
-    common = dict(scale=scale, causal=causal, window=window,
-                  valid_len=(t if tpad else None),
-                  block_q=block_q, block_k=block_k)
+    common = dict(scale=scale, tiles=tiles)
 
     # one BlockSpec builder per operand kind; the q/k index maps swap between
-    # the (bh, qi, kj) grid of the dQ pass and the (bh, kj, qi) grid of dK/dV
+    # the (bh, qi, k-walk) grid of the dQ pass and the (bkv, kj, q-walk)
+    # grid of dK/dV
     def qb(im):
         return pl.BlockSpec((1, block_q, dp), im, memory_space=_VMEM)
 
     def kb(im):
         return pl.BlockSpec((1, block_k, dp), im, memory_space=_VMEM)
 
-    def rb(im):
-        return pl.BlockSpec((1, block_q, 1), im, memory_space=_VMEM)
+    def rb(im):  # a q-block's statistics: one row, the queries in the lanes
+        return pl.BlockSpec((1, 1, block_q), im, memory_space=_VMEM)
 
-    live = (causal, window, block_q, block_k)
     q_at = lambda bh, i, j: (bh, i, 0)    # noqa: E731
-    k_at = lambda bh, i, j: (bh // group, _live_k(i, j, *live), 0)  # noqa: E731
+    r_at = lambda bh, i, j: (bh, 0, i)    # noqa: E731
+    k_at = lambda bh, i, j: (   # noqa: E731
+        bh // group, _walk(tiles.k_range(i), j), 0)
     # the dK/dV grid runs over key/value heads; r walks the group's query
-    # heads and, within each, its q-blocks
-    q_at2 = lambda bkv, j, r: (bkv * group + r // nq,   # noqa: E731
-                               _live_q(j, r % nq, *live, nq), 0)
+    # heads and, within each, the k-block's live q-blocks
+    steps = tiles.q_steps
+    q_at2 = lambda bkv, j, r: (   # noqa: E731
+        bkv * group + r // steps, _walk(tiles.q_range(j), r % steps), 0)
+    r_at2 = lambda bkv, j, r: (   # noqa: E731
+        bkv * group + r // steps, 0, _walk(tiles.q_range(j), r % steps))
     k_at2 = lambda bkv, j, r: (bkv, j, 0)   # noqa: E731
 
     extra = {}
@@ -388,22 +519,24 @@ def _flash_backward(q, k, v, out, lse, g, causal=False, interpret=False,
             dimension_semantics=("parallel", "parallel", "arbitrary")))
 
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, nk=nk, **common),
-        grid=(b * h, nq, nk),
-        in_specs=[qb(q_at), kb(k_at), kb(k_at), qb(q_at), rb(q_at), rb(q_at)],
+        functools.partial(_bwd_dq_kernel, **common),
+        grid=(b * h, tiles.nq, tiles.k_steps),
+        in_specs=[qb(q_at), kb(k_at), kb(k_at), qb(q_at), rb(r_at), rb(r_at)],
         out_specs=qb(q_at),
         out_shape=jax.ShapeDtypeStruct((b * h, tp, dp), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, dp), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, dp), jnp.float32),
+                        pltpu.VMEM((block_q, _LANES), jnp.float32),
+                        pltpu.VMEM((block_q, _LANES), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dq",
         **extra,
     )(qf, kf, vf, dof, lse, delta)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, nq=nq, group=group, **common),
-        grid=(b * kvh, nk, group * nq),
-        in_specs=[qb(q_at2), kb(k_at2), kb(k_at2), qb(q_at2), rb(q_at2),
-                  rb(q_at2)],
+        functools.partial(_bwd_dkv_kernel, group=group, **common),
+        grid=(b * kvh, tiles.nk, group * steps),
+        in_specs=[qb(q_at2), kb(k_at2), kb(k_at2), qb(q_at2), rb(r_at2),
+                  rb(r_at2)],
         out_specs=[kb(k_at2), kb(k_at2)],
         out_shape=[
             jax.ShapeDtypeStruct((b * kvh, tp, dp), k.dtype),
@@ -429,19 +562,17 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     a multiple of KV. Differentiable with a FUSED Pallas backward (dq +
     dk/dv kernels recomputing P from the lse residual — O(T) memory, no
     extra full forward). ``window`` (with ``causal``): query i sees keys
-    i − window < j ≤ i. ``block_q``/``block_k`` of 0 pick the
-    measured-optimal tile for the sequence length and head dim
-    (_BLOCK_TABLES; tools/tune_flash_attention.py re-derives them).
+    i − window < j ≤ i. ``block_q``/``block_k`` of 0 pick the tile the
+    tuner measured fastest for the head size, the sequence length and
+    whether there is a window (``_BLOCK_TABLES``;
+    ``tools/tune_flash_attention.py`` re-derives its rows and writes the
+    evidence, docs/flash_tune_v5e_gqa_window.json). Which tiles the call
+    walks follows from these static sizes alone (``tile_census`` counts
+    them).
     ``ops.attention.attention`` takes the same arguments and is the
     kernels' ``jax.numpy`` twin."""
-    bq, bk = _resolve_blocks(q, block_q, block_k)
-    return _flash_forward(q, k, v, causal, interpret,
-                          block_q=bq, block_k=bk, window=window)
-
-
-def _resolve_blocks(q, block_q, block_k):
-    auto_q, auto_k = _pick_blocks(q.shape[1], q.shape[3])
-    return block_q or auto_q, block_k or auto_k
+    plan = _plan(q.shape[1], q.shape[3], window, block_q, block_k)
+    return _flash_forward(q, k, v, plan, causal, interpret, window)[0]
 
 
 #: names a recomputation policy can keep (``jax.checkpoint_policies.
@@ -452,22 +583,18 @@ SAVEABLE = ("flash_out", "flash_lse")
 
 def _fa_fwd(q, k, v, causal, interpret, block_q, block_k, window):
     from jax.ad_checkpoint import checkpoint_name
-    bq, bk = _resolve_blocks(q, block_q, block_k)
-    out, lse = _flash_forward(q, k, v, causal, interpret,
-                              block_q=bq, block_k=bk, return_residuals=True,
-                              window=window)
+    plan = _plan(q.shape[1], q.shape[3], window, block_q, block_k)
+    out, lse = _flash_forward(q, k, v, plan, causal, interpret, window)
     out = checkpoint_name(out, SAVEABLE[0])
-    # kept without its trailing axis of 1, which a tiled layout pads to a
-    # lane's 128
-    lse = checkpoint_name(lse[..., 0], SAVEABLE[1])
+    lse = checkpoint_name(lse[:, 0], SAVEABLE[1])  # (B·H, Tp), dense
     return out, (q, k, v, out, lse)
 
 
 def _fa_bwd(causal, interpret, block_q, block_k, window, res, g):
     q, k, v, out, lse = res
-    bq, bk = _resolve_blocks(q, block_q, block_k)
-    return _flash_backward(q, k, v, out, lse[..., None], g, causal,
-                           interpret, block_q=bq, block_k=bk, window=window)
+    plan = _plan(q.shape[1], q.shape[3], window, block_q, block_k)
+    return _flash_backward(q, k, v, out, lse[:, None], g, plan, causal,
+                           interpret, window)
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
@@ -511,7 +638,7 @@ def _ring_impl(q, k, v, axis_name, n, causal, interpret):
     b, t, h, d = q.shape
     my = jax.lax.axis_index(axis_name)
     perm = [(j, (j + 1) % n) for j in range(n)]
-    bq, bk = _pick_blocks(t, d)
+    plan = _plan(t, d)
     M = jnp.full((b, h, t), -jnp.inf, jnp.float32)
     S = jnp.zeros((b, h, t), jnp.float32)
     A = jnp.zeros((b, t, h, d), jnp.float32)
@@ -524,10 +651,8 @@ def _ring_impl(q, k, v, axis_name, n, causal, interpret):
 
         def compute(args, _diag=is_diag):
             M_, S_, A_, k_c, v_c = args
-            o_i, lse_f = _flash_forward(
-                q, k_c, v_c, causal=_diag, interpret=interpret,
-                block_q=bq, block_k=bk, return_residuals=True)
-            lse_i = lse_f[:, :t, 0].reshape(b, h, t)
+            o_i, lse_f = _flash_forward(q, k_c, v_c, plan, _diag, interpret)
+            lse_i = lse_f[:, 0, :t].reshape(b, h, t)
             return _ring_combine(M_, S_, A_, o_i, lse_i)
 
         args = (M, S, A, k_cur, v_cur)
@@ -567,12 +692,10 @@ def _ring_fa_bwd(axis_name, n, causal, interpret, res, g):
     b, t, h, d = q.shape
     my = jax.lax.axis_index(axis_name)
     perm = [(j, (j + 1) % n) for j in range(n)]
-    bq, bk = _pick_blocks(t, d)
-    _, _, tpad, _ = _geometry(t, d, bq, bk)
-    lse_f = lse.reshape(b * h, t, 1)
-    if tpad:
-        # pad rows only meet zero-padded dO rows, so any finite value works
-        lse_f = jnp.pad(lse_f, ((0, 0), (0, tpad), (0, 0)))
+    plan = _plan(t, d)
+    # pad rows only meet zero-padded dO rows, so any finite value works
+    lse_f = jnp.pad(lse.reshape(b * h, 1, t),
+                    ((0, 0), (0, 0), (0, plan.tpad)))
 
     dq = jnp.zeros(q.shape, jnp.float32)
     dk_cur = jnp.zeros(k.shape, jnp.float32)
@@ -584,8 +707,7 @@ def _ring_fa_bwd(axis_name, n, causal, interpret, res, g):
         def compute(args, _diag=is_diag):
             dq_a, dk_c, dv_c, k_c, v_c = args
             dqi, dki, dvi = _flash_backward(
-                q, k_c, v_c, out, lse_f, g, causal=_diag,
-                interpret=interpret, block_q=bq, block_k=bk)
+                q, k_c, v_c, out, lse_f, g, plan, _diag, interpret)
             return (dq_a + dqi.astype(jnp.float32),
                     dk_c + dki.astype(jnp.float32),
                     dv_c + dvi.astype(jnp.float32))
