@@ -98,7 +98,8 @@ def has_classifier_forward(cfg) -> bool:
     """False for a token model: it has no eval or serve forward yet (token
     serving waits, ROADMAP Queue 2), so its train step is what there is to
     trace."""
-    return cfg.data.dataset != "tokens"
+    from ..models.transformer import FAMILIES
+    return cfg.model.name not in FAMILIES
 
 
 def _abstract_batch(cfg, batch_size: int):
@@ -108,6 +109,13 @@ def _abstract_batch(cfg, batch_size: int):
     if cfg.data.dataset == "tokens":  # inputs and next-token targets
         return {"tokens": jax.ShapeDtypeStruct(
             (batch_size, cfg.data.seq_len + 1), np.int32)}
+    if cfg.data.dataset == "blockdiff_tokens":  # ids and their noising
+        rows = (batch_size, cfg.data.seq_len)
+        return {"tokens": jax.ShapeDtypeStruct(rows, np.int32),
+                "masked": jax.ShapeDtypeStruct(rows, np.uint8),
+                "t": jax.ShapeDtypeStruct(
+                    (batch_size, rows[1] // cfg.model.block_length),
+                    np.float32)}
     if cfg.model.name == "logistic":
         img = jax.ShapeDtypeStruct((batch_size, cfg.model.input_size),
                                    np.float32)

@@ -80,6 +80,11 @@ def create_input_iterator(cfg, mode: str = "train", shard_index: int = 0,
         from .tokens import token_stream_iterator
         it = token_stream_iterator(bs, d.seq_len, cfg.model.vocab_held,
                                    seed=cfg.train.seed)
+    elif d.dataset == "blockdiff_tokens":
+        from .tokens import block_diffusion_iterator
+        it = block_diffusion_iterator(
+            bs, d.seq_len, cfg.model.mask_token_held, cfg.model.block_length,
+            cfg.model.noise_eps, seed=cfg.train.seed)
     elif d.dataset in ("cifar10", "cifar100"):
         it = cifar_iterator(d.dataset, d.data_dir, bs, mode,
                             seed=cfg.train.seed, shard_index=shard_index,

@@ -507,9 +507,28 @@ def biased_topk_route(x, router, bias, top_k: int, route_scale: float):
     picked = jnp.take_along_axis(scores, sel, axis=-1)
     w = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20) \
         * route_scale
-    counts = jnp.zeros((router.shape[-1],), jnp.float32).at[
-        sel.reshape(-1)].add(1.0)
-    return sel, w, counts
+    return sel, w, _assignments(sel, router.shape[-1])
+
+
+def softmax_topk_route(x, router, top_k: int):
+    """Softmax over every published expert, the ``top_k`` largest, weights
+    renormalised over the chosen; no bias, no scale. All in float32, as
+    ``biased_topk_route`` and for its reason. Returns (chosen (n, k) int32,
+    weights (n, k) f32, counts (E,) f32 of assignments)."""
+    p = jax.nn.softmax(jnp.dot(x.astype(jnp.float32),
+                               router.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST), axis=-1)
+    picked, sel = jax.lax.top_k(p, top_k)
+    w = picked / jnp.sum(picked, axis=-1, keepdims=True)
+    return sel, w, _assignments(sel, router.shape[-1])
+
+
+def _assignments(sel, experts: int):
+    return jnp.zeros((experts,), jnp.float32).at[sel.reshape(-1)].add(1.0)
+
+
+#: how a dropless layer scores and chooses (DroplessMoe.router)
+ROUTERS = ("sigmoid_bias", "softmax")
 
 
 def held_experts_sum(x, sel, w, gate, up, down, lo: int, dtype):
@@ -613,8 +632,9 @@ class DroplessMoe(nn.Module):
     top_k: int
     hidden: int                      # a routed expert's width
     shared_hidden: int               # the shared expert's (0: none)
-    route_scale: float = 1.0
+    route_scale: float = 1.0         # sigmoid_bias's
     dtype: Any = jnp.bfloat16
+    router: str = "sigmoid_bias"     # one of ROUTERS
 
     @nn.compact
     def __call__(self, x: jax.Array):
@@ -622,14 +642,19 @@ class DroplessMoe(nn.Module):
         if not 0 <= lo < hi <= self.num_experts:
             raise ValueError(f"experts_held {self.experts_held} is no range "
                              f"of {self.num_experts} experts")
+        if self.router not in ROUTERS:
+            raise ValueError(f"router {self.router!r} is none of {ROUTERS}")
         with jax.named_scope("route"):
             # the kernel alone lives in the module: the product, the
-            # sigmoid and the choice are biased_topk_route's, in float32
+            # scores and the choice are the route's, in float32
             kernel = Kernel(self.num_experts, name="router")(x.shape[-1])
-            bias = self.param("router_bias", nn.initializers.zeros,
-                              (self.num_experts,), jnp.float32)
-            sel, w, counts = biased_topk_route(
-                x, kernel, bias, self.top_k, self.route_scale)
+            if self.router == "softmax":  # no bias among the leaves at all
+                sel, w, counts = softmax_topk_route(x, kernel, self.top_k)
+            else:
+                bias = self.param("router_bias", nn.initializers.zeros,
+                                  (self.num_experts,), jnp.float32)
+                sel, w, counts = biased_topk_route(
+                    x, kernel, bias, self.top_k, self.route_scale)
         out = HeldExperts(lo, hi - lo, self.hidden, self.dtype,
                           name="experts")(x, sel, w)
         if self.shared_hidden:

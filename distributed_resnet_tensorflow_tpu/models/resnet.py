@@ -432,8 +432,8 @@ def create_model(model_cfg, dataset: str,
                            hidden_units=model_cfg.hidden_units,
                            dtype=dtype if compute_dtype is not None
                            else jnp.float32)
-    if model_cfg.name == "afmoe":
-        from .transformer import CausalDecoder
+    from .transformer import FAMILIES, CausalDecoder
+    if model_cfg.name in FAMILIES:
         return CausalDecoder(cfg=model_cfg, dtype=dtype,
                              attention_impl=model_cfg.attention_impl,
                              remat=remat, mesh=mesh)

@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -265,17 +265,40 @@ class VisionTransformer(nn.Module):
 
 
 # ---------------------------------------------------------------------------
-# Causal decoder: the token-model family (model.name="afmoe"). Built from a
-# list of layer kinds (``sliding_attention`` / ``full_attention``): RMS
-# norms before AND after each part, grouped query heads with per-head RMS
-# norms on queries and keys, rotary positions on the window layers only, a
-# sigmoid gate on the attention's output, SwiGLU feed-forward in the leading
-# dense layers and models/moe.DroplessMoe in the rest, an untied head over
-# the vocabulary rows held here. The residual stream and every norm are
-# float32; products run in ``dtype``.
+# Causal decoder: the token-model families. Built from a list of layer kinds
+# (``sliding_attention`` / ``full_attention``): RMS norms before each part,
+# grouped query heads with per-head RMS norms on queries and keys, SwiGLU
+# feed-forward in the leading dense layers and models/moe.DroplessMoe in the
+# rest, an untied head over the vocabulary rows held here. The residual
+# stream and every norm are float32; products run in ``dtype``. What a
+# family fixes beyond its sizes is in ``FAMILIES`` and nowhere else, keyed
+# on ``model.name`` (the config holds sizes; whether a name is a causal
+# decoder at all is ``name in FAMILIES``):
+#
+# * ``afmoe``: RMS norms AFTER each part too, a sigmoid gate on the
+#   attention's output, rotary positions on the window layers only, sigmoid
+#   routing with a rule-moved bias, the next-token loss;
+# * ``sdar_moe``: neither, rotary positions on every layer, softmax
+#   routing, and the block-diffusion loss of arXiv:2503.09573 over a noisy
+#   and a clean copy of each sequence under ``ops.attention.Mask``'s third
+#   kind.
 # ---------------------------------------------------------------------------
 
 LAYER_KINDS = ("sliding_attention", "full_attention")
+
+
+class Family(NamedTuple):
+    post_norms: bool         # an RMS norm on each branch's output
+    gated_attention: bool    # o ⊙ sigmoid(a W_gate) before the projection
+    rope_all_layers: bool    # rotary on the full_attention layers too
+    router: str              # models/moe.ROUTERS
+    objective: str           # next_token | block_diffusion
+
+
+FAMILIES = {
+    "afmoe": Family(True, True, False, "sigmoid_bias", "next_token"),
+    "sdar_moe": Family(False, False, True, "softmax", "block_diffusion"),
+}
 
 
 def causal_flash_or_dense(impl: str) -> str:
@@ -286,19 +309,19 @@ def causal_flash_or_dense(impl: str) -> str:
     return "flash" if jax.default_backend() == "tpu" else "dense"
 
 
-def causal_attention(q, k, v, window, impl: str, mesh=None):
-    """softmax(q kᵀ/√d) v under the causal mask and, with ``window``, the
-    band i − window < j ≤ i; k and v may carry fewer heads than q."""
+def causal_attention(q, k, v, mask, impl: str, mesh=None):
+    """softmax(q kᵀ/√d) v over the pairs ``mask`` counts (an
+    ``ops.attention.Mask``); k and v may carry fewer heads than q."""
     impl = causal_flash_or_dense(impl)
     if impl == "dense":
         from ..ops.attention import attention
-        return attention(q, k, v, True, window)
+        return attention(q, k, v, mask)
     if impl in ("flash", "flash_interpret"):
         from ..ops.pallas import flash_attention
         interpret = impl == "flash_interpret"
         return _per_shard(
-            lambda q, k, v: flash_attention(q, k, v, True, interpret,
-                                            window=window), mesh)(q, k, v)
+            lambda q, k, v: flash_attention(q, k, v, mask, interpret),
+            mesh)(q, k, v)
     raise ValueError(f"the decoder's attention_impl is auto | dense | flash "
                      f"| flash_interpret, not {impl!r}")
 
@@ -315,12 +338,15 @@ class RMSNorm(nn.Module):
             jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps) * scale
 
 
-def rotary(x: jax.Array, theta: float) -> jax.Array:
-    """Rotary positions 0..T-1 over the whole last axis of x (B, T, H, hd),
-    the rotate-half convention, in float32."""
+def rotary(x: jax.Array, theta: float, positions=None) -> jax.Array:
+    """Rotary positions over the whole last axis of x (B, T, H, hd), the
+    rotate-half convention, in float32: ``positions`` (T,), one id a token
+    whatever its place in the row, or 0..T-1."""
     t, hd = x.shape[1], x.shape[-1]
     inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    angles = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    if positions is None:
+        positions = jnp.arange(t)
+    angles = positions.astype(jnp.float32)[:, None] * inv[None, :]
     cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)[None, :, None]
     sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)[None, :, None]
     x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
@@ -338,9 +364,12 @@ class GroupedAttention(nn.Module):
     dtype: Any = jnp.bfloat16
     attention_impl: str = "auto"
     mesh: Any = None
+    gated: bool = True               # o ⊙ sigmoid(a W_gate)
+    rope_all_layers: bool = False    # rotary on the full layers too
+    mask: Any = None                 # an ops.attention.Mask in the causal one's place
 
     @nn.compact
-    def __call__(self, a: jax.Array) -> jax.Array:
+    def __call__(self, a: jax.Array, positions=None) -> jax.Array:
         b, t, d = a.shape
         h, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
         a = a.astype(self.dtype)
@@ -351,13 +380,17 @@ class GroupedAttention(nn.Module):
         q = RMSNorm(self.eps, name="q_norm")(q)
         k = RMSNorm(self.eps, name="k_norm")(k)
         sliding = self.kind == "sliding_attention"
-        if sliding:  # the full layers carry no positional term at all
-            q, k = rotary(q, self.rope_theta), rotary(k, self.rope_theta)
+        # afmoe's full layers carry no positional term at all
+        if sliding or self.rope_all_layers:
+            q = rotary(q, self.rope_theta, positions)
+            k = rotary(k, self.rope_theta, positions)
+        from ..ops.attention import Mask
+        mask = self.mask or Mask("causal", self.window if sliding else None)
         o = causal_attention(q.astype(self.dtype), k.astype(self.dtype), v,
-                             self.window if sliding else None,
-                             self.attention_impl, self.mesh)
-        o = o.reshape(b, t, h * hd) \
-            * nn.sigmoid(dense(h * hd, name="gate_proj")(a))
+                             mask, self.attention_impl, self.mesh)
+        o = o.reshape(b, t, h * hd)
+        if self.gated:
+            o = o * nn.sigmoid(dense(h * hd, name="gate_proj")(a))
         return dense(d, name="o_proj")(o)
 
 
@@ -367,23 +400,29 @@ class DecoderBlock(nn.Module):
     dtype: Any = jnp.bfloat16
     attention_impl: str = "auto"
     mesh: Any = None
+    mask: Any = None                 # GroupedAttention's
 
     @nn.compact
-    def __call__(self, x: jax.Array):
+    def __call__(self, x: jax.Array, positions=None):
         """x (B, T, d) float32 -> (x, counts): counts (E,) of this layer's
         assignments where it routes, a scalar 0 where it is dense."""
         c = self.cfg
+        family = FAMILIES[c.name]
         kind = c.layer_types[self.index]
         if kind not in LAYER_KINDS:
             raise ValueError(f"layer kind {kind!r} is none of {LAYER_KINDS}")
         norm = partial(RMSNorm, c.rms_norm_eps)
+
+        def joins(branch, name):  # as it is, or through a norm of its own
+            return norm(name=name)(branch) if family.post_norms else branch
         with jax.named_scope("attention"):
             a = GroupedAttention(
                 c.num_attention_heads, c.num_key_value_heads, c.head_dim,
                 kind, c.sliding_window, c.rope_theta, c.rms_norm_eps,
                 self.dtype, self.attention_impl, self.mesh,
-                name="attn")(norm(name="input_norm")(x))
-            x = x + norm(name="post_attn_norm")(a)
+                family.gated_attention, family.rope_all_layers, self.mask,
+                name="attn")(norm(name="input_norm")(x), positions)
+            x = x + joins(a, "post_attn_norm")
         m = norm(name="pre_mlp_norm")(x)
         if self.index < c.num_dense_layers:
             from .moe import SwiGLU
@@ -397,18 +436,24 @@ class DecoderBlock(nn.Module):
                 c.num_experts, tuple(c.experts_held),
                 c.num_experts_per_tok, c.moe_intermediate_size,
                 c.moe_intermediate_size * c.num_shared_experts,
-                c.route_scale, self.dtype, name="moe")(m.reshape(b * t, d))
+                c.route_scale, self.dtype, family.router,
+                name="moe")(m.reshape(b * t, d))
             f = f.reshape(b, t, d)
-        return x + norm(name="post_mlp_norm")(f), counts
+        return x + joins(f, "post_mlp_norm"), counts
 
 
 class CausalDecoder(nn.Module):
     """tokens (B, T) int32 -> logits (B, T, V) float32; with ``targets``
-    (B, T) the training outputs instead: ``{"loss", "correct", "counts"}``,
-    the mean next-token cross-entropy and the share of positions whose
-    largest logit is the target, computed a chunk of positions at a time
-    (the logits of a whole batch never exist), and per routing layer the
-    counts of assignments (``layer<i>`` -> (E,))."""
+    (B, T') the training outputs instead: ``{"loss", "correct", "counts"}``,
+    the mean cross-entropy of the first T' positions against them and the
+    share of positions whose largest logit is the target (``weights``
+    (B, T'): Σ w · nll over B·T', and the share over the positions of
+    weight > 0), computed a chunk of positions at a time (the logits of a
+    whole batch never exist, and positions past T' never meet the head),
+    and per routing layer the counts of assignments (``layer<i>`` -> (E,)).
+    ``positions`` (T,) are the tokens' rotary ids where they are not 0..T-1,
+    ``mask`` an ``ops.attention.Mask`` in the place of the layers' causal
+    ones."""
     cfg: Any
     dtype: Any = jnp.bfloat16
     attention_impl: str = "auto"
@@ -416,7 +461,8 @@ class CausalDecoder(nn.Module):
     mesh: Any = None
 
     @nn.compact
-    def __call__(self, tokens: jax.Array, train: bool = True, targets=None):
+    def __call__(self, tokens: jax.Array, train: bool = True, targets=None,
+                 positions=None, mask=None, weights=None):
         del train  # no dropout, no batch statistics
         c = self.cfg
         d, v = c.hidden_size, c.vocab_held
@@ -436,7 +482,7 @@ class CausalDecoder(nn.Module):
         counts = {}
         for i in range(len(c.layer_types)):
             x, got = block(c, i, self.dtype, self.attention_impl, self.mesh,
-                           name=f"layer{i}")(x)
+                           mask, name=f"layer{i}")(x, positions)
             if i >= c.num_dense_layers:
                 counts[f"layer{i}"] = got
         x = RMSNorm(c.rms_norm_eps, name="final_norm")(x)
@@ -447,12 +493,14 @@ class CausalDecoder(nn.Module):
                 return jnp.dot(x.astype(self.dtype), head.astype(self.dtype),
                                preferred_element_type=jnp.float32)
             loss, correct = chunked_next_token_loss(
-                x, head, targets, self.dtype)
+                x[:, :targets.shape[1]], head, targets, self.dtype, weights)
         return {"loss": loss, "correct": correct, "counts": counts}
 
-    def objective(self) -> "NextTokenObjective":
+    def objective(self):
         """The family's loss over its own batch (train/loop.py)."""
-        return NextTokenObjective(self.cfg)
+        return {"next_token": NextTokenObjective,
+                "block_diffusion": BlockDiffusionObjective}[
+                    FAMILIES[self.cfg.name].objective](self.cfg)
 
     def init_input(self, rows: int, data_cfg) -> jax.ShapeDtypeStruct:
         """What ``init`` is traced on: parameters do not depend on the
@@ -483,16 +531,8 @@ class NextTokenObjective:
         tokens = batch["tokens"]
         out = apply_fn({"params": variables["params"]}, tokens[:, :-1],
                        train=True, targets=tokens[:, 1:])
-        metrics = {"precision": out["correct"]}
-        if out["counts"]:
-            lo, hi = self.cfg.experts_held
-            held = jnp.stack([c[lo:hi] for c in out["counts"].values()])
-            per_layer = jnp.sum(held, axis=-1)
-            # expectation: tokens × top-k × held / published, each layer
-            metrics["moe_assignments_held"] = jnp.mean(per_layer)
-            metrics["moe_load_max_over_mean"] = jnp.max(
-                jnp.max(held, axis=-1) * (hi - lo)
-                / jnp.maximum(per_layer, 1.0))
+        metrics = {"precision": out["correct"],
+                   **_held_load(self.cfg, out["counts"])}
         return (out["loss"], metrics, variables["batch_stats"], [],
                 out["counts"])
 
@@ -504,6 +544,64 @@ class NextTokenObjective:
                 + self.cfg.load_balance_coeff * jnp.sign(jnp.mean(c) - c)
             params[layer] = dict(params[layer], moe=moe)
         return params
+
+
+def _held_load(cfg, counts) -> dict:
+    """The step's assignments to the experts held here, a mean over the
+    routing layers, and the fullest held expert against its layer's mean."""
+    if not counts:
+        return {}
+    lo, hi = cfg.experts_held
+    held = jnp.stack([c[lo:hi] for c in counts.values()])
+    per_layer = jnp.sum(held, axis=-1)
+    # expectation: tokens × top-k × held / published, each layer
+    return {"moe_assignments_held": jnp.mean(per_layer),
+            "moe_load_max_over_mean": jnp.max(
+                jnp.max(held, axis=-1) * (hi - lo)
+                / jnp.maximum(per_layer, 1.0))}
+
+
+class BlockDiffusionObjective:
+    """``{"tokens": int32 (N, L), "masked": uint8 (N, L), "t": float32
+    (N, L/B)}`` (data/tokens.block_diffusion_iterator): the block-diffusion
+    loss of arXiv:2503.09573, vectorised. The decoder runs once over 2L
+    positions a sequence, the noisy copy (a masked id replaced by
+    ``mask_token_held``) then the clean copy, a token of either copy at the
+    position id of its place in the sequence, under the block-diffusion
+    ``Mask``. The head reads the noisy copy alone, each position predicting
+    the id at its own place (no shift); an example's loss is
+    (1/L) Σ_i masked_i / t_block(i) · nll_i, the step's the mean over the
+    examples; ``precision`` is the share of masked positions whose largest
+    logit is the id. No auxiliary term and no rule after the update."""
+    batch_keys = ("tokens", "masked", "t")
+    after_update = None
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+
+    def prepare(self, batch, step, midx=None):
+        del step, midx
+        return batch
+
+    def forward(self, apply_fn, variables, batch):
+        from ..ops.attention import block_diffusion_mask
+        c = self.cfg
+        tokens, masked = batch["tokens"], batch["masked"] != 0
+        length = tokens.shape[1]
+        with jax.named_scope("blockdiff_input"):
+            noisy = jnp.where(masked, c.mask_token_held, tokens)
+            both = jnp.concatenate([noisy, tokens], axis=1)
+            positions = jnp.tile(jnp.arange(length), 2)
+            weights = masked / jnp.repeat(batch["t"], c.block_length, axis=1)
+        out = apply_fn({"params": variables["params"]}, both, train=True,
+                       targets=tokens, positions=positions,
+                       mask=block_diffusion_mask(length, c.block_length),
+                       weights=weights)
+        metrics = {"precision": out["correct"],
+                   "masked_share": jnp.mean(masked.astype(jnp.float32)),
+                   "loss_weight_mean": jnp.mean(weights),
+                   **_held_load(c, out["counts"])}
+        return out["loss"], metrics, variables["batch_stats"], [], None
 
 
 class _Embedding(nn.Module):
@@ -520,24 +618,31 @@ class _Embedding(nn.Module):
 LOSS_CHUNK = 2048
 
 
-def chunked_next_token_loss(x, head, targets, dtype):
+def chunked_next_token_loss(x, head, targets, dtype, weights=None):
     """Mean over all positions of logsumexp(x W) − (x W)[target], and the
     share of positions whose arg-max is the target; ``LOSS_CHUNK`` positions
-    at a time, each chunk's logits recomputed in the backward pass."""
+    at a time, each chunk's logits recomputed in the backward pass. With
+    ``weights`` (as ``targets``): Σ weight · nll over the number of
+    positions, and the share among the positions of weight > 0."""
     b, t, d = x.shape
     n = b * t
     chunk = math.gcd(n, LOSS_CHUNK)
     w = head.astype(dtype)
+    if weights is None:
+        weights = jnp.ones(targets.shape, jnp.float32)
+        counted = n
+    else:
+        counted = jnp.maximum(jnp.sum(weights > 0), 1)
 
     @jax.checkpoint
     def one(args):
-        xc, yc = args
+        xc, yc, wc = args
         logits = jnp.dot(xc.astype(dtype), w,
                          preferred_element_type=jnp.float32)
         picked = jnp.take_along_axis(logits, yc[:, None], axis=-1)[:, 0]
         nll = jax.nn.logsumexp(logits, axis=-1) - picked
         hit = (jnp.argmax(logits, axis=-1) == yc).astype(jnp.float32)
-        return jnp.sum(nll), jnp.sum(hit)
-    nll, hit = jax.lax.map(one, (x.reshape(n // chunk, chunk, d),
-                                 targets.reshape(n // chunk, chunk)))
-    return jnp.sum(nll) / n, jnp.sum(hit) / n
+        return jnp.sum(wc * nll), jnp.sum((wc > 0) * hit)
+    split = lambda a: a.reshape((n // chunk, chunk) + a.shape[2:])  # noqa: E731
+    nll, hit = jax.lax.map(one, (split(x), split(targets), split(weights)))
+    return jnp.sum(nll) / n, jnp.sum(hit) / counted

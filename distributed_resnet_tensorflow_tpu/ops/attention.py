@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -33,19 +34,105 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 
+class Mask(NamedTuple):
+    """Which (query, key) pairs of an attention call count: the one value
+    that ``attention`` below (a dense [T, T] mask), the Pallas kernels'
+    ``_Tiles`` (a tile's mask on traced positions, the live tiles) and
+    ``tile_census`` (Python ints) all read.
+
+    * ``full``: every pair;
+    * ``causal``: keys j <= i, with ``window`` the band i - window < j <= i;
+    * ``block_diffusion`` (arXiv:2503.09573, vectorised training): the
+      call's 2·``length`` positions are a noisy copy of a sequence followed
+      by its clean copy, position id i of a copy in diffusion block
+      i // ``block``. A noisy query sees the noisy keys of its own block and
+      the clean keys of earlier blocks; a clean query the clean keys up to
+      the end of its own block; no clean query sees a noisy key. Inside a
+      block attention runs both ways."""
+    kind: str = "full"
+    window: Optional[int] = None
+    length: int = 0
+    block: int = 0
+
+    @property
+    def copies(self) -> int:
+        """Equal segments the call's positions are laid out in."""
+        return 2 if self.kind == "block_diffusion" else 1
+
+    def counts(self, q_pos, k_pos, stride: Optional[int] = None):
+        """Bool, broadcast over int32 positions of queries and keys: does
+        the pair count? None where every pair does. ``stride`` is where the
+        clean copy starts when each copy was padded (``length`` else)."""
+        if self.kind == "full":
+            return None
+        if self.kind == "causal":
+            seen = q_pos >= k_pos
+            if self.window is not None:
+                seen = jnp.logical_and(seen, k_pos > q_pos - self.window)
+            return seen
+        stride = self.length if stride is None else stride
+        q_clean, k_clean = q_pos >= stride, k_pos >= stride
+        qb = _floordiv(q_pos - jnp.where(q_clean, stride, 0), self.block)
+        kb = _floordiv(k_pos - jnp.where(k_clean, stride, 0), self.block)
+        # a clean key counts for the blocks before the query's, and for its
+        # own where the query is clean; a noisy key for a noisy query of its
+        # own block. The copies are folded into the block numbers on each
+        # side (vectors along one axis), so that the pairs cost two
+        # comparisons and an OR: a kernel cannot select between two vectors
+        # of booleans
+        never = jnp.iinfo(jnp.int32).max
+        clean_keys = jnp.where(k_clean, kb, never) \
+            < qb + q_clean.astype(jnp.int32)
+        noisy_keys = jnp.where(k_clean, -2, kb) == jnp.where(q_clean, -1, qb)
+        return jnp.logical_or(clean_keys, noisy_keys)
+
+
+def _floordiv(x, n: int):
+    """x // n for positions (none negative): a shift where n is a power of
+    two, which a kernel's vector unit has and a division it may not."""
+    return x >> (n.bit_length() - 1) if n & (n - 1) == 0 else x // n
+
+
+FULL = Mask()
+CAUSAL = Mask("causal")
+
+
+def block_diffusion_mask(length: int, block: int) -> Mask:
+    if length <= 0 or block <= 0 or length % block:
+        raise ValueError(f"a sequence of {length} ids is no whole number of "
+                         f"diffusion blocks of {block}")
+    return Mask("block_diffusion", None, length, block)
+
+
+def as_mask(mask=False, window=None) -> Mask:
+    """A ``Mask`` as given, or the one the shorthand names: False full, True
+    causal, with ``window`` the causal band."""
+    if isinstance(mask, Mask):
+        if window is not None:
+            raise ValueError("a window beside a Mask: give Mask a window")
+        return mask
+    if window is not None and not mask:
+        raise ValueError("a window needs causal=True")
+    return Mask("causal", window) if mask else FULL
+
+
 def attention(q: jax.Array, k: jax.Array, v: jax.Array,
-              causal: bool = False, window=None) -> jax.Array:
+              causal=False, window=None) -> jax.Array:
     """Dense reference attention, and the ``jax.numpy`` twin of the Pallas
     kernels (ops/pallas/flash_attention.py takes the same arguments).
     Shapes: q (B, T, H, D) — batch, time, heads, head_dim; k and v
     (B, T, KV, D) with H a multiple of KV (query head h reads key/value head
-    h // (H/KV)). ``window`` (with ``causal``): query i sees keys
-    i − window < j ≤ i. fp32 softmax regardless of input dtype."""
+    h // (H/KV)). ``causal`` is False, True or a ``Mask``; ``window`` (with
+    ``causal=True``): query i sees keys i − window < j ≤ i. fp32 softmax
+    regardless of input dtype."""
     b, tq, h, d = q.shape
     tk, kvh = k.shape[1], k.shape[2]
-    if h % kvh or (window is not None and not causal):
-        raise ValueError(f"heads {h} over {kvh}, window {window} with "
-                         f"causal={causal}: not an attention this computes")
+    mask = as_mask(causal, window)
+    if h % kvh:
+        raise ValueError(f"{h} query heads are no multiple of {kvh} "
+                         "key/value heads")
+    if mask.copies == 2 and not tq == tk == 2 * mask.length:
+        raise ValueError(f"{mask} over {tq} queries and {tk} keys")
     scale = 1.0 / math.sqrt(d)
     if h != kvh:  # grouped heads: one key/value head to each group
         q = q.reshape(b, tq, kvh, h // kvh, d)
@@ -53,11 +140,11 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
     else:
         s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
     s = s * scale
-    if causal:
-        mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
-        if window is not None:
-            mask &= ~jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq - window)
-        s = jnp.where(mask, s, -jnp.inf)
+    # queries are the LAST tq positions of the key timeline
+    seen = mask.counts(jnp.arange(tq)[:, None] + (tk - tq),
+                       jnp.arange(tk)[None, :])
+    if seen is not None:
+        s = jnp.where(seen, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     if h != kvh:
         return jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype),

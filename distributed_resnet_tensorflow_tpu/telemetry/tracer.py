@@ -69,7 +69,9 @@ log = logging.getLogger(__name__)
 
 #: bump when the trace.json event shape changes (consumers key on it via
 #: the ``trace_dump`` metrics row and the file's otherData block)
-SPAN_SCHEMA_VERSION = 12  # 12: + input.tokens (data/tokens.py: one packed
+SPAN_SCHEMA_VERSION = 13  # 13: + input.noise (data/tokens.py: one batch's
+#                              block-diffusion noising drawn on the host)
+#                              12: + input.tokens (data/tokens.py: one packed
 #                              batch of a token stream)
 #                              11: + train.hook_read (a cadence hook's
 #                               late read of device metrics) and
@@ -111,6 +113,9 @@ SPAN_CATALOG = {
                     "charges the 'decode' stage",
     "input.tokens": "one batch of a token stream drawn and packed to "
                     "fixed sequences (data/tokens.py)",
+    "input.noise": "one batch's block-diffusion noising drawn on the host: "
+                   "a level per diffusion block, a mask per id "
+                   "(data/tokens.block_diffusion_noise)",
     "input.stack": "K host batches np.stack'ed (stacker thread; charges "
                    "the 'stack' stage)",
     "input.echo": "one source batch absorbed into the decoded-sample echo "

@@ -597,8 +597,8 @@ class Trainer:
             return "on" if flag else "off"
 
         attention = "n/a"
-        if cfg.model.name == "afmoe":
-            from ..models.transformer import causal_flash_or_dense
+        from ..models.transformer import FAMILIES, causal_flash_or_dense
+        if cfg.model.name in FAMILIES:
             attention = causal_flash_or_dense(self.model.attention_impl)
         if cfg.model.name == "vit":
             attention = self.model.attention_impl

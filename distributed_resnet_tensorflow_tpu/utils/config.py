@@ -22,7 +22,7 @@ class ModelConfig:
     the size/width axes the reference hard-coded (resnet_model.py:71-74 pins
     resnet_size=50 for both datasets)."""
 
-    name: str = "resnet"              # resnet | logistic | vit | afmoe
+    name: str = "resnet"              # resnet | logistic | vit | afmoe | sdar_moe
     resnet_size: int = 50             # cifar: 6n+2 ∈ {20,32,44,50,56,110,...}; imagenet: 18/34/50/101/152/200
     width_multiplier: int = 1         # Wide-ResNet (e.g. 28-10 → resnet_size=28, width=10)
     num_classes: int = 10
@@ -81,15 +81,18 @@ class ModelConfig:
     # auto = ring if mesh.sequence>1; flash on TPU at >=2048 tokens; else dense
     # (afmoe: auto = flash on TPU at any length, dense elsewhere)
     attention_impl: str = "auto"      # auto | dense | blockwise | flash | ring
-    # -- causal decoder family (name="afmoe": models/transformer.py
-    # CausalDecoder + models/moe.py DroplessMoe). Keys are the published
-    # config.json's where it has one; the sequence length is data.seq_len.
+    # -- causal decoder families (name in models/transformer.FAMILIES:
+    # CausalDecoder + models/moe.py DroplessMoe; what a family fixes beyond
+    # these sizes (norms, gate, where rotary runs, router, objective) is
+    # that table and no field here). Keys are
+    # the published config.json's where it has one; the sequence length is
+    # data.seq_len.
     hidden_size: int = 256
     num_attention_heads: int = 8      # query heads ...
     num_key_value_heads: int = 2      # ... over this many key/value heads
     head_dim: int = 32
     # one entry a layer: sliding_attention (window + rotary positions) |
-    # full_attention (causal, no positional term)
+    # full_attention (causal; a positional term where the family says so)
     layer_types: Tuple[str, ...] = ("sliding_attention", "full_attention")
     sliding_window: int = 64
     rope_theta: float = 10000.0
@@ -109,6 +112,12 @@ class ModelConfig:
     load_balance_coeff: float = 0.001
     mup_enabled: bool = True          # embedding output x sqrt(hidden_size)
     vocab_held: int = 512             # vocabulary rows held here (untied head)
+    # block diffusion (name="sdar_moe", arXiv:2503.09573): ids to a
+    # diffusion block; the held row that stands for a masked id (the data
+    # draws ids below it); the least noise level of a block
+    block_length: int = 4
+    mask_token_held: int = 511
+    noise_eps: float = 1e-3
 
 
 @dataclass
@@ -200,8 +209,10 @@ class DataConfig:
     verify_crc: bool = False
     # eval pipeline
     eval_batch_size: int = 100        # reference resnet_cifar_eval.py batch of 100
-    # token models (dataset="tokens", data/tokens.py): a batch is
-    # {"tokens": int32 [B, seq_len + 1]}, inputs and next-token targets
+    # token models (data/tokens.py). dataset="tokens": a batch is
+    # {"tokens": int32 [B, seq_len + 1]}, inputs and next-token targets;
+    # dataset="blockdiff_tokens": {"tokens": int32 [B, seq_len], "masked":
+    # uint8 [B, seq_len], "t": float32 [B, seq_len / model.block_length]}
     seq_len: int = 128
 
 
@@ -993,6 +1004,36 @@ def _trinity_mini_share8() -> ExperimentConfig:
     return cfg
 
 
+def _sdar_30b_a3b_share8() -> ExperimentConfig:
+    """SDAR-30B-A3B-Chat (JetLM, model_type sdar_moe) at its published
+    widths: ONE chip's share of a deployment in which eight chips share
+    each layer — experts 0-15 of 128, 18,992 of 151,936 vocabulary rows,
+    attention and router whole — and six of its 48 layers (every layer is
+    the same: grouped attention with rotary positions, softmax top-8
+    experts, no shared expert; the rest would lie on further chips as
+    pipeline stages). 645,623,296 parameters, 10.33 GB of state at 16 bytes
+    each; the block-diffusion objective in blocks of 4 over sequences of
+    4,096 ids, 8,192 positions each through the decoder."""
+    cfg = ExperimentConfig()
+    cfg.model = ModelConfig(
+        name="sdar_moe", compute_dtype="bfloat16", attention_impl="auto",
+        hidden_size=2048, num_attention_heads=32, num_key_value_heads=4,
+        head_dim=128, layer_types=("full_attention",) * 6,
+        rope_theta=1000000.0, rms_norm_eps=1e-6,
+        num_dense_layers=0, intermediate_size=6144,
+        moe_intermediate_size=768, num_experts=128, experts_held=(0, 16),
+        num_experts_per_tok=8, num_shared_experts=0, mup_enabled=False,
+        vocab_held=18992, block_length=4, mask_token_held=18991,
+        noise_eps=1e-3)
+    cfg.data = DataConfig(dataset="blockdiff_tokens", seq_len=4096)
+    cfg.optimizer = OptimizerConfig(
+        name="adamw", learning_rate=3e-4, weight_decay=0.1,
+        schedule="cosine", warmup_steps=2000, total_steps=100000)
+    cfg.train = TrainConfig(batch_size=2, train_steps=100000,
+                            steps_per_loop=1, remat=True)
+    return cfg
+
+
 def _cifar10_smoke() -> ExperimentConfig:
     """Local smoke test analog of reference scripts/submit_mac_dist.sh
     (1ps+2wk, bs=10, 100 steps on CPU — SURVEY.md §4.1)."""
@@ -1017,6 +1058,7 @@ PRESETS = {
     "vit_large_224": _vit_large_224,
     "vit_moe": _vit_moe,
     "trinity_mini_share8": _trinity_mini_share8,
+    "sdar_30b_a3b_share8": _sdar_30b_a3b_share8,
     "smoke": _cifar10_smoke,
 }
 

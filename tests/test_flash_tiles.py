@@ -13,7 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from distributed_resnet_tensorflow_tpu.ops.attention import attention
+from distributed_resnet_tensorflow_tpu.ops.attention import (
+    attention, as_mask, block_diffusion_mask)
 from distributed_resnet_tensorflow_tpu.ops.pallas.flash_attention import (
     _plan, _tiles, _walk, flash_attention, tile_census)
 
@@ -46,8 +47,9 @@ CENSUS_CASES = [
 
 @pytest.mark.parametrize("t,causal,window,bq,bk", CENSUS_CASES)
 def test_tile_census_against_the_mask_enumerated(t, causal, window, bq, bk):
-    plan = _plan(t, 128, window, bq, bk)
-    tiles = _tiles(t, causal, window, plan)
+    mask = as_mask(causal, window)
+    plan = _plan(t, 128, mask, bq, bk)
+    tiles = _tiles(t, mask, plan)
     bq, bk = plan.block_q, plan.block_k
     tp = t + plan.tpad
     counts = counted_pairs(tp, t, causal, window)
@@ -58,15 +60,15 @@ def test_tile_census_against_the_mask_enumerated(t, causal, window, bq, bk):
     # the same tiles, and every counted pair lies in one of them
     by_k = np.zeros_like(any_counts)
     for qi in range(tiles.nq):
-        lo, hi = tiles.k_range(qi)
+        (lo, hi), = tiles.k_runs(qi)
         assert hi - lo + 1 <= tiles.k_steps
         by_k[qi, lo:hi + 1] = True
         # the blocks held along the walk: the run, then its last block again
-        held = [int(_walk((lo, hi), j)) for j in range(tiles.k_steps)]
+        held = [int(_walk(((lo, hi),), j)) for j in range(tiles.k_steps)]
         assert held == [min(lo + j, hi) for j in range(tiles.k_steps)]
     by_q = np.zeros_like(any_counts)
     for kj in range(tiles.nk):
-        lo, hi = tiles.q_range(kj)
+        (lo, hi), = tiles.q_runs(kj)
         assert hi - lo + 1 <= tiles.q_steps
         by_q[lo:hi + 1, kj] = True
     np.testing.assert_array_equal(by_k, by_q)
@@ -104,14 +106,16 @@ def test_tile_census_of_the_cells_two_calls():
 
 def test_a_mask_is_the_counted_pairs_of_its_tile():
     t, window, bq, bk = 200, 50, 64, 32
-    plan = _plan(t, 128, window, bq, bk)
-    tiles = _tiles(t, True, window, plan)
+    mask = as_mask(True, window)
+    plan = _plan(t, 128, mask, bq, bk)
+    tiles = _tiles(t, mask, plan)
     counts = counted_pairs(t + plan.tpad, t, True, window)
     for qi, kj in ((0, 0), (1, 1), (2, 3), (3, 6), (3, 4)):
         want = counts[qi * bq:(qi + 1) * bq, kj * bk:(kj + 1) * bk]
-        got = np.broadcast_to(tiles.mask(qi, kj), (bq, bk))
+        got = np.broadcast_to(tiles.mask_of(qi, kj), (bq, bk))
         np.testing.assert_array_equal(got, want)
-        got_t = np.broadcast_to(tiles.mask(qi, kj, keys_first=True), (bk, bq))
+        got_t = np.broadcast_to(tiles.mask_of(qi, kj, keys_first=True),
+                                (bk, bq))
         np.testing.assert_array_equal(got_t, want.T)
 
 
@@ -175,6 +179,9 @@ def test_a_padded_noncausal_call_walks_every_tile():
 # least, against 0.02% between the two runs: PERF.md §6, PR 34)
 TUNED = ("docs/flash_tune_v5e_gqa_window.json",
          "docs/flash_tune_v5e_gqa_window_repeat.json")
+# the block-diffusion mask's calls (PR 35): T positions are two copies of
+# T/2 ids in diffusion blocks of 4
+TUNED_BLOCK_DIFFUSION = ("docs/flash_tune_v5e_blockdiff.json",)
 
 
 def tuned_results(files=TUNED):
@@ -186,12 +193,21 @@ def tuned_results(files=TUNED):
 
 
 def call_of(r) -> str:
-    return "%s-T%d-d%d-%don%d-w%s" % (
+    return "%s-T%d-d%d-%don%d-w%s%s" % (
         "repeat" if "repeat" in r["file"] else "sweep", r["t"], r["d"],
-        r["heads"], r["kv_heads"], r["window"])
+        r["heads"], r["kv_heads"], r["window"],
+        "-B%d" % r["diffusion_block"] if "diffusion_block" in r else "")
 
 
-@pytest.mark.parametrize("result", tuned_results(), ids=call_of)
+def mask_of(r):
+    if "diffusion_block" in r:
+        return block_diffusion_mask(r["t"] // 2, r["diffusion_block"])
+    return as_mask(True, r["window"])
+
+
+@pytest.mark.parametrize(
+    "result", tuned_results() + tuned_results(TUNED_BLOCK_DIFFUSION),
+    ids=call_of)
 def test_every_tuned_call_takes_its_winner(result):
     """``_BLOCK_TABLES`` gives every call the tuner's committed files hold
     (the cell's two among them: 8,192 tokens, head size 128, 32 query heads
@@ -201,11 +217,11 @@ def test_every_tuned_call_takes_its_winner(result):
     until tuner runs that it wins are committed."""
     t, d, window = result["t"], result["d"], result["window"]
     assert result["device"] == "TPU v5 lite"
-    plan = _plan(t, d, window)
+    plan = _plan(t, d, mask_of(result))
     winner = result["best"]["kernels"]
     assert "%dx%d" % (plan.block_q, plan.block_k) == winner
     point = result["points"][winner]
-    assert point["census"] == tile_census(t, d, True, window, 0, 0)
+    assert point["census"] == tile_census(t, d, mask_of(result), None, 0, 0)
     assert sum(point["kernel_ms"].values()) == min(
         sum(p["kernel_ms"].values()) for p in result["points"].values()
         if "kernel_ms" in p)
@@ -216,6 +232,23 @@ def test_the_cells_two_calls_are_tuned():
         calls = {(r["t"], r["d"], r["heads"], r["kv_heads"], r["window"])
                  for r in tuned_results([name])}
         assert {(8192, 128, 32, 4, 2048), (8192, 128, 32, 4, None)} <= calls
+
+
+def test_the_block_diffusion_cells_call_is_tuned_in_both_orders():
+    """4,096 ids a sequence (8,192 positions), blocks of 4, 32 heads on 4:
+    tuned as [noisy; clean] (what ships) and, once, with the copies
+    interleaved block by block under the causal walk, which lost by 1.43x
+    (PERF.md section 6, PR 35; the kernels cannot run that order any more)."""
+    calls = {(r["t"], r["d"], r["heads"], r["kv_heads"], r["diffusion_block"])
+             for r in tuned_results(TUNED_BLOCK_DIFFUSION)}
+    assert {(t, 128, 32, 4, 4) for t in (4096, 8192, 16384)} <= calls
+    (other,) = tuned_results(["docs/flash_tune_v5e_blockdiff_interleaved.json"])
+    shipped = next(r for r in tuned_results(TUNED_BLOCK_DIFFUSION) if r["t"] == 8192)
+    assert other["order"] == "interleaved" and other["t"] == 8192
+
+    def best(r):
+        return min(sum(p["kernel_ms"].values()) for p in r["points"].values())
+    assert best(other) > 1.4 * best(shipped)
 
 
 # -- the tuner: a TPU or nothing; rehearsed here through tune(interpret=True)
@@ -246,6 +279,7 @@ def test_the_tuner_rehearsed_in_interpret_mode(tuner, tmp_path):
                  pairs=[(16, 32), (32, 16), (0, 0)], reps=1, interpret=True)
     results = tuner.tune(out, seqs=[64], **shape)["results"]
     assert [(r["t"], r["window"]) for r in results] == [(64, 24), (64, None)]
+    assert not any("diffusion_block" in r for r in results)
     for r in results:
         assert r["device"].endswith(" interpret") and "TPU" not in r["device"]
         assert set(r["points"]) == {"16x32", "32x16", "0x0"}
@@ -258,6 +292,15 @@ def test_the_tuner_rehearsed_in_interpret_mode(tuner, tmp_path):
     again = tuner.tune(out, seqs=[64, 48], **shape)["results"]
     assert again[:2] == results
     assert [(r["t"], r["window"]) for r in again[2:]] == [(48, 24), (48, None)]
+
+    # the block-diffusion mask: a point of its own beside the causal ones
+    mixed = tuner.tune(out, seqs=[64], diffusion_block=4, **shape)["results"]
+    assert mixed[:4] == again and len(mixed) == 5
+    assert mixed[4]["diffusion_block"] == 4 and mixed[4]["window"] is None
+    for name, point in mixed[4]["points"].items():
+        bq, bk = map(int, name.split("x"))
+        assert point["census"] == tile_census(
+            64, 32, block_diffusion_mask(32, 4), None, bq, bk)
 
     with open(out) as f:
         on_disk = json.load(f)
@@ -285,12 +328,12 @@ def test_the_tuner_records_only_a_vmem_refusal(tuner, monkeypatch, error,
     monkeypatch.setattr(tuner, "call_ms", refuse)
     q = jnp.zeros((1, 64, 2, 32), jnp.bfloat16)
     if recorded:
-        row = tuner.measure(q, q, q, None, 32, 32, 1, True)
+        row = tuner.measure(q, q, q, as_mask(True), 32, 32, 1, True)
         assert row["error"].startswith("JaxRuntimeError: RESOURCE_EXHAUSTED")
         assert tuner.best_of({"32x32": row}) == {}
     else:
         with pytest.raises(type(error)):
-            tuner.measure(q, q, q, None, 32, 32, 1, True)
+            tuner.measure(q, q, q, as_mask(True), 32, 32, 1, True)
 
 
 # -- the real calls, compiled for chips that are described and not attached --
@@ -323,11 +366,15 @@ def compiled_text(fn, *args) -> str:
 
 @pytest.mark.parametrize("t,heads,kv,d,causal,window", [
     (8192, 32, 4, 128, True, 2048), (8192, 32, 4, 128, True, None),
-    (196, 2, 2, 64, False, None)])
+    (196, 2, 2, 64, False, None),
+    (8192, 32, 4, 128, block_diffusion_mask(4096, 4), None),
+    (2000, 8, 2, 128, block_diffusion_mask(1000, 8), None)])
 def test_the_kernels_compile_for_a_v5e(one_chip, t, heads, kv, d, causal,
                                        window):
-    """Mosaic takes the three kernels at the cell's shapes and at the
-    encoder's unaligned one (interpret mode checks no layout)."""
+    """Mosaic takes the three kernels at the cells' shapes (the token
+    cell's two calls, the block-diffusion cell's and a padded one of its
+    kind) and at the encoder's unaligned one (interpret mode checks no
+    layout)."""
     q = jax.ShapeDtypeStruct((2, t, heads, d), jnp.bfloat16, sharding=one_chip)
     k = jax.ShapeDtypeStruct((2, t, kv, d), jnp.bfloat16, sharding=one_chip)
     grad = jax.grad(lambda q, k, v: jnp.sum(flash_attention(

@@ -2,7 +2,9 @@
 
 For every point (T, head size, window) of a call shape (``--batch``,
 ``--heads``, ``--kv_heads``; bf16, causal) and every (block_q, block_k) of
-``--blocks``², measures
+``--blocks``², measures (with ``--diffusion_block B`` the mask is the
+block-diffusion one of ``ops.attention.Mask`` over a noisy and a clean copy
+of T/2 ids each, in diffusion blocks of B, and ``--windows`` is not read)
 
 * ``fwd_ms``: the forward call alone, and ``grad_ms``: forward and backward
   (``jax.grad`` of the summed output): each a compiled call, warmed up, the
@@ -47,6 +49,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from distributed_resnet_tensorflow_tpu.ops.attention import (  # noqa: E402
+    Mask, block_diffusion_mask)
 from distributed_resnet_tensorflow_tpu.ops.pallas.flash_attention import (  # noqa: E402
     flash_attention, tile_census)
 from distributed_resnet_tensorflow_tpu.utils.compile_cache import (  # noqa: E402
@@ -108,15 +112,22 @@ def kernel_ms(compiled, args, ms_a_call: float) -> dict:
     return {name: round(statistics.fmean(ms), 4) for name, ms in found.items()}
 
 
-def measure(q, k, v, window, bq, bk, reps, interpret=False) -> dict:
+def mask_of(t: int, window, diffusion_block: int = 0) -> Mask:
+    """The mask of a point: causal with its window, or block diffusion."""
+    if diffusion_block:
+        return block_diffusion_mask(t // 2, diffusion_block)
+    return Mask("causal", window)
+
+
+def measure(q, k, v, mask, bq, bk, reps, interpret=False) -> dict:
     """One (block_q, block_k) of one point. ``interpret`` (a rehearsal off
     the chip) leaves out the trace, which has no device to read."""
     def fwd(q, k, v):
-        return flash_attention(q, k, v, True, interpret, bq, bk, window)
+        return flash_attention(q, k, v, mask, interpret, bq, bk)
 
     grad = jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
                     argnums=(0, 1, 2))
-    row = {"census": tile_census(q.shape[1], q.shape[3], True, window, bq, bk)}
+    row = {"census": tile_census(q.shape[1], q.shape[3], mask, None, bq, bk)}
     try:
         row["fwd_ms"] = round(call_ms(fwd, (q, k, v), reps)[0], 4)
         ms, compiled = call_ms(grad, (q, k, v), reps)
@@ -150,9 +161,11 @@ def best_of(points: dict) -> dict:
 
 
 def tune(out_path, seqs, dims, windows, batch, heads, kv_heads, pairs, reps,
-         interpret=False) -> dict:
+         interpret=False, diffusion_block: int = 0) -> dict:
     """Measure every point not yet in ``out_path`` and write the file after
-    each. ``pairs``: (block_q, block_k), (0, 0) the module's own pick."""
+    each. ``pairs``: (block_q, block_k), (0, 0) the module's own pick.
+    ``diffusion_block`` > 0: the block-diffusion mask at every T of
+    ``seqs`` (T positions: two copies of T/2 ids), ``windows`` not read."""
     device = jax.devices()[0].device_kind + (" interpret" if interpret else "")
     out = {"dtype": "bfloat16", "causal": True, "results": []}
     if os.path.exists(out_path):
@@ -166,15 +179,20 @@ def tune(out_path, seqs, dims, windows, batch, heads, kv_heads, pairs, reps,
 
     def key(r):
         return (r["t"], r["d"], r["batch"], r["heads"], r["kv_heads"],
-                r["window"])
+                r["window"], r.get("diffusion_block", 0))
 
     done = {key(r) for r in results}
+    if diffusion_block:
+        windows = [None]
     for t, d, window in itertools.product(seqs, dims, windows):
         point = {"device": device, "jax": jax.__version__, "t": t, "d": d,
                  "batch": batch, "heads": heads, "kv_heads": kv_heads,
                  "window": window, "points": {}}
+        if diffusion_block:
+            point["diffusion_block"] = diffusion_block
         if key(point) in done or (window is not None and window >= t):
             continue
+        mask = mask_of(t, window, diffusion_block)
         rng = np.random.RandomState(0)
         q, k, v = (jnp.asarray(
             rng.randn(batch, t, h, d).astype(np.float32) * 0.3)
@@ -182,7 +200,7 @@ def tune(out_path, seqs, dims, windows, batch, heads, kv_heads, pairs, reps,
         for bq, bk in pairs:
             if (bq == 0) != (bk == 0) or max(bq, bk) > t:
                 continue
-            row = measure(q, k, v, window, bq, bk, reps, interpret)
+            row = measure(q, k, v, mask, bq, bk, reps, interpret)
             point["points"][f"{bq}x{bk}"] = row
             print(f"T={t} d={d} window={window} block {bq}x{bk}: "
                   f"{json.dumps(row)}", flush=True)
@@ -204,6 +222,10 @@ def main(argv=None):
     ap.add_argument("--dims", default="128")
     ap.add_argument("--windows", default="2048,none",
                     help="comma list of window sizes; none = plain causal")
+    ap.add_argument("--diffusion_block", type=int, default=0,
+                    help="> 0: the block-diffusion mask in blocks of this "
+                         "many ids; --seqs are then the call's positions, "
+                         "two copies of half as many ids")
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--heads", type=int, default=32)
     ap.add_argument("--kv_heads", type=int, default=4)
@@ -225,7 +247,8 @@ def main(argv=None):
                for w in args.windows.split(",")]
     tune(args.out, [int(t) for t in args.seqs.split(",")],
          [int(d) for d in args.dims.split(",")], windows, args.batch,
-         args.heads, args.kv_heads, pairs, args.reps)
+         args.heads, args.kv_heads, pairs, args.reps,
+         diffusion_block=args.diffusion_block)
     print(f"wrote {args.out}")
 
 
