@@ -51,7 +51,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Any, Tuple
+from typing import Any, NamedTuple, Tuple
 
 import flax.linen as nn
 import jax
@@ -415,46 +415,14 @@ class SwitchMlp(nn.Module):
 # routed sum and adds the shared expert; what the absent experts would add
 # belongs to the chips that hold them. On one chip it runs without its
 # exchange, and nothing here stands in for the absent chips.
+#
+# A WINDOW is a run of ``window_rows`` consecutive rows of a chunk's sorted
+# order of assignments, the held experts' first. The layer walks windows up
+# to the live count (the held experts' assignments) and no further, so the
+# rows of absent experts, seven in eight where a chip holds 16 of 128, are
+# seen by the int32 bookkeeping alone: they are never gathered, multiplied,
+# masked or summed. A window is also all the layer holds of a chunk at once.
 # ---------------------------------------------------------------------------
-
-
-@jax.custom_vjp
-def _dispatch(x, tok, slot):
-    """Tokens' rows laid out by sorted assignment: row i of the result is
-    token ``tok[i]`` of ``x`` (n, d). ``slot`` (n, k) says where each of a
-    token's k assignments sits in that layout, so the transpose is
-    ``_combine``: neither direction scatters."""
-    return x[tok]
-
-
-def _dispatch_fwd(x, tok, slot):
-    return x[tok], (tok, slot)
-
-
-def _dispatch_bwd(res, g):
-    tok, slot = res
-    return _combine(g, tok, slot), None, None
-
-
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
-
-
-@jax.custom_vjp
-def _combine(y, tok, slot):
-    """Sorted assignments' rows (n·k, d) summed back per token: (n, d)."""
-    return y[slot].sum(axis=1)
-
-
-def _combine_fwd(y, tok, slot):
-    return _combine(y, tok, slot), (tok, slot)
-
-
-def _combine_bwd(res, g):
-    tok, slot = res
-    return _dispatch(g, tok, slot), None, None
-
-
-_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def _live_rows(a, sizes):
@@ -471,9 +439,10 @@ def grouped_dot(x, w, sizes):
     to a kernel that visits the groups' tiles only: rows past their end
     are not written, in the product and in its transpose alike, and hold
     whatever the buffer held (the CPU's reference lowering writes zeros,
-    so only the chip shows it). A dropless layer's sorted buffer is mostly
-    such rows — the assignments of absent experts — and their gradient
-    rows are summed into the tokens' (``_dispatch``'s transpose)."""
+    so only the chip shows it). A window of a dropless layer's sorted
+    assignments ends in such rows (the last window's tail: half a window
+    at the expected load); zeroed, they are harmless to whatever reads a
+    whole window (the weights' product, the gradient's casts and sums)."""
     return _live_rows(jax.lax.ragged_dot(
         x, w, sizes, preferred_element_type=jnp.float32), sizes)
 
@@ -531,36 +500,219 @@ def _assignments(sel, experts: int):
 ROUTERS = ("sigmoid_bias", "softmax")
 
 
-def held_experts_sum(x, sel, w, gate, up, down, lo: int, dtype):
-    """Σ over a token's chosen experts that lie in [lo, lo + E_held) of
-    weight × SwiGLU expert, for one chunk of tokens. x (n, d); sel, w
-    (n, k); gate/up (E_held, d, m); down (E_held, m, d).
+def window_rows(n: int, k: int, held: int, published: int) -> int:
+    """Rows of a chunk's sorted buffer one window holds: the multiple of 128
+    rows (a tile of the matmul unit) at or above twice the load a balanced
+    router sends here, ``n·k·held/published``, and at most all ``n·k``. So
+    a chunk takes a second window only at twice its expected load, and a
+    layer that holds half or more of the published experts walks its whole
+    buffer as one window. From the shapes alone: there is nothing to set."""
+    expected = -(-n * k * held // published)
+    return min(n * k, -(-2 * expected // 128) * 128)
 
-    Dropless: the sorted buffer holds every assignment (n·k rows: all of
-    them may land here), so no imbalance can overflow it; the held
-    experts' assignments come first, grouped by expert, and the rows past
-    their end belong to absent experts and read zero (``grouped_dot``).
-    One buffer size whatever the load: a smaller buffer for the common
-    case behind a ``lax.cond`` was measured (PR 33: −5% of the step) and
-    taken out, because which chunks overflowed it depended on the seed and
-    the step's time with them (runs 1.4% apart where the bound holds half
-    of 1.5%)."""
+
+def _plan(sel, lo: int, e_held: int, rows: int):
+    """One chunk's bookkeeping, all of it ``(n·k,)`` int32 work.
+
+    ``order``: the assignments sorted by held expert, absent ones last,
+    padded to whole windows of ``rows``; ``ends`` the held groups' running
+    ends.
+    For the sum back to tokens (``_sum_to_tokens``) the tokens are sorted
+    by how many live assignments they have, fullest first: ``home`` is a
+    token's place in that order, ``live_slot`` each token's live slots
+    moved to the front of its row (the rest point past every window), the
+    rows in that order, and ``passes`` (k + 1,) the tiles of
+    ``_token_tile`` tokens that hold a j-th live slot, summed over the
+    passes before j."""
     n, k = sel.shape
-    e_held = gate.shape[0]
     local = sel - lo
     held = jnp.logical_and(local >= 0, local < e_held)
-    local = jnp.where(held, local, e_held).reshape(-1)  # absent: sorts last
-    order = jnp.argsort(local, stable=True).astype(jnp.int32)
-    slot = jnp.zeros_like(order).at[order].set(
-        jnp.arange(n * k, dtype=jnp.int32)).reshape(n, k)
-    sizes = jnp.zeros((e_held + 1,), jnp.int32).at[local].add(1)[:e_held]
-    ws = jnp.where(held, w, 0.0).reshape(-1)[order]
-    tok = order // k
-    xs = _dispatch(x.astype(dtype), tok, slot)
-    h = jax.nn.silu(grouped_dot(xs, gate.astype(dtype), sizes)) \
-        * grouped_dot(xs, up.astype(dtype), sizes)
-    y = grouped_dot(h.astype(dtype), down.astype(dtype), sizes)
-    return _combine(y * ws[:, None], tok, slot)
+    key = jnp.where(held, local, e_held).reshape(-1)  # absent: sorts last
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    # the inverse by a second sort: a scatter of n·k scalars costs the chip
+    # eight sorts of them (PR 33's trace: 2.43 against 0.30 ms a step)
+    slot = jnp.argsort(order).astype(jnp.int32).reshape(n, k)
+    sizes = jnp.sum(key[:, None] == jnp.arange(e_held), axis=0,
+                    dtype=jnp.int32)
+    padded = -(-n * k // rows) * rows
+    live = jnp.sum(held, axis=1, dtype=jnp.int32)
+    by_live = jnp.argsort(-live, stable=True).astype(jnp.int32)
+    tiles = -(-jnp.sum(live[:, None] > jnp.arange(k), axis=0,
+                       dtype=jnp.int32) // _token_tile(n))
+    return {"order": jnp.pad(order, (0, padded - n * k)),
+            "ends": jnp.cumsum(sizes),
+            "home": jnp.argsort(by_live).astype(jnp.int32),
+            "live_slot": jnp.sort(jnp.where(held, slot, 2 * padded),
+                                  axis=1)[by_live],
+            "passes": _before(tiles)}
+
+
+def _before(counts):
+    """(len + 1,) int32: the counts summed before each entry, then all."""
+    return jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(counts)])
+
+
+def _holder(before, i):
+    """The entry whose share of ``_before``'s running count holds ``i``."""
+    return jnp.sum(before[1:] <= i, dtype=jnp.int32)
+
+
+def _token_tile(n: int) -> int:
+    """Tokens a step of ``_sum_to_tokens`` adds to."""
+    return math.gcd(n, 512)
+
+
+class _Window(NamedTuple):
+    """What a step of the walk reads of its window: the chunk, the first row
+    in the chunk's sorted buffer, the tokens' rows in ``x``, the
+    assignments' places in ``w`` and the held groups' sizes inside the
+    window."""
+    chunk: jax.Array
+    start: jax.Array
+    tokens: jax.Array
+    places: jax.Array
+    sizes: jax.Array
+
+
+def _window(plan, first, i, rows: int) -> _Window:
+    n, k = plan["live_slot"].shape[1:]
+    chunk = _holder(first, i)
+    start = (i - first[chunk]) * rows
+    order = jax.lax.dynamic_slice(plan["order"], (chunk, start),
+                                  (1, rows))[0]
+    ends = jnp.clip(plan["ends"][chunk], start, start + rows) - start
+    return _Window(chunk, start, chunk * n + order // k,
+                   chunk * n * k + order, jnp.diff(ends, prepend=0))
+
+
+def _sum_to_tokens(acc, buf, plan, win: _Window):
+    """``acc`` (N, d), a chunk's tokens fullest first (``_plan``), with the
+    window's rows ``buf`` (rows, d) added to their tokens' rows. Pass j
+    gathers the j-th live row of the tokens that have one: fullest first,
+    those are the first tokens of the chunk, taken a tile at a time, so a
+    pass costs by the tokens it serves (most hold one or two of their
+    choices here, a few six) and the rows gathered are the live rows, up to
+    a tile a pass."""
+    n, k = plan["live_slot"].shape[1:]
+    tile, rows = _token_tile(n), buf.shape[0]
+    live_slot = plan["live_slot"][win.chunk] - win.start
+    passes = plan["passes"][win.chunk]
+
+    def one(i, acc):
+        j = _holder(passes, i)
+        first = (i - passes[j]) * tile
+        at = jax.lax.dynamic_slice(live_slot, (first, j), (tile, 1))[:, 0]
+        inside = jnp.logical_and(at >= 0, at < rows)[:, None]
+        part = jax.lax.dynamic_slice_in_dim(acc, win.chunk * n + first, tile)
+        part = part + jnp.where(inside, buf[jnp.clip(at, 0, rows - 1)], 0.0)
+        return jax.lax.dynamic_update_slice_in_dim(
+            acc, part, win.chunk * n + first, 0)
+    return jax.lax.fori_loop(0, passes[-1], one, acc)
+
+
+def _token_order(acc, plan):
+    """``_sum_to_tokens``' rows back in the tokens' own order."""
+    chunks, n = plan["home"].shape
+    return acc[(jnp.arange(chunks)[:, None] * n + plan["home"]).reshape(-1)]
+
+
+def _window_sum(dtype, xs, ws, gate, up, down, sizes):
+    """Weight × SwiGLU expert for a window's rows, sorted by expert: rows
+    past the groups' end read zero, here and in the gradient
+    (``grouped_dot``)."""
+    h = jax.nn.silu(grouped_dot(xs, gate, sizes)) * grouped_dot(xs, up, sizes)
+    return grouped_dot(h.astype(dtype), down, sizes) * ws[:, None]
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _walk(rows: int, dtype, x, w, gate, up, down, plan, first):
+    """The routed sum of ``held_experts_sum`` for all chunks, one window of
+    ``rows`` sorted assignments at a time; ``first`` (chunks + 1,) counts
+    the windows before each chunk, its last entry all of them. Reverse mode
+    does not go through a loop of a traced length, so the gradient is a
+    walk of its own: it keeps the inputs alone and makes each window's
+    rows again."""
+    return _walk_fwd(rows, dtype, x, w, gate, up, down, plan, first)[0]
+
+
+def _operands(dtype, x, w, gate, up, down):
+    """What every window of a walk reads: the tokens and the kernels in the
+    products' precision, the weights flat."""
+    return (x.astype(dtype), w.reshape(-1),
+            [a.astype(dtype) for a in (gate, up, down)])
+
+
+def _walk_fwd(rows, dtype, x, w, gate, up, down, plan, first):
+    xb, flat_w, kernels = _operands(dtype, x, w, gate, up, down)
+
+    def one(i, out):
+        win = _window(plan, first, i, rows)
+        y = _window_sum(dtype, xb[win.tokens], flat_w[win.places], *kernels,
+                        win.sizes)
+        return _sum_to_tokens(out, y, plan, win)
+    out = jax.lax.fori_loop(0, first[-1], one,
+                            jnp.zeros(x.shape, jnp.float32))
+    return _token_order(out, plan), (x, w, gate, up, down, plan, first)
+
+
+def _walk_bwd(rows, dtype, res, g):
+    x, w, gate, up, down, plan, first = res
+    xb, flat_w, kernels = _operands(dtype, x, w, gate, up, down)
+    g = g.astype(jnp.float32)
+
+    def one(i, carry):
+        dx, dw, dkernels = carry
+        win = _window(plan, first, i, rows)
+        _, vjp = jax.vjp(partial(_window_sum, dtype, sizes=win.sizes),
+                         xb[win.tokens], flat_w[win.places], *kernels)
+        dxs, dws, *dk = vjp(g[win.tokens])
+        # a window's rows of scalars to their (token, choice): the one
+        # scatter of the walk, and added, because the rows that pad a
+        # chunk's last window all name the chunk's first assignment
+        return (_sum_to_tokens(dx, dxs.astype(jnp.float32), plan, win),
+                dw.at[win.places].add(dws),
+                [a + b.astype(jnp.float32) for a, b in zip(dkernels, dk)])
+    zeros = lambda a: jnp.zeros(a.shape, jnp.float32)  # noqa: E731
+    dx, dw, dkernels = jax.lax.fori_loop(
+        0, first[-1], one, (zeros(x), zeros(flat_w),
+                            [zeros(gate), zeros(up), zeros(down)]))
+    return (_token_order(dx, plan).astype(x.dtype),
+            dw.reshape(w.shape).astype(w.dtype),
+            *(a.astype(b.dtype) for a, b in zip(dkernels, (gate, up, down))),
+            None, None)
+
+
+_walk.defvjp(_walk_fwd, _walk_bwd)
+
+
+def held_experts_sum(x, sel, w, gate, up, down, lo: int, published: int,
+                     chunk: int, dtype):
+    """Σ over a token's chosen experts that lie in [lo, lo + E_held) of
+    weight × SwiGLU expert. x (N, d); sel, w (N, k); gate/up (E_held, d,
+    m); down (E_held, m, d); ``published`` the router's width; the tokens
+    are taken ``chunk`` at a time. Returns (the sum (N, d) float32, the
+    windows walked a chunk, a mean over the chunks).
+
+    Dropless: a chunk's n·k assignments are sorted by expert, those of the
+    experts held here first, and the sorted order is walked in windows of
+    ``window_rows`` rows up to the live count L = the held experts'
+    assignments: ``ceil(L / rows)`` windows, all ``n·k`` rows when every
+    assignment lands here, none when none does. Only the ``(n·k,)`` int32
+    bookkeeping (``_plan``) sees the absent experts' assignments; every
+    gather, product, mask and sum of width d or m runs on a window's rows
+    (``_walk``, forward and backward). One path whatever the load, its cost
+    rising a window at a time: a smaller buffer for the common case behind
+    a ``lax.cond`` was measured (PR 33) and taken out, because a chunk that
+    overflowed it paid the full buffer and which chunks did was the
+    seed's."""
+    n_all, k = sel.shape
+    e_held = gate.shape[0]
+    rows = window_rows(chunk, k, e_held, published)
+    plan = jax.vmap(lambda s: _plan(s, lo, e_held, rows))(
+        sel.reshape(n_all // chunk, chunk, k))
+    windows = -(-plan["ends"][:, -1] // rows)
+    out = _walk(rows, dtype, x, w, gate, up, down, plan, _before(windows))
+    return out, jnp.mean(windows.astype(jnp.float32))
 
 
 class SwiGLU(nn.Module):
@@ -588,18 +740,20 @@ class Kernel(nn.Module):
                           (d, self.features))
 
 
-#: tokens to a sorted buffer of assignments (HeldExperts)
+#: tokens whose assignments are sorted together (HeldExperts)
 TOKEN_CHUNK = 4096
 
 
 class HeldExperts(nn.Module):
     """The stacked experts held here and their part of the routed sum
-    (``held_experts_sum``), a chunk of tokens at a time: a chunk's sorted
-    buffer is recomputed in the backward pass, so the buffer's worst case
-    (every assignment of every token lands here) is a chunk's and not the
-    batch's."""
+    (``held_experts_sum``), the tokens sorted a chunk at a time: a
+    window's rows are made again in the backward pass, so what the layer
+    keeps is its inputs, and the worst case of what it holds at once
+    (every assignment of every token lands here) is a window's and not the
+    batch's. ``__call__`` returns (the sum, the windows walked a chunk)."""
     lo: int
     held: int
+    published: int
     hidden: int
     dtype: Any = jnp.bfloat16
 
@@ -611,22 +765,18 @@ class HeldExperts(nn.Module):
         gate = self.param("gate", stack, (self.held, d, self.hidden))
         up = self.param("up", stack, (self.held, d, self.hidden))
         down = self.param("down", stack, (self.held, self.hidden, d))
-        chunk = math.gcd(n, TOKEN_CHUNK)
-
-        @jax.checkpoint
-        def one(args):
-            return held_experts_sum(*args, gate, up, down, self.lo, self.dtype)
-        split = lambda a: a.reshape((n // chunk, chunk) + a.shape[1:])  # noqa: E731
-        if chunk == n:
-            return one((x, sel, w))
-        return jax.lax.map(one, (split(x), split(sel), split(w))).reshape(n, d)
+        return held_experts_sum(x, sel, w, gate, up, down, self.lo,
+                                self.published, math.gcd(n, TOKEN_CHUNK),
+                                self.dtype)
 
 
 class DroplessMoe(nn.Module):
     """Routed experts over the held range + a shared expert (module
     comment above). ``__call__(x (n, d) f32) -> (out (n, d) f32, counts
     (E,))``; ``counts`` are the batch's assignments to each of the PUBLISHED
-    experts (what the router-bias rule reads, train/loop.py)."""
+    experts (what the router-bias rule reads, train/loop.py). ``walked``
+    returns a third value beside them: the windows of sorted assignments
+    the held experts walked, a mean over the chunks."""
     num_experts: int                 # the router's published width
     experts_held: Tuple[int, int]    # [lo, hi) of them live here
     top_k: int
@@ -636,8 +786,11 @@ class DroplessMoe(nn.Module):
     dtype: Any = jnp.bfloat16
     router: str = "sigmoid_bias"     # one of ROUTERS
 
-    @nn.compact
     def __call__(self, x: jax.Array):
+        return self.walked(x)[:2]
+
+    @nn.compact
+    def walked(self, x: jax.Array):
         lo, hi = self.experts_held
         if not 0 <= lo < hi <= self.num_experts:
             raise ValueError(f"experts_held {self.experts_held} is no range "
@@ -655,9 +808,9 @@ class DroplessMoe(nn.Module):
                                   (self.num_experts,), jnp.float32)
                 sel, w, counts = biased_topk_route(
                     x, kernel, bias, self.top_k, self.route_scale)
-        out = HeldExperts(lo, hi - lo, self.hidden, self.dtype,
-                          name="experts")(x, sel, w)
+        out, windows = HeldExperts(lo, hi - lo, self.num_experts, self.hidden,
+                                   self.dtype, name="experts")(x, sel, w)
         if self.shared_hidden:
             out = out + SwiGLU(self.shared_hidden, self.dtype,
                                name="shared")(x.astype(self.dtype))
-        return out.astype(jnp.float32), counts
+        return out.astype(jnp.float32), counts, windows

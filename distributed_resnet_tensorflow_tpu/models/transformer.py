@@ -404,8 +404,10 @@ class DecoderBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array, positions=None):
-        """x (B, T, d) float32 -> (x, counts): counts (E,) of this layer's
-        assignments where it routes, a scalar 0 where it is dense."""
+        """x (B, T, d) float32 -> (x, counts, windows): counts (E,) of this
+        layer's assignments and the windows its held experts walked a chunk
+        (models/moe.held_experts_sum) where it routes, scalars 0 where it
+        is dense."""
         c = self.cfg
         family = FAMILIES[c.name]
         kind = c.layer_types[self.index]
@@ -428,29 +430,34 @@ class DecoderBlock(nn.Module):
             from .moe import SwiGLU
             f = SwiGLU(c.intermediate_size, self.dtype,
                        name="mlp")(m.astype(self.dtype))
-            counts = jnp.zeros((), jnp.float32)
+            counts = windows = jnp.zeros((), jnp.float32)
         else:
             from .moe import DroplessMoe
             b, t, d = m.shape
-            f, counts = DroplessMoe(
-                c.num_experts, tuple(c.experts_held),
-                c.num_experts_per_tok, c.moe_intermediate_size,
-                c.moe_intermediate_size * c.num_shared_experts,
-                c.route_scale, self.dtype, family.router,
-                name="moe")(m.reshape(b * t, d))
+            # a method other than __call__ is profiled as "moe.walked":
+            # the scope the traces are read by is named here
+            with jax.named_scope("moe"):
+                f, counts, windows = DroplessMoe(
+                    c.num_experts, tuple(c.experts_held),
+                    c.num_experts_per_tok, c.moe_intermediate_size,
+                    c.moe_intermediate_size * c.num_shared_experts,
+                    c.route_scale, self.dtype, family.router,
+                    name="moe").walked(m.reshape(b * t, d))
             f = f.reshape(b, t, d)
-        return x + joins(f, "post_mlp_norm"), counts
+        return x + joins(f, "post_mlp_norm"), counts, windows
 
 
 class CausalDecoder(nn.Module):
     """tokens (B, T) int32 -> logits (B, T, V) float32; with ``targets``
-    (B, T') the training outputs instead: ``{"loss", "correct", "counts"}``,
+    (B, T') the training outputs instead: ``{"loss", "correct", "counts",
+    "windows"}``,
     the mean cross-entropy of the first T' positions against them and the
     share of positions whose largest logit is the target (``weights``
     (B, T'): Σ w · nll over B·T', and the share over the positions of
     weight > 0), computed a chunk of positions at a time (the logits of a
     whole batch never exist, and positions past T' never meet the head),
-    and per routing layer the counts of assignments (``layer<i>`` -> (E,)).
+    and per routing layer the counts of assignments (``layer<i>`` -> (E,))
+    and the windows its held experts walked a chunk (a list of scalars).
     ``positions`` (T,) are the tokens' rotary ids where they are not 0..T-1,
     ``mask`` an ``ops.attention.Mask`` in the place of the layers' causal
     ones."""
@@ -479,12 +486,14 @@ class CausalDecoder(nn.Module):
             from ..ops.pallas.flash_attention import SAVEABLE
             block = nn.remat(DecoderBlock, policy=jax.checkpoint_policies
                              .save_only_these_names(*SAVEABLE))
-        counts = {}
+        counts, windows = {}, []
         for i in range(len(c.layer_types)):
-            x, got = block(c, i, self.dtype, self.attention_impl, self.mesh,
-                           mask, name=f"layer{i}")(x, positions)
+            x, got, walked = block(c, i, self.dtype, self.attention_impl,
+                                   self.mesh, mask,
+                                   name=f"layer{i}")(x, positions)
             if i >= c.num_dense_layers:
                 counts[f"layer{i}"] = got
+                windows.append(walked)
         x = RMSNorm(c.rms_norm_eps, name="final_norm")(x)
         from .moe import Kernel
         head = Kernel(v, name="lm_head")(d)
@@ -494,7 +503,8 @@ class CausalDecoder(nn.Module):
                                preferred_element_type=jnp.float32)
             loss, correct = chunked_next_token_loss(
                 x[:, :targets.shape[1]], head, targets, self.dtype, weights)
-        return {"loss": loss, "correct": correct, "counts": counts}
+        return {"loss": loss, "correct": correct, "counts": counts,
+                "windows": windows}
 
     def objective(self):
         """The family's loss over its own batch (train/loop.py)."""
@@ -532,7 +542,7 @@ class NextTokenObjective:
         out = apply_fn({"params": variables["params"]}, tokens[:, :-1],
                        train=True, targets=tokens[:, 1:])
         metrics = {"precision": out["correct"],
-                   **_held_load(self.cfg, out["counts"])}
+                   **_held_load(self.cfg, out)}
         return (out["loss"], metrics, variables["batch_stats"], [],
                 out["counts"])
 
@@ -546,9 +556,13 @@ class NextTokenObjective:
         return params
 
 
-def _held_load(cfg, counts) -> dict:
+def _held_load(cfg, out) -> dict:
     """The step's assignments to the experts held here, a mean over the
-    routing layers, and the fullest held expert against its layer's mean."""
+    routing layers, the fullest held expert against its layer's mean, and
+    the windows of sorted assignments walked a chunk, a mean over chunks
+    and layers (1 at up to twice the expected load, models/moe.window_rows:
+    the expert layer's time rises with it a window at a time)."""
+    counts = out["counts"]
     if not counts:
         return {}
     lo, hi = cfg.experts_held
@@ -558,7 +572,8 @@ def _held_load(cfg, counts) -> dict:
     return {"moe_assignments_held": jnp.mean(per_layer),
             "moe_load_max_over_mean": jnp.max(
                 jnp.max(held, axis=-1) * (hi - lo)
-                / jnp.maximum(per_layer, 1.0))}
+                / jnp.maximum(per_layer, 1.0)),
+            "moe_windows": jnp.mean(jnp.stack(out["windows"]))}
 
 
 class BlockDiffusionObjective:
@@ -600,7 +615,7 @@ class BlockDiffusionObjective:
         metrics = {"precision": out["correct"],
                    "masked_share": jnp.mean(masked.astype(jnp.float32)),
                    "loss_weight_mean": jnp.mean(weights),
-                   **_held_load(c, out["counts"])}
+                   **_held_load(c, out)}
         return out["loss"], metrics, variables["batch_stats"], [], None
 
 

@@ -191,7 +191,8 @@ class LoggingHook(_CadenceHook):
         return {k: metrics[k] for k in (
             "loss", "cross_entropy", "precision", "learning_rate",
             # a routing model's load (models/transformer.NextTokenObjective)
-            "moe_assignments_held", "moe_load_max_over_mean") if k in metrics}
+            "moe_assignments_held", "moe_load_max_over_mean",
+            "moe_windows") if k in metrics}
 
     def _emit(self, step, metrics, values):
         # stamped with the values on the host: the device has finished

@@ -292,6 +292,145 @@ def test_dropless_under_a_router_forced_onto_one_expert(chunk, monkeypatch):
     assert float(counts[5]) == 96
 
 
+# -- the windowed walk of the sorted assignments (models/moe.held_experts_sum) --
+
+@pytest.mark.parametrize("n,k,held,published,rows", [
+    (4096, 8, 16, 128, 8192),    # the cells: a quarter of the buffer
+    (4096, 8, 128, 128, 32768),  # every published expert held: the buffer
+    (96, 4, 2, 16, 128),         # twice 48, up to a tile of 128 rows
+    (96, 4, 16, 16, 384)])
+def test_the_window_is_the_shapes_and_nothing_else(n, k, held, published, rows,
+                                                   monkeypatch):
+    """Twice the expected load in whole tiles, at most the buffer; no
+    argument beside the four shapes, no module attribute, no environment."""
+    import inspect
+    assert moe.window_rows(n, k, held, published) == rows
+    assert list(inspect.signature(moe.window_rows).parameters) == [
+        "n", "k", "held", "published"]
+    assert set(moe.window_rows.__code__.co_names) <= {"min"}
+    monkeypatch.setattr(moe, "TOKEN_CHUNK", 7)
+    monkeypatch.setenv("MOE_WINDOW_ROWS", "1")
+    assert moe.window_rows(n, k, held, published) == rows
+
+
+def _per_token_sum(x, sel, w, gate, up, down, lo):
+    """The held experts' part of the routed sum, a token and a choice at a
+    time, float32: what ``held_experts_sum`` has to give."""
+    def choice(xt, e, wt):
+        here = jnp.logical_and(e >= lo, e < lo + gate.shape[0])
+        at = jnp.clip(e - lo, 0, gate.shape[0] - 1)
+        h = jax.nn.silu(xt @ gate[at]) * (xt @ up[at])
+        return jnp.where(here, wt, 0.0) * (h @ down[at])
+    token = lambda xt, es, ws: jnp.sum(jax.vmap(  # noqa: E731
+        lambda e, wt: choice(xt, e, wt))(es, ws), axis=0)
+    return jax.vmap(token)(x, sel, w)
+
+
+def _choices(n, k, held, published, lo, live, seed):
+    """(n, k) distinct choices a token of which exactly ``live`` in all fall
+    on the held range [lo, lo + held)."""
+    rng = np.random.default_rng(seed)
+    here = np.arange(lo, lo + held)
+    absent = np.setdiff1d(np.arange(published), here)
+    sel = np.empty((n, k), np.int32)
+    for t in range(n):
+        c = live // n + (t < live % n)
+        row = np.concatenate([rng.choice(here, c, replace=False),
+                              rng.choice(absent, k - c, replace=False)])
+        sel[t] = rng.permutation(row)
+    return jnp.asarray(sel)
+
+
+WALKS = {  # (n, k, held, published, lo), live assignments: rows are 128 but in the last
+    "none": ((96, 4, 4, 32, 8), 0),
+    "the_expected_eighth": ((96, 4, 4, 32, 8), 48),
+    "exactly_a_window": ((96, 4, 4, 32, 8), 128),
+    "a_window_and_a_row": ((96, 4, 4, 32, 8), 129),
+    "every_assignment": ((96, 4, 4, 32, 8), 384),
+    "all_held_one_window": ((96, 4, 16, 16, 0), 384)}
+
+
+@pytest.mark.parametrize("case", list(WALKS))
+def test_the_windowed_sum_is_the_per_token_sum(case):
+    """Value, dx, dw and the three kernels' gradients against the plain
+    per-token sum at held loads of nothing, the expected share, exactly a
+    window, a row more (two windows) and everything (every window); the
+    windows walked are ceil(live / rows), none at no load."""
+    (n, k, held, published, lo), live = WALKS[case]
+    rows = moe.window_rows(n, k, held, published)
+    assert rows == (384 if case == "all_held_one_window" else 128)
+    keys = jax.random.split(jax.random.PRNGKey(live), 6)
+    sel = _choices(n, k, held, published, lo, live, seed=live)
+    assert int(jnp.sum((sel >= lo) & (sel < lo + held))) == live
+    x = jax.random.normal(keys[0], (n, 24))
+    w = jax.random.uniform(keys[1], (n, k)) + 0.1
+    gate, up = (jax.random.normal(key, (held, 24, 12)) / 5 for key in keys[2:4])
+    down = jax.random.normal(keys[4], (held, 12, 24)) / 3
+    r = jax.random.normal(keys[5], (n, 24))
+
+    def walked(*leaves):
+        return moe.held_experts_sum(leaves[0], sel, *leaves[1:], lo, published, n,
+                                    jnp.float32)
+    got, windows = jax.jit(walked)(x, w, gate, up, down)
+    np.testing.assert_allclose(got, _per_token_sum(x, sel, w, gate, up, down, lo),
+                               atol=2e-5)
+    assert float(windows) == -(-live // rows)
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(walked(*a)[0] * r),
+                           argnums=(0, 1, 2, 3, 4)))(x, w, gate, up, down)
+    want = jax.grad(lambda *a: jnp.sum(_per_token_sum(a[0], sel, *a[1:], lo) * r),
+                    argnums=(0, 1, 2, 3, 4))(x, w, gate, up, down)
+    for name, a, b in zip(("dx", "dw", "gate", "up", "down"), got, want):
+        np.testing.assert_allclose(a, b, atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("chunk", [32, 96])
+def test_the_windows_walked_are_a_mean_over_the_chunks(chunk, monkeypatch):
+    """``walked`` returns, beside what ``__call__`` returns, the windows the
+    held experts walked: ceil(a chunk's live assignments / rows), a mean
+    over the chunks. Expert 5 is forced on every token, so every chunk
+    holds more than its expected share."""
+    params = _moe_params(jax.random.PRNGKey(3))
+    params["router_bias"] = jnp.zeros((16,)).at[5].set(10.0)
+    x = jax.random.normal(jax.random.PRNGKey(4), (96, 64))
+    monkeypatch.setattr(moe, "TOKEN_CHUNK", chunk)
+    layer, share = _moe_layer((4, 8)), {"params": _share(params, 4, 8)}
+    out, counts, walked = layer.apply(share, x, method="walked")
+    sel, _, _ = moe.biased_topk_route(x, params["router"]["kernel"],
+                                      params["router_bias"], 4, 2.826)
+    live = np.sum((np.asarray(sel) >= 4) & (np.asarray(sel) < 8),
+                  axis=1).reshape(96 // chunk, chunk).sum(axis=1)
+    rows = moe.window_rows(chunk, 4, 4, 16)
+    assert rows == {32: 128, 96: 256}[chunk] and live.min() >= chunk
+    assert float(walked) == np.mean(-(-live // rows))
+    again, same = layer.apply(share, x)
+    np.testing.assert_array_equal(out, again)
+    np.testing.assert_array_equal(counts, same)
+
+
+def test_the_expert_layers_operations_carry_the_scopes_the_traces_are_read_by():
+    """``moe_ms`` and the experts' rooflines sum the device time of the
+    operations whose scope path holds ``moe`` (and ``experts``) as
+    components (benchmark/flops/afmoe.scope_seconds): the walk's loops,
+    forward and backward, have to sit under them, and the router under
+    ``moe`` and ``route``."""
+    import re
+
+    from benchmark.flops import afmoe
+    trainer = tiny_trainer()
+    tokens = jnp.asarray(batches(1)[0]["tokens"])
+
+    def loss(params):
+        return trainer.model.apply({"params": params}, tokens[:, :-1], train=True,
+                                   targets=tokens[:, 1:])["loss"]
+    text = jax.jit(jax.grad(loss)).lower(trainer.state.params).as_text(debug_info=True)
+    paths = {name.rsplit("/", 1)[0] for name in re.findall(r'loc\("([^"]+)"', text)}
+    walk = [p for p in paths if "while/body" in p and "experts" in p]
+    assert any("transpose(" in p for p in walk) and any("transpose(" not in p for p in walk)
+    for path in walk:
+        assert afmoe.scope_seconds({"scope_s": {path: 1.0}}, "moe", "experts") == 1.0, path
+    assert afmoe.scope_seconds({"scope_s": dict.fromkeys(paths, 1.0)}, "moe", "route")
+
+
 def test_the_decoder_trains_through_main(tmp_path):
     """``main.py train`` with the preset, cut to a CPU's size by --set."""
     from distributed_resnet_tensorflow_tpu import main as cli

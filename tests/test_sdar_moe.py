@@ -435,6 +435,35 @@ def test_the_shares_add_up():
     np.testing.assert_allclose(whole, want, atol=2e-5)
 
 
+@pytest.mark.parametrize("held,rows", [((0, 2), 128), ((4, 12), 384), ((0, 16), 384)])
+def test_the_step_reports_the_windows_walked(held, rows):
+    """``moe_windows`` among the step's metrics is the layers' mean of the
+    windows their held experts walked a chunk: from a share of an eighth
+    (rows 128 of a chunk's 384) to a half and the whole (the buffer, one
+    window whatever the load)."""
+    from distributed_resnet_tensorflow_tpu.models import moe
+    from distributed_resnet_tensorflow_tpu.models.transformer import _held_load
+    assert moe.window_rows(96, 4, held[1] - held[0], 16) == rows
+    layer = _moe_layer(held)
+    x = jax.random.normal(jax.random.PRNGKey(2), (96, 64))
+    params = _moe_layer((0, 16)).init(jax.random.PRNGKey(0), jnp.zeros((8, 64)))["params"]
+    share = dict(params, experts={n: v[held[0]:held[1]]
+                                  for n, v in params["experts"].items()})
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(moe, "TOKEN_CHUNK", 96)
+        _, counts, walked = layer.apply({"params": share}, x, method="walked")
+    live = float(jnp.sum(counts[held[0]:held[1]]))
+    assert float(walked) == -(-live // rows)
+
+    class Cfg:
+        experts_held = held
+    got = _held_load(Cfg, {"counts": {"layer0": counts, "layer1": counts},
+                           "windows": [walked, walked + 1.0]})
+    assert float(got["moe_windows"]) == float(walked) + 0.5
+    assert float(got["moe_assignments_held"]) == live
+    assert _held_load(Cfg, {"counts": {}, "windows": []}) == {}
+
+
 def test_the_start_gives_each_chips_range_one_choice_a_token(weights):
     """The reference's initialiser lays a router's columns in periods of
     experts / experts per token: whatever the token, its choices fall one
