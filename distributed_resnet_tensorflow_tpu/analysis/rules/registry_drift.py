@@ -1,6 +1,7 @@
-"""event-registry + span-catalog + config-knob: names must resolve.
+"""event-registry + span-catalog + scope-catalog + config-knob: names must
+resolve.
 
-Three drift checks against the project's declared registries:
+Four drift checks against the project's declared registries:
 
   * every ``write_event("<name>", ...)`` literal in code and every
     ``{"event": "<name>"}`` mention in docs/scripts must be declared in
@@ -11,6 +12,13 @@ Three drift checks against the project's declared registries:
     declared in ``telemetry.tracer.SPAN_CATALOG`` — trace.json consumers
     and the goodput classifier key on these names, so an unregistered
     span is invisible drift exactly like an unregistered event;
+  * every ``jax.named_scope("<name>")`` literal the package puts on device
+    operations, as a call or as a decorator (and every
+    ``named_scope("<name>")`` mention in docs/scripts), must be declared
+    in ``telemetry.tracer.SCOPE_CATALOG`` — the benchmark's per-layer
+    readers and ``benchmark/tools/step_parts.py`` find a trace's device
+    time by these path components, so a renamed scope is a metric that
+    silently reads nothing;
   * every ``--set a.b.c=`` knob referenced in code, scripts or docs must
     resolve against the ``utils.config.ExperimentConfig`` dataclasses —
     the knob a README advertises must actually exist (``cfg.override``
@@ -43,6 +51,8 @@ _KNOB_RE = re.compile(
 _DOC_EVENT_RE = re.compile(r'"event"\s*:\s*"(\w+)"')
 # span-name mentions in docs/scripts: span("input.wait") / ``span("x.y")``
 _DOC_SPAN_RE = re.compile(r'span\(\s*"([\w.]+)"')
+# scope mentions in docs/scripts: named_scope("rotary")
+_DOC_SCOPE_RE = re.compile(r'named_scope\(\s*"([\w.]+)"')
 
 
 def _event_names() -> set:
@@ -53,6 +63,11 @@ def _event_names() -> set:
 def _span_names() -> set:
     from ...telemetry.tracer import SPAN_CATALOG
     return set(SPAN_CATALOG)
+
+
+def _scope_names() -> set:
+    from ...telemetry.tracer import SCOPE_CATALOG
+    return set(SCOPE_CATALOG)
 
 
 def _knob_resolves(dotted: str) -> bool:
@@ -87,11 +102,22 @@ def _is_span_call(node: ast.Call) -> bool:
         isinstance(fn.value, ast.Name) and fn.value.id == "recorder"
 
 
+def _is_named_scope(node: ast.Call) -> bool:
+    """``jax.named_scope("...")`` or a bare ``named_scope("...")``, as a
+    ``with`` item or as a decorator (a decorator is a Call node too)."""
+    fn = node.func
+    if isinstance(fn, ast.Name):
+        return fn.id == "named_scope"
+    return isinstance(fn, ast.Attribute) and fn.attr == "named_scope" and \
+        isinstance(fn.value, ast.Name) and fn.value.id == "jax"
+
+
 def check(ctx) -> Iterable[Finding]:
     events = _event_names()
     spans = _span_names()
+    scopes = _scope_names()
 
-    # (a) write_event + span literals in python
+    # (a) write_event + span + named_scope literals in python
     for sf in ctx.all_python():
         if sf.tree is None:
             continue
@@ -114,8 +140,15 @@ def check(ctx) -> Iterable[Finding]:
                     f"tracer span {arg.value!r} is not declared in "
                     "telemetry.tracer.SPAN_CATALOG — register it there "
                     "first")
+            elif _is_named_scope(node) and arg.value not in scopes:
+                yield Finding(
+                    RULE_NAME, sf.rel, node.lineno,
+                    f"device scope {arg.value!r} is not declared in "
+                    "telemetry.tracer.SCOPE_CATALOG — register it there "
+                    "first (with the metric that reads it)")
 
-    # (b) {"event": "<name>"} and span("<name>") mentions in docs + scripts
+    # (b) {"event": "<name>"}, span("<name>") and named_scope("<name>")
+    # mentions in docs + scripts
     for sf in ctx.docs + ctx.scripts:
         for i, line in enumerate(sf.lines, 1):
             for m in _DOC_EVENT_RE.finditer(line):
@@ -131,6 +164,13 @@ def check(ctx) -> Iterable[Finding]:
                         RULE_NAME, sf.rel, i,
                         f"documented tracer span {m.group(1)!r} does not "
                         "exist in telemetry.tracer.SPAN_CATALOG — stale "
+                        "doc or missing registration")
+            for m in _DOC_SCOPE_RE.finditer(line):
+                if m.group(1) not in scopes:
+                    yield Finding(
+                        RULE_NAME, sf.rel, i,
+                        f"documented device scope {m.group(1)!r} does not "
+                        "exist in telemetry.tracer.SCOPE_CATALOG — stale "
                         "doc or missing registration")
 
     # (c) --set knob references everywhere

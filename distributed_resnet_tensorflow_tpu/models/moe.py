@@ -574,6 +574,7 @@ class _Window(NamedTuple):
     sizes: jax.Array
 
 
+@jax.named_scope("plan")
 def _window(plan, first, i, rows: int) -> _Window:
     n, k = plan["live_slot"].shape[1:]
     chunk = _holder(first, i)
@@ -585,6 +586,7 @@ def _window(plan, first, i, rows: int) -> _Window:
                    chunk * n * k + order, jnp.diff(ends, prepend=0))
 
 
+@jax.named_scope("to_tokens")
 def _sum_to_tokens(acc, buf, plan, win: _Window):
     """``acc`` (N, d), a chunk's tokens fullest first (``_plan``), with the
     window's rows ``buf`` (rows, d) added to their tokens' rows. Pass j
@@ -610,6 +612,7 @@ def _sum_to_tokens(acc, buf, plan, win: _Window):
     return jax.lax.fori_loop(0, passes[-1], one, acc)
 
 
+@jax.named_scope("to_tokens")
 def _token_order(acc, plan):
     """``_sum_to_tokens``' rows back in the tokens' own order."""
     chunks, n = plan["home"].shape
@@ -635,6 +638,7 @@ def _walk(rows: int, dtype, x, w, gate, up, down, plan, first):
     return _walk_fwd(rows, dtype, x, w, gate, up, down, plan, first)[0]
 
 
+@jax.named_scope("operands")
 def _operands(dtype, x, w, gate, up, down):
     """What every window of a walk reads: the tokens and the kernels in the
     products' precision, the weights flat."""
@@ -647,11 +651,14 @@ def _walk_fwd(rows, dtype, x, w, gate, up, down, plan, first):
 
     def one(i, out):
         win = _window(plan, first, i, rows)
-        y = _window_sum(dtype, xb[win.tokens], flat_w[win.places], *kernels,
-                        win.sizes)
+        with jax.named_scope("gather"):
+            xs, ws = xb[win.tokens], flat_w[win.places]
+        with jax.named_scope("products"):
+            y = _window_sum(dtype, xs, ws, *kernels, win.sizes)
         return _sum_to_tokens(out, y, plan, win)
-    out = jax.lax.fori_loop(0, first[-1], one,
-                            jnp.zeros(x.shape, jnp.float32))
+    with jax.named_scope("to_tokens"):
+        out = jnp.zeros(x.shape, jnp.float32)
+    out = jax.lax.fori_loop(0, first[-1], one, out)
     return _token_order(out, plan), (x, w, gate, up, down, plan, first)
 
 
@@ -663,23 +670,41 @@ def _walk_bwd(rows, dtype, res, g):
     def one(i, carry):
         dx, dw, dkernels = carry
         win = _window(plan, first, i, rows)
-        _, vjp = jax.vjp(partial(_window_sum, dtype, sizes=win.sizes),
-                         xb[win.tokens], flat_w[win.places], *kernels)
-        dxs, dws, *dk = vjp(g[win.tokens])
+        # the scopes name the parts in a trace and change no instruction,
+        # so each statement stays where it stood: the cotangent's rows are
+        # gathered after the window's forward, as before they had names
+        with jax.named_scope("gather"):
+            xs, ws = xb[win.tokens], flat_w[win.places]
+        with jax.named_scope("products"):
+            _, vjp = jax.vjp(partial(_window_sum, dtype, sizes=win.sizes),
+                             xs, ws, *kernels)
+        with jax.named_scope("gather"):
+            gs = g[win.tokens]
+        with jax.named_scope("products"):
+            dxs, dws, *dk = vjp(gs)
+            dxs = dxs.astype(jnp.float32)
+        dx = _sum_to_tokens(dx, dxs, plan, win)
         # a window's rows of scalars to their (token, choice): the one
         # scatter of the walk, and added, because the rows that pad a
         # chunk's last window all name the chunk's first assignment
-        return (_sum_to_tokens(dx, dxs.astype(jnp.float32), plan, win),
-                dw.at[win.places].add(dws),
-                [a + b.astype(jnp.float32) for a, b in zip(dkernels, dk)])
+        with jax.named_scope("to_tokens"):
+            dw = dw.at[win.places].add(dws)
+        with jax.named_scope("carry"):
+            dkernels = [a + b.astype(jnp.float32)
+                        for a, b in zip(dkernels, dk)]
+        return dx, dw, dkernels
     zeros = lambda a: jnp.zeros(a.shape, jnp.float32)  # noqa: E731
-    dx, dw, dkernels = jax.lax.fori_loop(
-        0, first[-1], one, (zeros(x), zeros(flat_w),
-                            [zeros(gate), zeros(up), zeros(down)]))
+    with jax.named_scope("to_tokens"):
+        dx, dw = zeros(x), zeros(flat_w)
+    with jax.named_scope("carry"):
+        dkernels = [zeros(gate), zeros(up), zeros(down)]
+    dx, dw, dkernels = jax.lax.fori_loop(0, first[-1], one,
+                                         (dx, dw, dkernels))
+    with jax.named_scope("carry"):
+        dkernels = [a.astype(b.dtype)
+                    for a, b in zip(dkernels, (gate, up, down))]
     return (_token_order(dx, plan).astype(x.dtype),
-            dw.reshape(w.shape).astype(w.dtype),
-            *(a.astype(b.dtype) for a, b in zip(dkernels, (gate, up, down))),
-            None, None)
+            dw.reshape(w.shape).astype(w.dtype), *dkernels, None, None)
 
 
 _walk.defvjp(_walk_fwd, _walk_bwd)
@@ -708,10 +733,12 @@ def held_experts_sum(x, sel, w, gate, up, down, lo: int, published: int,
     n_all, k = sel.shape
     e_held = gate.shape[0]
     rows = window_rows(chunk, k, e_held, published)
-    plan = jax.vmap(lambda s: _plan(s, lo, e_held, rows))(
-        sel.reshape(n_all // chunk, chunk, k))
-    windows = -(-plan["ends"][:, -1] // rows)
-    out = _walk(rows, dtype, x, w, gate, up, down, plan, _before(windows))
+    with jax.named_scope("plan"):
+        plan = jax.vmap(lambda s: _plan(s, lo, e_held, rows))(
+            sel.reshape(n_all // chunk, chunk, k))
+        windows = -(-plan["ends"][:, -1] // rows)
+        first = _before(windows)
+    out = _walk(rows, dtype, x, w, gate, up, down, plan, first)
     return out, jnp.mean(windows.astype(jnp.float32))
 
 

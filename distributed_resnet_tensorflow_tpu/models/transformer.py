@@ -382,12 +382,16 @@ class GroupedAttention(nn.Module):
         sliding = self.kind == "sliding_attention"
         # afmoe's full layers carry no positional term at all
         if sliding or self.rope_all_layers:
-            q = rotary(q, self.rope_theta, positions)
-            k = rotary(k, self.rope_theta, positions)
+            with jax.named_scope("rotary"):
+                q = rotary(q, self.rope_theta, positions)
+                k = rotary(k, self.rope_theta, positions)
         from ..ops.attention import Mask
         mask = self.mask or Mask("causal", self.window if sliding else None)
-        o = causal_attention(q.astype(self.dtype), k.astype(self.dtype), v,
-                             mask, self.attention_impl, self.mesh)
+        # the kernels (or their twin) with their casts, reshapes and block
+        # tables: `attention` without `core` is what the kernels do not do
+        with jax.named_scope("core"):
+            o = causal_attention(q.astype(self.dtype), k.astype(self.dtype),
+                                 v, mask, self.attention_impl, self.mesh)
         o = o.reshape(b, t, h * hd)
         if self.gated:
             o = o * nn.sigmoid(dense(h * hd, name="gate_proj")(a))

@@ -10,7 +10,8 @@ Three pillars (docs/observability.md):
 """
 from .goodput import CATEGORIES, GoodputMeter, goodput  # noqa: F401
 from .tracer import (  # noqa: F401
-    SPAN_CATALOG, SPAN_SCHEMA_VERSION, FlightRecorder, recorder, span)
+    SCOPE_CATALOG, SPAN_CATALOG, SPAN_SCHEMA_VERSION, FlightRecorder,
+    recorder, span)
 
 
 def configure_from_config(cfg, writer=None, process_index: int = 0) -> None:

@@ -50,7 +50,10 @@ contract as ``utils.metrics.EVENT_SCHEMAS``: the registry-drift lint rule
 (analysis/rules/registry_drift.py) resolves every ``span("<name>")``
 literal against the catalog, and unknown names warn once at runtime
 (observability must never kill a run). docs/observability.md is the
-operator-facing catalog.
+operator-facing catalog. The names the program puts on DEVICE operations
+(``jax.named_scope``) are registered beside it, in :data:`SCOPE_CATALOG`,
+under the same rule; they are trace-time metadata and nothing here runs
+for them.
 """
 from __future__ import annotations
 
@@ -61,7 +64,7 @@ import os
 import sys
 import threading
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, NamedTuple, Optional
 
 from ..utils.metrics import input_stages
 
@@ -226,6 +229,139 @@ SPAN_CATALOG = {
                     "model (preset/layout args; main.py plan and the "
                     "plan-drift gate phase)",
 }
+
+class Scope(NamedTuple):
+    """One component of a device operation's scope path (the ``tf_op`` stat
+    of a TPU trace, ``loc("...")`` in lowered text)."""
+    origin: str   # "scope": a jax.named_scope of ours; "module": a flax
+    #               module's name that a reader keys on; "jax": a
+    #               component JAX's own transforms add
+    under: str    # the registered components it always lies below
+    where: str    # the emit site
+    holds: str    # the operations under it
+    read_by: str  # the benchmark metric (or tool) that reads it
+
+
+#: every component of a device operation's scope path that something reads
+#: — register HERE first. The device side of SPAN_CATALOG, and the same
+#: contract: the registry-drift rule resolves every
+#: ``jax.named_scope("<literal>")`` (call and decorator) in the package and
+#: every ``named_scope("<name>")`` docs/observability.md mentions against
+#: it. A scope is trace-time metadata: nothing checks it at run time, and a
+#: program fetched from the compile cache shows the scopes of the commit
+#: that compiled it. A reader matches a component bare or wrapped by the
+#: backward pass's transforms (``transpose(jvp(attention))``); no name here
+#: may be a flax module's name in the decoder's paths but the two listed
+#: as such. ``step_parts.py`` is benchmark/tools/step_parts.py, which
+#: prints a kept trace by these rows.
+SCOPE_CATALOG = {
+    # the step program (train/loop.py, train/state.py)
+    "forward": Scope(
+        "scope", "", "train/loop.make_train_step: loss_fn",
+        "model apply, loss and in-loss decay; transpose(jvp(forward)) is "
+        "the backward pass",
+        "step_parts.py (the forward, recomputed and backward columns)"),
+    "optimizer": Scope(
+        "scope", "", "train/state.TrainState.apply_gradients; "
+        "train/loop.py (the ZeRO-1 update)",
+        "the weight update; reads near nothing where XLA fuses it into the "
+        "op that makes the gradient", "step_parts.py"),
+    "input_prep": Scope(
+        "scope", "", "train/loop.ClassifierObjective.prepare",
+        "the in-step device augmentation", "step_parts.py"),
+    "after_update": Scope(
+        "scope", "", "train/loop.make_train_step: update",
+        "the family's rule after the optimizer's update (afmoe: the "
+        "router-bias rule)", "step_parts.py"),
+    # the stager's unpack program (parallel/sharding.py)
+    "unpack": Scope(
+        "scope", "", "parallel/sharding._build_unpack",
+        "the staged bytes sliced and bitcast to the batch's leaves",
+        "step_parts.py"),
+    "augment": Scope(
+        "scope", "", "parallel/sharding._build_unpack",
+        "flip, crop and standardise on the staged uint8 crops",
+        "step_parts.py"),
+    # the decoder family (models/transformer.py, models/moe.py)
+    "blockdiff_input": Scope(
+        "scope", "forward",
+        "models/transformer.BlockDiffusionObjective.forward",
+        "masked ids replaced, the noisy and the clean copy joined, "
+        "position ids and 1/t weights", "step_parts.py"),
+    "attention": Scope(
+        "scope", "forward", "models/transformer.DecoderBlock",
+        "input norm, projections, head norms, rotary, the kernels, gate, "
+        "output projection, post-norm and the residual add",
+        "attention_ms, attention_rest_ms"),
+    "rotary": Scope(
+        "scope", "attention", "models/transformer.GroupedAttention",
+        "rotate-half and sin/cos over queries and keys",
+        "step_parts.py (inside attention_rest_ms)"),
+    "core": Scope(
+        "scope", "attention", "models/transformer.GroupedAttention",
+        "the flash kernels or their dense twin with their casts, reshapes "
+        "and block tables", "attention_rest_ms (attention without it)"),
+    "moe": Scope(
+        "scope", "forward", "models/transformer.DecoderBlock",
+        "route, experts and shared; the grouped products' kernels carry no "
+        "path in a TPU trace and are read by name", "moe_ms"),
+    "route": Scope(
+        "scope", "moe", "models/moe.DroplessMoe.walked",
+        "the float32 router product, scores, top-k and counts",
+        "step_parts.py (inside moe_ms)"),
+    "experts": Scope(
+        "module", "moe", "models/moe.HeldExperts, named by DroplessMoe",
+        "the held experts' walk: the six parts below; under it and under "
+        "none of them, the loops' own (the compiler's relayouts of the "
+        "kernels every window, which it names after the while)",
+        "moe_experts_roofline, sdar_experts_roofline"),
+    "shared": Scope(
+        "module", "moe", "models/moe.SwiGLU, named by DroplessMoe",
+        "the shared expert's three products",
+        "step_parts.py (inside moe_ms)"),
+    "plan": Scope(
+        "scope", "moe/experts", "models/moe.held_experts_sum, _window",
+        "a chunk's five sorts and a window's int32 bookkeeping",
+        "step_parts.py"),
+    "operands": Scope(
+        "scope", "moe/experts", "models/moe._operands",
+        "tokens and kernels cast to the products' precision, once a walk",
+        "step_parts.py"),
+    "gather": Scope(
+        "scope", "moe/experts", "models/moe._walk_fwd, _walk_bwd",
+        "a window's rows of tokens, weights and cotangents gathered",
+        "step_parts.py"),
+    "products": Scope(
+        "scope", "moe/experts", "models/moe._walk_fwd, _walk_bwd",
+        "silu x up, x weights, the live-row masks and casts around the "
+        "grouped products (the ragged-dot kernels are found by name)",
+        "step_parts.py; the kernels by moe_products_ms, "
+        "moe_products_roofline, moe_product_calls"),
+    "to_tokens": Scope(
+        "scope", "moe/experts",
+        "models/moe._sum_to_tokens, _token_order, _walk_fwd, _walk_bwd",
+        "a window's rows summed back to their tokens and written back, "
+        "the tokens' own order, the one scatter of the weights' gradient",
+        "step_parts.py"),
+    "carry": Scope(
+        "scope", "moe/experts", "models/moe._walk_bwd",
+        "the kernels' gradient added into the walk's float32 "
+        "accumulators, once a window", "moe_carry_ms"),
+    "lm_head": Scope(
+        "scope", "forward", "models/transformer.CausalDecoder",
+        "the chunked head and loss", "lm_head_ms"),
+    # JAX's own (jax/_src/ad_checkpoint.py), under nn.remat and
+    # jax.checkpoint: not ours to emit, ours to read
+    "checkpoint": Scope(
+        "jax", "", "nn.remat(DecoderBlock), the chunked head's loss",
+        "everything a rematerialised function's backward pass runs; alone "
+        "it does not tell recomputation", "step_parts.py"),
+    "rematted_computation": Scope(
+        "jax", "checkpoint", "the same, below checkpoint",
+        "the forward operations the backward pass computes again",
+        "recompute_ms"),
+}
+
 
 # unknown span names already warned about (warn once, like write_event)
 _UNKNOWN_SPANS_WARNED: set = set()

@@ -20,6 +20,7 @@ from distributed_resnet_tensorflow_tpu.ops.attention import attention
 from distributed_resnet_tensorflow_tpu.ops.pallas.flash_attention import (
     flash_attention)
 from distributed_resnet_tensorflow_tpu.parallel.mesh import create_mesh
+from distributed_resnet_tensorflow_tpu.telemetry.tracer import SCOPE_CATALOG
 from distributed_resnet_tensorflow_tpu.train.loop import Trainer
 from distributed_resnet_tensorflow_tpu.train.optimizers import _non_bn_mask
 from distributed_resnet_tensorflow_tpu.utils.config import get_preset
@@ -407,28 +408,72 @@ def test_the_windows_walked_are_a_mean_over_the_chunks(chunk, monkeypatch):
     np.testing.assert_array_equal(counts, same)
 
 
-def test_the_expert_layers_operations_carry_the_scopes_the_traces_are_read_by():
+@pytest.fixture(scope="module")
+def gradient_paths():
+    """The scope path of every operation in the lowered gradient of the tiny
+    decoder's loss, taken under ``forward`` as the step takes it."""
+    import re
+    trainer = tiny_trainer()
+    tokens = jnp.asarray(batches(1)[0]["tokens"])
+
+    @jax.named_scope("forward")
+    def loss(params):
+        return trainer.model.apply({"params": params}, tokens[:, :-1], train=True,
+                                   targets=tokens[:, 1:])["loss"]
+    text = jax.jit(jax.grad(loss)).lower(trainer.state.params).as_text(debug_info=True)
+    # an operation's name starts at the jitted function; the other strings
+    # are files and the pieces of a called function's own name
+    return {name.rsplit("/", 1)[0] for name in re.findall(r'loc\("(jit\([^"]+)"', text)
+            if "/" in name}
+
+
+def test_the_expert_layers_operations_carry_the_scopes_the_traces_are_read_by(gradient_paths):
     """``moe_ms`` and the experts' rooflines sum the device time of the
     operations whose scope path holds ``moe`` (and ``experts``) as
     components (benchmark/flops/afmoe.scope_seconds): the walk's loops,
     forward and backward, have to sit under them, and the router under
     ``moe`` and ``route``."""
-    import re
-
     from benchmark.flops import afmoe
-    trainer = tiny_trainer()
-    tokens = jnp.asarray(batches(1)[0]["tokens"])
-
-    def loss(params):
-        return trainer.model.apply({"params": params}, tokens[:, :-1], train=True,
-                                   targets=tokens[:, 1:])["loss"]
-    text = jax.jit(jax.grad(loss)).lower(trainer.state.params).as_text(debug_info=True)
-    paths = {name.rsplit("/", 1)[0] for name in re.findall(r'loc\("([^"]+)"', text)}
+    paths = gradient_paths
     walk = [p for p in paths if "while/body" in p and "experts" in p]
     assert any("transpose(" in p for p in walk) and any("transpose(" not in p for p in walk)
     for path in walk:
         assert afmoe.scope_seconds({"scope_s": {path: 1.0}}, "moe", "experts") == 1.0, path
     assert afmoe.scope_seconds({"scope_s": dict.fromkeys(paths, 1.0)}, "moe", "route")
+
+
+#: the registered scopes that lie inside a decoder block's attention and
+#: expert layer, as the rows ``benchmark/tools/step_parts.py`` prints
+PARTS = sorted(f"{s.under}/{name}" for name, s in SCOPE_CATALOG.items()
+               if s.origin == "scope" and s.under.split("/")[0] in ("attention", "moe"))
+
+
+@pytest.mark.parametrize("row", PARTS)
+def test_the_lowered_gradient_holds_every_registered_part(gradient_paths, row):
+    """Each part of attention and of the walk names operations of the first
+    forward pass, of the recomputation (``rematted_computation``) and of the
+    backward pass (``transpose(``), so that a reader by scope and
+    ``step_parts.py`` find its time in a trace; the kernels' gradient carry
+    is the backward walk's alone."""
+    from benchmark.tools import step_parts
+    passes = {step_parts.which_pass(p) for p in gradient_paths
+              if step_parts.row_of(p, SCOPE_CATALOG) == row}
+    want = {"backward"} if row.endswith("/carry") else {"forward", "recomputed", "backward"}
+    assert passes == want, (row, passes)
+
+
+def test_no_registered_scope_is_a_flax_modules_name():
+    """A reader matches path components by name: a ``jax.named_scope`` that
+    took a module's name (``gate``, ``router``) would read that module in.
+    ``moe`` and ``lm_head`` name a scope and the module it is wrapped
+    around: one thing twice."""
+    trainer = tiny_trainer()
+    modules = {str(getattr(k, "key", k)) for path, _ in
+               jax.tree_util.tree_flatten_with_path(trainer.state.params)[0] for k in path}
+    ours = {name for name, s in SCOPE_CATALOG.items() if s.origin == "scope"}
+    assert {"experts", "shared", "router", "gate", "attn", "q_proj"} <= modules
+    assert ours & modules == {"moe", "lm_head"}
+    assert {n for n, s in SCOPE_CATALOG.items() if s.origin == "module"} <= modules
 
 
 def test_the_decoder_trains_through_main(tmp_path):

@@ -84,6 +84,17 @@ def gone():
 
 def slam():
     raise SystemExit(9)                             # line 60: SystemExit literal
+
+
+def name_the_ops(x):
+    with jax.named_scope("made_up_scope"):          # line 64: registry-drift (scope catalog)
+        return x + 1
+
+
+@jax.named_scope("made_up_decorator_scope")         # line 68: the decorator form
+def name_them_too(x):
+    with jax.named_scope("attention"):              # registered: no finding
+        return x
 '''
 
 BAD_SH = '''\
@@ -97,6 +108,7 @@ BAD_MD = '''\
 # stale doc
 Watch for `{"event": "vanished_event"}` rows.
 Spans land via `span("vanished.span")` in the tracer.
+Device ops sit under `named_scope("vanished_scope")`, inside `named_scope("moe")`.
 '''
 
 
@@ -145,6 +157,21 @@ def test_each_rule_fires_with_file_and_line(bad_repo):
     assert (os.path.join("scripts", "bad.sh"), 4) in drift  # bad wildcard
     assert (os.path.join("docs", "bad.md"), 2) in drift     # stale doc event
     assert (os.path.join("docs", "bad.md"), 3) in drift     # stale doc span
+
+
+@pytest.mark.parametrize("where,line,name", [
+    (os.path.join(PKG, "bad.py"), 64, "made_up_scope"),
+    (os.path.join(PKG, "bad.py"), 68, "made_up_decorator_scope"),
+    (os.path.join("docs", "bad.md"), 4, "vanished_scope"),
+])
+def test_registry_drift_rejects_an_unregistered_device_scope(bad_repo, where, line, name):
+    """A ``jax.named_scope`` literal (call or decorator) and a scope a doc
+    names resolve against ``telemetry.tracer.SCOPE_CATALOG``; a registered
+    name (``attention``, ``moe``) on the same lines' neighbours is quiet."""
+    scopes = [f for f in run_lint(bad_repo)
+              if f.rule == "registry-drift" and "device scope" in f.message]
+    assert [(f.path, f.line) for f in scopes if repr(name) in f.message] == [(where, line)]
+    assert not any("'attention'" in f.message or "'moe'" in f.message for f in scopes)
 
 
 def test_suppression_comment_silences_rule(bad_repo):

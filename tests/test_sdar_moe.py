@@ -24,6 +24,7 @@ from distributed_resnet_tensorflow_tpu.ops.attention import (
 from distributed_resnet_tensorflow_tpu.ops.pallas.flash_attention import (
     _plan, _steps, _tiles, _walk, flash_attention, tile_census)
 from distributed_resnet_tensorflow_tpu.parallel.mesh import create_mesh
+from distributed_resnet_tensorflow_tpu.telemetry.tracer import SCOPE_CATALOG
 from distributed_resnet_tensorflow_tpu.train.loop import Trainer
 from distributed_resnet_tensorflow_tpu.utils.config import get_preset
 
@@ -386,6 +387,45 @@ def test_rotary_on_every_layer_at_the_positions_given():
 
 
 # -- the expert layer ---------------------------------------------------------
+
+#: the registered scopes that lie inside a decoder block's attention and
+#: expert layer, as the rows ``benchmark/tools/step_parts.py`` prints
+PARTS = sorted(f"{s.under}/{name}" for name, s in SCOPE_CATALOG.items()
+               if s.origin == "scope" and s.under.split("/")[0] in ("attention", "moe"))
+
+
+@pytest.fixture(scope="module")
+def gradient_paths():
+    """The scope path of every operation in the lowered gradient of the tiny
+    decoder's block-diffusion loss, taken under ``forward`` as the step
+    takes it."""
+    import re
+    trainer = tiny_trainer()
+    mine, _ = losses(trainer, batches(1)[0])
+    text = jax.jit(jax.grad(jax.named_scope("forward")(mine), has_aux=True)).lower(
+        trainer.state.params).as_text(debug_info=True)
+    return {name.rsplit("/", 1)[0] for name in re.findall(r'loc\("(jit\([^"]+)"', text)
+            if "/" in name}
+
+
+@pytest.mark.parametrize("row", PARTS + ["blockdiff_input"])
+def test_the_lowered_gradient_holds_every_registered_part(gradient_paths, row):
+    """As ``tests/test_afmoe.py``'s, under this family's objective: rotary
+    on every layer, no shared expert, ``blockdiff_input`` at the top of the
+    forward pass alone (ids and weights: nothing to differentiate). The
+    block has no post-norm, so nothing needs the sum of a walk made again:
+    recomputation keeps the walk's ``plan`` (the backward walk reads it) and
+    drops its windows (PERF.md section 7.9f), where Trinity's block pays
+    for them."""
+    from benchmark.tools import step_parts
+    passes = {step_parts.which_pass(p) for p in gradient_paths
+              if step_parts.row_of(p, SCOPE_CATALOG) == row}
+    windows = {f"moe/experts/{part}": {"forward", "backward"}
+               for part in ("operands", "gather", "products", "to_tokens")}
+    want = {"moe/experts/carry": {"backward"}, "blockdiff_input": {"forward"},
+            **windows}.get(row, {"forward", "recomputed", "backward"})
+    assert passes == want, (row, passes)
+
 
 def _moe_layer(held, experts=16):
     return DroplessMoe(experts, held, 4, 32, 0, 1.0, jnp.float32, "softmax")

@@ -1,31 +1,27 @@
 #!/usr/bin/env python3
-"""Read a kept profiler trace by hand: the step's device time by scope, the
-kernels by name, and what the program's threads were inside during the
-device's longest idle gaps.
+"""Read a kept profiler trace's host side by hand: what the program's threads
+were inside during the device's longest idle gaps.
 
     BENCH_KEEP_TRACE=<dir> python3 benchmark/run.py --workload <cell> ... --trace 1
     python tools/trace_split.py <dir>/<cell>.xplane.pb [--steps-per-dispatch K]
 
 What it reads, all on the profiler's one clock:
 
-* device 0's ``XLA Ops`` line, each op's self time grouped by the
-  ``jax.named_scope`` its ``tf_op`` stat carries (``forward``,
-  ``transpose(jvp(forward))`` = backward, ``optimizer``, ``input_prep``,
-  ``unpack``, ``augment``); what carries no scope is the named remainder
-  ``no scope (<module>)``. The window is the benchmark's: first to last
-  start of the most expensive module (``benchmark/harness/trace.py``);
-* the Pallas kernels' own events: a custom call's instruction is named
-  after the kernel's ``name=`` (``softmax_xent_fwd.1``);
 * the host plane's lines (one per thread): the flight recorder's spans
-  (``telemetry/tracer.SPAN_CATALOG``) that overlap each of the ten
-  longest idle gaps, innermost first, per thread.
+  (``telemetry/tracer.SPAN_CATALOG``), how often and how long each ran;
+* the ten longest idle gaps of device 0 inside the benchmark's window
+  (first to last start of the most expensive module,
+  ``benchmark/harness/trace.py``), each with the spans that overlap it,
+  innermost first, per thread.
 
-The per-layer readers the benchmark needs for these (``forward_ms`` ...,
-``xent_roofline``, ``stage_ms`` cut to the window) belong in
-``benchmark/harness/trace.py`` and are a ``benchmark`` PR's to add
-(PERF.md §7); this is the by-hand reading that PR starts from. Needs the
-``xplane_pb2`` that TensorFlow ships (``jax.profiler.ProfileData`` does not
-expose the ops' metadata stats).
+The device side (time by ``jax.named_scope``, the kernels by name) is
+``python3 benchmark/tools/step_parts.py <file>``, over
+``benchmark/harness/trace.reduce``; this file's own walk of it went with
+PR 37. What is left here moves into ``benchmark/harness/trace.py`` with the
+``benchmark`` issue that cuts ``stage_ms`` / ``input_wait_ms`` /
+``dispatch_ms`` to the window and names a gap after the innermost span
+(PERF.md section 7). Needs the ``xplane_pb2`` that TensorFlow ships
+(``jax.profiler.ProfileData`` shows no thread ids and no event stats).
 """
 from __future__ import annotations
 
@@ -37,14 +33,6 @@ from collections import defaultdict
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
-
-#: first match wins; the backward pass's ops hold "forward" too
-SCOPES = (("backward", "transpose(jvp(forward))"), ("forward", "forward"),
-          ("optimizer", "optimizer"), ("input_prep", "input_prep"),
-          ("augment", "/augment/"), ("unpack", "/unpack/"))
-KERNELS = ("softmax_xent_fwd", "softmax_xent_bwd", "flash_fwd",
-           "flash_bwd_dq", "flash_bwd_dkv")
-
 
 def load(path: str):
     """[(plane, line, name, start_ns, dur_ns, stats)] with metadata stats."""
@@ -77,13 +65,6 @@ def load(path: str):
     return out
 
 
-def scope_of(tf_op: str) -> str:
-    for label, needle in SCOPES:
-        if needle in tf_op:
-            return label
-    return ""
-
-
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("trace")
@@ -106,52 +87,9 @@ def main() -> int:
     lo, hi, periods, module = harness.step_window(plain["planes"][0])
     steps = periods * args.steps_per_dispatch
 
-    # -- device time by scope -------------------------------------------
     ops = [(full, s, d) for p, line, _, full, s, d, _ in events
            if p == dev0 and line == "XLA Ops"]
-    meta = {(full, s): (name, st) for p, line, name, full, s, d, st in events
-            if p == dev0 and line == "XLA Ops"}
-    modules = sorted((s, s + d, harness.family(full)) for p, line, _, full, s, d, _
-                     in events if p == dev0 and line == "XLA Modules")
-
-    def module_at(t):
-        for a, b, name in modules:
-            if a <= t < b:
-                return name
-        return "no module"
-    by_scope = defaultdict(float)
-    kernels = defaultdict(lambda: [0, 0.0])
-    unscoped = defaultdict(float)
-    for full, a, b, self_ns, _parent in harness.self_times(ops):
-        if b <= lo or a >= hi or b <= a:
-            continue
-        share = (min(b, hi) - max(a, lo)) / (b - a) * self_ns
-        name, st = meta[(full, a)]
-        tf_op = str(st.get("tf_op", ""))
-        label = scope_of(tf_op)
-        if not label:
-            label = f"no scope ({module_at(a)})"
-            unscoped[harness.family(full)] += share
-        by_scope[label] += share
-        kernel = harness.family(name)  # the custom call is named after it
-        if kernel in KERNELS:
-            kernels[kernel][0] += 1
-            kernels[kernel][1] += share
-    busy = sum(by_scope.values())
-    print(f"window {1e-9 * (hi - lo):.3f} s, {periods} periods of {module}, "
-          f"{steps} steps; device 0 busy (self times) {busy / 1e9:.3f} s "
-          f"= {busy / steps / 1e6:.3f} ms a step")
-    print("| scope | ms a step | share of busy |")
-    print("| --- | --- | --- |")
-    for label, ns in sorted(by_scope.items(), key=lambda kv: -kv[1]):
-        print(f"| {label} | {ns / steps / 1e6:.3f} | {100 * ns / busy:.2f}% |")
-    top = sorted(unscoped.items(), key=lambda kv: -kv[1])[:6]
-    print("largest ops without a scope:",
-          ", ".join(f"{n} {ns / steps / 1e6:.3f} ms" for n, ns in top))
-    for k, (n, ns) in kernels.items():
-        print(f"kernel {k}: {n} events in the window, {ns / steps / 1e3:.1f} us a step")
-    if not kernels:
-        print("no event carries a Pallas kernel's name")
+    print(f"window {1e-9 * (hi - lo):.3f} s, {periods} periods of {module}, {steps} steps")
 
     # -- the program's spans on the host plane --------------------------
     host = [(line, name, s, s + d, st) for p, line, name, _, s, d, st in events
@@ -214,11 +152,7 @@ def main() -> int:
                         "spans": spans, "host_event": best})
     if args.json:
         with open(args.json, "w") as f:
-            json.dump({"module": module, "steps": steps,
-                       "by_scope_ms": {k: v / steps / 1e6 for k, v in by_scope.items()},
-                       "kernels": {k: {"events": n, "us_a_step": ns / steps / 1e3}
-                                   for k, (n, ns) in kernels.items()},
-                       "gaps": reading}, f, indent=1)
+            json.dump({"module": module, "steps": steps, "gaps": reading}, f, indent=1)
     return 0
 
 
