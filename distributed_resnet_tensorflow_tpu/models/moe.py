@@ -627,50 +627,54 @@ def _token_order(acc, plan):
     return acc[plan["home"]]
 
 
-def _window_sum(dtype, xs, ws, gate, up, down, sizes):
-    """Weight × SwiGLU expert for a window's rows, sorted by expert: rows
-    past the groups' end read zero, here and in the gradient
-    (``grouped_dot``)."""
-    h = jax.nn.silu(grouped_dot(xs, gate, sizes)) * grouped_dot(xs, up, sizes)
+def _window_sum(dtype, xs, ws, kernels, sizes):
+    """Weight × expert for a window's rows, sorted by expert: SwiGLU where
+    ``kernels`` is (gate, up, down), relu² (``down(relu(x·up)²)``) where it
+    is (up, down). Rows past the groups' end read zero, here and in the
+    gradient (``grouped_dot``)."""
+    *gate, up, down = kernels
+    if gate:
+        h = jax.nn.silu(grouped_dot(xs, gate[0], sizes)) * grouped_dot(xs, up, sizes)
+    else:
+        h = jnp.square(jax.nn.relu(grouped_dot(xs, up, sizes)))
     return grouped_dot(h.astype(dtype), down, sizes) * ws[:, None]
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _walk(rows: int, dtype, x, w, gate, up, down, plan, windows):
+def _walk(rows: int, dtype, x, w, kernels, plan, windows):
     """The routed sum of ``held_experts_sum``, ``windows`` windows of
     ``rows`` sorted assignments, one at a time. Reverse mode does not go
     through a loop of a traced length, so the gradient is a walk of its
     own: it keeps the inputs alone and makes each window's rows again."""
-    return _walk_fwd(rows, dtype, x, w, gate, up, down, plan, windows)[0]
+    return _walk_fwd(rows, dtype, x, w, kernels, plan, windows)[0]
 
 
 @jax.named_scope("operands")
-def _operands(dtype, x, w, gate, up, down):
+def _operands(dtype, x, w, kernels):
     """What every window of a walk reads: the tokens and the kernels in the
     products' precision, the weights flat."""
-    return (x.astype(dtype), w.reshape(-1),
-            [a.astype(dtype) for a in (gate, up, down)])
+    return (x.astype(dtype), w.reshape(-1), [a.astype(dtype) for a in kernels])
 
 
-def _walk_fwd(rows, dtype, x, w, gate, up, down, plan, windows):
-    xb, flat_w, kernels = _operands(dtype, x, w, gate, up, down)
+def _walk_fwd(rows, dtype, x, w, kernels, plan, windows):
+    xb, flat_w, cast = _operands(dtype, x, w, kernels)
 
     def one(i, out):
         win = _window(plan, i, rows)
         with jax.named_scope("gather"):
             xs, ws = xb[win.tokens], flat_w[win.places]
         with jax.named_scope("products"):
-            y = _window_sum(dtype, xs, ws, *kernels, win.sizes)
+            y = _window_sum(dtype, xs, ws, cast, win.sizes)
         return _sum_to_tokens(out, y, plan, win)
     with jax.named_scope("to_tokens"):
         out = jnp.zeros(x.shape, jnp.float32)
     out = jax.lax.fori_loop(0, windows, one, out)
-    return _token_order(out, plan), (x, w, gate, up, down, plan, windows)
+    return _token_order(out, plan), (x, w, kernels, plan, windows)
 
 
 def _walk_bwd(rows, dtype, res, g):
-    x, w, gate, up, down, plan, windows = res
-    xb, flat_w, kernels = _operands(dtype, x, w, gate, up, down)
+    x, w, kernels, plan, windows = res
+    xb, flat_w, cast = _operands(dtype, x, w, kernels)
     g = g.astype(jnp.float32)
 
     def one(i, carry):
@@ -682,8 +686,8 @@ def _walk_bwd(rows, dtype, res, g):
         with jax.named_scope("gather"):
             xs, ws = xb[win.tokens], flat_w[win.places]
         with jax.named_scope("products"):
-            _, vjp = jax.vjp(partial(_window_sum, dtype, sizes=win.sizes),
-                             xs, ws, *kernels)
+            _, vjp = jax.vjp(lambda xs, ws, *k: _window_sum(
+                dtype, xs, ws, k, win.sizes), xs, ws, *cast)
         with jax.named_scope("gather"):
             gs = g[win.tokens]
         with jax.named_scope("products"):
@@ -703,13 +707,12 @@ def _walk_bwd(rows, dtype, res, g):
     with jax.named_scope("to_tokens"):
         dx, dw = zeros(x), zeros(flat_w)
     with jax.named_scope("carry"):
-        dkernels = [zeros(gate), zeros(up), zeros(down)]
+        dkernels = [zeros(a) for a in kernels]
     dx, dw, dkernels = jax.lax.fori_loop(0, windows, one, (dx, dw, dkernels))
     with jax.named_scope("carry"):
-        dkernels = [a.astype(b.dtype)
-                    for a, b in zip(dkernels, (gate, up, down))]
+        dkernels = tuple(a.astype(b.dtype) for a, b in zip(dkernels, kernels))
     return (_token_order(dx, plan).astype(x.dtype),
-            dw.reshape(w.shape).astype(w.dtype), *dkernels, None, None)
+            dw.reshape(w.shape).astype(w.dtype), dkernels, None, None)
 
 
 _walk.defvjp(_walk_fwd, _walk_bwd)
@@ -718,9 +721,10 @@ _walk.defvjp(_walk_fwd, _walk_bwd)
 def held_experts_sum(x, sel, w, gate, up, down, lo: int, published: int,
                      dtype):
     """Σ over a token's chosen experts that lie in [lo, lo + E_held) of
-    weight × SwiGLU expert. x (n, d); sel, w (n, k); gate/up (E_held, d,
-    m); down (E_held, m, d); ``published`` the router's width. Returns (the
-    sum (n, d) float32, the windows walked, float32).
+    weight × expert: SwiGLU, or relu² where ``gate`` is None (the choice is
+    static: one walk, two expert functions). x (n, d); sel, w (n, k);
+    gate/up (E_held, d, m); down (E_held, m, d); ``published`` the router's
+    width. Returns (the sum (n, d) float32, the windows walked, float32).
 
     Dropless: all n·k assignments are sorted together by expert, those of
     the experts held here first, and the sorted order is walked in windows
@@ -735,12 +739,13 @@ def held_experts_sum(x, sel, w, gate, up, down, lo: int, published: int,
     overflowed it paid the full buffer and which layers did was the
     seed's."""
     n, k = sel.shape
-    e_held = gate.shape[0]
+    e_held = down.shape[0]
     rows = walk_rows(n, k, e_held, published)
     with jax.named_scope("plan"):
         plan = _plan(sel, lo, e_held, rows)
         windows = -(-plan["ends"][-1] // rows)
-    out = _walk(rows, dtype, x, w, gate, up, down, plan, windows)
+    kernels = (up, down) if gate is None else (gate, up, down)
+    out = _walk(rows, dtype, x, w, kernels, plan, windows)
     return out, windows.astype(jnp.float32)
 
 
@@ -756,6 +761,24 @@ class SwiGLU(nn.Module):
         h = nn.silu(dense(self.hidden, name="gate")(x)) \
             * dense(self.hidden, name="up")(x)
         return dense(d, name="down")(h)
+
+
+class Relu2(nn.Module):
+    """relu(x W_up)² W_down, no biases: the non-gated expert."""
+    hidden: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        d = x.shape[-1]
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        h = jnp.square(nn.relu(dense(self.hidden, name="up")(x)))
+        return dense(d, name="down")(h)
+
+
+#: an expert's function, the walk's and the shared expert's alike
+#: (DroplessMoe.expert): SwiGLU over three kernels, relu² over two
+EXPERTS = {"swiglu": SwiGLU, "relu2": Relu2}
 
 
 class Kernel(nn.Module):
@@ -782,13 +805,16 @@ class HeldExperts(nn.Module):
     published: int
     hidden: int
     dtype: Any = jnp.bfloat16
+    expert: str = "swiglu"           # one of EXPERTS
 
     @nn.compact
     def __call__(self, x, sel, w):
         d = x.shape[-1]
         stack = nn.initializers.variance_scaling(
             1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=0)
-        gate = self.param("gate", stack, (self.held, d, self.hidden))
+        gate = None
+        if self.expert == "swiglu":
+            gate = self.param("gate", stack, (self.held, d, self.hidden))
         up = self.param("up", stack, (self.held, d, self.hidden))
         down = self.param("down", stack, (self.held, self.hidden, d))
         return held_experts_sum(x, sel, w, gate, up, down, self.lo,
@@ -810,6 +836,7 @@ class DroplessMoe(nn.Module):
     route_scale: float = 1.0         # sigmoid_bias's
     dtype: Any = jnp.bfloat16
     router: str = "sigmoid_bias"     # one of ROUTERS
+    expert: str = "swiglu"           # one of EXPERTS, routed and shared
 
     def __call__(self, x: jax.Array):
         return self.walked(x)[:2]
@@ -822,6 +849,8 @@ class DroplessMoe(nn.Module):
                              f"of {self.num_experts} experts")
         if self.router not in ROUTERS:
             raise ValueError(f"router {self.router!r} is none of {ROUTERS}")
+        if self.expert not in EXPERTS:
+            raise ValueError(f"expert {self.expert!r} is none of {tuple(EXPERTS)}")
         with jax.named_scope("route"):
             # the kernel alone lives in the module: the product, the
             # scores and the choice are the route's, in float32
@@ -834,8 +863,9 @@ class DroplessMoe(nn.Module):
                 sel, w, counts = biased_topk_route(
                     x, kernel, bias, self.top_k, self.route_scale)
         out, windows = HeldExperts(lo, hi - lo, self.num_experts, self.hidden,
-                                   self.dtype, name="experts")(x, sel, w)
+                                   self.dtype, self.expert,
+                                   name="experts")(x, sel, w)
         if self.shared_hidden:
-            out = out + SwiGLU(self.shared_hidden, self.dtype,
-                               name="shared")(x.astype(self.dtype))
+            out = out + EXPERTS[self.expert](self.shared_hidden, self.dtype,
+                                             name="shared")(x.astype(self.dtype))
         return out.astype(jnp.float32), counts, windows

@@ -281,10 +281,16 @@ class VisionTransformer(nn.Module):
 # * ``sdar_moe``: neither, rotary positions on every layer, softmax
 #   routing, and the block-diffusion loss of arXiv:2503.09573 over a noisy
 #   and a clean copy of each sequence under ``ops.attention.Mask``'s third
-#   kind.
+#   kind;
+# * ``nemotron_h``: ONE mixer a block (``MixerBlock``: x + mixer(norm(x))),
+#   the layer's kind choosing it: the Mamba-2 mixer (models/mamba.py), the
+#   expert layer with relu² experts, or attention with no head norms, no
+#   gate and no positional term; sigmoid routing with a rule-moved bias,
+#   the next-token loss.
 # ---------------------------------------------------------------------------
 
-LAYER_KINDS = ("sliding_attention", "full_attention")
+#: the attention kinds of both blocks, then the one-mixer block's other two
+LAYER_KINDS = ("sliding_attention", "full_attention", "mamba", "moe")
 
 
 class Family(NamedTuple):
@@ -293,12 +299,24 @@ class Family(NamedTuple):
     rope_all_layers: bool    # rotary on the full_attention layers too
     router: str              # models/moe.ROUTERS
     objective: str           # next_token | block_diffusion
+    head_norms: bool = True  # RMS norms on each query and key head
+    expert: str = "swiglu"   # models/moe.EXPERTS, routed and shared alike
+    one_mixer: bool = False  # MixerBlock in DecoderBlock's place
 
 
 FAMILIES = {
     "afmoe": Family(True, True, False, "sigmoid_bias", "next_token"),
     "sdar_moe": Family(False, False, True, "softmax", "block_diffusion"),
+    "nemotron_h": Family(False, False, False, "sigmoid_bias", "next_token",
+                         head_norms=False, expert="relu2", one_mixer=True),
 }
+
+
+def routes(cfg, i: int) -> bool:
+    """Whether layer i of a decoder holds an expert layer."""
+    if FAMILIES[cfg.name].one_mixer:
+        return cfg.layer_types[i] == "moe"
+    return i >= cfg.num_dense_layers
 
 
 def causal_flash_or_dense(impl: str) -> str:
@@ -367,6 +385,7 @@ class GroupedAttention(nn.Module):
     gated: bool = True               # o ⊙ sigmoid(a W_gate)
     rope_all_layers: bool = False    # rotary on the full layers too
     mask: Any = None                 # an ops.attention.Mask in the causal one's place
+    head_norms: bool = True          # RMS norms on each query and key head
 
     @nn.compact
     def __call__(self, a: jax.Array, positions=None) -> jax.Array:
@@ -377,8 +396,9 @@ class GroupedAttention(nn.Module):
         q = dense(h * hd, name="q_proj")(a).reshape(b, t, h, hd)
         k = dense(kv * hd, name="k_proj")(a).reshape(b, t, kv, hd)
         v = dense(kv * hd, name="v_proj")(a).reshape(b, t, kv, hd)
-        q = RMSNorm(self.eps, name="q_norm")(q)
-        k = RMSNorm(self.eps, name="k_norm")(k)
+        if self.head_norms:
+            q = RMSNorm(self.eps, name="q_norm")(q)
+            k = RMSNorm(self.eps, name="k_norm")(k)
         sliding = self.kind == "sliding_attention"
         # afmoe's full layers carry no positional term at all
         if sliding or self.rope_all_layers:
@@ -415,8 +435,8 @@ class DecoderBlock(nn.Module):
         c = self.cfg
         family = FAMILIES[c.name]
         kind = c.layer_types[self.index]
-        if kind not in LAYER_KINDS:
-            raise ValueError(f"layer kind {kind!r} is none of {LAYER_KINDS}")
+        if kind not in LAYER_KINDS[:2]:
+            raise ValueError(f"layer kind {kind!r} is none of {LAYER_KINDS[:2]}")
         norm = partial(RMSNorm, c.rms_norm_eps)
 
         def joins(branch, name):  # as it is, or through a norm of its own
@@ -427,7 +447,8 @@ class DecoderBlock(nn.Module):
                 kind, c.sliding_window, c.rope_theta, c.rms_norm_eps,
                 self.dtype, self.attention_impl, self.mesh,
                 family.gated_attention, family.rope_all_layers, self.mask,
-                name="attn")(norm(name="input_norm")(x), positions)
+                family.head_norms, name="attn")(norm(name="input_norm")(x),
+                                                positions)
             x = x + joins(a, "post_attn_norm")
         m = norm(name="pre_mlp_norm")(x)
         if self.index < c.num_dense_layers:
@@ -436,19 +457,70 @@ class DecoderBlock(nn.Module):
                        name="mlp")(m.astype(self.dtype))
             counts = windows = jnp.zeros((), jnp.float32)
         else:
-            from .moe import DroplessMoe
-            b, t, d = m.shape
-            # a method other than __call__ is profiled as "moe.walked":
-            # the scope the traces are read by is named here
-            with jax.named_scope("moe"):
-                f, counts, windows = DroplessMoe(
-                    c.num_experts, tuple(c.experts_held),
-                    c.num_experts_per_tok, c.moe_intermediate_size,
-                    c.moe_intermediate_size * c.num_shared_experts,
-                    c.route_scale, self.dtype, family.router,
-                    name="moe").walked(m.reshape(b * t, d))
-            f = f.reshape(b, t, d)
+            f, counts, windows = _expert_layer(c, family, self.dtype, m)
         return x + joins(f, "post_mlp_norm"), counts, windows
+
+
+def _expert_layer(c, family, dtype, m):
+    """The routed experts held here and the shared one over m (B, T, d),
+    inside the current module: (out, counts, windows)."""
+    from .moe import DroplessMoe
+    b, t, d = m.shape
+    shared = c.moe_shared_expert_intermediate_size \
+        or c.moe_intermediate_size * c.num_shared_experts
+    # a method other than __call__ is profiled as "moe.walked": the scope
+    # the traces are read by is named here
+    with jax.named_scope("moe"):
+        f, counts, windows = DroplessMoe(
+            c.num_experts, tuple(c.experts_held), c.num_experts_per_tok,
+            c.moe_intermediate_size, shared, c.route_scale, dtype,
+            family.router, family.expert,
+            name="moe").walked(m.reshape(b * t, d))
+    return f.reshape(b, t, d), counts, windows
+
+
+class MixerBlock(nn.Module):
+    """x + mixer(RMSNorm(x)), ONE mixer a block, the layer's kind choosing
+    it: ``mamba`` (models/mamba.Mamba2), ``moe`` (the expert layer) or
+    ``full_attention`` (the family's GroupedAttention, causal). Returns
+    (x, counts, windows) as ``DecoderBlock`` does."""
+    cfg: Any
+    index: int
+    dtype: Any = jnp.bfloat16
+    attention_impl: str = "auto"
+    mesh: Any = None
+    mask: Any = None
+
+    @nn.compact
+    def __call__(self, x: jax.Array, positions=None):
+        c = self.cfg
+        family = FAMILIES[c.name]
+        kind = c.layer_types[self.index]
+        norm = partial(RMSNorm, c.rms_norm_eps, name="input_norm")
+        counts = windows = jnp.zeros((), jnp.float32)
+        if kind == "mamba":
+            from .mamba import Mamba2
+            with jax.named_scope("mamba"):
+                x = x + Mamba2(c.mamba_num_heads, c.mamba_head_dim, c.n_groups,
+                               c.ssm_state_size, c.conv_kernel, c.chunk_size,
+                               c.rms_norm_eps, (c.time_step_floor, c.time_step_min,
+                                                c.time_step_max), self.dtype,
+                               name="mamba")(norm()(x))
+        elif kind == "moe":
+            f, counts, windows = _expert_layer(c, family, self.dtype, norm()(x))
+            x = x + f
+        elif kind == "full_attention":
+            with jax.named_scope("attention"):
+                x = x + GroupedAttention(
+                    c.num_attention_heads, c.num_key_value_heads, c.head_dim,
+                    kind, c.sliding_window, c.rope_theta, c.rms_norm_eps,
+                    self.dtype, self.attention_impl, self.mesh,
+                    family.gated_attention, family.rope_all_layers, self.mask,
+                    family.head_norms, name="attn")(norm()(x), positions)
+        else:
+            raise ValueError(f"a one-mixer block is mamba, moe or "
+                             f"full_attention, not {kind!r}")
+        return x, counts, windows
 
 
 class CausalDecoder(nn.Module):
@@ -481,21 +553,21 @@ class CausalDecoder(nn.Module):
         x = embedding[tokens].astype(jnp.float32)
         if c.mup_enabled:
             x = x * math.sqrt(d)
-        block = DecoderBlock
+        block = MixerBlock if FAMILIES[c.name].one_mixer else DecoderBlock
         if self.remat:
             # a block's activations are recomputed in the backward pass,
             # but for the flash kernel's output and logsumexp (134 MB and
             # 2 MB a layer at 16,384 tokens): the forward kernel then runs
             # once a step, not twice
             from ..ops.pallas.flash_attention import SAVEABLE
-            block = nn.remat(DecoderBlock, policy=jax.checkpoint_policies
+            block = nn.remat(block, policy=jax.checkpoint_policies
                              .save_only_these_names(*SAVEABLE))
         counts, windows = {}, []
         for i in range(len(c.layer_types)):
             x, got, walked = block(c, i, self.dtype, self.attention_impl,
                                    self.mesh, mask,
                                    name=f"layer{i}")(x, positions)
-            if i >= c.num_dense_layers:
+            if routes(c, i):
                 counts[f"layer{i}"] = got
                 windows.append(walked)
         x = RMSNorm(c.rms_norm_eps, name="final_norm")(x)

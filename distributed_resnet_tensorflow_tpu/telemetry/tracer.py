@@ -316,8 +316,8 @@ SCOPE_CATALOG = {
         "kernels every window, which it names after the while)",
         "moe_experts_roofline, sdar_experts_roofline"),
     "shared": Scope(
-        "module", "moe", "models/moe.SwiGLU, named by DroplessMoe",
-        "the shared expert's three products",
+        "module", "moe", "models/moe.SwiGLU or Relu2, named by DroplessMoe",
+        "the shared expert's three products (SwiGLU) or two (relu²)",
         "step_parts.py (inside moe_ms)"),
     "plan": Scope(
         "scope", "moe/experts", "models/moe.held_experts_sum, _window",
@@ -350,6 +350,23 @@ SCOPE_CATALOG = {
     "lm_head": Scope(
         "scope", "forward", "models/transformer.CausalDecoder",
         "the chunked head and loss", "lm_head_ms"),
+    # the Mamba-2 mixer (models/mamba.py) of the one-mixer blocks
+    "mamba": Scope(
+        "scope", "forward", "models/transformer.MixerBlock",
+        "the block's norm, the input projection, the three parts below, "
+        "the output projection and the residual add", "mamba_ms"),
+    "conv": Scope(
+        "scope", "mamba", "models/mamba.Mamba2",
+        "the causal depthwise convolution over x, B and C and its SiLU",
+        "step_parts.py (inside mamba_ms)"),
+    "scan": Scope(
+        "scope", "mamba", "models/mamba.Mamba2",
+        "softplus of the step sizes, the chunked scan (ops/ssd.py) with its "
+        "reshapes, and the D term", "ssd_roofline"),
+    "gated_norm": Scope(
+        "scope", "mamba", "models/mamba.Mamba2",
+        "y times SiLU(z) and the grouped RMS norm",
+        "step_parts.py (inside mamba_ms)"),
     # JAX's own (jax/_src/ad_checkpoint.py), under nn.remat and
     # jax.checkpoint: not ours to emit, ours to read
     "checkpoint": Scope(

@@ -22,7 +22,7 @@ class ModelConfig:
     the size/width axes the reference hard-coded (resnet_model.py:71-74 pins
     resnet_size=50 for both datasets)."""
 
-    name: str = "resnet"              # resnet | logistic | vit | afmoe | sdar_moe
+    name: str = "resnet"              # resnet | logistic | vit | afmoe | sdar_moe | nemotron_h
     resnet_size: int = 50             # cifar: 6n+2 ∈ {20,32,44,50,56,110,...}; imagenet: 18/34/50/101/152/200
     width_multiplier: int = 1         # Wide-ResNet (e.g. 28-10 → resnet_size=28, width=10)
     num_classes: int = 10
@@ -92,7 +92,8 @@ class ModelConfig:
     num_key_value_heads: int = 2      # ... over this many key/value heads
     head_dim: int = 32
     # one entry a layer: sliding_attention (window + rotary positions) |
-    # full_attention (causal; a positional term where the family says so)
+    # full_attention (causal; a positional term where the family says so);
+    # a one-mixer family's (nemotron_h) also mamba | moe
     layer_types: Tuple[str, ...] = ("sliding_attention", "full_attention")
     sliding_window: int = 64
     rope_theta: float = 10000.0
@@ -106,6 +107,9 @@ class ModelConfig:
     experts_held: Tuple[int, int] = (0, 16)
     num_experts_per_tok: int = 4
     num_shared_experts: int = 1
+    # the shared expert's width where it is not num_shared_experts x
+    # moe_intermediate_size (0)
+    moe_shared_expert_intermediate_size: int = 0
     route_scale: float = 1.0
     # rate of the router-bias rule (arXiv:2408.15664): after each update
     # b += coeff * sign(mean(load) - load); no auxiliary loss term
@@ -118,6 +122,20 @@ class ModelConfig:
     block_length: int = 4
     mask_token_held: int = 511
     noise_eps: float = 1e-3
+    # the Mamba-2 mixer (layer kind "mamba", models/mamba.py): heads x head
+    # size channels, B and C in n_groups groups of ssm_state_size, a causal
+    # convolution conv_kernel wide, the chunked scan in chunks of chunk_size
+    mamba_num_heads: int = 8
+    mamba_head_dim: int = 16
+    n_groups: int = 2
+    ssm_state_size: int = 16
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    # the mixer's start: Δ log-uniform on [time_step_min, time_step_max],
+    # floored at time_step_floor (dt_bias is its softplus inverse)
+    time_step_min: float = 1e-3
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
 
 
 @dataclass
@@ -1034,6 +1052,38 @@ def _sdar_30b_a3b_share8() -> ExperimentConfig:
     return cfg
 
 
+def _nemotron3_nano_share16() -> ExperimentConfig:
+    """NVIDIA-Nemotron-3-Nano-30B-A3B (model_type nemotron_h) at its
+    published widths: ONE chip's share of a deployment in which sixteen
+    chips share each layer — experts 0-7 of 128, 16,384 of 131,072
+    vocabulary rows, the Mamba-2 mixers, attention, router and shared
+    expert whole — and the first nine of its 52 blocks, MEMEM*EME (M
+    Mamba-2, E experts, * attention: one whole period; the rest would lie
+    on further chips as pipeline stages). 666,963,456 parameters, 10.67 GB
+    of state at 16 bytes each; sequences of 8,192 tokens under per-block
+    recomputation and a chunked loss."""
+    cfg = ExperimentConfig()
+    pattern = {"M": "mamba", "E": "moe", "*": "full_attention"}
+    cfg.model = ModelConfig(
+        name="nemotron_h", compute_dtype="bfloat16", attention_impl="auto",
+        hidden_size=2688, num_attention_heads=32, num_key_value_heads=2,
+        head_dim=128, layer_types=tuple(pattern[k] for k in "MEMEM*EME"),
+        rms_norm_eps=1e-5, num_dense_layers=0, intermediate_size=1856,
+        moe_intermediate_size=1856, num_experts=128, experts_held=(0, 8),
+        num_experts_per_tok=6, num_shared_experts=1,
+        moe_shared_expert_intermediate_size=3712, route_scale=2.5,
+        load_balance_coeff=0.001, mup_enabled=False, vocab_held=16384,
+        mamba_num_heads=64, mamba_head_dim=64, n_groups=8,
+        ssm_state_size=128, conv_kernel=4, chunk_size=128)
+    cfg.data = DataConfig(dataset="tokens", seq_len=8192)
+    cfg.optimizer = OptimizerConfig(
+        name="adamw", learning_rate=3e-4, weight_decay=0.1,
+        schedule="cosine", warmup_steps=2000, total_steps=100000)
+    cfg.train = TrainConfig(batch_size=2, train_steps=100000,
+                            steps_per_loop=1, remat=True)
+    return cfg
+
+
 def _cifar10_smoke() -> ExperimentConfig:
     """Local smoke test analog of reference scripts/submit_mac_dist.sh
     (1ps+2wk, bs=10, 100 steps on CPU — SURVEY.md §4.1)."""
@@ -1059,6 +1109,7 @@ PRESETS = {
     "vit_moe": _vit_moe,
     "trinity_mini_share8": _trinity_mini_share8,
     "sdar_30b_a3b_share8": _sdar_30b_a3b_share8,
+    "nemotron3_nano_share16": _nemotron3_nano_share16,
     "smoke": _cifar10_smoke,
 }
 
