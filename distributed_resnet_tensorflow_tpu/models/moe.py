@@ -528,6 +528,45 @@ def walk_rows(n: int, k: int, held: int, published: int) -> int:
     return min(window_rows(n, k, held, published), k * WINDOW_TOKENS)
 
 
+#: a grouped product's width that is a multiple of this is left as it is;
+#: any other is padded to a multiple of twice this (``product_widths``)
+PRODUCT_TILE = 256
+
+
+def product_widths(d: int, m: int) -> Tuple[int, int]:
+    """The widths the walk hands the grouped products for experts d wide
+    outside and m inside. The TPU compiler's kernel for
+    ``jax.lax.ragged_dot`` tiles a width by the largest of 512, 256 and 128
+    that divides it, and a call is paced by its grid steps: 2,688 × 1,856
+    (1,856 laid out as 1,920) ran in blocks of 128 × 128. So a width that
+    is no multiple of ``PRODUCT_TILE`` is rounded up to a multiple of twice
+    it: 2,688 × 1,856 runs as 3,072 × 2,048, in blocks of 512 (on a v5e
+    the step is 0.4% faster so than at 2,816 × 2,048, whose blocks are 256
+    and 512). A multiple is left alone, and with it the program. The pad is
+    zeros, which add exact zeros: relu(0)² = 0, silu(0)·0 = 0, and a zero
+    row of ``down`` adds nothing. From the shapes alone: there is nothing
+    to set."""
+    def up(width):
+        if width % PRODUCT_TILE == 0:
+            return width
+        return -(-width // (2 * PRODUCT_TILE)) * (2 * PRODUCT_TILE)
+    return up(d), up(m)
+
+
+def _pad_to(a, shape):
+    """``a`` with zeros after its end on each axis, up to ``shape``."""
+    if a.shape == tuple(shape):
+        return a
+    return jnp.pad(a, [(0, s - n) for n, s in zip(a.shape, shape)])
+
+
+def _cut_to(a, shape):
+    """The first ``shape`` of ``a``: what ``_pad_to`` added, taken off."""
+    if a.shape == tuple(shape):
+        return a
+    return jax.lax.slice(a, (0,) * a.ndim, tuple(shape))
+
+
 def _plan(sel, lo: int, e_held: int, rows: int):
     """A layer's bookkeeping, all of it ``(n·k,)`` int32 work.
 
@@ -652,8 +691,17 @@ def _walk(rows: int, dtype, x, w, kernels, plan, windows):
 @jax.named_scope("operands")
 def _operands(dtype, x, w, kernels):
     """What every window of a walk reads: the tokens and the kernels in the
-    products' precision, the weights flat."""
-    return (x.astype(dtype), w.reshape(-1), [a.astype(dtype) for a in kernels])
+    products' precision, zero-padded to ``product_widths``, the weights
+    flat. The parameters keep their widths: the pad lives in the walk."""
+    *ins, down = kernels
+    e, m, d = down.shape
+    dp, mp = product_widths(d, m)
+    # the tokens first: traced in this order, widths that need no pad
+    # compile to the very program they compiled to before the pad existed
+    xb, flat_w = _pad_to(x.astype(dtype), (x.shape[0], dp)), w.reshape(-1)
+    cast = [_pad_to(a.astype(dtype), (e, dp, mp)) for a in ins]
+    cast.append(_pad_to(down.astype(dtype), (e, mp, dp)))
+    return xb, flat_w, cast
 
 
 def _walk_fwd(rows, dtype, x, w, kernels, plan, windows):
@@ -664,7 +712,8 @@ def _walk_fwd(rows, dtype, x, w, kernels, plan, windows):
         with jax.named_scope("gather"):
             xs, ws = xb[win.tokens], flat_w[win.places]
         with jax.named_scope("products"):
-            y = _window_sum(dtype, xs, ws, cast, win.sizes)
+            y = _cut_to(_window_sum(dtype, xs, ws, cast, win.sizes),
+                        (rows, x.shape[1]))
         return _sum_to_tokens(out, y, plan, win)
     with jax.named_scope("to_tokens"):
         out = jnp.zeros(x.shape, jnp.float32)
@@ -675,7 +724,8 @@ def _walk_fwd(rows, dtype, x, w, kernels, plan, windows):
 def _walk_bwd(rows, dtype, res, g):
     x, w, kernels, plan, windows = res
     xb, flat_w, cast = _operands(dtype, x, w, kernels)
-    g = g.astype(jnp.float32)
+    with jax.named_scope("operands"):
+        g = _pad_to(g.astype(jnp.float32), xb.shape)
 
     def one(i, carry):
         dx, dw, dkernels = carry
@@ -692,7 +742,7 @@ def _walk_bwd(rows, dtype, res, g):
             gs = g[win.tokens]
         with jax.named_scope("products"):
             dxs, dws, *dk = vjp(gs)
-            dxs = dxs.astype(jnp.float32)
+            dxs = _cut_to(dxs, (rows, x.shape[1])).astype(jnp.float32)
         dx = _sum_to_tokens(dx, dxs, plan, win)
         # a window's rows of scalars to their (token, choice): the one
         # scatter of the walk, and added, because the rows that pad the
@@ -700,7 +750,7 @@ def _walk_bwd(rows, dtype, res, g):
         with jax.named_scope("to_tokens"):
             dw = dw.at[win.places].add(dws)
         with jax.named_scope("carry"):
-            dkernels = [a + b.astype(jnp.float32)
+            dkernels = [a + _cut_to(b, a.shape).astype(jnp.float32)
                         for a, b in zip(dkernels, dk)]
         return dx, dw, dkernels
     zeros = lambda a: jnp.zeros(a.shape, jnp.float32)  # noqa: E731

@@ -324,8 +324,9 @@ SCOPE_CATALOG = {
         "a layer's five sorts and a window's int32 bookkeeping",
         "step_parts.py"),
     "operands": Scope(
-        "scope", "moe/experts", "models/moe._operands",
-        "tokens and kernels cast to the products' precision, once a walk",
+        "scope", "moe/experts", "models/moe._operands, _walk_bwd",
+        "tokens and kernels cast to the products' precision and "
+        "zero-padded to product_widths, the cotangent padded, once a walk",
         "step_parts.py"),
     "gather": Scope(
         "scope", "moe/experts", "models/moe._walk_fwd, _walk_bwd",
@@ -333,8 +334,9 @@ SCOPE_CATALOG = {
         "step_parts.py"),
     "products": Scope(
         "scope", "moe/experts", "models/moe._walk_fwd, _walk_bwd",
-        "silu x up, x weights, the live-row masks and casts around the "
-        "grouped products (the ragged-dot kernels are found by name)",
+        "silu x up, x weights, the live-row masks, casts and cuts back to "
+        "the published width around the grouped products (the ragged-dot "
+        "kernels are found by name)",
         "step_parts.py; the kernels by moe_products_ms, "
         "moe_products_roofline, moe_product_calls"),
     "to_tokens": Scope(

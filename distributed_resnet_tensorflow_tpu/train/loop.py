@@ -596,10 +596,15 @@ class Trainer:
         def onoff(flag) -> str:
             return "on" if flag else "off"
 
-        attention = "n/a"
+        attention = widths = "n/a"
         from ..models.transformer import FAMILIES, causal_flash_or_dense
         if cfg.model.name in FAMILIES:
             attention = causal_flash_or_dense(self.model.attention_impl)
+            from ..models.moe import product_widths
+            d, m = cfg.model.hidden_size, cfg.model.moe_intermediate_size
+            padded = product_widths(d, m)
+            widths = "as published" if padded == (d, m) else \
+                "{}x{} from {}x{}".format(*padded, d, m)
         if cfg.model.name == "vit":
             attention = self.model.attention_impl
             if attention == "auto":  # no seq axis (create_model resolves it)
@@ -616,6 +621,7 @@ class Trainer:
             "device_augment": onoff(device_augment_enabled(cfg, "train")),
             "device_dataset": onoff(device_dataset_enabled(cfg, "train")),
             "attention": attention,
+            "moe.product_widths": widths,
             "zero1": onoff(self.zero1_active),
             "step.compiler_options": ",".join(
                 f"{k}={v}" for k, v in

@@ -3,7 +3,9 @@ against a brute-force enumeration of the mask), the three kernels against
 their twin at shapes that hold dead tiles, masked ones and ones whose every
 pair counts in one call (interpret mode on the CPU), every row of the block
 table against the tuner's committed results, the tuner itself rehearsed in
-interpret mode, and the real calls compiled for a described v5e."""
+interpret mode, and the real calls compiled for a described v5e, with the
+blocks the compiler gives the expert layer's grouped products there (the
+described chip's tests stay in this one file)."""
 import importlib.util
 import json
 import os
@@ -407,3 +409,49 @@ def test_the_ring_compiles_for_four_v5e(topo, chunk, heads, d, causal):
     for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                  "collective-permute"):
         assert name in text
+
+
+def ragged_dot_blocks(text):
+    """The blocks of every ``ragged-dot-none`` kernel (the compiler's own
+    lowering of ``jax.lax.ragged_dot``) in a compiled program: the
+    ``window_bounds`` of its operands and result, read from its Mosaic
+    body."""
+    import base64
+    import re
+    blocks = []
+    for line in text.splitlines():
+        if not re.match(r"\s*(ROOT )?%ragged-dot-none(\.\d+)? = ", line):
+            continue
+        body = base64.b64decode(
+            re.search(r'"body":"([^"]+)"', line).group(1)).decode()
+        blocks.append([tuple(int(v) for v in bounds.split(", ")) for bounds in
+                       re.findall(r"window_bounds = array<i64: ([\d, ]+)>", body)])
+    return blocks
+
+
+@pytest.mark.parametrize("rows,groups,d,m,gated,calls,least", [
+    (12288, 8, 2688, 1856, False, 6, 512),   # nemotron3_nano: relu², padded
+    (32768, 16, 2048, 1024, True, 9, 512)])  # trinity_mini: SwiGLU, as published
+def test_the_grouped_products_take_wide_blocks_on_a_v5e(
+        one_chip, rows, groups, d, m, gated, calls, least):
+    """One window's grouped products at a cell's sizes, forward and
+    gradient, as the walk runs them (``_operands``, ``_window_sum``): every
+    block of every kernel is ``least`` wide beside its 512 rows. Nemotron's
+    2,688 x 1,856 ran in blocks of 128 x 128 before the walk padded it to
+    3,072 x 2,048; Trinity's widths are left alone and keep their
+    512 x 512."""
+    from distributed_resnet_tensorflow_tpu.models import moe
+
+    def loss(x, w, kernels, sizes):
+        xb, flat_w, cast = moe._operands(jnp.bfloat16, x, w, kernels)
+        return jnp.sum(moe._window_sum(jnp.bfloat16, xb, flat_w, cast, sizes))
+
+    def shaped(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    kernels = [shaped((groups, d, m))] * (2 if gated else 1) + [shaped((groups, m, d))]
+    text = compiled_text(jax.grad(loss, (0, 1, 2)), shaped((rows, d)), shaped((rows,)),
+                         kernels, shaped((groups,), jnp.int32))
+    blocks = ragged_dot_blocks(text)
+    assert len(blocks) == calls
+    widths = {v for call in blocks for bounds in call for v in bounds if v != 1}
+    assert widths == {least}, blocks

@@ -4,6 +4,8 @@ ops/ssd.py, relu² experts in models/moe.DroplessMoe) against its plain
 reference (benchmark/reference/nemotron_h.py, the recurrence a position at
 a time) and against itself, at sizes a CPU holds; the flash kernels in
 interpret mode."""
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -311,6 +313,40 @@ def _per_expert_sum(x, sel, w, up, down, lo):
 def test_the_relu2_walk_is_the_per_expert_loop(bound, monkeypatch):
     """One window and several: value and every gradient of the walk over
     two kernels against a loop over the held experts."""
+    _walk_against_the_loop(bound, monkeypatch)
+
+
+@pytest.mark.parametrize("tile,products", [(16, (32, 32)), (8, (24, 16)), (4, (24, 12))])
+@pytest.mark.parametrize("bound", [4096, 32])
+def test_the_padded_relu2_walk_is_the_per_expert_loop(bound, tile, products, monkeypatch):
+    """The walk hands the grouped products each width that ``PRODUCT_TILE``
+    does not divide padded to a multiple of twice it (24 x 12 runs as
+    32 x 32 under a tile of 16, as 24 x 16 under 8 and as itself under 4);
+    value and every gradient come back in the parameters' widths and are
+    the loop's, in one window and several."""
+    monkeypatch.setattr(moe, "PRODUCT_TILE", tile)
+    assert moe.product_widths(24, 12) == products
+    _walk_against_the_loop(bound, monkeypatch, products)
+
+
+def _ragged_dot_widths(fn, *args):
+    """(k, n) of every grouped product in ``fn``'s program, nested ones too."""
+    found = set()
+
+    def visit(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name.startswith("ragged_dot"):
+                found.add((eqn.invars[0].aval.shape[1], eqn.outvars[0].aval.shape[-1]))
+            for param in eqn.params.values():
+                for sub in param if isinstance(param, (tuple, list)) else [param]:
+                    sub = getattr(sub, "jaxpr", sub)  # a closed jaxpr's own
+                    if hasattr(sub, "eqns"):
+                        visit(sub)
+    visit(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def _walk_against_the_loop(bound, monkeypatch, products=None):
     n, k, held, published, lo = 96, 3, 4, 16, 4
     monkeypatch.setattr(moe, "WINDOW_TOKENS", bound)
     keys = jax.random.split(jax.random.PRNGKey(bound), 6)
@@ -327,11 +363,49 @@ def test_the_relu2_walk_is_the_per_expert_loop(bound, monkeypatch):
     np.testing.assert_allclose(got, _per_expert_sum(x, sel, w, up, down, lo), atol=2e-5)
     live = int(jnp.sum((sel >= lo) & (sel < lo + held)))
     assert float(windows) == -(-live // moe.walk_rows(n, k, held, published))
-    got = jax.grad(lambda *a: jnp.sum(walked(*a)[0] * r), argnums=(0, 1, 2, 3))(x, w, up, down)
+    grad = jax.grad(lambda *a: jnp.sum(walked(*a)[0] * r), argnums=(0, 1, 2, 3))
+    got = grad(x, w, up, down)
     want = jax.grad(lambda *a: jnp.sum(_per_expert_sum(a[0], sel, *a[1:], lo) * r),
                     argnums=(0, 1, 2, 3))(x, w, up, down)
     for name, a, b in zip(("dx", "dw", "up", "down"), got, want):
+        assert a.shape == b.shape, name
         np.testing.assert_allclose(a, b, atol=5e-5 * float(jnp.max(jnp.abs(b))), err_msg=name)
+    if products is not None:
+        assert _ragged_dot_widths(grad, x, w, up, down) == {products, products[::-1]}
+
+
+@pytest.mark.parametrize("published,padded", [
+    ((2688, 1856), (3072, 2048)), ((2048, 1024), (2048, 1024)), ((2048, 768), (2048, 768)),
+    ((2816, 1280), (2816, 1280)), ((64, 32), (512, 512)), ((257, 512), (512, 512))])
+def test_the_product_widths_pad_what_no_tile_divides(published, padded):
+    """Nemotron's 2,688 x 1,856 runs as 3,072 x 2,048; the SwiGLU cells'
+    widths (Trinity 2,048 x 1,024, SDAR 2,048 x 768) are multiples of 256
+    already and are left as they are, as is any multiple of 256 that is
+    none of 512."""
+    assert moe.product_widths(*published) == padded
+
+
+def test_the_pad_leaves_the_layers_parameters_and_gradients_as_published():
+    """64 x 32 experts run their products at 512 x 512: the parameters, and
+    the gradient the layer hands the optimizer, keep 64 and 32."""
+    layer = _moe_layer((4, 8))
+    x = jax.random.normal(jax.random.PRNGKey(1), (24, 64))
+    params = layer.init(jax.random.PRNGKey(0), x)["params"]
+    assert moe.product_widths(64, 32) == (512, 512)
+    assert params["experts"]["up"].shape == (4, 64, 32)
+    assert params["experts"]["down"].shape == (4, 32, 64)
+    grads = jax.grad(lambda p: jnp.sum(layer.apply({"params": p}, x)[0]))(params)
+    shapes = partial(jax.tree_util.tree_map, lambda a: a.shape)
+    assert shapes(grads) == shapes(params)
+    assert float(jnp.linalg.norm(grads["experts"]["up"])) > 0
+
+
+@pytest.mark.parametrize("tile,want", [(256, "512x512 from 64x32"), (16, "as published")])
+def test_the_resolved_line_states_the_product_widths(dense_trainer, tile, want, monkeypatch):
+    """``Trainer.resolutions`` says whether the pad engaged: the tiny model's
+    64 x 32 experts are padded under the shipped tile and not under 16."""
+    monkeypatch.setattr(moe, "PRODUCT_TILE", tile)
+    assert dense_trainer.resolutions()["moe.product_widths"] == want
 
 
 def _moe_layer(held, experts=16):
