@@ -286,7 +286,13 @@ class VisionTransformer(nn.Module):
 #   the layer's kind choosing it: the Mamba-2 mixer (models/mamba.py), the
 #   expert layer with relu² experts, or attention with no head norms, no
 #   gate and no positional term; sigmoid routing with a rule-moved bias,
-#   the next-token loss.
+#   the next-token loss;
+# * ``glm4_moe_lite``: latent attention (``LatentAttention``: queries and
+#   keys/values through low-rank latents, a rotary part split from the
+#   rest, one rotary key shared by every head), neither post-norms nor a
+#   gate, sigmoid routing with a rule-moved bias, and after the last layer
+#   the multi-token-prediction blocks of ``PredictionBlock`` beside the
+#   next-token loss (``num_nextn_predict_layers`` of them).
 # ---------------------------------------------------------------------------
 
 #: the attention kinds of both blocks, then the one-mixer block's other two
@@ -302,6 +308,7 @@ class Family(NamedTuple):
     head_norms: bool = True  # RMS norms on each query and key head
     expert: str = "swiglu"   # models/moe.EXPERTS, routed and shared alike
     one_mixer: bool = False  # MixerBlock in DecoderBlock's place
+    attention: str = "grouped"  # GroupedAttention | "latent": LatentAttention
 
 
 FAMILIES = {
@@ -309,6 +316,8 @@ FAMILIES = {
     "sdar_moe": Family(False, False, True, "softmax", "block_diffusion"),
     "nemotron_h": Family(False, False, False, "sigmoid_bias", "next_token",
                          head_norms=False, expert="relu2", one_mixer=True),
+    "glm4_moe_lite": Family(False, False, True, "sigmoid_bias", "next_token",
+                            head_norms=False, attention="latent"),
 }
 
 
@@ -418,6 +427,91 @@ class GroupedAttention(nn.Module):
         return dense(d, name="o_proj")(o)
 
 
+class LatentAttention(nn.Module):
+    """Latent attention in its plain (training) form, causal. Queries:
+    ``c_q = RMSNorm(a W_qa)`` of ``q_rank``, ``q = c_q W_qb`` split per head
+    into ``nope`` and ``rope`` parts. Keys and values: ``[c_kv | k_r] = a
+    W_kva``, ``c_kv`` RMS-normed, ``[k_n | v] = c_kv W_kvb`` per head;
+    ``k_r``, ``rope`` wide, is ONE key part every head shares. Rotary
+    positions (rotate-half) on the rope parts alone; a head's query and key
+    are ``[q_n ; q_r]`` and ``[k_n ; k_r]``, ``nope + rope`` wide, its value
+    ``v_dim``. No biases, no head norms, no gate."""
+    num_heads: int
+    q_rank: int
+    kv_rank: int
+    nope: int
+    rope: int
+    v_dim: int
+    rope_theta: float
+    eps: float
+    dtype: Any = jnp.bfloat16
+    attention_impl: str = "auto"
+    mesh: Any = None
+    mask: Any = None                 # an ops.attention.Mask in the causal one's place
+
+    @nn.compact
+    def __call__(self, a: jax.Array, positions=None) -> jax.Array:
+        b, t, d = a.shape
+        h, nope, rope, dv = self.num_heads, self.nope, self.rope, self.v_dim
+        widths = dict(q_lora_rank=self.q_rank, kv_lora_rank=self.kv_rank,
+                      qk_nope_head_dim=nope, qk_rope_head_dim=rope,
+                      v_head_dim=dv)
+        unset = [name for name, width in widths.items() if width <= 0]
+        if unset:
+            raise ValueError(f"latent attention needs every width: "
+                             f"{', '.join(unset)} not set (> 0)")
+        impl = causal_flash_or_dense(self.attention_impl)
+        if impl in ("flash", "flash_interpret") and dv != nope + rope:
+            raise ValueError(
+                f"the flash kernels take one head size for queries, keys and "
+                f"values: v_head_dim {dv} is not qk_nope_head_dim {nope} + "
+                f"qk_rope_head_dim {rope}")
+        a = a.astype(self.dtype)
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        norm = partial(RMSNorm, self.eps)
+        # both latents and their norms, the up-projections, the rotary and
+        # the shared rotary key broadcast to the heads: `attention` without
+        # `latent` and `core` is the output projection, the block's norm and
+        # the residual add
+        with jax.named_scope("latent"):
+            c_q = norm(name="q_a_norm")(dense(self.q_rank, name="q_a_proj")(a))
+            q = dense(h * (nope + rope), name="q_b_proj")(
+                c_q.astype(self.dtype)).reshape(b, t, h, nope + rope)
+            c_kv = dense(self.kv_rank + rope, name="kv_a_proj")(a)
+            k_r = c_kv[..., self.kv_rank:].reshape(b, t, 1, rope)
+            c_kv = norm(name="kv_a_norm")(c_kv[..., :self.kv_rank])
+            kv = dense(h * (nope + dv), name="kv_b_proj")(
+                c_kv.astype(self.dtype)).reshape(b, t, h, nope + dv)
+            q_r = rotary(q[..., nope:], self.rope_theta, positions)
+            k_r = rotary(k_r, self.rope_theta, positions)
+            q = jnp.concatenate([q[..., :nope], q_r.astype(self.dtype)], -1)
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_r.astype(self.dtype), (b, t, h, rope))], -1)
+            v = kv[..., nope:]
+        from ..ops.attention import Mask
+        with jax.named_scope("core"):
+            o = causal_attention(q, k, v, self.mask or Mask("causal"), impl,
+                                 self.mesh)
+        return dense(d, name="o_proj")(o.reshape(b, t, h * dv))
+
+
+def _attention_module(c, family, kind: str, dtype, impl: str, mesh, mask):
+    """The family's attention for a layer of ``kind``, named ``attn``, made
+    inside the current module."""
+    if family.attention == "latent":
+        return LatentAttention(
+            c.num_attention_heads, c.q_lora_rank, c.kv_lora_rank,
+            c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim,
+            c.rope_theta, c.rms_norm_eps, dtype, impl, mesh, mask,
+            name="attn")
+    return GroupedAttention(
+        c.num_attention_heads, c.num_key_value_heads, c.head_dim, kind,
+        c.sliding_window, c.rope_theta, c.rms_norm_eps, dtype, impl, mesh,
+        family.gated_attention, family.rope_all_layers, mask,
+        family.head_norms, name="attn")
+
+
 class DecoderBlock(nn.Module):
     cfg: Any                         # the ModelConfig (utils/config.py)
     index: int
@@ -433,32 +527,65 @@ class DecoderBlock(nn.Module):
         (models/moe.held_experts_sum) where it routes, scalars 0 where it
         is dense."""
         c = self.cfg
-        family = FAMILIES[c.name]
-        kind = c.layer_types[self.index]
-        if kind not in LAYER_KINDS[:2]:
-            raise ValueError(f"layer kind {kind!r} is none of {LAYER_KINDS[:2]}")
-        norm = partial(RMSNorm, c.rms_norm_eps)
+        return _decoder_layer(c, c.layer_types[self.index],
+                              self.index < c.num_dense_layers, self.dtype,
+                              self.attention_impl, self.mesh, self.mask, x,
+                              positions)
 
-        def joins(branch, name):  # as it is, or through a norm of its own
-            return norm(name=name)(branch) if family.post_norms else branch
-        with jax.named_scope("attention"):
-            a = GroupedAttention(
-                c.num_attention_heads, c.num_key_value_heads, c.head_dim,
-                kind, c.sliding_window, c.rope_theta, c.rms_norm_eps,
-                self.dtype, self.attention_impl, self.mesh,
-                family.gated_attention, family.rope_all_layers, self.mask,
-                family.head_norms, name="attn")(norm(name="input_norm")(x),
-                                                positions)
-            x = x + joins(a, "post_attn_norm")
-        m = norm(name="pre_mlp_norm")(x)
-        if self.index < c.num_dense_layers:
-            from .moe import SwiGLU
-            f = SwiGLU(c.intermediate_size, self.dtype,
-                       name="mlp")(m.astype(self.dtype))
-            counts = windows = jnp.zeros((), jnp.float32)
-        else:
-            f, counts, windows = _expert_layer(c, family, self.dtype, m)
-        return x + joins(f, "post_mlp_norm"), counts, windows
+
+def _decoder_layer(c, kind: str, dense: bool, dtype, impl: str, mesh, mask,
+                   x, positions):
+    """A ``DecoderBlock``'s body inside the current module: attention, then
+    the dense SwiGLU (``dense``) or the expert layer, each after an RMS norm
+    and, where the family has them, before one. Returns (x, counts,
+    windows)."""
+    family = FAMILIES[c.name]
+    if kind not in LAYER_KINDS[:2]:
+        raise ValueError(f"layer kind {kind!r} is none of {LAYER_KINDS[:2]}")
+    norm = partial(RMSNorm, c.rms_norm_eps)
+
+    def joins(branch, name):  # as it is, or through a norm of its own
+        return norm(name=name)(branch) if family.post_norms else branch
+    with jax.named_scope("attention"):
+        a = _attention_module(c, family, kind, dtype, impl, mesh, mask)(
+            norm(name="input_norm")(x), positions)
+        x = x + joins(a, "post_attn_norm")
+    m = norm(name="pre_mlp_norm")(x)
+    if dense:
+        from .moe import SwiGLU
+        f = SwiGLU(c.intermediate_size, dtype, name="mlp")(m.astype(dtype))
+        counts = windows = jnp.zeros((), jnp.float32)
+    else:
+        f, counts, windows = _expert_layer(c, family, dtype, m)
+    return x + joins(f, "post_mlp_norm"), counts, windows
+
+
+class PredictionBlock(nn.Module):
+    """One multi-token-prediction block (DeepSeek-V3, arXiv:2412.19437
+    section 2.2): ``u = W_eh [RMSNorm_e(e) ; RMSNorm_h(h)]`` from the
+    embedding ``e`` (B, T, d) of the id one further on and the hidden state
+    ``h`` (B, T, d) that predicted it, one layer of the expert kind (the
+    family's attention and expert layer, causal over the same positions).
+    Returns (x, RMSNorm(x), counts, windows): the state the next block
+    reads, what the decoder's own head reads, and the expert layer's two
+    as ``DecoderBlock`` returns them."""
+    cfg: Any
+    dtype: Any = jnp.bfloat16
+    attention_impl: str = "auto"
+    mesh: Any = None
+    mask: Any = None
+
+    @nn.compact
+    def __call__(self, h: jax.Array, e: jax.Array, positions=None):
+        c = self.cfg
+        norm = partial(RMSNorm, c.rms_norm_eps)
+        u = jnp.concatenate([norm(name="enorm")(e), norm(name="hnorm")(h)], -1)
+        x = nn.Dense(c.hidden_size, use_bias=False, dtype=self.dtype,
+                     name="eh_proj")(u.astype(self.dtype)).astype(jnp.float32)
+        x, counts, windows = _decoder_layer(
+            c, "full_attention", False, self.dtype, self.attention_impl,
+            self.mesh, self.mask, x, positions)
+        return x, norm(name="norm")(x), counts, windows
 
 
 def _expert_layer(c, family, dtype, m):
@@ -511,12 +638,9 @@ class MixerBlock(nn.Module):
             x = x + f
         elif kind == "full_attention":
             with jax.named_scope("attention"):
-                x = x + GroupedAttention(
-                    c.num_attention_heads, c.num_key_value_heads, c.head_dim,
-                    kind, c.sliding_window, c.rope_theta, c.rms_norm_eps,
-                    self.dtype, self.attention_impl, self.mesh,
-                    family.gated_attention, family.rope_all_layers, self.mask,
-                    family.head_norms, name="attn")(norm()(x), positions)
+                x = x + _attention_module(
+                    c, family, kind, self.dtype, self.attention_impl,
+                    self.mesh, self.mask)(norm()(x), positions)
         else:
             raise ValueError(f"a one-mixer block is mamba, moe or "
                              f"full_attention, not {kind!r}")
@@ -536,7 +660,10 @@ class CausalDecoder(nn.Module):
     and the windows its held experts walked a chunk (a list of scalars).
     ``positions`` (T,) are the tokens' rotary ids where they are not 0..T-1,
     ``mask`` an ``ops.attention.Mask`` in the place of the layers' causal
-    ones."""
+    ones. With ``num_nextn_predict_layers`` D > 0 (T' = T, the next-token
+    objective) also ``"mtp_loss"`` and ``"mtp_correct"``: the mean over the
+    D prediction blocks of their loss and share (``predict_further``), whose
+    routing counts join ``counts`` as ``mtp<k>``."""
     cfg: Any
     dtype: Any = jnp.bfloat16
     attention_impl: str = "auto"
@@ -553,15 +680,8 @@ class CausalDecoder(nn.Module):
         x = embedding[tokens].astype(jnp.float32)
         if c.mup_enabled:
             x = x * math.sqrt(d)
-        block = MixerBlock if FAMILIES[c.name].one_mixer else DecoderBlock
-        if self.remat:
-            # a block's activations are recomputed in the backward pass,
-            # but for the flash kernel's output and logsumexp (134 MB and
-            # 2 MB a layer at 16,384 tokens): the forward kernel then runs
-            # once a step, not twice
-            from ..ops.pallas.flash_attention import SAVEABLE
-            block = nn.remat(block, policy=jax.checkpoint_policies
-                             .save_only_these_names(*SAVEABLE))
+        block = self._recomputed(
+            MixerBlock if FAMILIES[c.name].one_mixer else DecoderBlock)
         counts, windows = {}, []
         for i in range(len(c.layer_types)):
             x, got, walked = block(c, i, self.dtype, self.attention_impl,
@@ -570,17 +690,86 @@ class CausalDecoder(nn.Module):
             if routes(c, i):
                 counts[f"layer{i}"] = got
                 windows.append(walked)
+        hidden = x
         x = RMSNorm(c.rms_norm_eps, name="final_norm")(x)
         from .moe import Kernel
         head = Kernel(v, name="lm_head")(d)
         with jax.named_scope("lm_head"):
             if targets is None:
-                return jnp.dot(x.astype(self.dtype), head.astype(self.dtype),
-                               preferred_element_type=jnp.float32)
-            loss, correct = chunked_next_token_loss(
-                x[:, :targets.shape[1]], head, targets, self.dtype, weights)
-        return {"loss": loss, "correct": correct, "counts": counts,
-                "windows": windows}
+                logits = jnp.dot(x.astype(self.dtype), head.astype(self.dtype),
+                                 preferred_element_type=jnp.float32)
+            else:
+                loss, correct = chunked_next_token_loss(
+                    x[:, :targets.shape[1]], head, targets, self.dtype,
+                    weights)
+        if targets is None:
+            if c.num_nextn_predict_layers and self.is_initializing():
+                # the prediction blocks' parameters, made on the ids
+                # themselves; logits never run them
+                self.predict_further(hidden, embedding, head, tokens,
+                                     positions, mask)
+            return logits
+        out = {"loss": loss, "correct": correct, "counts": counts,
+               "windows": windows}
+        if c.num_nextn_predict_layers:
+            mtp = self.predict_further(hidden, embedding, head, targets,
+                                       positions, mask)
+            out.update(mtp_loss=mtp["loss"], mtp_correct=mtp["correct"])
+            counts.update(mtp["counts"])
+            windows.extend(mtp["windows"])
+        return out
+
+    def _recomputed(self, block):
+        """``block`` as the decoder runs it: with ``remat``, its activations
+        are recomputed in the backward pass but for the flash kernel's
+        output and logsumexp (134 MB and 2 MB a layer at 16,384 tokens and
+        head size 128): the forward kernel then runs once a step, not
+        twice."""
+        if not self.remat:
+            return block
+        from ..ops.pallas.flash_attention import SAVEABLE
+        return nn.remat(block, policy=jax.checkpoint_policies
+                        .save_only_these_names(*SAVEABLE))
+
+    def predict_further(self, hidden, embedding, head, targets, positions,
+                        mask):
+        """The multi-token-prediction blocks over the main stack's last
+        hidden state ``hidden`` (B, T, d), before its final norm, and the
+        targets (B, T): block k (0-based) reads at position i the embedding
+        of the id i + k + 1 (``targets[:, i + k]``) and the state block
+        k - 1 left there (the main stack's for k = 0), and predicts the id
+        i + k + 2 (``targets[:, i + k + 1]``) through the decoder's head
+        after the block's own RMS norm.
+        Each block's loss is Σ nll over the B·T positions (the positions
+        past the row's end weigh 0), so over T whatever is left out:
+        DeepSeek-V3's 1/T. Runs inside the current module (``mtp<k>``)."""
+        c = self.cfg
+        depth = c.num_nextn_predict_layers
+        if FAMILIES[c.name].objective != "next_token":
+            raise ValueError(f"multi-token prediction needs the next-token "
+                             f"objective, not {FAMILIES[c.name].objective!r}")
+        b, t = targets.shape
+        block = self._recomputed(PredictionBlock)
+        counts, windows, losses, hits = {}, [], [], []
+        h = hidden
+        with jax.named_scope("mtp"):
+            for k in range(depth):
+                ids = jnp.pad(targets[:, k:], ((0, 0), (0, k + 1)))
+                seen = jnp.arange(t) + k + 1 < t  # the id predicted exists
+                e = embedding[ids[:, :t]].astype(jnp.float32)
+                h, y, got, walked = block(c, self.dtype, self.attention_impl,
+                                          self.mesh, mask,
+                                          name=f"mtp{k}")(h, e, positions)
+                counts[f"mtp{k}"] = got
+                windows.append(walked)
+                with jax.named_scope("lm_head"):
+                    loss, correct = chunked_next_token_loss(
+                        y, head, ids[:, 1:], self.dtype,
+                        jnp.broadcast_to(seen, (b, t)).astype(jnp.float32))
+                losses.append(loss)
+                hits.append(correct)
+        return {"loss": sum(losses) / depth, "correct": sum(hits) / depth,
+                "counts": counts, "windows": windows}
 
     def objective(self):
         """The family's loss over its own batch (train/loop.py)."""
@@ -598,7 +787,10 @@ class CausalDecoder(nn.Module):
 class NextTokenObjective:
     """``{"tokens": int32 (B, T + 1)}``: inputs are all but the last id of
     a row, targets all but the first; the loss is the mean next-token
-    cross-entropy over every position of the batch, with no auxiliary term.
+    cross-entropy over every position of the batch, with no auxiliary term
+    but, where the model has prediction blocks (``num_nextn_predict_layers``
+    > 0), their loss times ``mtp_loss_weight`` (DeepSeek-V3's λ), read
+    beside it as ``mtp_loss`` and ``mtp_precision``.
     ``after_update`` is the router-bias rule (arXiv:2408.15664): after the
     optimizer's update, ``b += load_balance_coeff · sign(mean(c) − c)`` from
     the step's counts ``c`` of assignments to each published expert; the
@@ -617,10 +809,14 @@ class NextTokenObjective:
         tokens = batch["tokens"]
         out = apply_fn({"params": variables["params"]}, tokens[:, :-1],
                        train=True, targets=tokens[:, 1:])
+        loss = out["loss"]
         metrics = {"precision": out["correct"],
                    **_held_load(self.cfg, out)}
-        return (out["loss"], metrics, variables["batch_stats"], [],
-                out["counts"])
+        if "mtp_loss" in out:
+            loss = loss + self.cfg.mtp_loss_weight * out["mtp_loss"]
+            metrics.update(mtp_loss=out["mtp_loss"],
+                           mtp_precision=out["mtp_correct"])
+        return (loss, metrics, variables["batch_stats"], [], out["counts"])
 
     def after_update(self, params, counts):
         params = dict(params)

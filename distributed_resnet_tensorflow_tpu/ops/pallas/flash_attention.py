@@ -102,17 +102,33 @@ _LANES = 128
 # grows with them (2,100 tokens at head size 128 run as 3,072). No caller on
 # the chip sends one today (the encoder's 197 fit one tile): sweep such a T
 # before trusting its row.
+# Head size 256 (latent attention's 192 + 64, 20 heads of their own keys
+# and values, causal; docs/flash_tune_v5e_mla256.json) is the winner over
+# {256,512,1024}² at T = 8,192: (1024, 512) by 1.1% over (512, 1024) and
+# 2.6% over (512, 512) in the three kernels' summed time. The dK/dV kernel
+# cannot hold (1024, 1024) in its 16 MB of scoped VMEM (RESOURCE_EXHAUSTED).
 _BLOCK_TABLES = {
     (64, False): ((4096, (512, 512)), (8192, (1024, 1024))),
     (128, False): ((2048, (512, 512)), (8192, (1024, 1024))),
     (128, True): ((8192, (512, 512)),),
     (128, "block_diffusion"): ((4096, (512, 512)), (16384, (1024, 1024))),
+    (256, False): ((8192, (1024, 512)),),
 }
 
 
+#: (largest head size, the table's head size) in order: the row a call's
+#: head size reads
+_HEAD_ROWS = ((96, 64), (128, 128), (256, 256))
+
+
 def _pick_blocks(t: int, d: int, mask: Mask = FULL) -> tuple:
-    """The table's (block_q, block_k) for the call."""
-    head = 64 if d <= 96 else 128
+    """The table's (block_q, block_k) for the call: the row of the head
+    size ``_HEAD_ROWS`` gives and of the mask's kind, else of that head
+    size's plain causal kind. A head size past the last row raises."""
+    head = next((row for upper, row in _HEAD_ROWS if d <= upper), None)
+    if head is None:
+        raise ValueError(f"no row of the flash kernels' block tables serves "
+                         f"head size {d} (at most {_HEAD_ROWS[-1][0]})")
     kind = mask.kind if mask.copies == 2 else mask.window is not None
     table = _BLOCK_TABLES.get((head, kind), _BLOCK_TABLES[head, False])
     for upper, blocks in table:

@@ -289,16 +289,22 @@ SCOPE_CATALOG = {
         "masked ids replaced, the noisy and the clean copy joined, "
         "position ids and 1/t weights", "step_parts.py"),
     "attention": Scope(
-        "scope", "forward", "models/transformer.DecoderBlock",
-        "input norm, projections, head norms, rotary, the kernels, gate, "
-        "output projection, post-norm and the residual add",
+        "scope", "forward", "models/transformer._decoder_layer, MixerBlock",
+        "input norm, projections, head norms or latents, rotary, the "
+        "kernels, gate, output projection, post-norm and the residual add",
         "attention_ms, attention_rest_ms"),
+    "latent": Scope(
+        "scope", "attention", "models/transformer.LatentAttention",
+        "both latents' projections and RMS norms, the query and key/value "
+        "up-projections, the rotary key split off and broadcast to the "
+        "heads, the rotary", "mla_latent_ms"),
     "rotary": Scope(
         "scope", "attention", "models/transformer.GroupedAttention",
         "rotate-half and sin/cos over queries and keys",
         "step_parts.py (inside attention_rest_ms)"),
     "core": Scope(
-        "scope", "attention", "models/transformer.GroupedAttention",
+        "scope", "attention",
+        "models/transformer.GroupedAttention, LatentAttention",
         "the flash kernels or their dense twin with their casts, reshapes "
         "and block tables", "attention_rest_ms (attention without it)"),
     "moe": Scope(
@@ -350,8 +356,15 @@ SCOPE_CATALOG = {
         "the kernels' gradient added into the walk's float32 "
         "accumulators, once a window", "moe_carry_ms"),
     "lm_head": Scope(
-        "scope", "forward", "models/transformer.CausalDecoder",
-        "the chunked head and loss", "lm_head_ms"),
+        "scope", "forward", "models/transformer.CausalDecoder, "
+        "predict_further (under mtp)",
+        "the chunked head and loss, the main stack's and each prediction "
+        "block's", "lm_head_ms"),
+    "mtp": Scope(
+        "scope", "forward", "models/transformer.CausalDecoder.predict_further",
+        "the multi-token-prediction blocks: the next ids' embedding, both "
+        "norms, W_eh, the block (its attention and moe scopes under it) "
+        "and its head's loss chunks", "mtp_ms"),
     # the Mamba-2 mixer (models/mamba.py) of the one-mixer blocks
     "mamba": Scope(
         "scope", "forward", "models/transformer.MixerBlock",

@@ -192,7 +192,9 @@ class LoggingHook(_CadenceHook):
             "loss", "cross_entropy", "precision", "learning_rate",
             # a routing model's load (models/transformer.NextTokenObjective)
             "moe_assignments_held", "moe_load_max_over_mean",
-            "moe_windows") if k in metrics}
+            "moe_windows",
+            # its prediction blocks' loss and share (NextTokenObjective)
+            "mtp_loss", "mtp_precision") if k in metrics}
 
     def _emit(self, step, metrics, values):
         # stamped with the values on the host: the device has finished

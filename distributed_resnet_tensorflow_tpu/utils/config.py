@@ -22,7 +22,7 @@ class ModelConfig:
     the size/width axes the reference hard-coded (resnet_model.py:71-74 pins
     resnet_size=50 for both datasets)."""
 
-    name: str = "resnet"              # resnet | logistic | vit | afmoe | sdar_moe | nemotron_h
+    name: str = "resnet"              # resnet | logistic | vit | afmoe | sdar_moe | nemotron_h | glm4_moe_lite
     resnet_size: int = 50             # cifar: 6n+2 ∈ {20,32,44,50,56,110,...}; imagenet: 18/34/50/101/152/200
     width_multiplier: int = 1         # Wide-ResNet (e.g. 28-10 → resnet_size=28, width=10)
     num_classes: int = 10
@@ -136,6 +136,23 @@ class ModelConfig:
     time_step_min: float = 1e-3
     time_step_max: float = 0.1
     time_step_floor: float = 1e-4
+    # latent attention (a family whose FAMILIES row says attention="latent"):
+    # queries through a q_lora_rank latent, keys and values through ONE
+    # kv_lora_rank latent beside ONE qk_rope_head_dim rotary key that every
+    # head shares; a head's query and key are qk_nope_head_dim +
+    # qk_rope_head_dim wide (rotary on the last qk_rope_head_dim), its value
+    # v_head_dim; 0 (no width) is refused by LatentAttention, so a latent
+    # preset states every one
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # multi-token prediction (DeepSeek-V3, arXiv:2412.19437 section 2.2):
+    # blocks after the last layer, the k-th predicting the id k + 1 further
+    # on; their mean loss enters the objective times mtp_loss_weight
+    num_nextn_predict_layers: int = 0
+    mtp_loss_weight: float = 0.3
 
 
 @dataclass
@@ -1084,6 +1101,38 @@ def _nemotron3_nano_share16() -> ExperimentConfig:
     return cfg
 
 
+def _glm47_flash_share8() -> ExperimentConfig:
+    """GLM-4.7-Flash (zai-org, model_type glm4_moe_lite) at its published
+    widths: ONE chip's share of a deployment in which eight chips share
+    each layer — experts 0-7 of 64, 19,360 of 154,880 vocabulary rows,
+    latent attention, router, shared expert, the dense layer and the
+    multi-token-prediction block whole — and six of its 47 layers plus the
+    prediction block: the leading dense layer and four expert layers (the
+    layers after the dense one are all alike; the rest would lie on
+    further chips as pipeline stages). 706,518,848 parameters, 11.30 GB of
+    state at 16 bytes each; sequences of 8,192 tokens under per-block
+    recomputation and a chunked loss, the chunked head called twice."""
+    cfg = ExperimentConfig()
+    cfg.model = ModelConfig(
+        name="glm4_moe_lite", compute_dtype="bfloat16", attention_impl="auto",
+        hidden_size=2048, num_attention_heads=20, num_key_value_heads=20,
+        layer_types=("full_attention",) * 5, rope_theta=1000000.0,
+        rms_norm_eps=1e-5, num_dense_layers=1, intermediate_size=10240,
+        moe_intermediate_size=1536, num_experts=64, experts_held=(0, 8),
+        num_experts_per_tok=4, num_shared_experts=1, route_scale=1.8,
+        load_balance_coeff=0.001, mup_enabled=False, vocab_held=19360,
+        q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=192,
+        qk_rope_head_dim=64, v_head_dim=256, num_nextn_predict_layers=1,
+        mtp_loss_weight=0.3)
+    cfg.data = DataConfig(dataset="tokens", seq_len=8192)
+    cfg.optimizer = OptimizerConfig(
+        name="adamw", learning_rate=3e-4, weight_decay=0.1,
+        schedule="cosine", warmup_steps=2000, total_steps=100000)
+    cfg.train = TrainConfig(batch_size=2, train_steps=100000,
+                            steps_per_loop=1, remat=True)
+    return cfg
+
+
 def _cifar10_smoke() -> ExperimentConfig:
     """Local smoke test analog of reference scripts/submit_mac_dist.sh
     (1ps+2wk, bs=10, 100 steps on CPU — SURVEY.md §4.1)."""
@@ -1110,6 +1159,7 @@ PRESETS = {
     "trinity_mini_share8": _trinity_mini_share8,
     "sdar_30b_a3b_share8": _sdar_30b_a3b_share8,
     "nemotron3_nano_share16": _nemotron3_nano_share16,
+    "glm47_flash_share8": _glm47_flash_share8,
     "smoke": _cifar10_smoke,
 }
 

@@ -184,6 +184,9 @@ TUNED = ("docs/flash_tune_v5e_gqa_window.json",
 # the block-diffusion mask's calls (PR 35): T positions are two copies of
 # T/2 ids in diffusion blocks of 4
 TUNED_BLOCK_DIFFUSION = ("docs/flash_tune_v5e_blockdiff.json",)
+# the latent-attention cell's call (PR 41): 8,192 tokens, 20 heads of head
+# size 256, each with its own keys and values, causal
+TUNED_LATENT = ("docs/flash_tune_v5e_mla256.json",)
 
 
 def tuned_results(files=TUNED):
@@ -208,8 +211,8 @@ def mask_of(r):
 
 
 @pytest.mark.parametrize(
-    "result", tuned_results() + tuned_results(TUNED_BLOCK_DIFFUSION),
-    ids=call_of)
+    "result", tuned_results() + tuned_results(TUNED_BLOCK_DIFFUSION)
+    + tuned_results(TUNED_LATENT), ids=call_of)
 def test_every_tuned_call_takes_its_winner(result):
     """``_BLOCK_TABLES`` gives every call the tuner's committed files hold
     (the cell's two among them: 8,192 tokens, head size 128, 32 query heads
@@ -234,6 +237,17 @@ def test_the_cells_two_calls_are_tuned():
         calls = {(r["t"], r["d"], r["heads"], r["kv_heads"], r["window"])
                  for r in tuned_results([name])}
         assert {(8192, 128, 32, 4, 2048), (8192, 128, 32, 4, None)} <= calls
+
+
+def test_the_latent_cells_call_is_tuned_over_every_pair():
+    """The head-256 row rests on a sweep of all of {256,512,1024}² at the
+    cell's call, the pair VMEM cannot hold recorded as such."""
+    (result,) = tuned_results(TUNED_LATENT)
+    assert (result["t"], result["d"], result["heads"], result["kv_heads"],
+            result["window"]) == (8192, 256, 20, 20, None)
+    pairs = {f"{q}x{k}" for q in (256, 512, 1024) for k in (256, 512, 1024)}
+    assert set(result["points"]) == pairs
+    assert "RESOURCE_EXHAUSTED" in result["points"]["1024x1024"]["error"]
 
 
 def test_the_block_diffusion_cells_call_is_tuned_in_both_orders():
@@ -370,13 +384,15 @@ def compiled_text(fn, *args) -> str:
     (8192, 32, 4, 128, True, 2048), (8192, 32, 4, 128, True, None),
     (196, 2, 2, 64, False, None),
     (8192, 32, 4, 128, block_diffusion_mask(4096, 4), None),
-    (2000, 8, 2, 128, block_diffusion_mask(1000, 8), None)])
+    (2000, 8, 2, 128, block_diffusion_mask(1000, 8), None),
+    (8192, 20, 20, 256, True, None)])
 def test_the_kernels_compile_for_a_v5e(one_chip, t, heads, kv, d, causal,
                                        window):
     """Mosaic takes the three kernels at the cells' shapes (the token
     cell's two calls, the block-diffusion cell's and a padded one of its
-    kind) and at the encoder's unaligned one (interpret mode checks no
-    layout)."""
+    kind, the latent-attention cell's at head size 256, whose row has to
+    fit the dK/dV kernel's scoped VMEM) and at the encoder's unaligned one
+    (interpret mode checks no layout)."""
     q = jax.ShapeDtypeStruct((2, t, heads, d), jnp.bfloat16, sharding=one_chip)
     k = jax.ShapeDtypeStruct((2, t, kv, d), jnp.bfloat16, sharding=one_chip)
     grad = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
