@@ -14,9 +14,13 @@ u (n, T, d) -> (n, T, d):
 * ``out = y W_out``.
 
 The projections run in ``dtype`` with float32 results; Δ, A, D, the
-convolution's sums, the gate and the norm are float32. Scopes: ``conv``
-(the convolution and its SiLU), ``scan`` (the scan with its reshapes and the
-D term), ``gated_norm``; the block wraps the whole in ``mamba``.
+convolution's sums, the gate and the norm are float32. The convolution and
+its SiLU (``conv_silu``) carry a gradient written by hand: one pass over x
+and the cotangent for the SiLU's cotangent, dw and db, one more for dx,
+where autodiff writes a shifted copy of the cotangent a tap and reads them
+all back. Scopes: ``conv`` (the convolution, its SiLU and their gradient),
+``scan`` (the scan with its reshapes and the D term), ``gated_norm``; the
+block wraps the whole in ``mamba``.
 """
 from __future__ import annotations
 
@@ -40,6 +44,41 @@ def causal_conv(x, weight, bias):
     for i in range(k):
         out = out + padded[:, i:i + t] * weight[i]
     return out
+
+
+@jax.custom_vjp
+def conv_silu(x, weight, bias):
+    """SiLU(``causal_conv(x, weight, bias)``), with a gradient written by
+    hand: autodiff transposes the K shifted slices into K shifted copies of
+    the cotangent, written out and read back to be summed into dx."""
+    return nn.silu(causal_conv(x, weight, bias))
+
+
+def _conv_silu_fwd(x, weight, bias):
+    return conv_silu(x, weight, bias), (x, weight, bias)
+
+
+def _conv_silu_bwd(res, g):
+    """One pass over x and g for the SiLU's cotangent g' (the sum again,
+    not kept), dw and db; one over g' for dx, the forward's shifted sum
+    over g' padded at the end, with the taps reversed."""
+    x, weight, bias = res
+    k, t = weight.shape[0], x.shape[1]
+    pre = causal_conv(x, weight, bias)
+    sig = jax.nn.sigmoid(pre)
+    gp = g.astype(jnp.float32) * sig * (1 + pre * (1 - sig))
+    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
+    dw = jnp.stack([jnp.sum(padded[:, i:i + t] * gp, axis=(0, 1))
+                    for i in range(k)])
+    db = jnp.sum(gp, axis=(0, 1))
+    ends = jnp.pad(gp, ((0, 0), (0, k - 1), (0, 0)))
+    dx = ends[:, :t] * weight[k - 1]
+    for i in range(1, k):
+        dx = dx + ends[:, i:i + t] * weight[k - 1 - i]
+    return (dx.astype(x.dtype), dw.astype(weight.dtype), db.astype(bias.dtype))
+
+
+conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
 
 
 def dt_init(key, shape, floor: float, low: float, high: float):
@@ -77,7 +116,7 @@ class Mamba2(nn.Module):
             in_axis=0, out_axis=1), (self.conv_kernel, xbc))
         conv_b = self.param("conv_bias", nn.initializers.zeros, (xbc,))
         with jax.named_scope("conv"):
-            xs = nn.silu(causal_conv(xs, conv_w, conv_b))
+            xs = conv_silu(xs, conv_w, conv_b)
         dt_bias = self.param("dt_bias", lambda k, sh: dt_init(k, sh, *self.time_step), (h,))
         a_log = self.param("A_log", lambda k, sh: jnp.log(jax.random.uniform(
             k, sh, jnp.float32, 1.0, 16.0)), (h,))

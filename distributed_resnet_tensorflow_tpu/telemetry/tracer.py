@@ -372,7 +372,8 @@ SCOPE_CATALOG = {
         "the output projection and the residual add", "mamba_ms"),
     "conv": Scope(
         "scope", "mamba", "models/mamba.Mamba2",
-        "the causal depthwise convolution over x, B and C and its SiLU",
+        "the causal depthwise convolution over x, B and C, its SiLU and "
+        "their hand-written gradient",
         "step_parts.py (inside mamba_ms)"),
     "scan": Scope(
         "scope", "mamba", "models/mamba.Mamba2",

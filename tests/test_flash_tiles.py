@@ -4,8 +4,9 @@ their twin at shapes that hold dead tiles, masked ones and ones whose every
 pair counts in one call (interpret mode on the CPU), every row of the block
 table against the tuner's committed results, the tuner itself rehearsed in
 interpret mode, and the real calls compiled for a described v5e, with the
-blocks the compiler gives the expert layer's grouped products there (the
-described chip's tests stay in this one file)."""
+blocks the compiler gives the expert layer's grouped products there and the
+memory traffic of the Mamba-2 convolution's gradient (the described chip's
+tests stay in this one file)."""
 import importlib.util
 import json
 import os
@@ -471,3 +472,57 @@ def test_the_grouped_products_take_wide_blocks_on_a_v5e(
     assert len(blocks) == calls
     widths = {v for call in blocks for bounds in call for v in bounds if v != 1}
     assert widths == {least}, blocks
+
+
+def entry_traffic(text, shape):
+    """Arrays of ``shape`` (an HLO type, ``f32[2,8192,6144]``) that each
+    operation of a compiled program's entry writes, and how many of them
+    all of its operations read and write together; parameters, tuples and
+    their elements and bitcasts move nothing."""
+    import re
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[entry.index("\n", 1) + 1:entry.index("\n}")]
+    types, written, moved = {}, {}, 0
+    for line in entry.splitlines():
+        name, rest = re.match(r"\s*(?:ROOT )?(%\S+) = (.*)", line).groups()
+        depth, end = 0, 0
+        for end, ch in enumerate(rest):
+            depth += ch in "([{"
+            depth -= ch in ")]}"
+            if ch == " " and depth == 0:
+                break
+        kind, rest = rest[:end], rest[end + 1:]
+        op = rest[:rest.index("(")]
+        types[name] = kind
+        if op in ("parameter", "get-tuple-element", "bitcast", "tuple"):
+            continue
+        args = rest[len(op):].split("), ")[0]
+        written[name] = kind.count(shape + "{")
+        moved += written[name] + sum(types.get(a, "").startswith(shape + "{")
+                                     for a in re.findall(r"%[\w.\-]+", args))
+    return written, moved
+
+
+@pytest.mark.parametrize("gradient", ["hand-written", "autodiff"])
+def test_the_convolutions_gradient_makes_two_passes_on_a_v5e(one_chip, gradient):
+    """The Mamba-2 convolution and its SiLU, differentiated at the
+    Nemotron cell's shape (x (2, 8192, 6144) float32, 403 MB): by
+    ``conv_silu``'s hand-written gradient no operation writes more than one
+    such array and the entry moves five of them (x and g read, g' written
+    and read, dx written: 2.01 GB, under 2.1); autodiff of the same sum
+    writes the four taps' shifted cotangents out and reads them back, 13
+    arrays (5.23 GB), and fails both."""
+    from distributed_resnet_tensorflow_tpu.models.mamba import causal_conv, conv_silu
+    fn = conv_silu if gradient == "hand-written" else (
+        lambda x, w, b: jax.nn.silu(causal_conv(x, w, b)))
+
+    def shaped(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    big = (2, 8192, 6144)
+    text = compiled_text(lambda x, w, b, g: jax.vjp(fn, x, w, b)[1](g),
+                         shaped(big), shaped((4, big[2])), shaped((big[2],)), shaped(big))
+    written, moved = entry_traffic(text, "f32[2,8192,6144]")
+    gb = moved * 4 * np.prod(big) / 1e9
+    holds = max(written.values()) <= 1 and gb <= 2.1
+    assert holds == (gradient == "hand-written"), (written, moved, gb)
+    assert moved == (5 if gradient == "hand-written" else 13), (written, moved)
